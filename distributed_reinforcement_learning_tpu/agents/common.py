@@ -155,9 +155,9 @@ def scan_learn(learn_fn):
     `lax.scan` runs K optimizer steps back-to-back in ONE compiled
     dispatch — the math is identical to K sequential `learn` calls (the
     step counter, LR schedule, and optimizer moments all advance inside
-    the scan), but the host never intervenes between steps. Through a
-    remote or tunneled device, the per-step dispatch gap costs more than
-    the step itself; this strips it. The trade is freshness: weights
+    the scan), but the host never intervenes between steps: this strips
+    the per-step dispatch gap (its size is not measured on the attached
+    chip). The trade is freshness: weights
     publish at K-step granularity (IMPALA's V-trace corrects exactly
     this off-policy staleness).
     """

@@ -141,6 +141,12 @@ def native_available() -> bool:
         return False
 
 
+def build_error() -> str | None:
+    """The compiler/loader output that made `native_available()` False
+    (callers then fall back to the Python plane), else None."""
+    return None if native_available() else _build_error
+
+
 def _as_u8p(buf) -> Any:
     if isinstance(buf, memoryview):
         buf = np.frombuffer(buf, np.uint8)  # zero-copy

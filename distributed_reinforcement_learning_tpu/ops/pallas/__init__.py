@@ -55,6 +55,38 @@ def pick_block(
     return b if b < block or b % block != 0 else block
 
 
+def batch_partitioned(call, in_dims: tuple[int, ...], out_dims: tuple[int, ...]):
+    """`call` (arrays -> tuple of arrays, ending in a `pallas_call`),
+    made to run per device when it is traced for a multi-device mesh.
+
+    A Mosaic kernel inside a program jitted over a mesh does not lower
+    ("Mosaic kernels cannot be automatically partitioned. Please wrap
+    the call in a shard_map") — which is every kernel in a
+    `ShardedLearner` step on a multi-chip host. Each kernel here is
+    independent along one batch dim per operand (`in_dims`) and result
+    (`out_dims`), so under a context mesh (`ShardedLearner` traces its
+    step inside `jax.sharding.use_abstract_mesh`) the call is wrapped in
+    a `shard_map` over every mesh axis: batch dims split over the
+    `data` axis, everything else replicated, each device running the
+    kernel on its own rows. With no context mesh (one device), or
+    already inside a `shard_map` (Anakin mesh, ring attention), `call`
+    is returned unchanged.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return call
+    # Imported here: `parallel` imports `ops` at module level.
+    from distributed_reinforcement_learning_tpu.parallel.mesh import DATA_AXIS, P
+
+    batch_axis = DATA_AXIS if DATA_AXIS in mesh.axis_names else None
+
+    def specs(dims):
+        return tuple(P(*([None] * d), batch_axis) for d in dims)
+
+    return jax.shard_map(call, in_specs=specs(in_dims),
+                         out_specs=specs(out_dims), check_vma=False)
+
+
 def resolve_backend(backend: str = "auto", opt_in_env: str | None = None) -> str:
     """-> 'pallas' | 'pallas_interpret' | 'reference'.
 

@@ -37,6 +37,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distributed_reinforcement_learning_tpu.ops.attention import _MASK_VALUE as _NEG
+from distributed_reinforcement_learning_tpu.ops.pallas import batch_partitioned
 
 _BLOCK_Q = 128
 _BLOCK_KV = 128
@@ -288,20 +289,33 @@ def _bwd_call(q, k, v, qs, ks, do, lse, delta, bq, bkv, interpret):
 
 @functools.cache
 def _make_flash(bq: int, bkv: int, interpret: bool):
+    # Every kernel is independent along the batch*heads dim: under a
+    # mesh each device runs it on its own rows (batch_partitioned reads
+    # the context mesh at TRACE time, hence inside these functions).
+    def fwd_call(q, k, v, qs, ks):
+        return batch_partitioned(
+            lambda *a: tuple(_fwd_call(*a, bq, bkv, interpret)),
+            (0,) * 5, (0, 0))(q, k, v, qs, ks)
+
+    def bwd_call(q, k, v, qs, ks, do, lse, delta):
+        return batch_partitioned(
+            lambda *a: _bwd_call(*a, bq, bkv, interpret),
+            (0,) * 8, (0, 0, 0))(q, k, v, qs, ks, do, lse, delta)
+
     @jax.custom_vjp
     def f(q, k, v, qs, ks):
-        out, _ = _fwd_call(q, k, v, qs, ks, bq, bkv, interpret)
+        out, _ = fwd_call(q, k, v, qs, ks)
         return out
 
     def f_fwd(q, k, v, qs, ks):
-        out, lse = _fwd_call(q, k, v, qs, ks, bq, bkv, interpret)
+        out, lse = fwd_call(q, k, v, qs, ks)
         return out, (q, k, v, qs, ks, out, lse)
 
     def f_bwd(res, do):
         q, k, v, qs, ks, out, lse = res
         delta = jnp.sum(
             do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True)
-        dq, dk, dv = _bwd_call(q, k, v, qs, ks, do, lse, delta, bq, bkv, interpret)
+        dq, dk, dv = bwd_call(q, k, v, qs, ks, do, lse, delta)
         return dq, dk, dv, None, None
 
     f.defvjp(f_fwd, f_bwd)

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from distributed_reinforcement_learning_tpu.ops.pallas import pick_block
+from distributed_reinforcement_learning_tpu.ops.pallas import batch_partitioned, pick_block
 
 # Batch tile: multiple of the fp32 lane width; the whole [T, BLOCK_B]
 # working set (6 arrays x T<=64 x 256 x 4B ~ 400 KB) sits far under VMEM.
@@ -77,25 +77,30 @@ def vtrace_pallas(
 ) -> tuple[jax.Array, jax.Array]:
     """-> (vs [T, B], clipped_rhos [T, B]), both to be stop-gradiented by
     the caller (`ops.vtrace.from_importance_weights` does)."""
-    T, B = log_rhos.shape
-    block_b = pick_block(B, _BLOCK_B)
-    grid = (B // block_b,)
-    seq_spec = pl.BlockSpec((T, block_b), lambda i: (0, i), memory_space=pltpu.VMEM)
-    boot_spec = pl.BlockSpec((1, block_b), lambda i: (0, i), memory_space=pltpu.VMEM)
     kernel = functools.partial(
         _vtrace_kernel, clip_rho=clip_rho_threshold, clip_c=clip_c_threshold
     )
-    vs, rhos = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[seq_spec, seq_spec, seq_spec, seq_spec, boot_spec],
-        out_specs=[seq_spec, seq_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((T, B), jnp.float32),
-            jax.ShapeDtypeStruct((T, B), jnp.float32),
-        ],
-        interpret=interpret,
-    )(
+
+    def call(log_rhos, discounts, rewards, values, bootstrap):
+        # Shapes are read HERE: under a mesh this runs per device, on
+        # that device's [T, B / n] columns.
+        T, B = log_rhos.shape
+        block_b = pick_block(B, _BLOCK_B)
+        seq_spec = pl.BlockSpec((T, block_b), lambda i: (0, i), memory_space=pltpu.VMEM)
+        boot_spec = pl.BlockSpec((1, block_b), lambda i: (0, i), memory_space=pltpu.VMEM)
+        return tuple(pl.pallas_call(
+            kernel,
+            grid=(B // block_b,),
+            in_specs=[seq_spec, seq_spec, seq_spec, seq_spec, boot_spec],
+            out_specs=[seq_spec, seq_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct((T, B), jnp.float32),
+                jax.ShapeDtypeStruct((T, B), jnp.float32),
+            ],
+            interpret=interpret,
+        )(log_rhos, discounts, rewards, values, bootstrap))
+
+    vs, rhos = batch_partitioned(call, (1, 1, 1, 1, 1), (1, 1))(
         log_rhos.astype(jnp.float32),
         discounts.astype(jnp.float32),
         rewards.astype(jnp.float32),

@@ -19,6 +19,7 @@ per-model annotations.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -116,7 +117,7 @@ class ShardedLearner:
         in_shardings = (self.state_sharding,) + (self._data_sh,) * num_data_args
         out_shardings = (self.state_sharding,) + (self._repl,) * num_aux_outputs
         self.learn = jax.jit(
-            agent._learn,
+            self._on_mesh(agent._learn),
             in_shardings=in_shardings,
             out_shardings=out_shardings,
             donate_argnums=(0,),
@@ -133,7 +134,7 @@ class ShardedLearner:
                 and hasattr(agent, "_apply_grads")):
             params_sh = self.state_sharding.params
             self.grads = jax.jit(
-                agent._grads,
+                self._on_mesh(agent._grads),
                 in_shardings=(self.state_sharding,) + (self._data_sh,) * 2,
                 out_shardings=(params_sh, self._repl, self._repl),
             )
@@ -152,11 +153,27 @@ class ShardedLearner:
 
             self.stacked_data_sharding = NamedSharding(mesh, P(None, mesh_lib.DATA_AXIS))
             self.learn_many = jax.jit(
-                scan_learn(agent._learn),
+                self._on_mesh(scan_learn(agent._learn)),
                 in_shardings=(self.state_sharding, self.stacked_data_sharding),
                 out_shardings=(self.state_sharding, self._repl),
                 donate_argnums=(0,),
             )
+
+    def _on_mesh(self, fn):
+        """`fn`, traced with this learner's mesh as JAX's context mesh.
+
+        GSPMD partitions everything in the step by itself except a
+        Pallas kernel, which has to be told the mesh to wrap itself in
+        a `shard_map` (`ops/pallas.batch_partitioned` asks
+        `jax.sharding.get_abstract_mesh()`); without this the step does
+        not lower on a multi-chip TPU host."""
+
+        @functools.wraps(fn)
+        def traced(*args):
+            with jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh):
+                return fn(*args)
+
+        return traced
 
     def init_state(self, rng: jax.Array):
         """Initialize the TrainState directly into its mesh sharding."""

@@ -252,9 +252,9 @@ class ImpalaLearner(PublishCadenceMixin):
         self.weights = weights
         self.batch_size = batch_size
         # K>1: dequeue K batches and run them as ONE lax.scan dispatch
-        # (learn_many). Strips the per-step dispatch gap — the dominant
-        # cost on remote/tunneled devices — at the price of weights
-        # publishing at K-step granularity. Works single-jit and over a
+        # (learn_many). Strips the per-step dispatch gap (not measured
+        # on the attached chip) at the price of weights publishing at
+        # K-step granularity. Works single-jit and over a
         # mesh (ShardedLearner.learn_many scans the pjit-sharded step).
         self.updates_per_call = max(1, int(updates_per_call))
         self.logger = logger or MetricsLogger(None)

@@ -36,6 +36,7 @@ from distributed_reinforcement_learning_tpu.runtime import (
 )
 from distributed_reinforcement_learning_tpu.runtime.weights import WeightStore
 from distributed_reinforcement_learning_tpu.utils.config import RuntimeConfig, load_config
+from distributed_reinforcement_learning_tpu.utils.device import open_devices
 from distributed_reinforcement_learning_tpu.utils.logger import MetricsLogger
 
 
@@ -299,6 +300,7 @@ def train_anakin(config_path: str, section: str, num_updates: int,
     actor restart in the distributed topology)."""
     import numpy as np
 
+    open_devices("anakin")
     agent_cfg, rt = load_config(config_path, section)
     if _algo_of(agent_cfg) != "impala":
         raise ValueError("anakin mode currently runs the IMPALA family")
@@ -313,6 +315,7 @@ def train_anakin(config_path: str, section: str, num_updates: int,
     state = state._replace(train=train)
     chunk = max(1, min(chunk, num_updates))
     returns = []
+    last_loss = None
     maybe_configure("anakin", 0, run_dir)  # env-gated run-wide telemetry
     frames_per_update = anakin.num_envs * agent_cfg.trajectory
     while int(state.train.step) < num_updates:
@@ -330,12 +333,14 @@ def train_anakin(config_path: str, section: str, num_updates: int,
             _OBS.gauge("anakin/steps_per_s", u / dt)
             _OBS.gauge("anakin/frames_per_s", u * frames_per_update / dt)
         returns.append(mean_ret)
+        last_loss = float(m["total_loss"][-1])
         print(f"[anakin] step {int(state.train.step)}: mean_return {mean_ret:.1f} "
-              f"({eps:.0f} episodes, loss {float(m['total_loss'][-1]):.2f})")
+              f"({eps:.0f} episodes, loss {last_loss:.2f})")
         if ckpt is not None:
             ckpt.save(int(state.train.step), state.train, {})
     return {
         "frames": int(state.train.step) * anakin.num_envs * agent_cfg.trajectory,
+        "last_loss": last_loss,
         "chunk_mean_returns": [round(r, 2) for r in returns],
         "mean_return_last_chunk": round(returns[-1], 2) if returns else None,
     }
@@ -399,6 +404,7 @@ def train_anakin_apex(config_path: str, section: str, num_updates: int,
     each pixel transition stores TWO 84x84x4 uint8 stacks (s and s',
     ~56 KB), so the default ring costs ~1.8 GB of device memory; the
     host topology's 100k default would triple that."""
+    open_devices("anakin-apex")
     agent_cfg, rt = load_config(config_path, section)
     if _algo_of(agent_cfg) != "apex":
         raise ValueError("anakin-apex mode runs the Ape-X family")
@@ -439,6 +445,7 @@ def train_anakin_r2d2(config_path: str, section: str, num_updates: int,
     defaults to min(replay_capacity, 4096) sequences — the ring lives in
     device memory, so the host topology's 100k default would swamp HBM
     for pixel observations."""
+    open_devices("anakin-r2d2")
     agent_cfg, rt = load_config(config_path, section)
     if _algo_of(agent_cfg) != "r2d2":
         raise ValueError("anakin-r2d2 mode runs the R2D2 family")
@@ -476,6 +483,7 @@ def train_local(config_path: str, section: str, num_updates: int,
     every `checkpoint_interval` updates by running the sync loop in
     chunks (the loops target absolute `learner.train_steps`, so chunked
     calls compose; actor episode returns persist across chunks)."""
+    open_devices("local")
     agent_cfg, rt = load_config(config_path, section)
     learner, actors, run_fn = build_local(agent_cfg, rt, run_dir=run_dir, seed=seed)
     maybe_configure("local", 0, run_dir)  # env-gated run-wide telemetry

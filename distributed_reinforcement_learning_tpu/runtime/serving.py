@@ -309,9 +309,11 @@ def run_replica(
     )
     from distributed_reinforcement_learning_tpu.runtime.weights import WeightStore
     from distributed_reinforcement_learning_tpu.utils.config import load_config
+    from distributed_reinforcement_learning_tpu.utils.device import open_devices
 
     del num_updates  # replicas serve until the topology stops
     task = max(task, 0)
+    open_devices(f"infer {task}")
     agent_cfg, rt = load_config(config_path, section)
     port = _env_int("DRL_INFER_PORT", 0) or (rt.server_port + 1000 + task)
     host, lport = resolve_learner_addr(rt)

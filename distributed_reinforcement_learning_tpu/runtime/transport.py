@@ -1804,6 +1804,7 @@ def run_role(
 
     from distributed_reinforcement_learning_tpu.runtime import launch
     from distributed_reinforcement_learning_tpu.utils.config import load_config
+    from distributed_reinforcement_learning_tpu.utils.device import open_devices
     from distributed_reinforcement_learning_tpu.utils.logger import MetricsLogger
 
     agent_cfg, rt = load_config(config_path, section)
@@ -1845,6 +1846,7 @@ def run_role(
         from distributed_reinforcement_learning_tpu.parallel import distributed
 
         multihost = distributed.initialize()
+        open_devices("learner")
         if tier is not None and multihost:
             raise ValueError(
                 "the learner tier (DRL_LEARNER_SEATS) and the jax.distributed "
@@ -1913,6 +1915,9 @@ def run_role(
             rt = dataclasses.replace(rt, batch_size=local_batch)
         logger = MetricsLogger(run_dir)  # actors log nothing: no writer for them
         queue = _make_queue(rt.queue_size)
+        # Which plane this run is on: _make_queue falls back to the
+        # Python queue without a word when cpp/*.cc does not build.
+        print(f"[learner] data plane: {type(queue).__name__}")
         from distributed_reinforcement_learning_tpu.runtime.weights import WeightStore
 
         weights = WeightStore()
@@ -2157,6 +2162,7 @@ def run_role(
     elif mode == "actor":
         if task < 0:
             raise ValueError("actor mode needs --task k")
+        open_devices(f"actor {task}")
         # Multi-learner topology: each learner process needs its local
         # batch share fed, so launch scripts partition actors across the
         # learners (addressing contract: resolve_learner_addr).

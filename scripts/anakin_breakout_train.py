@@ -98,6 +98,11 @@ def main() -> None:
     from distributed_reinforcement_learning_tpu.envs import breakout_jax, invaders_jax, pong_jax
     from distributed_reinforcement_learning_tpu.runtime.anakin import AnakinImpala
     from distributed_reinforcement_learning_tpu.utils.checkpoint import Checkpointer
+    from distributed_reinforcement_learning_tpu.utils.device import (
+        enable_compile_cache, open_devices)
+
+    enable_compile_cache()
+    open_devices("anakin")
 
     env_mod = {"breakout": breakout_jax, "pong": pong_jax,
                "invaders": invaders_jax}[args.env]

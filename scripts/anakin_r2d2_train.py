@@ -85,6 +85,11 @@ def main() -> None:
     from distributed_reinforcement_learning_tpu.envs import breakout_jax, pong_jax
     from distributed_reinforcement_learning_tpu.runtime.anakin_r2d2 import AnakinR2D2
     from distributed_reinforcement_learning_tpu.utils.checkpoint import Checkpointer
+    from distributed_reinforcement_learning_tpu.utils.device import (
+        enable_compile_cache, open_devices)
+
+    enable_compile_cache()
+    open_devices("anakin-r2d2")
 
     env_mod = {"breakout": breakout_jax, "pong": pong_jax}[args.env]
     if args.eval_steps is None:

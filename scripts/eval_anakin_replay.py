@@ -48,6 +48,11 @@ def main() -> None:
     import jax
 
     from distributed_reinforcement_learning_tpu.runtime import launch
+    from distributed_reinforcement_learning_tpu.utils.device import (
+        enable_compile_cache, open_devices)
+
+    enable_compile_cache()
+    open_devices("eval")
 
     agent_cfg, rt = launch.load_config(args.config, args.section)
     env_mod, obs_transform = launch._jittable_env_for(agent_cfg, rt)

@@ -286,6 +286,17 @@ def main() -> None:
         # follow-on).
         p.error("--respawn needs --learners 1 (a pjit group or a "
                 "learner tier can only restart wholesale)")
+    learner_platform = (args.platform or os.environ.get("JAX_PLATFORMS", "")
+                        ).split(",")[0].strip().lower()
+    if args.learners > 1 and learner_platform not in ("", "cpu"):
+        # A chip belongs to ONE process, and this launcher does not
+        # divide a host's chips between learner processes: seat 1 would
+        # ask for the chips seat 0 holds and fail (or sit in the runtime
+        # until utils/device.open_devices gives up on it).
+        p.error(f"--learners {args.learners} starts {args.learners} JAX "
+                f"processes on this host and platform "
+                f"{learner_platform!r} gives its chips to one process: "
+                f"pass --platform cpu")
     launcher = os.path.join(REPO, ALGO_LAUNCHER[algo])
 
     class Role:

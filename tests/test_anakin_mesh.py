@@ -14,8 +14,6 @@ Three layers:
   rejected at construction.
 """
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,27 +26,6 @@ from distributed_reinforcement_learning_tpu.runtime.anakin_apex import AnakinApe
 from distributed_reinforcement_learning_tpu.runtime.anakin_r2d2 import AnakinR2D2
 
 
-# Container pin (PR 7, same discipline as PR 6's apex-ingest rtol pin):
-# this image ships jax 0.4.37, which predates the TOP-LEVEL
-# `jax.shard_map` API (and its `check_vma=` kwarg) that
-# runtime/anakin_mesh.shard_mapped_chunk and these tests target — every
-# shard_map-backed test here fails at import-of-the-attr time with
-# "AttributeError: module 'jax' has no attribute 'shard_map'"
-# (pre-existing at HEAD with all changes stashed; 0.4.37 only has the
-# experimental `jax.experimental.shard_map.shard_map` with the older
-# `check_rep=` signature, so aliasing would change tested semantics).
-# Skipping keeps the tier-1 failure fingerprint clean signal instead of
-# six known-environmental FAILs; DRL_RUN_ANAKIN_MESH=1 forces the tests
-# to run anyway (e.g. after a container jax upgrade, to verify before
-# deleting this gate). The construction-time guard test below needs no
-# shard_map and still runs everywhere.
-_NEEDS_SHARD_MAP = pytest.mark.skipif(
-    not hasattr(jax, "shard_map")
-    and os.environ.get("DRL_RUN_ANAKIN_MESH", "") != "1",
-    reason="container jax predates top-level jax.shard_map "
-           "(DRL_RUN_ANAKIN_MESH=1 forces)")
-
-
 def _apex_agent():
     return ApexAgent(ApexConfig(obs_shape=(4,), num_actions=2))
 
@@ -58,7 +35,6 @@ def _tree_allclose(a, b, **kw):
     assert all(jax.tree.leaves(ok)), ok
 
 
-@_NEEDS_SHARD_MAP
 class TestLearnAxisNameEquivalence:
     def test_apex_pmean_same_batch_matches_single_device(self):
         agent = _apex_agent()
@@ -74,16 +50,18 @@ class TestLearnAxisNameEquivalence:
             done=jnp.arange(B) % 3 == 0,
         )
         w = jnp.linspace(0.5, 1.0, B)
-        ref_state, ref_td, ref_m = agent._learn(state, batch, w)
+        # Both sides jitted: op-by-op dispatch of a whole learn step
+        # costs ~50 s here and proves nothing more.
+        ref_state, ref_td, ref_m = jax.jit(agent._learn)(state, batch, w)
 
         mesh = make_mesh(8)
-        f = jax.shard_map(
+        f = jax.jit(jax.shard_map(
             lambda s, b, ww: agent._learn(s, b, ww, axis_name=DATA_AXIS),
             mesh=mesh,
             in_specs=(P(), P(), P()),   # every device gets the SAME batch
             out_specs=(P(), P(), P()),
             check_vma=False,            # td is device-varying in general
-        )
+        ))
         sh_state, sh_td, sh_m = f(state, batch, w)
         _tree_allclose(ref_state.params, sh_state.params, atol=1e-6)
         np.testing.assert_allclose(ref_td, sh_td, atol=1e-6)
@@ -108,20 +86,19 @@ class TestLearnAxisNameEquivalence:
             initial_c=jnp.zeros((B, 16)),
         )
         w = jnp.ones((B,))
-        ref_state, ref_pri, _ = agent._learn(state, batch, w)
+        ref_state, ref_pri, _ = jax.jit(agent._learn)(state, batch, w)
         mesh = make_mesh(8)
-        f = jax.shard_map(
+        f = jax.jit(jax.shard_map(
             lambda s, b, ww: agent._learn(s, b, ww, axis_name=DATA_AXIS),
             mesh=mesh, in_specs=(P(), P(), P()), out_specs=(P(), P(), P()),
             check_vma=False,
-        )
+        ))
         sh_state, sh_pri, _ = f(state, batch, w)
         _tree_allclose(ref_state.params, sh_state.params, atol=1e-6)
         np.testing.assert_allclose(ref_pri, sh_pri, atol=1e-6)
 
 
 class TestAnakinApexMesh:
-    @_NEEDS_SHARD_MAP
     def test_counts_and_finiteness(self):
         mesh = make_mesh(8)
         an = AnakinApex(_apex_agent(), num_envs=16, batch_size=32,
@@ -140,7 +117,6 @@ class TestAnakinApexMesh:
         assert last["replay_size"] == min(9 * an.write_width, an.capacity)
         assert int(state.train.step) == 5 * 2
 
-    @_NEEDS_SHARD_MAP
     def test_params_identical_across_devices(self):
         mesh = make_mesh(8)
         an = AnakinApex(_apex_agent(), num_envs=8, batch_size=8,
@@ -167,7 +143,6 @@ class TestAnakinApexMesh:
                        steps_per_collect=4, mesh=mesh)
 
 
-@_NEEDS_SHARD_MAP
 class TestAnakinR2D2Mesh:
     def test_counts_and_finiteness(self):
         mesh = make_mesh(8)

@@ -1,16 +1,18 @@
-"""bench.py output contract under failure modes (VERDICT r4 item 1).
+"""bench.py output contract under failure modes.
 
-The driver takes bench.py's LAST stdout line as the round's official
-metric; r4 lost its number to a timeout because the old bench emitted
-only at the very end. These tests pin the two protections added in r5
-by running bench.py as a real subprocess (CPU backend, trimmed
-sections):
+The driver takes bench.py's LAST stdout line as the official metric, and
+a bench that emitted only at the very end once lost its number to a
+timeout. These tests pin the protections by running bench.py as a real
+subprocess (BENCH_PLATFORM=cpu — a smoke of the bench's mechanics,
+trimmed sections):
 
 - budget gating: with the wall-clock budget effectively exhausted,
-  sections are skipped (and recorded) but the final line still parses;
-- the wedge watchdog: with the budget set before the process even
-  started (negative), the watchdog force-emits a parseable line and
-  exits 0 — the behavior a mid-section tunnel hang relies on.
+  sections are skipped (and recorded) and the final line still parses;
+- the watchdog: with the budget set before the process even started
+  (negative), the watchdog force-emits a parseable line;
+- no failure path exits 0: a run with no measurement, the watchdog, a
+  failed section and a missing accelerator all end non-zero, and
+  nothing re-runs the bench on another backend.
 """
 
 import importlib.util
@@ -19,6 +21,8 @@ import os
 import subprocess
 import sys
 from pathlib import Path
+
+import pytest
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -33,7 +37,6 @@ def _load_bench():
 
 _TRIMMED = {
     "BENCH_PLATFORM": "cpu",
-    "BENCH_CPU_FALLBACK": "0",
     "BENCH_SWEEP": "8",
     "BENCH_ITERS": "2",
     "BENCH_SCAN": "0", "BENCH_FOLD": "0", "BENCH_RESNET": "0",
@@ -71,8 +74,12 @@ def _run_bench(budget: str, cwd, extra_env=None, timeout: float = 280.0):
 
 def test_budget_skips_sections_but_final_line_parses(tmp_path):
     proc, last = _run_bench(budget="45", cwd=tmp_path)
-    assert proc.returncode == 0
+    # Every measuring section was gated off: a run with no measurement
+    # is a failed run (exit code), whose last line still parses.
+    assert proc.returncode != 0
+    assert "no learn-step measurement" in last["extra"]["error"]
     assert last["metric"] and "value" in last and "vs_baseline" in last
+    assert last["extra"]["device"]["platform"] == "cpu"
     # est 90 s > budget 45 s: the learn sweep section is deterministically
     # gated off — and must be RECORDED, not silently dropped. The compact
     # stdout line carries only the COUNT; the section NAMES live in the
@@ -86,16 +93,71 @@ def test_budget_skips_sections_but_final_line_parses(tmp_path):
 
 def test_watchdog_force_emits_while_main_thread_is_wedged(tmp_path):
     """budget = -301 puts the watchdog's deadline (budget + 300 s grace)
-    in the past at thread start, and BENCH_TEST_WEDGE_S parks the main
-    thread the way a tunnel-wedged section does: the WATCHDOG (not the
-    normal exit path, which is still asleep) must emit the parseable
-    final line and exit 0."""
+    in the past at thread start, and BENCH_TEST_STALL_S parks the main
+    thread the way a section stuck in a device call does: the WATCHDOG
+    (not the normal exit path, which is still asleep) must emit the
+    parseable final line — and exit NON-ZERO: the run did not finish."""
     proc, last = _run_bench(budget="-301", cwd=tmp_path,
-                            extra_env={"BENCH_TEST_WEDGE_S": "60"},
+                            extra_env={"BENCH_TEST_STALL_S": "60"},
                             timeout=90.0)
-    assert proc.returncode == 0
+    assert proc.returncode != 0
     assert last["metric"] and "value" in last
     assert "watchdog" in last["extra"], last["extra"]
+
+
+class TestDeviceHandling:
+    """No probe, no fall-back, no default peak: where bench.py cannot
+    say which device a number came from, it fails."""
+
+    def test_no_accelerator_and_no_forced_platform_exits_nonzero(self, tmp_path):
+        env = {k: v for k, v in os.environ.items() if k != "BENCH_PLATFORM"}
+        proc = subprocess.run(
+            [sys.executable, str(REPO / "bench.py")], cwd=tmp_path,
+            env={**env, "JAX_PLATFORMS": "cpu"},
+            capture_output=True, text=True, timeout=120.0)
+        assert proc.returncode != 0
+        assert proc.stdout.strip() == "", proc.stdout  # no result line
+        assert "no accelerator" in proc.stderr
+
+    def test_failed_section_exits_nonzero(self, tmp_path):
+        """A section that raises is recorded AND turns the exit code:
+        zero on-device envs cannot build the anakin section."""
+        proc, last = _run_bench(
+            budget="2700", cwd=tmp_path,
+            extra_env={"BENCH_ANAKIN": "1", "BENCH_ANAKIN_ENVS": "0"})
+        assert proc.returncode != 0
+        assert last["extra"]["failed_sections"] == ["anakin"], last["extra"]
+        assert last["value"] > 0  # the learn sweep still measured
+
+    @pytest.mark.parametrize("kind", ["TPU v5 lite", "TPU v5e"])
+    def test_v5e_peaks_carry_flops_and_hbm(self, monkeypatch, kind):
+        import jax
+
+        bench = _load_bench()
+        dev = type("D", (), {"device_kind": kind})()
+        monkeypatch.setattr(jax, "devices", lambda *a: [dev])
+        peaks, source = bench._device_peaks()
+        assert peaks == {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+        assert kind.lower() in source
+        assert bench._peak_flops() == (197e12, source)
+
+    def test_unknown_device_kind_is_an_error_not_a_default(self, monkeypatch):
+        import jax
+
+        bench = _load_bench()
+        dev = type("D", (), {"device_kind": "TPU v9 imaginary"})()
+        monkeypatch.setattr(jax, "devices", lambda *a: [dev])
+        with pytest.raises(RuntimeError, match="no published peaks"):
+            bench._peak_flops()
+        # On the CPU (BENCH_PLATFORM=cpu) no device metric is reported
+        # at all, so the table is never asked.
+        assert bench._mfu_fields(1e9, 1e-3) == {}
+
+    def test_probe_fallback_and_chunk_gates_are_gone(self):
+        bench = _load_bench()
+        for name in ("_probe_backend", "_run_cpu_fallback",
+                     "check_chunk_gates"):
+            assert not hasattr(bench, name), name
 
 
 class TestTransportCompare:
@@ -863,63 +925,3 @@ class TestChaosCompare:
         drill = verdict["seat_drill"]
         assert drill["corrupt"] == 0
         assert drill["survivor_publisher"] and drill["survivor_solo"]
-
-
-class TestDeviceChunkGate:
-    """check_chunk_gates (bench.py): the ROADMAP's anakin device_chunk_s
-    regression gate, driven as a pure function over (extra, platform,
-    gates) — no accelerator needed."""
-
-    GATES = {"tpu": {
-        "anakin_breakout": {"num_envs": 256, "chunk": 20,
-                            "max_device_chunk_s": 0.52},
-        "anakin_r2d2": {"num_envs": 256, "chunk": 50,
-                        "max_device_chunk_s": 0.031},
-    }}
-
-    def test_regression_detected_and_pass_recorded(self):
-        bench = _load_bench()
-        extra = {
-            "anakin_breakout": {"num_envs": 256, "chunk": 20,
-                                "device_chunk_s": 0.61},   # over the limit
-            "anakin_r2d2": {"num_envs": 256, "chunk": 50,
-                            "device_chunk_s": 0.025},      # within it
-        }
-        report = bench.check_chunk_gates(extra, "tpu", self.GATES)
-        assert report["regressed"] == ["anakin_breakout"]
-        assert report["checked"]["anakin_breakout"]["ok"] is False
-        assert report["checked"]["anakin_r2d2"]["ok"] is True
-
-    def test_config_mismatch_is_not_compared(self):
-        bench = _load_bench()
-        extra = {"anakin_breakout": {"num_envs": 128, "chunk": 20,
-                                     "device_chunk_s": 9.9}}
-        report = bench.check_chunk_gates(extra, "tpu", self.GATES)
-        assert report["regressed"] == []
-        mismatch = report["checked"]["anakin_breakout"]["config_mismatch"]
-        assert mismatch == {"num_envs": [128, 256]}
-
-    def test_missing_platform_and_failed_section_skip(self):
-        bench = _load_bench()
-        report = bench.check_chunk_gates({}, "cpu", self.GATES)
-        assert "skipped" in report
-        # A section that errored (no device_chunk_s) is simply not gated.
-        extra = {"anakin_breakout": {"error": "OOM"}}
-        report2 = bench.check_chunk_gates(extra, "tpu", self.GATES)
-        assert report2["checked"] == {} and report2["regressed"] == []
-
-    def test_env_kill_switch(self, monkeypatch):
-        bench = _load_bench()
-        monkeypatch.setenv("BENCH_CHUNK_GATE", "0")
-        assert bench.check_chunk_gates({}, "tpu", self.GATES) is None
-
-    def test_committed_gates_file_shape(self):
-        """The committed gates file parses and pins all four anakin
-        sections at their r04 v5e shapes."""
-        gates = json.loads(
-            (REPO / "benchmarks" / "device_chunk_gates.json").read_text())
-        assert set(gates["tpu"]) == {"anakin", "anakin_breakout",
-                                     "anakin_r2d2", "anakin_apex"}
-        for section, g in gates["tpu"].items():
-            assert g["max_device_chunk_s"] > 0, section
-            assert "num_envs" in g and "chunk" in g, section

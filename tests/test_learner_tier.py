@@ -380,11 +380,9 @@ class TestAllreduceEquivalence:
         model_parallel=2) ATTACHES under allreduce, the negotiated plan
         carries a model-sharded class, and three tier-wrapped steps on
         each half-batch keep the two seats bit-identical to each other
-        and within the documented tolerance of the UNION-BATCH pjit
-        learner (rtol 1e-3 / atol 1e-6 after 3 Adam steps — the same
-        pin as the single-device tier). Both sides compile the same
-        GSPMD layout, so the pin isolates exactly what the tier adds:
-        the owner-scoped partitioned exchange."""
+        and within the documented tolerance of the UNION-BATCH
+        single-device learner (rtol 1e-3 / atol 1e-6 after 3 Adam steps
+        — the same pin as the single-device tier)."""
         import jax
 
         from distributed_reinforcement_learning_tpu.parallel import (
@@ -406,9 +404,12 @@ class TestAllreduceEquivalence:
             return sl.place_state(agent.sync_target(
                 agent.init_state(jax.random.PRNGKey(0))))
 
-        s = fresh_state()
+        # The reference is the plain SINGLE-DEVICE learner on the union
+        # batch: the mesh layout and the tier's exchange both have to
+        # reproduce it.
+        s = agent.sync_target(agent.init_state(jax.random.PRNGKey(0)))
         for _ in range(3):
-            s, _, _ = sl.learn(s, *sl.shard_batch((union, isw)))
+            s, _, _ = agent.learn(s, union, isw)
         union_params = jax.tree.map(np.asarray, s.params)
 
         class MeshSeat:

@@ -25,6 +25,19 @@ from distributed_reinforcement_learning_tpu.data.native import (  # noqa: E402
 )
 
 
+def test_makefile_builds_the_sources_native_py_builds():
+    """`make` and the first-import build must produce the same library
+    (the Makefile once lacked batch_stack.cc), and a build that works
+    reports no error."""
+    import os
+    import re
+
+    makefile = open(os.path.join(native._CPP_DIR, "Makefile")).read()
+    srcs = re.search(r"^SRCS := (.*)$", makefile, re.M).group(1).split()
+    assert tuple(srcs) == native._SOURCES
+    assert native.build_error() is None
+
+
 class TestCodec:
     def test_roundtrip_dict(self):
         tree = {

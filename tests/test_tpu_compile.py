@@ -1,0 +1,280 @@
+"""Ahead-of-time compiles for a DESCRIBED TPU v5e — no chip attached.
+
+The TPU compiler ships with libtpu and compiles for a topology that is
+described, not opened (`/opt/skills/guides/on-chip-measurement`, section
+2). That catches what interpret mode cannot — a slice not aligned to the
+tiling, a kernel over its VMEM budget, a program Mosaic refuses — at the
+shapes the main path really runs (`config.json` sections `impala`,
+`apex`, `r2d2_pixel`; the Anakin chunk `chip_smoke.py` drives), and
+costs no chip time.
+
+A compile that passes is not a chip run: nothing executes here, so
+these tests say nothing about results or speed.
+
+`resolve_backend("auto")` reads `jax.default_backend()`, which is the
+CPU in this process, so the whole-step tests answer that one question
+as the chip would (`kernels_as_on_chip`) — the program grows no option
+for it.
+
+The kernels and the float32 IMPALA step run in tier-1; the other whole
+steps (10-25 s each) are marked slow and run before a chip call.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import distributed_reinforcement_learning_tpu.ops.pallas as pallas_pkg
+from distributed_reinforcement_learning_tpu.ops import lstm as lstm_ops
+from distributed_reinforcement_learning_tpu.ops.pallas.attention import (
+    flash_attention_bhtd, flash_blocks)
+from distributed_reinforcement_learning_tpu.ops.pallas.lstm import lstm_pallas
+from distributed_reinforcement_learning_tpu.ops.pallas.vtrace import vtrace_pallas
+from distributed_reinforcement_learning_tpu.utils import synthetic
+from distributed_reinforcement_learning_tpu.utils.config import load_config
+
+CONFIG = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                      "config.json")
+
+
+@pytest.fixture(scope="module")
+def four_chips():
+    """The devices of a described four-chip v5e host."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / no such topology
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return list(topo.devices)
+
+
+@pytest.fixture(scope="module")
+def chip(four_chips):
+    """One described v5e device as a sharding (the compile target)."""
+    return SingleDeviceSharding(four_chips[0])
+
+
+@pytest.fixture
+def kernels_as_on_chip(monkeypatch):
+    """`auto` kernel selection answers as on the chip: the real
+    resolver (env gates included) with `jax.default_backend()` reading
+    "tpu" for the duration of that one call."""
+    real = pallas_pkg.resolve_backend
+
+    def resolve(backend="auto", opt_in_env=None):
+        with pytest.MonkeyPatch.context() as m:
+            m.setattr(pallas_pkg.jax, "default_backend", lambda: "tpu")
+            return real(backend, opt_in_env)
+
+    monkeypatch.setattr(pallas_pkg, "resolve_backend", resolve)
+    monkeypatch.setattr(lstm_ops, "resolve_backend", resolve)
+
+
+def _on(chip, tree):
+    """Shapes of `tree`'s leaves, placed on the described device."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
+
+
+def _kernel_calls(compiled, inside: str = "") -> int:
+    """Mosaic kernels in the compiled program (`tpu_custom_call`), of
+    those whose op name mentions `inside`. An interpret-mode kernel
+    lowers to plain HLO and counts zero."""
+    return sum("tpu_custom_call" in line and inside in line
+               for line in compiled.as_text().splitlines())
+
+
+# T-2 = 18 is the learn step's view of a 20-step unroll; B = 32 the
+# `impala` batch, 8 its per-device share on a four-chip mesh, 20 the
+# Anakin env count of that section, 256 the largest learn-sweep batch
+# one grid step owns.
+@pytest.mark.parametrize("T,B", [(18, 32), (18, 8), (18, 20), (20, 256), (20, 512)])
+def test_vtrace_kernel_compiles(chip, T, B):
+    s = jax.ShapeDtypeStruct((T, B), jnp.float32, sharding=chip)
+    boot = jax.ShapeDtypeStruct((B,), jnp.float32, sharding=chip)
+    compiled = vtrace_pallas.lower(s, s, s, s, boot).compile()
+    assert _kernel_calls(compiled) == 1
+
+
+# (seq_len, batch, lstm) of `r2d2_pixel`, `r2d2`, and the IMPALA
+# stored-state width at a learn-sweep batch.
+@pytest.mark.parametrize("T,B,H", [(20, 32, 256), (10, 32, 512), (20, 256, 256)])
+def test_lstm_kernel_fwd_bwd_compiles(chip, T, B, H):
+    def loss(xg, wh, keep, h0, c0):
+        h_all, hT, cT = lstm_pallas(xg, wh, keep, h0, c0)
+        return jnp.sum(h_all * h_all) + jnp.sum(hT) + jnp.sum(cT)
+
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        f32(T, B, 4 * H), f32(H, 4 * H), f32(T, B, 1), f32(B, H), f32(B, H)
+    ).compile()
+    assert _kernel_calls(compiled) == 2  # forward + BPTT
+
+
+# (batch*heads, T, head_dim): the bench's transformer (256 wide, 4
+# heads, T=32), a long-context row, and a bf16 row.
+@pytest.mark.parametrize("BH,T,D,dtype", [
+    (128, 32, 64, jnp.float32),
+    (16, 2048, 64, jnp.bfloat16),
+    (64, 512, 64, jnp.bfloat16),
+])
+def test_flash_attention_fwd_bwd_compiles(chip, BH, T, D, dtype):
+    block = flash_blocks(T)
+    assert block > 0
+
+    def loss(q, k, v, seg):
+        out = flash_attention_bhtd(q, k, v, seg, seg, block_q=block,
+                                   block_kv=block)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    qkv = jax.ShapeDtypeStruct((BH, T, D), dtype, sharding=chip)
+    seg = jax.ShapeDtypeStruct((BH, T), jnp.int32, sharding=chip)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv, seg).compile()
+    assert _kernel_calls(compiled) == 3  # forward, dq, dkv
+
+
+def _impala_learn_compiled(chip, dtype):
+    import dataclasses
+
+    from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent
+
+    cfg, rt = load_config(CONFIG, "impala")
+    agent = ImpalaAgent(dataclasses.replace(cfg, dtype=dtype))
+    state = jax.eval_shape(agent.init_state, jax.random.PRNGKey(0))
+    batch = synthetic.synthetic_impala_batch(
+        rt.batch_size, cfg.trajectory, cfg.obs_shape, cfg.num_actions,
+        cfg.lstm_size)
+    return agent.learn.lower(_on(chip, state), _on(chip, batch)).compile()
+
+
+def test_impala_learn_step_holds_the_vtrace_kernel(chip, kernels_as_on_chip):
+    """`impala` section widths (84x84x4 frames, LSTM 256, T=20, B=32):
+    the step compiles for the v5e with both V-trace passes as Mosaic
+    kernels — what chip_smoke.py asserts again on the chip itself."""
+    compiled = _impala_learn_compiled(chip, jnp.float32)
+    assert _kernel_calls(compiled, "vtrace_pallas") == 2
+
+
+def _sharded_learn_compiled(devices, agent, *data):
+    """`ShardedLearner.learn` over the `(data,)` mesh `run_role` builds
+    on a multi-chip host, compiled for `data`'s shapes (batch, and for
+    the replay families the IS weights)."""
+    from distributed_reinforcement_learning_tpu.parallel import (
+        ShardedLearner, data_sharding, make_mesh)
+
+    learner = ShardedLearner(agent, make_mesh(devices=devices),
+                             num_data_args=len(data), num_aux_outputs=len(data))
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        jax.eval_shape(agent.init_state, jax.random.PRNGKey(0)),
+        learner.state_sharding)
+    rows = data_sharding(learner.mesh)
+    data = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rows), data)
+    return learner.learn.lower(state, *data).compile()
+
+
+@pytest.mark.slow
+def test_sharded_impala_learn_step_compiles_for_four_chips(
+        four_chips, kernels_as_on_chip):
+    """What a learner on a four-chip host runs: `ShardedLearner` over
+    the `(data,)` mesh `run_role` builds. GSPMD cannot partition a
+    Mosaic kernel, so each V-trace pass has to arrive as a shard_map
+    over the mesh (`ops/pallas.batch_partitioned`): per-device [18, 8]
+    kernels, one gradient all-reduce, and no all-gather of the batch.
+    (This compile refused the step before PR 21, and it refused a
+    `custom_partitioning` wrapper exactly as four real chips then did.)"""
+    from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent
+
+    cfg, rt = load_config(CONFIG, "impala")
+    compiled = _sharded_learn_compiled(
+        four_chips, ImpalaAgent(cfg),
+        synthetic.synthetic_impala_batch(
+            rt.batch_size, cfg.trajectory, cfg.obs_shape, cfg.num_actions,
+            cfg.lstm_size))
+    text = compiled.as_text()
+    assert _kernel_calls(compiled, "vtrace_pallas") == 2
+    assert "f32[18,8]" in text and "all-reduce" in text
+    assert "all-gather" not in text
+
+
+@pytest.mark.slow
+def test_sharded_transformer_learn_step_compiles_for_four_chips(
+        four_chips, kernels_as_on_chip):
+    """The same for the flash-attention kernels (`auto` on TPU whenever
+    T divides by a block): the data-parallel Transformer-R2D2 step at
+    the bench's width (256, 4 heads, T=32) over the four-chip mesh."""
+    from distributed_reinforcement_learning_tpu.agents.xformer import (
+        XformerAgent, XformerConfig)
+
+    cfg = XformerConfig(obs_shape=(8,), num_actions=4, seq_len=32, burn_in=0,
+                        d_model=256, num_heads=4, num_layers=2)
+    compiled = _sharded_learn_compiled(
+        four_chips, XformerAgent(cfg),
+        *synthetic.synthetic_xformer_batch(32, cfg.seq_len, cfg.obs_shape,
+                                           cfg.num_actions))
+    assert _kernel_calls(compiled) > 0
+    assert "all-gather" not in compiled.as_text()
+
+
+@pytest.mark.slow
+def test_impala_learn_step_bf16_compiles(chip, kernels_as_on_chip):
+    compiled = _impala_learn_compiled(chip, jnp.bfloat16)
+    assert _kernel_calls(compiled, "vtrace_pallas") == 2
+
+
+@pytest.mark.slow
+def test_apex_learn_step_compiles(chip, kernels_as_on_chip):
+    from distributed_reinforcement_learning_tpu.agents.apex import ApexAgent
+
+    cfg, rt = load_config(CONFIG, "apex")
+    agent = ApexAgent(cfg)
+    state = jax.eval_shape(agent.init_state, jax.random.PRNGKey(0))
+    batch, is_weight = synthetic.synthetic_apex_batch(
+        rt.batch_size, cfg.obs_shape, cfg.num_actions, obs_dtype="uint8")
+    agent.learn.lower(_on(chip, state), _on(chip, batch),
+                      _on(chip, is_weight)).compile()
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("lstm_kernel", ["0", "1"])
+def test_r2d2_pixel_learn_step_compiles(chip, kernels_as_on_chip, monkeypatch,
+                                        lstm_kernel):
+    """Default (XLA scan) and with the opt-in fused LSTM kernel."""
+    from distributed_reinforcement_learning_tpu.agents.r2d2 import R2D2Agent
+
+    monkeypatch.setenv("DRL_LSTM_PALLAS", lstm_kernel)
+    cfg, rt = load_config(CONFIG, "r2d2_pixel")
+    agent = R2D2Agent(cfg)
+    state = jax.eval_shape(agent.init_state, jax.random.PRNGKey(0))
+    batch, is_weight = synthetic.synthetic_r2d2_batch(
+        rt.batch_size, cfg.seq_len, cfg.obs_shape, cfg.num_actions,
+        cfg.lstm_size)
+    batch = batch._replace(state=batch.state.astype("uint8"))
+    compiled = agent.learn.lower(_on(chip, state), _on(chip, batch),
+                                 _on(chip, is_weight)).compile()
+    assert (_kernel_calls(compiled) > 0) == (lstm_kernel == "1")
+
+
+@pytest.mark.slow
+def test_anakin_chunk_compiles(chip, kernels_as_on_chip):
+    """The fused collect+learn chunk at the `impala` section's widths
+    (20 on-device Breakout envs, 2 updates a chunk)."""
+    from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent
+    from distributed_reinforcement_learning_tpu.envs import breakout_jax
+    from distributed_reinforcement_learning_tpu.runtime.anakin import AnakinImpala
+
+    cfg, rt = load_config(CONFIG, "impala")
+    anakin = AnakinImpala(ImpalaAgent(cfg), rt.num_actors * rt.envs_per_actor,
+                          env=breakout_jax)
+    state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+    compiled = anakin.train_chunk.lower(_on(chip, state), 2).compile()
+    assert _kernel_calls(compiled, "vtrace_pallas") == 2

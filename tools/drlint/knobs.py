@@ -361,9 +361,6 @@ _ENTRIES = [
          "tests/test_native.py",
          "force the C++ tsan stress build/run despite the container's "
          "known-failing toolchain"),
-    Knob("DRL_RUN_ANAKIN_MESH", "flag", "0 (env-documented skip)",
-         "tests/test_anakin_mesh.py",
-         "force the container-jax shard_map mesh tests"),
     Knob("DRL_RUN_IMPALA_STALE", "flag", "0 (env-documented skip)",
          "tests/test_e2e.py",
          "force the FP-trajectory-sensitive publish-interval return test"),

@@ -46,6 +46,9 @@ def main() -> None:
     if platform:
         import jax
         jax.config.update("jax_platforms", platform)
+    from distributed_reinforcement_learning_tpu.utils.device import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.mode == "anakin":
         # On-device replay training (runtime/anakin_r2d2.py).

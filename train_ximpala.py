@@ -44,6 +44,9 @@ def main() -> None:
     if platform:
         import jax
         jax.config.update("jax_platforms", platform)
+    from distributed_reinforcement_learning_tpu.utils.device import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.mode == "local":
         from distributed_reinforcement_learning_tpu.runtime.launch import train_local
