@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from distributed_reinforcement_learning_tpu.agents import common
 from distributed_reinforcement_learning_tpu.models.impala_net import ImpalaActorCritic, apply_stored_state
+from distributed_reinforcement_learning_tpu.observability import scopes
 from distributed_reinforcement_learning_tpu.ops import vtrace
 
 
@@ -101,11 +102,12 @@ class ImpalaAgent:
         )
         self.tx = common.rmsprop_with_clip(self._schedule, cfg.gradient_clip_norm)
         self.act = jax.jit(self._act)
-        self.learn = jax.jit(self._learn, donate_argnums=(0,))
+        self.learn = jax.jit(scopes.tagged(self._learn), donate_argnums=(0,))
         # K optimizer steps per dispatch (lax.scan over stacked batches):
         # strips the per-step host->device dispatch gap (not measured on
         # the attached chip).
-        self.learn_many = jax.jit(common.scan_learn(self._learn), donate_argnums=(0,))
+        self.learn_many = jax.jit(scopes.tagged(common.scan_learn(self._learn)),
+                                  donate_argnums=(0,))
 
     # -- init ------------------------------------------------------------
     def init_state(self, rng: jax.Array) -> common.TrainState:
@@ -143,6 +145,7 @@ class ImpalaAgent:
         return ActOutput(action, out.policy, out.h, out.c)
 
     # -- learn -----------------------------------------------------------
+    @jax.named_scope(scopes.LOSS)
     def _loss(self, params, batch: ImpalaBatch):
         cfg = self.cfg
         forward = functools.partial(apply_stored_state, self.model)
@@ -187,10 +190,12 @@ class ImpalaAgent:
         }
         return total, metrics
 
+    @jax.named_scope(scopes.LEARN)
     def _learn(self, state: common.TrainState, batch: ImpalaBatch):
         grads, metrics = jax.grad(self._loss, has_aux=True)(state.params, batch)
-        updates, opt_state = self.tx.update(grads, state.opt_state, state.params)
-        params = jax.tree.map(lambda p, u: p + u, state.params, updates)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, opt_state = self.tx.update(grads, state.opt_state, state.params)
+            params = jax.tree.map(lambda p, u: p + u, state.params, updates)
         metrics["grad_norm"] = common.global_norm(grads)
         metrics["learning_rate"] = self._schedule(state.step)
         new_state = state.replace(params=params, opt_state=opt_state, step=state.step + 1)

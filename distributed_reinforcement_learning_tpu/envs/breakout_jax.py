@@ -50,6 +50,7 @@ import numpy as np
 from distributed_reinforcement_learning_tpu.envs import breakout_sim as sim
 from distributed_reinforcement_learning_tpu.envs import pixel_jax
 from distributed_reinforcement_learning_tpu.envs.pixel_jax import preprocess as _preprocess
+from distributed_reinforcement_learning_tpu.observability import scopes
 
 NUM_ACTIONS = sim.BreakoutCore.num_actions  # NOOP / FIRE / RIGHT / LEFT
 OBS_SHAPE = (84, 84, 4)
@@ -286,8 +287,9 @@ def step(
     (bricks, lives, frames, paddle_x, ball_dead, ball_x, ball_y, vx, vy,
      reward, game_over) = carry
 
-    raw = jax.vmap(_render)(bricks, paddle_x, ball_dead, ball_x, ball_y)
-    stack = pixel_jax.observe(raw, state.prev_raw, state.stack)
+    with jax.named_scope(scopes.RENDER):
+        raw = jax.vmap(_render)(bricks, paddle_x, ball_dead, ball_x, ball_y)
+        stack = pixel_jax.observe(raw, state.prev_raw, state.stack)
 
     returns = state.returns + reward
     episode_return = jnp.where(game_over, returns, 0.0)
@@ -310,10 +312,11 @@ def step(
 
     # Auto-reset game-over slots (fresh board; obs = reset observation).
     fresh = _reset_fields(n)
-    raw0 = jax.vmap(_render)(
-        fresh["bricks"], fresh["paddle_x"], fresh["ball_dead"],
-        fresh["ball_x"], fresh["ball_y"])
-    stack0 = pixel_jax.reset_stack(raw0)
+    with jax.named_scope(scopes.RENDER):
+        raw0 = jax.vmap(_render)(
+            fresh["bricks"], fresh["paddle_x"], fresh["ball_dead"],
+            fresh["ball_x"], fresh["ball_y"])
+        stack0 = pixel_jax.reset_stack(raw0)
 
     pick = pixel_jax.make_pick(game_over)
     new_state = BreakoutState(

@@ -36,6 +36,7 @@ import numpy as np
 from distributed_reinforcement_learning_tpu.envs import invaders_sim as sim
 from distributed_reinforcement_learning_tpu.envs import pixel_jax
 from distributed_reinforcement_learning_tpu.envs.pixel_jax import preprocess as _preprocess
+from distributed_reinforcement_learning_tpu.observability import scopes
 
 NUM_ACTIONS = sim.InvadersCore.num_actions  # NOOP/FIRE/R/L/RFIRE/LFIRE
 OBS_SHAPE = (84, 84, 4)
@@ -386,8 +387,9 @@ def step(
         bomb_live=bomb_live, bomb_x=bomb_x, bomb_y=bomb_y,
         shield_hp=shield_hp, lives=lives, frames=frames,
         returns=state.returns + reward)
-    raw = _render_state(fields)
-    stack = pixel_jax.observe(raw, state.prev_raw, state.stack)
+    with jax.named_scope(scopes.RENDER):
+        raw = _render_state(fields)
+        stack = pixel_jax.observe(raw, state.prev_raw, state.stack)
 
     episode_return = jnp.where(game_over, fields["returns"], 0.0)
     lost_life = lives < lives_before
@@ -399,8 +401,9 @@ def step(
         reward = jnp.where(lost_life & ~game_over, -1.0, reward)
 
     fresh = _reset_fields(n)
-    raw0 = _render_state(fresh)
-    stack0 = pixel_jax.reset_stack(raw0)
+    with jax.named_scope(scopes.RENDER):
+        raw0 = _render_state(fresh)
+        stack0 = pixel_jax.reset_stack(raw0)
     pick = pixel_jax.make_pick(game_over)
     new_fields = {k: pick(fresh[k], fields[k]) for k in fresh}
     new_state = InvadersState(

@@ -35,6 +35,7 @@ import numpy as np
 from distributed_reinforcement_learning_tpu.envs import pixel_jax
 from distributed_reinforcement_learning_tpu.envs import pong_sim as sim
 from distributed_reinforcement_learning_tpu.envs.pixel_jax import preprocess as _preprocess
+from distributed_reinforcement_learning_tpu.observability import scopes
 
 NUM_ACTIONS = sim.PongCore.num_actions  # NOOP/FIRE/RIGHT/LEFT/RIGHTFIRE/LEFTFIRE
 OBS_SHAPE = (84, 84, 4)
@@ -291,17 +292,19 @@ def step(
      serve_timer, serve_dir, rally, ball_x, ball_y, vx, vy, reward,
      game_over) = carry
 
-    raw = jax.vmap(_render)(player_y, enemy_y, ball_dead, ball_x, ball_y)
-    stack = pixel_jax.observe(raw, state.prev_raw, state.stack)
+    with jax.named_scope(scopes.RENDER):
+        raw = jax.vmap(_render)(player_y, enemy_y, ball_dead, ball_x, ball_y)
+        stack = pixel_jax.observe(raw, state.prev_raw, state.stack)
 
     returns = state.returns + reward
     episode_return = jnp.where(game_over, returns, 0.0)
 
     fresh = _reset_fields(n)
-    raw0 = jax.vmap(_render)(
-        fresh["player_y"], fresh["enemy_y"], fresh["ball_dead"],
-        fresh["ball_x"], fresh["ball_y"])
-    stack0 = pixel_jax.reset_stack(raw0)
+    with jax.named_scope(scopes.RENDER):
+        raw0 = jax.vmap(_render)(
+            fresh["player_y"], fresh["enemy_y"], fresh["ball_dead"],
+            fresh["ball_x"], fresh["ball_y"])
+        stack0 = pixel_jax.reset_stack(raw0)
 
     pick = pixel_jax.make_pick(game_over)
     new_state = PongState(

@@ -26,7 +26,9 @@ from distributed_reinforcement_learning_tpu.observability.metrics import (
 )
 from distributed_reinforcement_learning_tpu.observability.trace import (
     TraceEmitter,
+    chip_span,
     load_trace,
 )
 
-__all__ = ["TELEMETRY", "Telemetry", "TraceEmitter", "load_trace", "maybe_configure"]
+__all__ = ["TELEMETRY", "Telemetry", "TraceEmitter", "chip_span", "load_trace",
+           "maybe_configure"]

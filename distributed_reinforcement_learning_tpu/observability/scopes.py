@@ -1,0 +1,66 @@
+"""The one vocabulary of names for a profile of this program.
+
+Device scopes go through `jax.named_scope` inside the compiled steps:
+they are `op_name` metadata on every HLO instruction (fusions too), so
+they cost nothing at run time and the profiler's `hlo_stats` gives them
+back per op. Host spans go through `observability.trace.chip_span`
+around the fused loops' chunks. Nothing else in the program spells
+these strings; a reader of a profile (docs/performance.md
+"Observability", the benchmark's per-layer metrics) matches on them.
+
+A nested scope is written out in full (`collect/env/render`, not
+`render`): a `lax.scan` or a `jax.jit` in between puts `while/body` or
+`jit(step)` into the path, so only a name that carries its own parents
+is found again as one substring. The deepest name in an op's path is the
+scope the op belongs to. Backward ops of the loss appear under
+`transpose(jvp(learn/loss))`, forward ops under `jvp(learn/loss)`.
+"""
+
+from __future__ import annotations
+
+import functools
+
+# Scope names are metadata, and JAX (0.9.0) leaves metadata out of the
+# key of its persistent compile cache. A cache directory that another
+# commit filled therefore hands back THAT commit's executable, with its
+# names (or none): seen here as a profile with no scope in it. The name
+# of a jitted function is the HLO module's name, which IS in the key, so
+# the jitted steps that carry scopes go through `tagged`. Bump the tag
+# when a scope is added, renamed or moved; nothing else reads it.
+CACHE_TAG = "s1"
+
+
+def tagged(fn):
+    """`fn` under the name `<name>_<CACHE_TAG>`, for `jax.jit`."""
+
+    @functools.wraps(fn)
+    def wrapper(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    wrapper.__name__ = f"{fn.__name__}_{CACHE_TAG}"
+    return wrapper
+
+
+# -- device scopes --------------------------------------------------------
+COLLECT = "collect"  # the scan over env steps: loop control + stacked outputs
+ACT = "collect/act"  # obs prep, torso, LSTM, sampling (`agent._act`)
+ENV = "collect/env"  # env dynamics (`env.step` less rendering)
+RENDER = "collect/env/render"  # raw screen, 2-frame max, luma, resize, stack
+RECORD = "collect/record"  # the per-step record + carry of the rollout
+TO_BATCH_MAJOR = "to_batch_major"  # [T, B, ...] rollout -> [B, T, ...] batch
+REPLAY = "replay"  # device ring: ingest, sample, priority write-back
+LEARN = "learn"  # all of one optimizer step
+LOSS = "learn/loss"  # forward (backward: transpose(jvp(learn/loss)))
+VTRACE = "learn/vtrace"  # V-trace targets (Pallas kernel on the TPU)
+OPTIMIZER = "learn/optimizer"  # optimizer update + parameter add
+
+IMPALA_CHUNK_SCOPES = (COLLECT, ACT, ENV, RENDER, RECORD, TO_BATCH_MAJOR,
+                       LEARN, LOSS, VTRACE, OPTIMIZER)
+REPLAY_CHUNK_SCOPES = (COLLECT, REPLAY, LEARN)
+
+# -- host spans of the fused loops (runtime/launch.py) ---------------------
+STEP_READ = "anakin/step_read"  # int(state.train.step) at the loop head
+DISPATCH = "anakin/dispatch"  # the train_chunk call
+WAIT = "anakin/wait"  # first blocking read of the chunk's metrics
+REPORT = "anakin/report"  # host sums, gauges, the log line
+CHECKPOINT = "anakin/checkpoint"

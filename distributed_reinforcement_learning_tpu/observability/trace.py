@@ -5,9 +5,10 @@ reduces everything to windowed means — "publish averaged 3 ms" cannot
 show the one 400 ms stall that starved the chip. A `TraceEmitter`
 records every stage invocation as a complete-duration event (`ph: "X"`)
 in the Trace Event Format, so `trace-<role>-<rank>.json` opens directly
-in Perfetto (ui.perfetto.dev) or chrome://tracing — next to the XLA
-device trace `ProfilerSession` captures, giving host timeline + device
-timeline side by side.
+in Perfetto (ui.perfetto.dev) or chrome://tracing. A process that owns
+a chip opens its spans through `chip_span`, which also puts them on the
+host plane of any live `jax.profiler` trace (`ProfilerSession`, the
+benchmark's traced run): host and device on one clock, in one file.
 
 Timestamps are wall-clock epoch microseconds (not perf_counter): spans
 from different PROCESSES of one run then align on a shared axis, which
@@ -138,6 +139,29 @@ class TraceEmitter:
             self._file.write("\n]\n")
             self._file.close()
             self._file = None
+
+
+@contextlib.contextmanager
+def chip_span(name: str, emitter: "TraceEmitter | None" = None) -> Iterator[None]:
+    """A host span of a process that owns a chip, on the profiler's clock.
+
+    Opens a `jax.profiler.TraceAnnotation`: a no-op unless a profiler
+    session is live, and then an event on the host plane of the SAME
+    `.xplane.pb` as the device ops — one clock, nothing to align. With an
+    `emitter` (the process's `TELEMETRY.trace`, None while telemetry is
+    off) the span also goes to the wall-clock Chrome trace that
+    `scripts/obs_report.py` merges across processes. Actor processes
+    (no device, no profiler) keep `TELEMETRY.span()`."""
+    from jax.profiler import TraceAnnotation
+
+    wall = time.time() if emitter is not None else 0.0
+    t0 = time.perf_counter()
+    with TraceAnnotation(name):
+        try:
+            yield
+        finally:
+            if emitter is not None:
+                emitter.emit(name, wall, time.perf_counter() - t0)
 
 
 def load_trace(path: str) -> list[dict]:

@@ -22,6 +22,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from distributed_reinforcement_learning_tpu.observability import scopes
+
 
 class VTraceReturns(NamedTuple):
     """Outputs of the V-trace recursion (both stop-gradiented)."""
@@ -129,6 +131,7 @@ def from_importance_weights(
     )
 
 
+@jax.named_scope(scopes.VTRACE)
 def from_softmax(
     behavior_policy: jax.Array,
     target_policy: jax.Array,

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from distributed_reinforcement_learning_tpu.agents.common import TrainState
 from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent, ImpalaBatch
 from distributed_reinforcement_learning_tpu.envs import cartpole_jax
+from distributed_reinforcement_learning_tpu.observability import scopes
 
 
 class AnakinState(NamedTuple):
@@ -75,7 +76,8 @@ class AnakinImpala:
         # constant buffer, which donation rejects; the state is small
         # (CartPole MLP+LSTM), so the copy is noise.
         if mesh is None:
-            self.train_chunk = jax.jit(self._train_chunk, static_argnums=(1,))
+            self.train_chunk = jax.jit(scopes.tagged(self._train_chunk),
+                                       static_argnums=(1,))
         else:
             # Multi-chip Anakin: envs shard over the `data` axis (each
             # chip steps + acts on its env shard), the TrainState follows
@@ -103,7 +105,7 @@ class AnakinImpala:
                 obs=data, prev_action=data, h=data, c=data, rng=repl,
             )
             self.train_chunk = jax.jit(
-                self._train_chunk, static_argnums=(1,),
+                scopes.tagged(self._train_chunk), static_argnums=(1,),
                 in_shardings=(self._state_sharding,),
                 out_shardings=(self._state_sharding, repl),
             )
@@ -141,49 +143,57 @@ class AnakinImpala:
     def _env_step(self, params, carry, _):
         env, obs, prev_action, h, c, rng = carry
         rng, k_act, k_env = jax.random.split(rng, 3)
-        out = self.agent._act(params, obs, prev_action, h, c, k_act)
-        env, next_obs, reward, done, ep_ret = self.env.step(
-            env, self._env_action(out.action), k_env)
-        mask_fn = getattr(self.env, "completed_episode_mask",
-                          lambda done, _state: done)
-        record = dict(
-            state=obs,
-            reward=reward,
-            done=done,
-            action=out.action,
-            behavior_policy=out.policy,
-            previous_action=prev_action,
-            initial_h=h,
-            initial_c=c,
-            episode_return=ep_ret,
-            # True episode ends (life-loss `done`s excluded), so chunk
-            # metrics can report a real mean completed-episode return.
-            episode_completed=mask_fn(done, env),
-        )
-        keep = (~done).astype(out.h.dtype)[:, None]
-        carry = (env, next_obs, jnp.where(done, 0, out.action).astype(jnp.int32),
-                 out.h * keep, out.c * keep, rng)
+        with jax.named_scope(scopes.ACT):
+            out = self.agent._act(params, obs, prev_action, h, c, k_act)
+        with jax.named_scope(scopes.ENV):
+            env, next_obs, reward, done, ep_ret = self.env.step(
+                env, self._env_action(out.action), k_env)
+        with jax.named_scope(scopes.RECORD):
+            mask_fn = getattr(self.env, "completed_episode_mask",
+                              lambda done, _state: done)
+            record = dict(
+                state=obs,
+                reward=reward,
+                done=done,
+                action=out.action,
+                behavior_policy=out.policy,
+                previous_action=prev_action,
+                initial_h=h,
+                initial_c=c,
+                episode_return=ep_ret,
+                # True episode ends (life-loss `done`s excluded), so chunk
+                # metrics can report a real mean completed-episode return.
+                episode_completed=mask_fn(done, env),
+            )
+            keep = (~done).astype(out.h.dtype)[:, None]
+            carry = (env, next_obs,
+                     jnp.where(done, 0, out.action).astype(jnp.int32),
+                     out.h * keep, out.c * keep, rng)
         return carry, record
 
     # -- one update: T-step collect then learn ---------------------------
     def _update(self, state: AnakinState, _):
         T = self.agent.cfg.trajectory
         carry = (state.env, state.obs, state.prev_action, state.h, state.c, state.rng)
-        carry, rec = jax.lax.scan(
-            functools.partial(self._env_step, state.train.params), carry, None, length=T)
+        with jax.named_scope(scopes.COLLECT):
+            carry, rec = jax.lax.scan(
+                functools.partial(self._env_step, state.train.params), carry,
+                None, length=T)
         env, obs, prev_action, h, c, rng = carry
         # rec fields are [T, B, ...]; the learner wants [B, T, ...].
         bt = lambda name: jnp.swapaxes(rec[name], 0, 1)
-        batch = ImpalaBatch(
-            state=bt("state"),
-            reward=bt("reward"),
-            action=bt("action"),
-            done=bt("done"),
-            behavior_policy=bt("behavior_policy"),
-            previous_action=bt("previous_action"),
-            initial_h=bt("initial_h"),
-            initial_c=bt("initial_c"),
-        )
+        with jax.named_scope(scopes.TO_BATCH_MAJOR):
+            batch = ImpalaBatch(
+                state=bt("state"),
+                reward=bt("reward"),
+                action=bt("action"),
+                done=bt("done"),
+                behavior_policy=bt("behavior_policy"),
+                previous_action=bt("previous_action"),
+                initial_h=bt("initial_h"),
+                initial_c=bt("initial_c"),
+            )
+        # `_learn` names itself (scopes.LEARN and below).
         train, metrics = self.agent._learn(state.train, batch)
         metrics["episode_return_sum"] = rec["episode_return"].sum()
         # Real episode ends; for life-loss envs rec["done"] also fires on

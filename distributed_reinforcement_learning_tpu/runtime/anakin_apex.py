@@ -36,6 +36,7 @@ from distributed_reinforcement_learning_tpu.agents.apex import ApexAgent, ApexBa
 from distributed_reinforcement_learning_tpu.data import device_replay
 from distributed_reinforcement_learning_tpu.data.device_replay import DeviceReplay
 from distributed_reinforcement_learning_tpu.envs import cartpole_jax
+from distributed_reinforcement_learning_tpu.observability import scopes
 from distributed_reinforcement_learning_tpu.runtime.anakin_mesh import (
     DataMeshReplayMixin,
     batched_specs,
@@ -183,9 +184,10 @@ class AnakinApex(DataMeshReplayMixin):
         local env shard, so the flat width is the LOCAL one."""
         carry = (state.env, state.obs, state.prev_action, state.episodes,
                  state.rng)
-        carry, rec = jax.lax.scan(
-            functools.partial(self._env_step, state.train.params), carry,
-            None, length=self.steps_per_collect)
+        with jax.named_scope(scopes.COLLECT):
+            carry, rec = jax.lax.scan(
+                functools.partial(self._env_step, state.train.params), carry,
+                None, length=self.steps_per_collect)
         env, obs, prev_action, episodes, rng = carry
         flat = lambda name: rec[name].reshape((self.write_width_local,)
                                               + rec[name].shape[2:])
@@ -203,6 +205,7 @@ class AnakinApex(DataMeshReplayMixin):
                                    episodes=episodes, rng=rng)
         return new_state, batch, stats
 
+    @jax.named_scope(scopes.REPLAY)
     def _ingest(self, train, replay: DeviceReplay, batch: ApexBatch
                 ) -> DeviceReplay:
         errs = self.agent._td_error(train, batch)  # [W]
@@ -217,11 +220,14 @@ class AnakinApex(DataMeshReplayMixin):
         def one_learn(carry, _):
             train, replay, rng = carry
             rng, k = jax.random.split(rng)
-            replay, batch, idx, weights = device_replay.sample(
-                replay, k, self.batch_local, axis_name=self._axis)
-            train, td, metrics = self.agent._learn(train, batch, weights,
-                                                   axis_name=self._axis)
-            replay = device_replay.update_priorities(replay, idx, td)
+            with jax.named_scope(scopes.REPLAY):
+                replay, batch, idx, weights = device_replay.sample(
+                    replay, k, self.batch_local, axis_name=self._axis)
+            with jax.named_scope(scopes.LEARN):
+                train, td, metrics = self.agent._learn(
+                    train, batch, weights, axis_name=self._axis)
+            with jax.named_scope(scopes.REPLAY):
+                replay = device_replay.update_priorities(replay, idx, td)
             return (train, replay, rng), metrics
 
         rng, k_learn = jax.random.split(state.rng)

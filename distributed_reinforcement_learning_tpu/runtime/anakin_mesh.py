@@ -28,12 +28,11 @@ PER-DEVICE count — chunk metrics report the psum'd global `replay_size`.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_reinforcement_learning_tpu.data.device_replay import DeviceReplay
+from distributed_reinforcement_learning_tpu.observability import scopes
 from distributed_reinforcement_learning_tpu.parallel.mesh import DATA_AXIS
 
 
@@ -98,8 +97,9 @@ class DataMeshReplayMixin:
         self.batch_local = batch_size // self.dshard
         self._axis = DATA_AXIS if mesh is not None else None
         if mesh is None:
-            self.train_chunk = jax.jit(self._train_chunk, static_argnums=(1,))
-            self.collect_chunk = jax.jit(self._collect_chunk,
+            self.train_chunk = jax.jit(scopes.tagged(self._train_chunk),
+                                       static_argnums=(1,))
+            self.collect_chunk = jax.jit(scopes.tagged(self._collect_chunk),
                                          static_argnums=(1,))
         else:
             self._specs = self._state_specs()
@@ -133,7 +133,6 @@ def shard_mapped_chunk(mesh, specs, body):
     out_spec is replicated.
     """
 
-    @functools.partial(jax.jit, static_argnums=(1,))
     def call(state, num: int):
         def local_body(s):
             s = s._replace(rng=s.rng[0])
@@ -144,4 +143,5 @@ def shard_mapped_chunk(mesh, specs, body):
             local_body, mesh=mesh, in_specs=(specs,), out_specs=(specs, P()))
         return f(state)
 
-    return call
+    call.__name__ = body.__name__  # the HLO module is named for the body
+    return jax.jit(scopes.tagged(call), static_argnums=(1,))

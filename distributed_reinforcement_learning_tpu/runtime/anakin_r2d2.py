@@ -50,6 +50,7 @@ from distributed_reinforcement_learning_tpu.data.device_replay import (
     DeviceReplay,
 )
 from distributed_reinforcement_learning_tpu.envs import cartpole_jax
+from distributed_reinforcement_learning_tpu.observability import scopes
 from distributed_reinforcement_learning_tpu.parallel.mesh import DATA_AXIS as _DATA_AXIS, P
 from distributed_reinforcement_learning_tpu.runtime.anakin_mesh import (
     DataMeshReplayMixin,
@@ -203,9 +204,10 @@ class AnakinR2D2(DataMeshReplayMixin):
         h0, c0 = state.h, state.c  # sequence-start stored state
         carry = (state.env, state.obs, state.prev_action, state.h, state.c,
                  state.episodes, state.rng)
-        carry, rec = jax.lax.scan(
-            functools.partial(self._env_step, state.train.params), carry,
-            None, length=cfg.seq_len)
+        with jax.named_scope(scopes.COLLECT):
+            carry, rec = jax.lax.scan(
+                functools.partial(self._env_step, state.train.params), carry,
+                None, length=cfg.seq_len)
         env, obs, prev_action, h, c, episodes, rng = carry
         bt = lambda name: jnp.swapaxes(rec[name], 0, 1)
         batch = R2D2Batch(
@@ -222,12 +224,14 @@ class AnakinR2D2(DataMeshReplayMixin):
                                    h=h, c=c, episodes=episodes, rng=rng)
         return new_state, batch, stats
 
+    @jax.named_scope(scopes.REPLAY)
     def _ingest(self, train, replay: DeviceReplay, batch: R2D2Batch
                 ) -> DeviceReplay:
         """Score + write B new sequences into the ring at `ptr`."""
         errs = self.agent._td_error(train, batch)  # [B]
         return device_replay.ingest(replay, batch, errs)
 
+    @jax.named_scope(scopes.REPLAY)
     def _sample(self, replay: DeviceReplay, rng: jax.Array):
         return device_replay.sample(replay, rng, self.batch_local,
                                     axis_name=self._axis)
@@ -242,9 +246,11 @@ class AnakinR2D2(DataMeshReplayMixin):
             train, replay, rng = carry
             rng, k = jax.random.split(rng)
             replay, batch, idx, weights = self._sample(replay, k)
-            train, new_err, metrics = self.agent._learn(train, batch, weights,
-                                                        axis_name=self._axis)
-            replay = device_replay.update_priorities(replay, idx, new_err)
+            with jax.named_scope(scopes.LEARN):
+                train, new_err, metrics = self.agent._learn(
+                    train, batch, weights, axis_name=self._axis)
+            with jax.named_scope(scopes.REPLAY):
+                replay = device_replay.update_priorities(replay, idx, new_err)
             return (train, replay, rng), metrics
 
         rng, k_learn = jax.random.split(state.rng)

@@ -26,7 +26,11 @@ from distributed_reinforcement_learning_tpu.envs.batched import BatchedEnv
 from distributed_reinforcement_learning_tpu.envs.cartpole import pomdp_project
 from distributed_reinforcement_learning_tpu.envs.registry import make_env
 from distributed_reinforcement_learning_tpu.observability import TELEMETRY as _OBS
-from distributed_reinforcement_learning_tpu.observability import maybe_configure
+from distributed_reinforcement_learning_tpu.observability import (
+    chip_span,
+    maybe_configure,
+    scopes,
+)
 from distributed_reinforcement_learning_tpu.runtime import (
     apex_runner,
     impala_runner,
@@ -38,6 +42,7 @@ from distributed_reinforcement_learning_tpu.runtime.weights import WeightStore
 from distributed_reinforcement_learning_tpu.utils.config import RuntimeConfig, load_config
 from distributed_reinforcement_learning_tpu.utils.device import open_devices
 from distributed_reinforcement_learning_tpu.utils.logger import MetricsLogger
+from distributed_reinforcement_learning_tpu.utils.profiling import ProfilerSession
 
 
 def _make_batched_env(rt: RuntimeConfig, actor_index: int, num_actions: int) -> BatchedEnv:
@@ -287,6 +292,44 @@ def _restore_train(checkpoint_dir, train):
     return ckpt, train
 
 
+def _read_step(state) -> int:
+    """The optimizer step at a fused loop's head (a device read)."""
+    with chip_span(scopes.STEP_READ, _OBS.trace):
+        return int(state.train.step)
+
+
+def _run_chunk(anakin, state, u: int, steps_per_update: int, log, ckpt):
+    """One chunk of a fused loop's host side under its `chip_span`s
+    (observability/scopes.py): dispatch `u` chunk updates of
+    `steps_per_update` optimizer steps each, wait, report
+    (`log(step, mean_return, episodes, metrics)` is the chunk's line),
+    checkpoint. Per chunk and not the whole loop, so that no frame keeps
+    an earlier state's device buffers alive. -> (state, mean return)."""
+    import numpy as np
+
+    trace = _OBS.trace
+    t0 = time.perf_counter()
+    with chip_span(scopes.DISPATCH, trace):
+        state, m = anakin.train_chunk(state, u)
+    with chip_span(scopes.WAIT, trace):
+        episodes_done = np.asarray(m["episodes_done"])
+    # The read above is the chunk's device sync, so dt is honest device
+    # time for the whole compiled chunk.
+    dt = time.perf_counter() - t0
+    with chip_span(scopes.REPORT, trace):
+        eps = float(episodes_done.sum())
+        mean_ret = float(np.asarray(m["episode_return_sum"]).sum()) / max(eps, 1.0)
+        if _OBS.enabled:
+            _OBS.count("anakin/updates", u * steps_per_update)
+            _OBS.gauge("anakin/device_chunk_s", dt)
+        step = int(state.train.step)
+        print(log(step, mean_ret, eps, m))
+    if ckpt is not None:
+        with chip_span(scopes.CHECKPOINT, trace):
+            ckpt.save(step, state.train, {})
+    return state, mean_ret
+
+
 def train_anakin(config_path: str, section: str, num_updates: int,
                  chunk: int = 50, seed: int = 0, num_envs: int | None = None,
                  checkpoint_dir: str | None = None,
@@ -298,8 +341,6 @@ def train_anakin(config_path: str, section: str, num_updates: int,
     saves/restores the TrainState per chunk (env/LSTM state is
     ephemeral: a resume starts fresh episodes, same as every
     actor restart in the distributed topology)."""
-    import numpy as np
-
     open_devices("anakin")
     agent_cfg, rt = load_config(config_path, section)
     if _algo_of(agent_cfg) != "impala":
@@ -314,33 +355,27 @@ def train_anakin(config_path: str, section: str, num_updates: int,
     ckpt, train = _restore_train(checkpoint_dir, state.train)
     state = state._replace(train=train)
     chunk = max(1, min(chunk, num_updates))
-    returns = []
-    last_loss = None
     maybe_configure("anakin", 0, run_dir)  # env-gated run-wide telemetry
-    frames_per_update = anakin.num_envs * agent_cfg.trajectory
-    while int(state.train.step) < num_updates:
-        u = min(chunk, num_updates - int(state.train.step))
-        t0 = time.perf_counter()
-        state, m = anakin.train_chunk(state, u)
-        eps = float(np.asarray(m["episodes_done"]).sum())
-        mean_ret = float(np.asarray(m["episode_return_sum"]).sum()) / max(eps, 1.0)
-        # The float() reads above are the chunk's device sync, so dt is
-        # honest device time for the whole compiled chunk.
-        dt = time.perf_counter() - t0
-        if _OBS.enabled:
-            _OBS.count("anakin/updates", u)
-            _OBS.gauge("anakin/device_chunk_s", dt)
-            _OBS.gauge("anakin/steps_per_s", u / dt)
-            _OBS.gauge("anakin/frames_per_s", u * frames_per_update / dt)
-        returns.append(mean_ret)
-        last_loss = float(m["total_loss"][-1])
-        print(f"[anakin] step {int(state.train.step)}: mean_return {mean_ret:.1f} "
-              f"({eps:.0f} episodes, loss {last_loss:.2f})")
-        if ckpt is not None:
-            ckpt.save(int(state.train.step), state.train, {})
+    last = {"loss": None}
+
+    def log(step: int, mean_ret: float, eps: float, m) -> str:
+        last["loss"] = float(m["total_loss"][-1])
+        return (f"[anakin] step {step}: mean_return {mean_ret:.1f} "
+                f"({eps:.0f} episodes, loss {last['loss']:.2f})")
+
+    returns = []
+    profiler = ProfilerSession.from_env()  # DRL_PROFILE_DIR: one on_step a chunk
+    try:
+        while (step := _read_step(state)) < num_updates:
+            profiler.on_step(step)
+            state, mean_ret = _run_chunk(
+                anakin, state, min(chunk, num_updates - step), 1, log, ckpt)
+            returns.append(mean_ret)
+    finally:
+        profiler.close()
     return {
         "frames": int(state.train.step) * anakin.num_envs * agent_cfg.trajectory,
-        "last_loss": last_loss,
+        "last_loss": last["loss"],
         "chunk_mean_returns": [round(r, 2) for r in returns],
         "mean_return_last_chunk": round(returns[-1], 2) if returns else None,
     }
@@ -355,33 +390,27 @@ def _replay_chunk_loop(anakin, state, num_updates: int, chunk: int, ckpt,
     one collect + K learns (K = updates_per_collect), so chunk sizing
     and the frame count are in collect-updates and the final chunk may
     overshoot by up to K-1 optimizer steps."""
-    import numpy as np
-
     state, _ = anakin.collect_chunk(state, warm)
     K = anakin.updates_per_collect
+    maybe_configure(label, 0, run_dir)  # env-gated run-wide telemetry
+
+    def log(step: int, mean_ret: float, eps: float, m) -> str:
+        return (f"[{label}] step {step}: mean_return {mean_ret:.1f} "
+                f"({eps:.0f} episodes, loss {float(m['loss'][-1]):.4f}, "
+                f"eps {float(m['epsilon_mean'][-1]):.3f})")
+
     collects = warm
     returns = []
-    maybe_configure(label, 0, run_dir)  # env-gated run-wide telemetry
-    while int(state.train.step) < num_updates:
-        remaining_steps = num_updates - int(state.train.step)
-        u = max(1, min(chunk, -(-remaining_steps // K)))
-        t0 = time.perf_counter()
-        state, m = anakin.train_chunk(state, u)
-        collects += u
-        eps = float(np.asarray(m["episodes_done"]).sum())
-        mean_ret = float(np.asarray(m["episode_return_sum"]).sum()) / max(eps, 1.0)
-        dt = time.perf_counter() - t0  # float() reads above = device sync
-        if _OBS.enabled:
-            _OBS.count("anakin/updates", u * K)
-            _OBS.gauge("anakin/device_chunk_s", dt)
-            _OBS.gauge("anakin/steps_per_s", u * K / dt)
-            _OBS.gauge("anakin/frames_per_s", u * frames_per_collect / dt)
-        returns.append(mean_ret)
-        print(f"[{label}] step {int(state.train.step)}: mean_return "
-              f"{mean_ret:.1f} ({eps:.0f} episodes, loss "
-              f"{float(m['loss'][-1]):.4f}, eps {float(m['epsilon_mean'][-1]):.3f})")
-        if ckpt is not None:
-            ckpt.save(int(state.train.step), state.train, {})
+    profiler = ProfilerSession.from_env()  # DRL_PROFILE_DIR: one on_step a chunk
+    try:
+        while (step := _read_step(state)) < num_updates:
+            profiler.on_step(step)
+            u = max(1, min(chunk, -(-(num_updates - step) // K)))
+            state, mean_ret = _run_chunk(anakin, state, u, K, log, ckpt)
+            collects += u
+            returns.append(mean_ret)
+    finally:
+        profiler.close()
     return {
         "frames": collects * frames_per_collect,
         "chunk_mean_returns": [round(r, 2) for r in returns],

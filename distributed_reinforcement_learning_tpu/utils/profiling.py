@@ -9,15 +9,17 @@ profiling is first-class:
   `profile/<stage>_ms` means every `log_every` steps. This splits "the
   step took 40ms" into queue-wait vs device-compute vs weight-publication
   — the split that tells you whether the data plane or the chip is the
-  bottleneck (SURVEY §7 hard part (a)). When the run-wide telemetry is
-  enabled (observability/), every stage invocation additionally becomes
-  a span on the process's Chrome-trace timeline — the TIMELINE the means
-  cannot show (one 400 ms publish stall vs "publish averaged 3 ms") —
-  and each flush mirrors the stage means as `stage/<name>_ms` gauges
-  into the telemetry shard.
+  bottleneck (SURVEY §7 hard part (a)). Every stage invocation is also
+  a `chip_span`: an event beside the device ops in any live
+  `jax.profiler` trace and, when the run-wide telemetry is enabled
+  (observability/), a span on the process's Chrome-trace timeline — the
+  TIMELINE the means cannot show (one 400 ms publish stall vs "publish
+  averaged 3 ms").
 - `ProfilerSession`: captures a real `jax.profiler` device trace (XLA op
-  timeline, viewable in TensorBoard/Perfetto) for a configured window of
-  train steps. Enabled via env vars so any launcher/run picks it up:
+  timeline with the scope names of observability/scopes.py, viewable in
+  TensorBoard/Perfetto) for a configured window of train steps — of the
+  host-loop learners and of the fused loops (one `on_step` per chunk).
+  Enabled via env vars so any launcher/run picks it up:
       DRL_PROFILE_DIR=/tmp/trace DRL_PROFILE_START=50 DRL_PROFILE_STEPS=5
 """
 
@@ -29,6 +31,7 @@ import time
 from typing import Iterator
 
 from distributed_reinforcement_learning_tpu.observability import TELEMETRY as _OBS
+from distributed_reinforcement_learning_tpu.observability import chip_span
 from distributed_reinforcement_learning_tpu.utils.logger import MetricsLogger
 
 
@@ -63,19 +66,16 @@ class StageTimer:
 
     @contextlib.contextmanager
     def stage(self, name: str) -> Iterator[None]:
-        # Trace handle read once: disabled telemetry costs one attribute
-        # load here, no wall-clock read, no allocation.
-        trace = _OBS.trace
-        wall = time.time() if trace is not None else 0.0
+        # Trace handle read once: with telemetry off the span is only the
+        # profiler's annotation (inert unless a profile is being taken).
         t0 = time.perf_counter()
         try:
-            yield
+            with chip_span(name, _OBS.trace):
+                yield
         finally:
             dt = time.perf_counter() - t0
             self._sums[name] = self._sums.get(name, 0.0) + dt
             self._counts[name] = self._counts.get(name, 0) + 1
-            if trace is not None:
-                trace.emit(name, wall, dt)
 
     def step_done(self, step: int) -> None:
         """Mark one train step; every `log_every` steps emit + reset means.
@@ -96,9 +96,6 @@ class StageTimer:
                 {f"{self.prefix}{n}_ms": ms for n, ms in self.last_means_ms.items()},
                 step,
             )
-        if _OBS.enabled:
-            for name, ms in self.last_means_ms.items():
-                _OBS.gauge(f"stage/{name}_ms", ms)
         self._sums.clear()
         self._counts.clear()
         self._steps = 0
@@ -135,7 +132,13 @@ class ProfilerSession:
         if not self._active and step >= self.start_step:
             import jax
 
-            jax.profiler.start_trace(self.out_dir)
+            # No Python tracer: it slows the loop the trace is there to
+            # observe and makes stop_trace hold the thread for seconds.
+            # Device planes, chip_span annotations and the runtime's own
+            # host events need only the host tracer.
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(self.out_dir, profiler_options=options)
             self._active = True
             self._stop_at = step + self.num_steps
         elif self._active and step >= self._stop_at:

@@ -175,14 +175,14 @@ class TestTelemetryShards:
         t = Telemetry()
         t.configure(str(tmp_path), "learner", rank=0, flush_interval=0)
         try:
-            t.gauge("stage/learn_ms", 10.0)
+            t.gauge("learner/batch_wait_ms", 10.0)
             t.flush()
-            t.gauge("stage/learn_ms", 30.0)
+            t.gauge("learner/batch_wait_ms", 30.0)
             t.flush()
         finally:
             t.close()
         windows = _gauges(_read_jsonl(tmp_path / "learner-0.jsonl"),
-                          "stage/learn_ms")
+                          "learner/batch_wait_ms")
         assert [w["mean"] for w in windows] == [10.0, 30.0]
         assert all(w["n"] == 1 for w in windows)
 

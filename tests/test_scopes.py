@@ -1,0 +1,194 @@
+"""The profile vocabulary (`observability/scopes.py`): device scopes in
+the compiled fused chunks, the fused loops' host spans on the
+profiler's clock, and what went with them (ISSUE 24)."""
+
+import dataclasses
+import glob
+import os
+import re
+
+import jax
+import pytest
+
+from distributed_reinforcement_learning_tpu.observability import (
+    TELEMETRY,
+    Telemetry,
+    chip_span,
+    load_trace,
+    scopes,
+)
+from distributed_reinforcement_learning_tpu.utils.profiling import (
+    ProfilerSession,
+    StageTimer,
+)
+
+PKG = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                   "distributed_reinforcement_learning_tpu")
+
+
+def _op_names(jitted, *args) -> set[str]:
+    text = jitted.lower(*args).compile().as_text()
+    return set(re.findall(r'op_name="([^"]+)"', text))
+
+
+@pytest.fixture(scope="module")
+def impala_chunk_names():
+    """`op_name`s of a tiny `AnakinImpala.train_chunk` on the Breakout
+    env (the renderer's scope lives in the env)."""
+    from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent
+    from distributed_reinforcement_learning_tpu.envs import breakout_jax
+    from distributed_reinforcement_learning_tpu.runtime.anakin import AnakinImpala
+    from distributed_reinforcement_learning_tpu.utils.config import load_config
+
+    cfg, _ = load_config("config.json", "impala")
+    anakin = AnakinImpala(ImpalaAgent(dataclasses.replace(cfg, trajectory=4)),
+                          2, env=breakout_jax)
+    return _op_names(anakin.train_chunk, anakin.init(jax.random.PRNGKey(0)), 1)
+
+
+@pytest.mark.parametrize(
+    "name", scopes.IMPALA_CHUNK_SCOPES + (f"transpose(jvp({scopes.LOSS}))",))
+def test_impala_chunk_carries_scope(impala_chunk_names, name):
+    assert any(name in n for n in impala_chunk_names), name
+
+
+def test_impala_chunk_module_name_carries_the_cache_tag():
+    """Metadata is not in the compile-cache key; the module's name is."""
+    from distributed_reinforcement_learning_tpu.agents.impala import (
+        ImpalaAgent, ImpalaConfig)
+    from distributed_reinforcement_learning_tpu.runtime.anakin import AnakinImpala
+
+    anakin = AnakinImpala(ImpalaAgent(ImpalaConfig(
+        obs_shape=(4,), num_actions=2, trajectory=4, lstm_size=16)), 2)
+    text = anakin.train_chunk.lower(anakin.init(jax.random.PRNGKey(0)), 1).as_text()
+    assert f"_train_chunk_{scopes.CACHE_TAG}" in text.split("\n", 1)[0]
+
+
+def _r2d2():
+    from distributed_reinforcement_learning_tpu.agents.r2d2 import (
+        R2D2Agent, R2D2Config)
+    from distributed_reinforcement_learning_tpu.envs.cartpole import pomdp_project
+    from distributed_reinforcement_learning_tpu.runtime.anakin_r2d2 import AnakinR2D2
+
+    cfg = R2D2Config(obs_shape=(2,), num_actions=2, seq_len=6, burn_in=2,
+                     lstm_size=16, learning_rate=1e-3)
+    return AnakinR2D2(R2D2Agent(cfg), num_envs=4, capacity=16, batch_size=4,
+                      obs_transform=pomdp_project, updates_per_collect=1)
+
+
+def _apex():
+    from distributed_reinforcement_learning_tpu.agents.apex import (
+        ApexAgent, ApexConfig)
+    from distributed_reinforcement_learning_tpu.runtime.anakin_apex import AnakinApex
+
+    cfg = ApexConfig(obs_shape=(4,), num_actions=2, start_learning_rate=1e-3)
+    return AnakinApex(ApexAgent(cfg), num_envs=4, steps_per_collect=4,
+                      capacity=32, batch_size=8)
+
+
+@pytest.fixture(scope="module", params=[_r2d2, _apex], ids=["r2d2", "apex"])
+def replay_chunk_names(request):
+    anakin = request.param()
+    return _op_names(anakin.train_chunk, anakin.init(jax.random.PRNGKey(0)), 1)
+
+
+@pytest.mark.parametrize("name", scopes.REPLAY_CHUNK_SCOPES)
+def test_replay_chunk_carries_top_level_scope(replay_chunk_names, name):
+    assert any(re.search(rf"(^|[/(]){name}(/|\)|$)", n)
+               for n in replay_chunk_names), name
+
+
+def _host_events(profile_dir: str) -> list[str]:
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        profile_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    return [ev.name for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events]
+
+
+def test_train_anakin_profile_holds_one_span_per_chunk(tmp_path, monkeypatch):
+    """`DRL_PROFILE_DIR` on a fused loop: one profiler session (no
+    Python tracer), and per chunk one dispatch / wait / report span on
+    the host plane of the same `.xplane.pb` as the device ops."""
+    from distributed_reinforcement_learning_tpu.runtime.launch import train_anakin
+
+    calls = {"start": [], "stop": 0}
+    start, stop = jax.profiler.start_trace, jax.profiler.stop_trace
+
+    def counted_start(log_dir, *a, **kw):
+        calls["start"].append(kw["profiler_options"].python_tracer_level)
+        return start(log_dir, *a, **kw)
+
+    def counted_stop():
+        calls["stop"] += 1
+        return stop()
+
+    monkeypatch.setattr(jax.profiler, "start_trace", counted_start)
+    monkeypatch.setattr(jax.profiler, "stop_trace", counted_stop)
+    monkeypatch.setenv("DRL_PROFILE_DIR", str(tmp_path))
+    monkeypatch.setenv("DRL_PROFILE_START", "0")
+    monkeypatch.setenv("DRL_PROFILE_STEPS", "1000")
+    train_anakin("config.json", "impala_cartpole", num_updates=4, chunk=2)
+    assert calls == {"start": [0], "stop": 1}
+    names = _host_events(str(tmp_path))
+    for span in (scopes.DISPATCH, scopes.WAIT, scopes.REPORT):
+        assert names.count(span) == 2, (span, names.count(span))
+    # the session starts after the first read: second chunk + the loop's exit
+    assert names.count(scopes.STEP_READ) == 2
+    assert scopes.CHECKPOINT not in names  # no checkpoint_dir was given
+
+
+def test_stage_timer_is_silent_with_telemetry_off_and_emits_with_it_on(tmp_path):
+    assert TELEMETRY.trace is None
+    assert TELEMETRY.span("x") is TELEMETRY.span("y")  # the shared no-op
+    timer = StageTimer(None, log_every=1)
+    with timer.stage("learn"):
+        pass
+    timer.step_done(1)
+    assert "learn" in timer.last_means_ms
+    assert not list(tmp_path.iterdir())
+    # with an emitter the same span also lands in the Chrome trace
+    t = Telemetry()
+    t.configure(str(tmp_path), "learner", rank=0, flush_interval=0)
+    try:
+        with chip_span("publish", t.trace):
+            pass
+    finally:
+        t.close()
+    events = load_trace(str(tmp_path / "trace-learner-0.json"))
+    assert [e["name"] for e in events if e.get("ph") == "X"] == ["publish"]
+
+
+def test_stage_timer_stage_lands_on_the_profilers_host_plane(tmp_path):
+    timer = StageTimer(None, log_every=100)
+    sess = ProfilerSession(str(tmp_path), start_step=0, num_steps=10)
+    sess.on_step(0)
+    with timer.stage("dequeue"):
+        jax.block_until_ready(jax.jit(lambda v: v + 1)(1.0))
+    sess.close()
+    assert _host_events(str(tmp_path)).count("dequeue") == 1
+
+
+def _program_sources():
+    for path in glob.glob(os.path.join(PKG, "**", "*.py"), recursive=True):
+        with open(path) as f:
+            yield os.path.relpath(path, PKG), f.read()
+
+
+@pytest.mark.parametrize("pattern", [
+    r"stage/\{?\w*\}?_ms", "anakin/steps_per_s", "anakin/frames_per_s"])
+def test_removed_gauges_have_no_emitter(pattern):
+    hits = [rel for rel, text in _program_sources() if re.search(pattern, text)]
+    assert hits == []
+
+
+def test_scope_and_span_names_are_spelled_in_one_place():
+    """No literal `named_scope("...")` / `chip_span("...")` outside
+    `observability/scopes.py`: the program names them through it."""
+    literal = re.compile(r"(named_scope|chip_span|TraceAnnotation)\(\s*[\"']")
+    hits = [rel for rel, text in _program_sources()
+            if rel != os.path.join("observability", "scopes.py")
+            and literal.search(text)]
+    assert hits == []
