@@ -36,6 +36,12 @@ consecutive post-frameskip raw frames -> luma -> INTER_AREA resize to
 an 84x210 matrix by pre-cropping the row weights) -> [84, 84] uint8 ->
 4-frame newest-last stack. The resize is two small matmuls per frame —
 MXU work, which is the point of doing it on-device.
+
+No picture is state. The 2-frame max needs the previous raw frame, and
+that frame is `_render` of five small fields (`_DRAWN`: the 6x18 board,
+paddle x, ball dead / x / y; 121 bytes an env), so `step` renders it again
+from the fields it was handed instead of carrying 100,800 bytes of RGB
+per env from step to step.
 """
 
 from __future__ import annotations
@@ -69,20 +75,25 @@ _BASE[sim.WALL_TOP:sim.WALL_TOP + 4, :] = sim.WALL
 _BASE[sim.WALL_TOP:, :sim.WALL_SIDE] = sim.WALL
 _BASE[sim.WALL_TOP:, W - sim.WALL_SIDE:] = sim.WALL
 
-# Per-pixel brick coordinates: which (row, col) a pixel belongs to, and
-# whether it is inside the brick field at all.
-_ROW_IDX = np.clip((_YS - sim.BRICK_TOP) // sim.BRICK_H, 0, 5)  # [210, 1]
-_COL_IDX = np.clip((_XS - sim.WALL_SIDE) // sim.BRICK_W, 0, 17)  # [1, 160]
-_IN_FIELD = (
-    (_YS >= sim.BRICK_TOP) & (_YS < sim.BRICK_TOP + 6 * sim.BRICK_H)
-    & (_XS >= sim.WALL_SIDE) & (_XS < sim.WALL_SIDE + 18 * sim.BRICK_W)
-)  # [210, 160]
-_ROW_RGB = np.asarray(sim.ROW_COLORS, np.uint8)  # [6, 3]
+# The brick field inside the screen: 6 rows x 18 columns of BRICK_H x
+# BRICK_W pixels, and the colour of the brick row a scanline crosses.
+_FIELD_PAD = (
+    (sim.BRICK_TOP, H - sim.BRICK_TOP - 6 * sim.BRICK_H),
+    (sim.WALL_SIDE, W - sim.WALL_SIDE - 18 * sim.BRICK_W),
+)
+_ROW_RGB_Y = np.asarray(sim.ROW_COLORS, np.uint8)[
+    np.clip((np.arange(H) - sim.BRICK_TOP) // sim.BRICK_H, 0, 5)]  # [210, 3]
 _SPRITE = np.asarray(sim.SPRITE, np.uint8)
 _ROW_POINTS = np.asarray(sim.ROW_POINTS, np.float32)
 
+
 class BreakoutState(NamedTuple):
-    """Batched game + observation-pipeline state (`[N, ...]` leaves)."""
+    """Batched game + observation-pipeline state (`[N, ...]` leaves).
+
+    The observation stack is the only picture here. The last raw frame
+    is not: it always equals `_render` of this state's `_DRAWN` fields
+    (true after `reset`, kept by every `step`), so nothing has to store it.
+    """
 
     bricks: jax.Array      # [N, 6, 18] bool
     lives: jax.Array       # [N] i32
@@ -93,7 +104,6 @@ class BreakoutState(NamedTuple):
     ball_y: jax.Array      # [N] f32
     vx: jax.Array          # [N] f32
     vy: jax.Array          # [N] f32
-    prev_raw: jax.Array    # [N, 210, 160, 3] u8 — last adapter-step frame
     stack: jax.Array       # [N, 84, 84, 4] u8 — current observation
     returns: jax.Array     # [N] f32 raw (unclipped) episode return
 
@@ -102,21 +112,20 @@ class BreakoutState(NamedTuple):
 
 
 def _render(bricks, paddle_x, ball_dead, ball_x, ball_y) -> jax.Array:
-    """`[210, 160, 3]` uint8 frame, `breakout_sim.render` draw order."""
-    f = jnp.asarray(_BASE)
-    alive = bricks[jnp.asarray(_ROW_IDX[:, 0])][:, jnp.asarray(_COL_IDX[0, :])]
-    brick_mask = alive & jnp.asarray(_IN_FIELD)
-    row_colors = jnp.asarray(_ROW_RGB)[jnp.asarray(_ROW_IDX[:, 0])]  # [210, 3]
-    f = jnp.where(brick_mask[:, :, None], row_colors[:, None, :], f)
+    """`[210, 160, 3]` uint8 frame, `breakout_sim.render` draw order.
 
-    px = paddle_x.astype(jnp.int32)
+    Written for the TPU compiler, which keeps whatever feeds a broadcast
+    over the colour channels out of the luma reduction that consumes the
+    frame: two masks (live brick, sprite) are all that reaches HBM per
+    frame, made in one pass from the 6x18 board by `repeat` + `pad` (no
+    per-pixel gather), and the selects below fuse into the reduction.
+    """
     ys, xs = jnp.asarray(_YS), jnp.asarray(_XS)
+    px = paddle_x.astype(jnp.int32)
     paddle = (
         (ys >= sim.PADDLE_Y) & (ys < sim.PADDLE_Y + sim.PADDLE_H)
         & (xs >= px) & (xs < px + sim.PADDLE_W)
     )
-    f = jnp.where(paddle[:, :, None], jnp.asarray(_SPRITE), f)
-
     by = ball_y.astype(jnp.int32)
     bx = ball_x.astype(jnp.int32)
     ball = (
@@ -124,7 +133,22 @@ def _render(bricks, paddle_x, ball_dead, ball_x, ball_y) -> jax.Array:
         & (ys >= by) & (ys < by + _BALL)
         & (xs >= bx) & (xs < bx + _BALL)
     )
-    return jnp.where(ball[:, :, None], jnp.asarray(_SPRITE), f)
+    sprite = paddle | ball
+    field = jnp.repeat(jnp.repeat(bricks, sim.BRICK_H, axis=0), sim.BRICK_W, axis=1)
+    brick = jnp.pad(field, _FIELD_PAD) & ~sprite  # sprites are drawn over bricks
+
+    f = jnp.where(brick[:, :, None], jnp.asarray(_ROW_RGB_Y)[:, None, :],
+                  jnp.asarray(_BASE))
+    return jnp.where(sprite[:, :, None], jnp.asarray(_SPRITE), f)
+
+
+# The state fields `_render` draws: 108 + 1 booleans and three float32.
+_DRAWN = ("bricks", "paddle_x", "ball_dead", "ball_x", "ball_y")
+
+
+def _render_batch(fields) -> jax.Array:
+    """`[N, 210, 160, 3]` uint8 frames of a mapping of `[N, ...]` fields."""
+    return jax.vmap(_render)(*(fields[k] for k in _DRAWN))
 
 
 # -- physics (single env; vmapped) ------------------------------------------
@@ -246,9 +270,8 @@ def reset(rng: jax.Array, num_envs: int) -> tuple[BreakoutState, jax.Array]:
     the cartpole_jax signature."""
     del rng
     f = _reset_fields(num_envs)
-    raw = jax.vmap(_render)(
-        f["bricks"], f["paddle_x"], f["ball_dead"], f["ball_x"], f["ball_y"])
-    state = BreakoutState(prev_raw=raw, stack=pixel_jax.reset_stack(raw), **f)
+    raw = _render_batch(f)
+    state = BreakoutState(stack=pixel_jax.reset_stack(raw), **f)
     return state, state.stack
 
 
@@ -269,6 +292,14 @@ def step(
     raw return where the game ended else 0. `done` is the TRAINING
     signal: game-over or (with `life_loss`) a lost life — the
     reference's shaping (`train_impala.py:149-154`).
+
+    Order: emulate, auto-reset the game FIELDS of game-over slots, then
+    one render -> 2-frame max -> luma -> resize pass for every slot. The
+    max's previous frame is `_render` of the `_DRAWN` fields `state` came
+    in with, because that is what the last step drew (the invariant on
+    `BreakoutState`); a game-over slot takes its fresh fields for both
+    frames and zeros for the older stack slots, which is the reset
+    observation. No frame outlives the pass.
     """
     n = state.lives.shape[0]
     lives_before = state.lives
@@ -286,10 +317,6 @@ def step(
         carry = emulate(carry, actions, launch_vx[i], max_frames)
     (bricks, lives, frames, paddle_x, ball_dead, ball_x, ball_y, vx, vy,
      reward, game_over) = carry
-
-    with jax.named_scope(scopes.RENDER):
-        raw = jax.vmap(_render)(bricks, paddle_x, ball_dead, ball_x, ball_y)
-        stack = pixel_jax.observe(raw, state.prev_raw, state.stack)
 
     returns = state.returns + reward
     episode_return = jnp.where(game_over, returns, 0.0)
@@ -310,30 +337,24 @@ def step(
         # so episode_return stays the true game score.
         reward = jnp.where(lost_life & ~game_over, -1.0, reward)
 
-    # Auto-reset game-over slots (fresh board; obs = reset observation).
+    # Auto-reset game-over slots: select the game state, never pictures.
     fresh = _reset_fields(n)
-    with jax.named_scope(scopes.RENDER):
-        raw0 = jax.vmap(_render)(
-            fresh["bricks"], fresh["paddle_x"], fresh["ball_dead"],
-            fresh["ball_x"], fresh["ball_y"])
-        stack0 = pixel_jax.reset_stack(raw0)
-
     pick = pixel_jax.make_pick(game_over)
-    new_state = BreakoutState(
-        bricks=pick(fresh["bricks"], bricks),
-        lives=pick(fresh["lives"], lives),
-        frames=pick(fresh["frames"], frames),
-        paddle_x=pick(fresh["paddle_x"], paddle_x),
-        ball_dead=pick(fresh["ball_dead"], ball_dead),
-        ball_x=pick(fresh["ball_x"], ball_x),
-        ball_y=pick(fresh["ball_y"], ball_y),
-        vx=pick(fresh["vx"], vx),
-        vy=pick(fresh["vy"], vy),
-        prev_raw=pick(raw0, raw),
-        stack=pick(stack0, stack),
-        returns=pick(fresh["returns"], returns),
-    )
-    return new_state, new_state.stack, reward, done, episode_return
+    fields = {k: pick(fresh[k], v) for k, v in dict(
+        bricks=bricks, lives=lives, frames=frames, paddle_x=paddle_x,
+        ball_dead=ball_dead, ball_x=ball_x, ball_y=ball_y, vx=vx, vy=vy,
+        returns=returns).items()}
+    entered = {k: pick(fresh[k], getattr(state, k)) for k in _DRAWN}
+
+    with jax.named_scope(scopes.RENDER):
+        maxed = jnp.maximum(_render_batch(fields), _render_batch(entered))
+        frame = jax.vmap(_preprocess)(maxed)
+        older = jnp.where(game_over[:, None, None, None], jnp.uint8(0),
+                          state.stack[..., 1:])
+        stack = jnp.concatenate([older, frame[..., None]], axis=-1)
+
+    new_state = BreakoutState(stack=stack, **fields)
+    return new_state, stack, reward, done, episode_return
 
 
 def completed_episode_mask(done: jax.Array, new_state: BreakoutState) -> jax.Array:

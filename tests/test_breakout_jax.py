@@ -6,12 +6,16 @@ JAX env must reproduce frames, physics, rewards, and the stacked
 observation stream from a matched state.
 """
 
+import functools
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent, ImpalaConfig
-from distributed_reinforcement_learning_tpu.envs import breakout_jax, breakout_sim
+from distributed_reinforcement_learning_tpu.envs import breakout_jax, breakout_sim, pixel_jax
 from distributed_reinforcement_learning_tpu.envs.atari import AtariPreprocessor, preprocess_frame
 from distributed_reinforcement_learning_tpu.envs.breakout_sim import BreakoutSimRaw
 from distributed_reinforcement_learning_tpu.runtime.anakin import AnakinImpala
@@ -60,6 +64,35 @@ class TestRenderParity:
                                       want[breakout_sim.WALL_TOP:])
         assert (got[:breakout_sim.WALL_TOP] == 0).all()
 
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_random_boards_match_numpy_render(self, seed):
+        """Any board, paddle and ball (over bricks, beside walls, dead):
+        the frame made from `repeat` + `pad` masks is the simulator's."""
+        rng = np.random.default_rng(seed)
+        n = 16
+        bricks = rng.random((n, 6, 18)) < 0.5
+        paddle_x = rng.integers(breakout_sim.WALL_SIDE,
+                                breakout_sim.W - breakout_sim.WALL_SIDE
+                                - breakout_sim.PADDLE_W + 1, n)
+        dead = rng.random(n) < 0.25
+        ball_x = rng.uniform(breakout_sim.WALL_SIDE, breakout_sim.W - 10, n)
+        ball_y = rng.uniform(breakout_sim.WALL_TOP + 4, breakout_sim.H - 2, n)
+        ball_y[:4] = rng.uniform(breakout_sim.BRICK_TOP, breakout_sim.BRICK_TOP + 36, 4)
+        got = np.asarray(jax.vmap(breakout_jax._render)(
+            jnp.asarray(bricks), jnp.asarray(paddle_x, jnp.float32),
+            jnp.asarray(dead), jnp.asarray(ball_x, jnp.float32),
+            jnp.asarray(ball_y, jnp.float32)))
+        core = breakout_sim.BreakoutCore(seed=0)
+        for i in range(n):
+            core.reset()
+            core.bricks[:] = bricks[i]
+            core.paddle_x = int(paddle_x[i])
+            core._ball_dead = bool(dead[i])
+            core.ball_x, core.ball_y = float(np.float32(ball_x[i])), float(np.float32(ball_y[i]))
+            np.testing.assert_array_equal(
+                got[i, breakout_sim.WALL_TOP:],
+                core.render()[breakout_sim.WALL_TOP:], err_msg=f"env {i}")
+
     def test_preprocess_matches_host_pipeline(self):
         """luma+resize+crop on device == `atari.preprocess_frame` (u8 +-1
         from float-association differences in the resize matmuls)."""
@@ -81,6 +114,10 @@ class TestDynamicsParity:
         obs_h = pre.reset()
         core = pre.env._core
         launched(core)
+        # The JAX env keeps no raw frame: its 2-frame max redraws the last
+        # one from the state it is handed, so to that env the ball was in
+        # flight in the last frame too. Show the host the same last frame.
+        pre._raw_buffer[-1] = core.render()
 
         state, obs_j = breakout_jax.reset(jax.random.PRNGKey(0), 1)
         state = jax_launched(state)
@@ -206,8 +243,6 @@ class TestAnakinBreakout:
         assert np.isfinite(np.asarray(m["total_loss"])).all()
 
     def test_obs_shape_guard(self):
-        import pytest
-
         with pytest.raises(ValueError):
             AnakinImpala(ImpalaAgent(self.cfg(obs_shape=(4,), num_actions=4)),
                          2, env=breakout_jax)
@@ -235,3 +270,131 @@ class TestAnakinBreakout:
             lambda a, b: np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5),
             jax.device_get(ref_st.train.params), jax.device_get(st.train.params))
+
+
+# -- parity with the formulation that carried the RGB raster ----------------
+
+
+class _RasterState(NamedTuple):
+    """PR 24's state: the game, and the last RGB frame beside it."""
+
+    game: breakout_jax.BreakoutState
+    prev_raw: jax.Array  # [N, 210, 160, 3] u8
+
+
+def _raster_reset(n):
+    state, obs = breakout_jax.reset(jax.random.PRNGKey(0), n)
+    return _RasterState(state, breakout_jax._render_batch(state._asdict())), obs
+
+
+@functools.partial(jax.jit, static_argnames=("max_frames",))
+def _raster_step(rs, actions, rng, max_frames):
+    """`breakout_jax.step` as PR 24 had it, kept plain: the state carries
+    the RGB raster; render the live board AND the reset board, 2-frame
+    max against the carried raster, `preprocess`, and select the PICTURES
+    (raster and stack) on game-over."""
+    state, prev_raw = rs
+    n = state.lives.shape[0]
+    draws = jax.random.randint(rng, (4, n), 0, 4)
+    launch_vx = jnp.asarray([-2.0, -1.0, 1.0, 2.0], jnp.float32)[draws]
+    carry = (state.bricks, state.lives, state.frames, state.paddle_x,
+             state.ball_dead, state.ball_x, state.ball_y, state.vx, state.vy,
+             jnp.zeros((n,), jnp.float32), jnp.zeros((n,), bool))
+    emulate = jax.vmap(breakout_jax._emulate_frame, in_axes=(0, 0, 0, None))
+    for i in range(4):
+        carry = emulate(carry, actions.astype(jnp.int32), launch_vx[i],
+                        max_frames)
+    *live, reward, game_over = carry
+    names = ("bricks", "lives", "frames", "paddle_x", "ball_dead", "ball_x",
+             "ball_y", "vx", "vy")
+    live = dict(zip(names, live))
+
+    raw = breakout_jax._render_batch(live)
+    stack = pixel_jax.observe(raw, prev_raw, state.stack)
+
+    live["returns"] = state.returns + reward
+    episode_return = jnp.where(game_over, live["returns"], 0.0)
+    lost_life = live["lives"] < state.lives
+    done = game_over | lost_life
+    reward = jnp.where(lost_life & ~game_over, -1.0, reward)
+
+    fresh = breakout_jax._reset_fields(n)
+    raw0 = breakout_jax._render_batch(fresh)
+    stack0 = pixel_jax.reset_stack(raw0)
+    pick = pixel_jax.make_pick(game_over)
+    new_state = breakout_jax.BreakoutState(
+        stack=pick(stack0, stack), **{k: pick(fresh[k], live[k]) for k in live})
+    return (_RasterState(new_state, pick(raw0, raw)), new_state.stack, reward,
+            done, episode_return)
+
+
+class TestNoRasterInState:
+    """`step` selects the game state on auto-reset and renders once; the
+    parent selected the pictures and carried the last RGB frame."""
+
+    N, STEPS, MAX_FRAMES = 8, 100, 240
+
+    def _rollout(self):
+        """Envs 0-3 play at random (life losses); 4-5 enter with one life
+        and a falling ball (game over by lives); 6 with one brick left
+        right above a rising ball (cleared board); 7 idles on NOOP into
+        `max_frames`. Yields what both formulations return each step."""
+        n = self.N
+        rs, obs_r = _raster_reset(n)
+        state, obs = breakout_jax.reset(jax.random.PRNGKey(0), n)
+        np.testing.assert_array_equal(np.asarray(obs), np.asarray(obs_r))
+
+        def edit(s):
+            i = jnp.arange(n)
+            dying, clearing = (i == 4) | (i == 5), i == 6
+            launched = dying | clearing
+            last = jnp.zeros((6, 18), bool).at[5, 9].set(True)
+            return s._replace(
+                lives=jnp.where(dying, 1, s.lives),
+                bricks=jnp.where(clearing[:, None, None], last, s.bricks),
+                ball_dead=s.ball_dead & ~launched,
+                ball_x=jnp.where(launched, 83.0, s.ball_x),
+                ball_y=jnp.where(dying, 190.0, jnp.where(clearing, 100.0, s.ball_y)),
+                vy=jnp.where(dying, 3.0, jnp.where(clearing, -3.0, s.vy)))
+
+        state, rs = edit(state), rs._replace(game=edit(rs.game))
+        rng = np.random.default_rng(11)
+        for t in range(self.STEPS):
+            a = rng.integers(0, 4, size=n)
+            a[7] = breakout_sim.NOOP
+            a, key = jnp.asarray(a), jax.random.PRNGKey(1000 + t)
+            out = breakout_jax.step(state, a, key, max_frames=self.MAX_FRAMES)
+            out_r = _raster_step(rs, a, key, max_frames=self.MAX_FRAMES)
+            state, rs = out[0], out_r[0]
+            yield t, out, out_r
+
+    def test_bit_identical_to_the_raster_formulation(self):
+        seen = dict(life_loss=0, game_over=0, cleared=0, timed_out=0,
+                    after_reset=0)
+        was_over = np.zeros(self.N, bool)
+        for t, out, out_r in self._rollout():
+            (state, obs, reward, done, ep), (rs, obs_r, *rest_r) = out, out_r
+            for name, got, want in zip(("obs", "reward", "done", "episode_return"),
+                                       (obs, reward, done, ep), (obs_r, *rest_r)):
+                np.testing.assert_array_equal(
+                    np.asarray(got), np.asarray(want), err_msg=f"{name}, step {t}")
+            for name in state._fields:
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(state, name)),
+                    np.asarray(getattr(rs.game, name)),
+                    err_msg=f"state.{name}, step {t}")
+            over = np.asarray(breakout_jax.completed_episode_mask(done, state))
+            seen["life_loss"] += int((np.asarray(done) & ~over).sum())
+            seen["game_over"] += int(over[4:6].sum())
+            seen["cleared"] += int(over[6])
+            seen["timed_out"] += int(over[7])
+            seen["after_reset"] += int(was_over.sum())
+            was_over = over
+        assert all(seen.values()), f"rollout never covered: {seen}"
+
+    def test_no_leaf_of_the_state_is_larger_than_the_observation(self):
+        state, obs = jax.eval_shape(
+            lambda: breakout_jax.reset(jax.random.PRNGKey(0), self.N))
+        assert "prev_raw" not in state._fields
+        for name, leaf in state._asdict().items():
+            assert leaf.size * leaf.dtype.itemsize <= obs.size * obs.dtype.itemsize, name

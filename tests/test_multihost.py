@@ -106,7 +106,9 @@ def test_socket_topology_two_learners_with_restart(tmp_path):
                         break
                 except (ConnectionError, OSError):
                     pass  # learners still compiling/binding
-                _time.sleep(2.0)
+                # 12 updates take under a second on an idle machine: a slower
+                # poll can miss every version between 3 and the learners' exit.
+                _time.sleep(0.2)
             for c in clients.values():
                 c.close()
             return seen

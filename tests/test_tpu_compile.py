@@ -278,3 +278,28 @@ def test_anakin_chunk_compiles(chip, kernels_as_on_chip):
     state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
     compiled = anakin.train_chunk.lower(_on(chip, state), 2).compile()
     assert _kernel_calls(compiled, "vtrace_pallas") == 2
+
+
+def test_breakout_step_keeps_no_raster_and_one_luma(chip):
+    """`breakout_jax.step` at 64 envs: no RGB raster `u8[N,210,160,3]` is
+    a buffer of the compiled step (inside a fusion it is never written),
+    and one reduction makes the `[N,210,160]` luma. The step that carried
+    the last frame as state and rendered the reset board beside the live
+    one (before PR 25) shows ten such buffers and two reductions."""
+    import re
+
+    from distributed_reinforcement_learning_tpu.envs import breakout_jax
+
+    n = 64
+    state = jax.eval_shape(
+        lambda: breakout_jax.reset(jax.random.PRNGKey(0), n)[0])
+    text = breakout_jax.step.lower(
+        _on(chip, state),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=chip),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=chip),
+    ).compile().as_text()
+    buffers = text[text.index("\nENTRY"):]  # fused computations come before
+    assert f"u8[{n},210,160,3]" not in buffers
+    assert f"u8[{n},210,160,3]" in text  # the frame exists, inside a fusion
+    lumas = re.findall(rf"= \w+\[{n},210,160\]\S* reduce\(", text)
+    assert len(lumas) == 1, lumas
