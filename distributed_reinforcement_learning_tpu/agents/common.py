@@ -15,6 +15,8 @@ import jax.numpy as jnp
 import optax
 from flax import struct
 
+from distributed_reinforcement_learning_tpu.observability import scopes
+
 
 @struct.dataclass
 class TrainState:
@@ -208,7 +210,7 @@ def epsilon_greedy(
 
 
 def sequence_double_q_td(main_q, target_q, action, reward, discounts,
-                         *, burn_in: int, rescale_eps: float):
+                         *, burn_in: int, rescale_eps: float, n_step: int = 1):
     """Shared R2D2-family target math (`agent/r2d2.py:64-87`).
 
     Burn-in slice, (t, t+1) alignment, double-Q action selection on the
@@ -216,6 +218,14 @@ def sequence_double_q_td(main_q, target_q, action, reward, discounts,
     Inputs are full-sequence `[B, T, ...]`; returns (target_value, sav)
     over the supervised positions. One implementation serves both the
     LSTM and the transformer agents so the replay semantics cannot drift.
+
+    `n_step` > 1 (paper: 5): the target at t is
+    h(sum_{k<n} (prod_{j<k} d_{t+j}) r_{t+k}
+      + (prod_{j<n} d_{t+j}) h^-1(Q_target(s_{t+n}, argmax_a Q(s_{t+n}, a))))
+    with d = `discounts` (gamma, 0 after a done). Where t + n runs past
+    the sequence the horizon is cut at its last step, as
+    `rlax.n_step_bootstrapped_returns` does: the last positions bootstrap
+    from the last state over fewer steps.
     """
     from distributed_reinforcement_learning_tpu.ops import dqn, value_rescale
 
@@ -228,7 +238,21 @@ def sequence_double_q_td(main_q, target_q, action, reward, discounts,
     next_sav = dqn.take_state_action_value(target_b[:, 1:], next_action)
 
     descaled = value_rescale.inverse_value_rescale(next_sav, rescale_eps)
-    raw_target = jax.lax.stop_gradient(descaled * disc_b[:, :-1] + reward_b[:, :-1])
+    if n_step == 1:
+        raw_target = descaled * disc_b[:, :-1] + reward_b[:, :-1]
+    else:
+        # descaled[:, t] is the value of s_{t+1}. Start from the value
+        # n steps ahead (the last one where that runs off the end) and
+        # fold the rewards in back to front; the padding (reward 0,
+        # discount 1) makes the steps past the end the identity.
+        steps = descaled.shape[1]
+        pad = ((0, 0), (0, n_step - 1))
+        raw_target = jnp.pad(descaled, pad, mode="edge")[:, n_step - 1:]
+        rew = jnp.pad(reward_b[:, :-1], pad)
+        disc = jnp.pad(disc_b[:, :-1], pad, constant_values=1.0)
+        for k in reversed(range(n_step)):
+            raw_target = rew[:, k:k + steps] + disc[:, k:k + steps] * raw_target
+    raw_target = jax.lax.stop_gradient(raw_target)
     target_value = value_rescale.value_rescale(raw_target, rescale_eps)
     return target_value, sav
 
@@ -236,11 +260,13 @@ def sequence_double_q_td(main_q, target_q, action, reward, discounts,
 class SequenceReplayLearnMixin:
     """td_error/loss/learn shared by the sequence-replay agents.
 
-    Host class provides `_sequence_td(params, target_params, batch)`
-    -> (target_value, sav) — optionally with a third scalar model aux
-    loss (e.g. the MoE router's load-balancing term), added to the TD
-    loss as-is — and `self.tx`. Loss = IS-weighted mean over time of
-    squared TD (`agent/r2d2.py:88-89`).
+    Host class provides `_sequence_td(params, target_params, batch,
+    unroll_scope=None)` -> (target_value, sav) — optionally with a third
+    scalar model aux loss (e.g. the MoE router's load-balancing term),
+    added to the TD loss as-is — and `self.tx`. `unroll_scope` is the
+    profile name for a sequential recurrence inside the forward, given
+    by the learn step only (a family without one ignores it). Loss =
+    IS-weighted mean over time of squared TD (`agent/r2d2.py:88-89`).
 
     Priority: the reference's quirk |mean_t TD| (`agent/r2d2.py:151-153`
     — signed TDs cancel across the sequence, so a high-error sequence
@@ -263,8 +289,10 @@ class SequenceReplayLearnMixin:
         tv, sav = self._sequence_td(state.params, state.target_params, batch)[:2]
         return self._seq_priority(tv, sav)
 
+    @jax.named_scope(scopes.LOSS)
     def _loss(self, params, target_params, batch, is_weight):
-        out = self._sequence_td(params, target_params, batch)
+        out = self._sequence_td(params, target_params, batch,
+                                unroll_scope=scopes.UNROLL)
         tv, sav = out[:2]
         aux = out[2] if len(out) > 2 else 0.0
         per_seq = jnp.mean(jnp.square(tv - sav), axis=1)
@@ -272,6 +300,7 @@ class SequenceReplayLearnMixin:
         priorities = self._seq_priority(tv, sav)
         return loss, priorities
 
+    @jax.named_scope(scopes.LEARN)
     def _learn(self, state, batch, is_weight, axis_name: str | None = None):
         (loss, priorities), grads = jax.value_and_grad(self._loss, has_aux=True)(
             state.params, state.target_params, batch, is_weight
@@ -282,8 +311,9 @@ class SequenceReplayLearnMixin:
             # gradient so replicated params stay identical across devices.
             grads = jax.lax.pmean(grads, axis_name)
             loss = jax.lax.pmean(loss, axis_name)
-        updates, opt_state = self.tx.update(grads, state.opt_state, state.params)
-        params = jax.tree.map(lambda p, u: p + u, state.params, updates)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, opt_state = self.tx.update(grads, state.opt_state, state.params)
+            params = jax.tree.map(lambda p, u: p + u, state.params, updates)
         new_state = state.replace(params=params, opt_state=opt_state, step=state.step + 1)
         metrics = {"loss": loss, "grad_norm": global_norm(grads)}
         return new_state, priorities, metrics

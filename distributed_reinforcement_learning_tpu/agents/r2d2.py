@@ -56,6 +56,11 @@ class R2D2Config:
     torso_width: int = 1
     # Fold /255 into conv0 (conv torsos): uint8 frames feed the model raw.
     fold_normalize: bool = False
+    # n-step double-Q targets (paper: 5); 1 = the reference's 1-step.
+    n_step: int = 1
+    # None = the reference's Dense(128) head with a learned mean; an
+    # integer = the paper's two dueling streams of that width (512).
+    dueling_hidden: int | None = None
 
 
 class R2D2Batch(NamedTuple):
@@ -76,7 +81,8 @@ class R2D2Agent(common.SequenceReplayLearnMixin):
         self.model = R2D2Net(num_actions=cfg.num_actions, lstm_size=cfg.lstm_size,
                              dtype=cfg.dtype, torso=cfg.torso,
                              torso_width=cfg.torso_width,
-                             fold_normalize=cfg.fold_normalize)
+                             fold_normalize=cfg.fold_normalize,
+                             dueling_hidden=cfg.dueling_hidden)
         self.tx = common.adam_with_clip(cfg.learning_rate,
                                         clip_norm=cfg.gradient_clip_norm)
         self.act = jax.jit(self._act)
@@ -121,13 +127,15 @@ class R2D2Agent(common.SequenceReplayLearnMixin):
     # _td_error/_loss/_learn come from SequenceReplayLearnMixin; this
     # supplies the model forward. Burn-in, double-Q, and rescaling live
     # in `common.sequence_double_q_td` (`agent/r2d2.py:64-87`).
-    def _sequence_td(self, params, target_params, batch: R2D2Batch):
+    def _sequence_td(self, params, target_params, batch: R2D2Batch,
+                     unroll_scope: str | None = None):
         cfg = self.cfg
         obs = self._prep_obs(batch.state)
         unroll = lambda p: self.model.apply(
             p, obs, batch.previous_action, batch.done, batch.initial_h, batch.initial_c,
-            method=self.model.unroll)
+            unroll_scope, method=self.model.unroll)
         discounts = (~batch.done).astype(jnp.float32) * cfg.discount_factor
         return common.sequence_double_q_td(
             unroll(params), unroll(target_params), batch.action, batch.reward,
-            discounts, burn_in=cfg.burn_in, rescale_eps=cfg.rescale_eps)
+            discounts, burn_in=cfg.burn_in, rescale_eps=cfg.rescale_eps,
+            n_step=cfg.n_step)

@@ -273,7 +273,9 @@ class XformerAgent(common.SequenceReplayLearnMixin):
     # supplies the transformer forward. Replay semantics live in
     # `common.sequence_double_q_td` — shared with the LSTM agent so the
     # two families cannot drift.
-    def _sequence_td(self, params, target_params, batch: XformerBatch, model=None):
+    def _sequence_td(self, params, target_params, batch: XformerBatch, model=None,
+                     unroll_scope: str | None = None):
+        del unroll_scope  # attention has no sequential recurrence to name
         cfg = self.cfg
         model = model or self.model
         obs = common.normalize_obs(batch.state, self.cfg.dtype)

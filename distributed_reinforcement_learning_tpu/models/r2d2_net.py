@@ -11,6 +11,8 @@ the sequence-start stored state like the reference
 
 from __future__ import annotations
 
+import contextlib
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -23,7 +25,13 @@ _glorot = nn.initializers.xavier_uniform()
 
 
 class R2D2Net(nn.Module):
-    """Torso + action embed -> LSTM -> dueling head (value - mean).
+    """Torso + action embed -> LSTM -> dueling head.
+
+    `dueling_hidden=None` is the reference's head: `Dense(128)` ->
+    `Dense(A)` minus a LEARNED `Dense(1)` mean. An integer (paper: 512)
+    is the published dueling head (Wang et al. 2016 as Kapturowski et
+    al. 2019 use it): a value stream `Dense(n) -> Dense(1)` and an
+    advantage stream `Dense(n) -> Dense(A)`, Q = V + A - mean_a A.
 
     Single-step signature matches `model/r2d2_lstm.py:26-47`: returns
     (q_value [N, A], h, c).
@@ -45,6 +53,7 @@ class R2D2Net(nn.Module):
     # Fold /255 into conv0's kernel; integer frames flow in raw
     # (see NatureConv). Conv torsos only.
     fold_normalize: bool = False
+    dueling_hidden: int | None = None
 
     def setup(self):
         if self.torso == "mlp":
@@ -61,9 +70,27 @@ class R2D2Net(nn.Module):
                     dtype=self.dtype, input_scale=scale, name="torso")
         self.action_embed = ActionEmbedding(self.num_actions, dtype=self.dtype)
         self.cell = LSTMCell(self.lstm_size, dtype=self.dtype, backend=self.cell_backend)
-        self.head_fc = nn.Dense(128, kernel_init=_glorot, dtype=self.dtype)
-        self.value = nn.Dense(self.num_actions, kernel_init=_glorot, dtype=self.dtype)
-        self.mean = nn.Dense(1, kernel_init=_glorot, dtype=self.dtype)
+        dense = lambda n: nn.Dense(n, kernel_init=_glorot, dtype=self.dtype)
+        if self.dueling_hidden is None:
+            self.head_fc = dense(128)
+            self.value = dense(self.num_actions)
+            self.mean = dense(1)
+        else:
+            self.value_fc = dense(self.dueling_hidden)
+            self.value_out = dense(1)
+            self.advantage_fc = dense(self.dueling_hidden)
+            self.advantage_out = dense(self.num_actions)
+
+    def _head(self, h: jax.Array) -> jax.Array:
+        """LSTM outputs `[..., H]` -> Q-values `[..., A]`, float32."""
+        if self.dueling_hidden is None:
+            q = nn.relu(self.head_fc(h))
+            q = self.value(q) - self.mean(q)
+        else:
+            v = self.value_out(nn.relu(self.value_fc(h)))
+            adv = self.advantage_out(nn.relu(self.advantage_fc(h)))
+            q = v + adv - jnp.mean(adv, axis=-1, keepdims=True)
+        return q.astype(jnp.float32)
 
     def _torso(self, x: jax.Array) -> jax.Array:
         """[N, ...obs] -> [N, F] features."""
@@ -84,14 +111,13 @@ class R2D2Net(nn.Module):
         a = self.action_embed(prev_action)
         z = jnp.concatenate([x, a], axis=-1)
         new_h, new_c = self.cell(z, h, c)
-        q = nn.relu(self.head_fc(new_h))
-        q = self.value(q) - self.mean(q)
-        return q.astype(jnp.float32), new_h, new_c
+        return self._head(new_h), new_h, new_c
 
     def __call__(self, obs, prev_action, h, c):
         return self.step(obs, prev_action, h, c)
 
-    def unroll(self, obs_seq, prev_action_seq, done_seq, h0, c0):
+    def unroll(self, obs_seq, prev_action_seq, done_seq, h0, c0,
+               scope: str | None = None):
         """Q-values over a `[B, T, ...]` sequence from stored start state.
 
         done-masked like `model/r2d2_lstm.py:78-80`: (h, c) are zeroed
@@ -105,13 +131,16 @@ class R2D2Net(nn.Module):
         (`model/r2d2_lstm.py:65-112`). Conv torsos flatten [B, T] into
         the batch dim for the pass (2-D feature maps keep their own
         trailing dims).
+
+        `scope`: a `jax.named_scope` around the recurrence alone; the
+        learn step names it (observability/scopes.py UNROLL), the
+        scoring of new sequences does not.
         """
         B, T = obs_seq.shape[:2]
         x = self._torso(obs_seq.reshape((B * T,) + obs_seq.shape[2:]))
         x = x.reshape((B, T, -1))
         a = self.action_embed(prev_action_seq)
         z = jnp.concatenate([x, a], axis=-1)
-        h_all, _ = self.cell.unroll(z, done_seq, h0, c0)
-        q = nn.relu(self.head_fc(h_all))
-        q = self.value(q) - self.mean(q)
-        return q.astype(jnp.float32)
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
+            h_all, _ = self.cell.unroll(z, done_seq, h0, c0)
+        return self._head(h_all)

@@ -27,7 +27,7 @@ import functools
 # of a jitted function is the HLO module's name, which IS in the key, so
 # the jitted steps that carry scopes go through `tagged`. Bump the tag
 # when a scope is added, renamed or moved; nothing else reads it.
-CACHE_TAG = "s1"
+CACHE_TAG = "s2"
 
 
 def tagged(fn):
@@ -49,14 +49,21 @@ RENDER = "collect/env/render"  # raw screen, 2-frame max, luma, resize, stack
 RECORD = "collect/record"  # the per-step record + carry of the rollout
 TO_BATCH_MAJOR = "to_batch_major"  # [T, B, ...] rollout -> [B, T, ...] batch
 REPLAY = "replay"  # device ring: ingest, sample, priority write-back
+REPLAY_SCORE = "replay/score"  # TD error of the new sequences, both nets
+REPLAY_WRITE = "replay/write"  # the ring write at `ptr`
+REPLAY_SAMPLE = "replay/sample"  # cumsum, stratified search, batch gather
+REPLAY_PRIORITIES = "replay/priorities"  # write-back of sampled priorities
 LEARN = "learn"  # all of one optimizer step
 LOSS = "learn/loss"  # forward (backward: transpose(jvp(learn/loss)))
+UNROLL = "learn/loss/unroll"  # the LSTM recurrence alone, learn step only
 VTRACE = "learn/vtrace"  # V-trace targets (Pallas kernel on the TPU)
 OPTIMIZER = "learn/optimizer"  # optimizer update + parameter add
 
 IMPALA_CHUNK_SCOPES = (COLLECT, ACT, ENV, RENDER, RECORD, TO_BATCH_MAJOR,
                        LEARN, LOSS, VTRACE, OPTIMIZER)
 REPLAY_CHUNK_SCOPES = (COLLECT, REPLAY, LEARN)
+R2D2_CHUNK_SCOPES = (ACT, ENV, RECORD, REPLAY_SCORE, REPLAY_WRITE,
+                     REPLAY_SAMPLE, REPLAY_PRIORITIES, LOSS, UNROLL, OPTIMIZER)
 
 # -- host spans of the fused loops (runtime/launch.py) ---------------------
 STEP_READ = "anakin/step_read"  # int(state.train.step) at the loop head

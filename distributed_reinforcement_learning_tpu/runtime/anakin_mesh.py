@@ -29,6 +29,7 @@ PER-DEVICE count — chunk metrics report the psum'd global `replay_size`.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_reinforcement_learning_tpu.data.device_replay import DeviceReplay
@@ -86,6 +87,11 @@ class DataMeshReplayMixin:
     (`self.num_envs_local`, `self.batch_local`); this mixin wires the
     single-device jit vs shard_map dispatch, the per-device rng split at
     init, and the psum/pmean metric reducers.
+
+    Both chunks DONATE their state: the ring is the state (6.9 GB at the
+    `r2d2_atari` section's sizes), and an undonated chunk holds it twice,
+    as input and as output. A caller rebinds (`state, m =
+    train_chunk(state, n)`) and keeps no reference into the old state.
     """
 
     def _setup_mesh(self, mesh, *, num_envs: int, batch_size: int,
@@ -98,9 +104,9 @@ class DataMeshReplayMixin:
         self._axis = DATA_AXIS if mesh is not None else None
         if mesh is None:
             self.train_chunk = jax.jit(scopes.tagged(self._train_chunk),
-                                       static_argnums=(1,))
+                                       static_argnums=(1,), donate_argnums=(0,))
             self.collect_chunk = jax.jit(scopes.tagged(self._collect_chunk),
-                                         static_argnums=(1,))
+                                         static_argnums=(1,), donate_argnums=(0,))
         else:
             self._specs = self._state_specs()
             self.train_chunk = shard_mapped_chunk(
@@ -110,17 +116,34 @@ class DataMeshReplayMixin:
 
     def _place_init(self, state, k_run):
         """Mesh mode: one independent rng stream per device, state placed
-        into its shardings. No-op single-device."""
-        if self.mesh is None:
-            return state
-        state = state._replace(rng=jax.random.split(k_run, self.dshard))
-        return jax.device_put(state, state_shardings(self.mesh, self._specs))
+        into its shardings. Either way every leaf ends in a buffer of its
+        own (`initial_lstm_state` hands out one zero array as h and as c),
+        since a donated state cannot hold one buffer twice."""
+        if self.mesh is not None:
+            state = state._replace(rng=jax.random.split(k_run, self.dshard))
+            state = jax.device_put(state,
+                                   state_shardings(self.mesh, self._specs))
+        seen = set()
+
+        def own(x):
+            if isinstance(x, jax.core.Tracer):  # `jax.eval_shape(init)`
+                return x
+            buffer = x.addressable_shards[0].data.unsafe_buffer_pointer()
+            if buffer in seen:
+                return jnp.copy(x)
+            seen.add(buffer)
+            return x
+
+        return jax.tree.map(own, state)
 
     def _psum(self, tree):
         return jax.lax.psum(tree, self._axis) if self._axis else tree
 
     def _pmean(self, x):
         return jax.lax.pmean(x, self._axis) if self._axis else x
+
+    def _pmax(self, x):
+        return jax.lax.pmax(x, self._axis) if self._axis else x
 
 
 def shard_mapped_chunk(mesh, specs, body):
@@ -144,4 +167,5 @@ def shard_mapped_chunk(mesh, specs, body):
         return f(state)
 
     call.__name__ = body.__name__  # the HLO module is named for the body
-    return jax.jit(scopes.tagged(call), static_argnums=(1,))
+    return jax.jit(scopes.tagged(call), static_argnums=(1,),
+                   donate_argnums=(0,))

@@ -177,24 +177,28 @@ class AnakinR2D2(DataMeshReplayMixin):
     def _env_step(self, params, carry, _):
         env, obs, prev_action, h, c, episodes, rng = carry
         rng, k_act, k_env = jax.random.split(rng, 3)
-        action, _q, new_h, new_c = self.agent._act(
-            params, obs, h, c, prev_action, self._epsilon(episodes), k_act)
-        env_action = (action % self.env.NUM_ACTIONS
-                      if self.agent.cfg.num_actions != self.env.NUM_ACTIONS
-                      else action)
-        env, next_obs, reward, done, ep_ret = self.env.step(env, env_action, k_env)
-        mask_fn = getattr(self.env, "completed_episode_mask",
-                          lambda done, _state: done)
-        record = dict(
-            state=obs, previous_action=prev_action, action=action,
-            reward=reward, done=done, episode_return=ep_ret,
-            episode_completed=mask_fn(done, env),
-        )
-        keep = (~done).astype(new_h.dtype)[:, None]
-        carry = (env, self.obs_transform(next_obs),
-                 jnp.where(done, 0, action).astype(jnp.int32),
-                 new_h * keep, new_c * keep,
-                 episodes + done.astype(jnp.int32), rng)
+        with jax.named_scope(scopes.ACT):
+            action, _q, new_h, new_c = self.agent._act(
+                params, obs, h, c, prev_action, self._epsilon(episodes), k_act)
+        with jax.named_scope(scopes.ENV):
+            env_action = (action % self.env.NUM_ACTIONS
+                          if self.agent.cfg.num_actions != self.env.NUM_ACTIONS
+                          else action)
+            env, next_obs, reward, done, ep_ret = self.env.step(
+                env, env_action, k_env)
+        with jax.named_scope(scopes.RECORD):
+            mask_fn = getattr(self.env, "completed_episode_mask",
+                              lambda done, _state: done)
+            record = dict(
+                state=obs, previous_action=prev_action, action=action,
+                reward=reward, done=done, episode_return=ep_ret,
+                episode_completed=mask_fn(done, env),
+            )
+            keep = (~done).astype(new_h.dtype)[:, None]
+            carry = (env, self.obs_transform(next_obs),
+                     jnp.where(done, 0, action).astype(jnp.int32),
+                     new_h * keep, new_c * keep,
+                     episodes + done.astype(jnp.int32), rng)
         return carry, record
 
     def _collect(self, state: AnakinR2D2State):
@@ -208,13 +212,13 @@ class AnakinR2D2(DataMeshReplayMixin):
             carry, rec = jax.lax.scan(
                 functools.partial(self._env_step, state.train.params), carry,
                 None, length=cfg.seq_len)
-        env, obs, prev_action, h, c, episodes, rng = carry
-        bt = lambda name: jnp.swapaxes(rec[name], 0, 1)
-        batch = R2D2Batch(
-            state=bt("state"), previous_action=bt("previous_action"),
-            action=bt("action"), reward=bt("reward"), done=bt("done"),
-            initial_h=h0, initial_c=c0,
-        )
+            env, obs, prev_action, h, c, episodes, rng = carry
+            bt = lambda name: jnp.swapaxes(rec[name], 0, 1)
+            batch = R2D2Batch(
+                state=bt("state"), previous_action=bt("previous_action"),
+                action=bt("action"), reward=bt("reward"), done=bt("done"),
+                initial_h=h0, initial_c=c0,
+            )
         stats = {
             "episode_return_sum": rec["episode_return"].sum(),
             "episodes_done": rec["episode_completed"].sum().astype(jnp.float32),
@@ -224,14 +228,15 @@ class AnakinR2D2(DataMeshReplayMixin):
                                    h=h, c=c, episodes=episodes, rng=rng)
         return new_state, batch, stats
 
-    @jax.named_scope(scopes.REPLAY)
     def _ingest(self, train, replay: DeviceReplay, batch: R2D2Batch
                 ) -> DeviceReplay:
         """Score + write B new sequences into the ring at `ptr`."""
-        errs = self.agent._td_error(train, batch)  # [B]
-        return device_replay.ingest(replay, batch, errs)
+        with jax.named_scope(scopes.REPLAY_SCORE):
+            errs = self.agent._td_error(train, batch)  # [B]
+        with jax.named_scope(scopes.REPLAY_WRITE):
+            return device_replay.ingest(replay, batch, errs)
 
-    @jax.named_scope(scopes.REPLAY)
+    @jax.named_scope(scopes.REPLAY_SAMPLE)
     def _sample(self, replay: DeviceReplay, rng: jax.Array):
         return device_replay.sample(replay, rng, self.batch_local,
                                     axis_name=self._axis)
@@ -246,15 +251,15 @@ class AnakinR2D2(DataMeshReplayMixin):
             train, replay, rng = carry
             rng, k = jax.random.split(rng)
             replay, batch, idx, weights = self._sample(replay, k)
-            with jax.named_scope(scopes.LEARN):
-                train, new_err, metrics = self.agent._learn(
-                    train, batch, weights, axis_name=self._axis)
-            with jax.named_scope(scopes.REPLAY):
+            # `_learn` names itself (scopes.LEARN and below).
+            train, new_err, metrics = self.agent._learn(
+                train, batch, weights, axis_name=self._axis)
+            with jax.named_scope(scopes.REPLAY_PRIORITIES):
                 replay = device_replay.update_priorities(replay, idx, new_err)
-            return (train, replay, rng), metrics
+            return (train, replay, rng), (metrics, weights.min())
 
         rng, k_learn = jax.random.split(state.rng)
-        (train, replay, _), metrics = jax.lax.scan(
+        (train, replay, _), (metrics, weight_min) = jax.lax.scan(
             one_learn, (train, replay, k_learn), None,
             length=self.updates_per_collect)
         metrics = jax.tree.map(lambda m: m[-1], metrics)
@@ -268,6 +273,13 @@ class AnakinR2D2(DataMeshReplayMixin):
         last_sync = jnp.where(do_sync, train.step, state.last_sync)
         metrics.update(self._psum(stats))
         metrics["replay_size"] = self._psum(replay.size.astype(jnp.float32))
+        # Counters of the ring (alpha-transformed priorities; empty slots
+        # hold 0) and of the learn steps of this update.
+        metrics["priority_mean"] = self._pmean(
+            replay.priorities.sum() / jnp.maximum(replay.size, 1))
+        metrics["priority_max"] = self._pmax(replay.priorities.max())
+        metrics["is_weight_min"] = -self._pmax(-weight_min.min())
+        metrics["target_syncs"] = do_sync.astype(jnp.float32)
         metrics["epsilon_mean"] = self._pmean(
             self._epsilon(state.episodes).mean())
         return state._replace(train=train, replay=replay, rng=rng,

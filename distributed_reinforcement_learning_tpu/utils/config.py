@@ -148,6 +148,10 @@ def load_config(path: str | Path, section: str):
             torso=d.get("torso", "mlp"),
             torso_width=d.get("torso_width", 1),
             fold_normalize=d.get("fold_normalize", False),
+            # The paper's Atari configuration (section `r2d2_atari`);
+            # absent = the reference's 1-step targets and Dense(128) head.
+            n_step=d.get("n_step", 1),
+            dueling_hidden=d.get("dueling_hidden", None),
         )
     elif algorithm == "xformer":
         agent_cfg = XformerConfig(

@@ -29,6 +29,7 @@ class TestReferenceSchema:
         ("impala", "impala"), ("apex", "apex"), ("r2d2", "r2d2"),
         ("impala_cartpole", "impala"), ("xformer", "xformer"),
         ("impala_invaders", "impala"), ("r2d2_pixel", "r2d2"),
+        ("r2d2_atari", "r2d2"),
     ])
     def test_repo_config_sections_load(self, section, algo):
         agent_cfg, rt = load_config("config.json", section)

@@ -98,6 +98,32 @@ def test_replay_chunk_carries_top_level_scope(replay_chunk_names, name):
                for n in replay_chunk_names), name
 
 
+@pytest.fixture(scope="module")
+def r2d2_chunk_names():
+    return _op_names(_r2d2().train_chunk, _r2d2().init(jax.random.PRNGKey(0)), 1)
+
+
+@pytest.mark.parametrize("name", scopes.R2D2_CHUNK_SCOPES)
+def test_r2d2_chunk_carries_scope(r2d2_chunk_names, name):
+    """The names `perfbench/layer_metrics/replay_*`, `seq_learn_*` and
+    `lstm_unroll_*` read (ISSUE 26)."""
+    assert any(name in n for n in r2d2_chunk_names), name
+
+
+def test_r2d2_backward_recurrence_keeps_the_unroll_name(r2d2_chunk_names):
+    """The inner scope is entered again inside the transposed outer one:
+    `transpose(jvp(learn/loss))/.../learn/loss/unroll/...`."""
+    assert any(f"transpose(jvp({scopes.LOSS}))" in n and scopes.UNROLL in n
+               for n in r2d2_chunk_names)
+
+
+def test_r2d2_scoring_unroll_is_not_named_for_the_learn_step(r2d2_chunk_names):
+    """The same `R2D2Net.unroll` scores the new sequences; only the
+    learn step's recurrence is `learn/loss/unroll`."""
+    scoring = [n for n in r2d2_chunk_names if scopes.REPLAY_SCORE in n]
+    assert scoring and not any(scopes.LEARN in n for n in scoring)
+
+
 def _host_events(profile_dir: str) -> list[str]:
     from jax.profiler import ProfileData
 

@@ -5,7 +5,8 @@ described, not opened (`/opt/skills/guides/on-chip-measurement`, section
 2). That catches what interpret mode cannot — a slice not aligned to the
 tiling, a kernel over its VMEM budget, a program Mosaic refuses — at the
 shapes the main path really runs (`config.json` sections `impala`,
-`apex`, `r2d2_pixel`; the Anakin chunk `chip_smoke.py` drives), and
+`apex`, `r2d2_pixel`, `r2d2_atari`; the Anakin chunk `chip_smoke.py`
+drives), and
 costs no chip time.
 
 A compile that passes is not a chip run: nothing executes here, so
@@ -278,6 +279,38 @@ def test_anakin_chunk_compiles(chip, kernels_as_on_chip):
     state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
     compiled = anakin.train_chunk.lower(_on(chip, state), 2).compile()
     assert _kernel_calls(compiled, "vtrace_pallas") == 2
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("chunk, n", [("collect_chunk", 8), ("train_chunk", 4)])
+def test_r2d2_atari_chunk_fits_with_its_ring_donated(chip, kernels_as_on_chip,
+                                                     chunk, n):
+    """The fused replay chunks at the `r2d2_atari` section's sizes (256
+    envs, a ring of 2,048 x 120 x 84 x 84 x 4 uint8 = 6.94 GB): the
+    donated state is aliased whole, so the program holds the ring once
+    (undonated: twice, 14.2 GB before any activation), and arguments +
+    scratch stay under the chip's 16.9 GB. No Mosaic kernel: the LSTM
+    stays on the XLA scan."""
+    from distributed_reinforcement_learning_tpu.agents.r2d2 import R2D2Agent
+    from distributed_reinforcement_learning_tpu.envs import breakout_jax
+    from distributed_reinforcement_learning_tpu.runtime.anakin_r2d2 import (
+        AnakinR2D2)
+
+    cfg, rt = load_config(CONFIG, "r2d2_atari")
+    anakin = AnakinR2D2(
+        R2D2Agent(cfg), num_envs=rt.num_actors * rt.envs_per_actor,
+        batch_size=rt.batch_size, capacity=rt.replay_capacity,
+        target_sync_interval=rt.target_sync_interval,
+        updates_per_collect=rt.updates_per_call, env=breakout_jax)
+    state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+    compiled = getattr(anakin, chunk).lower(_on(chip, state), n).compile()
+    mem = compiled.memory_analysis()
+    ring = 2048 * 120 * 84 * 84 * 4
+    assert mem.alias_size_in_bytes == mem.argument_size_in_bytes > ring
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert held < 12e9, held  # 10.7 GB when this was written
+    assert _kernel_calls(compiled) == 0
 
 
 def test_breakout_step_keeps_no_raster_and_one_luma(chip):
