@@ -131,7 +131,7 @@ class AnakinApex(DataMeshReplayMixin):
         train = self.agent.init_state(k_train)
         env, obs = self.env.reset(k_env, self.num_envs)
         obs = self.obs_transform(obs)
-        replay = device_replay.make(self._zero_transitions(obs), self.capacity)
+        replay = device_replay.make(self._transition_entries(obs), self.capacity)
         state = AnakinApexState(
             train=train, replay=replay, env=env, obs=obs,
             prev_action=jnp.zeros(self.num_envs, jnp.int32),
@@ -141,15 +141,16 @@ class AnakinApex(DataMeshReplayMixin):
         )
         return self._place_init(state, k_run)
 
-    def _zero_transitions(self, obs: jax.Array) -> ApexBatch:
-        C = self.capacity
+    def _transition_entries(self, obs: jax.Array) -> ApexBatch:
+        """Shape and dtype of ONE stored transition, leaf by leaf."""
+        entry = jax.ShapeDtypeStruct
+        frame = entry(obs.shape[1:], obs.dtype)
         return ApexBatch(
-            state=jnp.zeros((C, *obs.shape[1:]), obs.dtype),
-            next_state=jnp.zeros((C, *obs.shape[1:]), obs.dtype),
-            previous_action=jnp.zeros((C,), jnp.int32),
-            action=jnp.zeros((C,), jnp.int32),
-            reward=jnp.zeros((C,), jnp.float32),
-            done=jnp.zeros((C,), bool),
+            state=frame, next_state=frame,
+            previous_action=entry((), jnp.int32),
+            action=entry((), jnp.int32),
+            reward=entry((), jnp.float32),
+            done=entry((), jnp.bool_),
         )
 
     # -- collection ------------------------------------------------------

@@ -142,7 +142,7 @@ class AnakinR2D2(DataMeshReplayMixin):
         env, obs = self.env.reset(k_env, self.num_envs)
         obs = self.obs_transform(obs)
         h, c = self.agent.initial_lstm_state(self.num_envs)
-        replay = device_replay.make(self._zero_sequences(), self.capacity)
+        replay = device_replay.make(self._sequence_entries(), self.capacity)
         state = AnakinR2D2State(
             train=train, replay=replay, env=env, obs=obs,
             prev_action=jnp.zeros(self.num_envs, jnp.int32),
@@ -153,20 +153,22 @@ class AnakinR2D2(DataMeshReplayMixin):
         )
         return self._place_init(state, k_run)
 
-    def _zero_sequences(self) -> R2D2Batch:
+    def _sequence_entries(self) -> R2D2Batch:
+        """Shape and dtype of ONE stored sequence, leaf by leaf."""
         cfg = self.agent.cfg
-        obs0 = self.obs_transform(
-            jnp.zeros((1, *self.env.OBS_SHAPE),
-                      jnp.uint8 if len(self.env.OBS_SHAPE) == 3 else jnp.float32))
-        C, T = self.capacity, cfg.seq_len
+        obs = jax.eval_shape(
+            lambda: self.obs_transform(self.env.reset(
+                jax.random.PRNGKey(0), 1)[1]))
+        T = cfg.seq_len
+        entry = jax.ShapeDtypeStruct
         return R2D2Batch(
-            state=jnp.zeros((C, T, *obs0.shape[1:]), obs0.dtype),
-            previous_action=jnp.zeros((C, T), jnp.int32),
-            action=jnp.zeros((C, T), jnp.int32),
-            reward=jnp.zeros((C, T), jnp.float32),
-            done=jnp.zeros((C, T), bool),
-            initial_h=jnp.zeros((C, cfg.lstm_size), jnp.float32),
-            initial_c=jnp.zeros((C, cfg.lstm_size), jnp.float32),
+            state=entry((T, *obs.shape[1:]), obs.dtype),
+            previous_action=entry((T,), jnp.int32),
+            action=entry((T,), jnp.int32),
+            reward=entry((T,), jnp.float32),
+            done=entry((T,), jnp.bool_),
+            initial_h=entry((cfg.lstm_size,), jnp.float32),
+            initial_c=entry((cfg.lstm_size,), jnp.float32),
         )
 
     # -- collection ------------------------------------------------------

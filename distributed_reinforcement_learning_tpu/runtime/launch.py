@@ -21,6 +21,7 @@ from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent, Im
 from distributed_reinforcement_learning_tpu.agents.r2d2 import R2D2Agent, R2D2Config
 from distributed_reinforcement_learning_tpu.agents.xformer import XformerAgent, XformerConfig
 from distributed_reinforcement_learning_tpu.agents.ximpala import XImpalaAgent, XImpalaConfig
+from distributed_reinforcement_learning_tpu.data import device_replay
 from distributed_reinforcement_learning_tpu.data.fifo import TrajectoryQueue
 from distributed_reinforcement_learning_tpu.envs.batched import BatchedEnv
 from distributed_reinforcement_learning_tpu.envs.cartpole import pomdp_project
@@ -390,6 +391,7 @@ def _replay_chunk_loop(anakin, state, num_updates: int, chunk: int, ckpt,
     one collect + K learns (K = updates_per_collect), so chunk sizing
     and the frame count are in collect-updates and the final chunk may
     overshoot by up to K-1 optimizer steps."""
+    print(f"[{label}] {device_replay.describe_storage(state.replay)}")
     state, _ = anakin.collect_chunk(state, warm)
     K = anakin.updates_per_collect
     maybe_configure(label, 0, run_dir)  # env-gated run-wide telemetry

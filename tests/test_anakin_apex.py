@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from distributed_reinforcement_learning_tpu.agents.apex import ApexAgent, ApexConfig
+from distributed_reinforcement_learning_tpu.data import device_replay
 from distributed_reinforcement_learning_tpu.runtime.anakin_apex import AnakinApex
 
 
@@ -91,7 +92,11 @@ class TestPixelSmoke:
         an = AnakinApex(ApexAgent(cfg), num_envs=2, steps_per_collect=3,
                         capacity=12, batch_size=4, env=breakout_jax)
         st = an.init(jax.random.PRNGKey(0))
-        assert st.replay.storage.state.dtype == jnp.uint8
+        # The ring holds the stacks as words; what is sampled is uint8.
+        assert st.replay.storage.state.words.dtype == jnp.uint32
         st, _ = an.collect_chunk(st, 1)
+        sampled = device_replay.sample(st.replay, jax.random.PRNGKey(2), 4)[1]
+        assert (sampled.state.dtype, sampled.next_state.shape) == (
+            jnp.uint8, (4, 84, 84, 4))
         st, m = an.train_chunk(st, 1)
         assert np.isfinite(np.asarray(m["loss"])).all()

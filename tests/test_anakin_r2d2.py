@@ -155,8 +155,11 @@ class TestPixelR2D2:
         an = AnakinR2D2(R2D2Agent(cfg), num_envs=2, capacity=8,
                         batch_size=2, env=breakout_jax)
         st = an.init(jax.random.PRNGKey(0))
-        assert st.replay.storage.state.dtype == jnp.uint8
+        # The ring holds the stacks as words; what is sampled is uint8.
+        assert st.replay.storage.state.words.dtype == jnp.uint32
         st, _ = an.collect_chunk(st, 1)
+        sampled = an._sample(st.replay, jax.random.PRNGKey(2))[1].state
+        assert (sampled.dtype, sampled.shape) == (jnp.uint8, (2, 4, 84, 84, 4))
         st, m = an.train_chunk(st, 1)
         assert np.isfinite(np.asarray(m["loss"])).all()
         ev = an.greedy_eval(st.train.params, 2, 8, jax.random.PRNGKey(1))

@@ -271,8 +271,8 @@ def test_n_step_horizon_is_cut_at_the_sequences_end():
 
 def _ring(capacity=16, fill=12):
     r = np.random.RandomState(5)
-    storage = {"x": jnp.zeros((capacity, 3), jnp.float32)}
-    replay = device_replay.make(storage, capacity)
+    replay = device_replay.make(
+        {"x": jax.ShapeDtypeStruct((3,), jnp.float32)}, capacity)
     new = {"x": jnp.asarray(r.normal(size=(fill, 3)).astype(np.float32))}
     errs = jnp.asarray(np.abs(r.normal(size=fill)).astype(np.float32))
     return device_replay.ingest(replay, new, errs), r
@@ -298,7 +298,8 @@ def test_ring_write_is_fifo_like_the_numpy_ring():
     capacity, width = 8, 4
     storage = {"x": np.zeros((capacity, 2), np.float32)}
     pri, ptr, size = np.zeros(capacity), 0, 0
-    replay = device_replay.make({"x": jnp.zeros((capacity, 2))}, capacity)
+    replay = device_replay.make(
+        {"x": jax.ShapeDtypeStruct((2,), jnp.float32)}, capacity)
     r = np.random.RandomState(9)
     for _ in range(3):  # the third write overwrites the first
         new = {"x": r.normal(size=(width, 2)).astype(np.float32)}
@@ -331,25 +332,36 @@ def test_priority_write_back_reaches_every_sampled_index():
 # -- donated chunks -------------------------------------------------------------
 
 
-def _tiny_anakin(**kw):
-    cfg = R2D2Config(obs_shape=(2,), num_actions=2, seq_len=6, burn_in=2,
+def _tiny_anakin(obs_shape=(2,), obs_transform=pomdp_project, **kw):
+    cfg = R2D2Config(obs_shape=obs_shape, num_actions=2, seq_len=6, burn_in=2,
                      lstm_size=16, learning_rate=1e-3, n_step=3,
                      dueling_hidden=8, priority_eta=0.9)
     return AnakinR2D2(R2D2Agent(cfg), num_envs=4, capacity=16, batch_size=4,
-                      obs_transform=pomdp_project, updates_per_collect=2, **kw)
+                      obs_transform=obs_transform, updates_per_collect=2, **kw)
 
 
+def _as_bytes(obs):
+    return jnp.clip(obs * 32.0 + 128.0, 0, 255).astype(jnp.uint8)
+
+
+@pytest.mark.parametrize("leaf", ["plain_ring", "word_ring"])
 @pytest.mark.parametrize("chunk", ["collect_chunk", "train_chunk"])
-def test_donated_chunk_reuses_the_rings_buffer(chunk):
-    an = _tiny_anakin()
+def test_donated_chunk_reuses_the_rings_buffer(chunk, leaf):
+    """Both stored forms: the POMDP view (int32) as it is, and
+    byte observations as the `WordRing`'s one `uint32` array."""
+    an = (_tiny_anakin((4,), _as_bytes) if leaf == "word_ring"
+          else _tiny_anakin())
     state = an.init(jax.random.PRNGKey(0))
     if chunk == "train_chunk":
         state, _ = an.collect_chunk(state, 4)
-    ring = state.replay.storage.state
+    array = lambda s: getattr(s.replay.storage.state, "words",
+                              s.replay.storage.state)
+    ring = array(state)
+    assert ring.dtype == (jnp.uint32 if leaf == "word_ring" else jnp.int32)
     where = ring.unsafe_buffer_pointer()
     new_state, _ = getattr(an, chunk)(state, 2)
     assert ring.is_deleted()  # donated: the caller's reference is gone
-    assert new_state.replay.storage.state.unsafe_buffer_pointer() == where
+    assert array(new_state).unsafe_buffer_pointer() == where
 
 
 def test_chunk_counters_ride_in_the_metrics():
