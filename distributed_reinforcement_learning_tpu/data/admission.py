@@ -34,14 +34,12 @@ wire:
   (`"folded"`), so no priority mass is ever silently lost — the
   zero-lost-mass conservation pin.
 
-Gates follow the repo's adjudication rule: `DRL_ACTOR_PRIORITY` /
-`DRL_ADMISSION` force on/off; unset defers to the committed
-`benchmarks/admission_verdict.json` (bench.py admission_compare).
+Gates: `DRL_ACTOR_PRIORITY` / `DRL_ADMISSION`, both off by default;
+not measured on the chip.
 """
 
 from __future__ import annotations
 
-import json
 import os
 import threading
 import time
@@ -52,6 +50,7 @@ import numpy as np
 from distributed_reinforcement_learning_tpu.data.replay import PrioritizedReplay
 from distributed_reinforcement_learning_tpu.data.replay_service import make_scorer
 from distributed_reinforcement_learning_tpu.observability import TELEMETRY as _OBS
+from distributed_reinforcement_learning_tpu.utils.environ import env_flag, env_float
 
 # Priority transform constants — THE backend transform
 # (data/replay.py): p = (|e| + EPS) ** ALPHA. Admission corrections are
@@ -64,69 +63,18 @@ ALPHA = PrioritizedReplay.ALPHA
 # import runtime/). tests/test_admission.py pins the two maps equal.
 ALGO_MODES = {"apex": "transition", "r2d2": "sequence", "xformer": "sequence"}
 
-_VERDICT_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "benchmarks", "admission_verdict.json")
-
-_flag_lock = threading.Lock()
-_flags: dict[str, bool | None] = {"priority": None, "admission": None}
-
-
-def _verdict_flag(key: str) -> bool:
-    try:
-        with open(_VERDICT_PATH) as f:
-            return bool(json.load(f).get(key, False))
-    except (OSError, ValueError):
-        return False
-
-
-def _resolve_flag(name: str, env_key: str, verdict_key: str) -> bool:
-    with _flag_lock:
-        cached = _flags[name]
-    if cached is not None:
-        return cached
-    env = os.environ.get(env_key, "").strip().lower()
-    if env in ("1", "true", "yes", "on"):
-        value = True
-    elif env in ("0", "false", "no", "off"):
-        value = False
-    else:
-        value = _verdict_flag(verdict_key)
-    with _flag_lock:
-        _flags[name] = value
-    return value
-
 
 def actor_priority_enabled() -> bool:
-    """DRL_ACTOR_PRIORITY=1 forces actor-side scoring + stamping on, =0
-    off; unset defers to the committed `benchmarks/admission_verdict.json`
-    (`actor_priority_auto_enable`) — the repo's 1.2x adjudication rule.
-    Resolved once per process; `refresh_flags()` re-reads."""
-    return _resolve_flag("priority", "DRL_ACTOR_PRIORITY",
-                         "actor_priority_auto_enable")
+    """`DRL_ACTOR_PRIORITY`: actor-side scoring + stamping. Off by
+    default; not measured on the chip."""
+    return env_flag("DRL_ACTOR_PRIORITY", False)
 
 
 def admission_enabled() -> bool:
-    """DRL_ADMISSION=1 forces priority-mass admission (backpressure
-    thinning) on, =0 off; unset defers to the committed verdict
-    (`admission_auto_enable`). Admission rides the stamp, so it is
-    inert unless `actor_priority_enabled()` too."""
-    return _resolve_flag("admission", "DRL_ADMISSION", "admission_auto_enable")
-
-
-def refresh_flags() -> None:
-    """Re-resolve the env/verdict gates (after monkeypatching env)."""
-    with _flag_lock:
-        _flags["priority"] = None
-        _flags["admission"] = None
-
-
-def _env_float(key: str, default: float) -> float:
-    try:
-        raw = os.environ.get(key, "").strip()
-        return float(raw) if raw else default
-    except ValueError:
-        return default
+    """`DRL_ADMISSION`: priority-mass admission (backpressure thinning).
+    Off by default; not measured on the chip. Admission rides the
+    stamp, so it is inert unless `actor_priority_enabled()` too."""
+    return env_flag("DRL_ADMISSION", False)
 
 
 def transform(errors: np.ndarray) -> np.ndarray:
@@ -198,8 +146,7 @@ class DutyMeter:
             return self._ewma
 
     def total(self) -> float:
-        """Cumulative busy seconds since construction (bench.py
-        admission_compare's ingest-CPU numerator)."""
+        """Cumulative busy seconds since construction."""
         with self._lock:
             return self._total
 
@@ -261,9 +208,9 @@ class AdmissionController:
         self.mode = mode
         self.scorer_name = scorer_name
         self._scorer = scorer
-        self.lo = _env_float("DRL_ADMISSION_LO", 0.5)
-        self.hi = max(_env_float("DRL_ADMISSION_HI", 0.9), self.lo + 1e-6)
-        self.floor = min(max(_env_float("DRL_ADMISSION_FLOOR", 0.1), 1e-3), 1.0)
+        self.lo = env_float("DRL_ADMISSION_LO", 0.5)
+        self.hi = max(env_float("DRL_ADMISSION_HI", 0.9), self.lo + 1e-6)
+        self.floor = min(max(env_float("DRL_ADMISSION_FLOOR", 0.1), 1e-3), 1.0)
         self._lock = threading.Lock()
         self._pressure = 0.0
         self._mu = 0.0
@@ -292,9 +239,9 @@ class AdmissionController:
 
     def pressure(self) -> float:
         """Effective pressure 0..1: `DRL_ADMISSION_PRESSURE` override
-        (tests/bench drive the ladder without a loaded learner) or the
+        (tests drive the ladder without a loaded learner) or the
         reply-fed EWMA."""
-        override = _env_float("DRL_ADMISSION_PRESSURE", -1.0)
+        override = env_float("DRL_ADMISSION_PRESSURE", -1.0)
         if override >= 0.0:
             return min(override, 1.0)
         with self._lock:

@@ -9,7 +9,7 @@ kernel lives here so both import the same bytes-for-bytes behavior
 (tests/test_collective_partition.py pins byte-identity against the
 weight-shard aliases).
 
-Kept numpy + stdlib only: parallel/collective.py's bench/test children
+Kept numpy + stdlib only: parallel/collective.py's test children
 rely on a jax-free import footprint.
 """
 

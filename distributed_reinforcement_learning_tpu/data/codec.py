@@ -26,9 +26,8 @@ producer was busy re-flattening pytrees):
    change), one buffer allocation, and per-leaf memcpys. Decode mirrors
    it with a layout cache keyed by the exact header bytes. Cache-hit
    blobs are byte-identical to cold encodes (pinned by
-   tests/test_codec_fastpath.py). Gated by `DRL_CODEC_CACHE` (1 on,
-   0 off; unset defers to the committed
-   `benchmarks/codec_verdict.json` adjudication — the repo's 1.2x rule).
+   tests/test_codec_fastpath.py). Gated by `DRL_CODEC_CACHE` (off by
+   default; not measured on the chip).
 
 2. **Frame-stack dedup** (`encode(..., dedup=True)`): Atari-style
    observations `[T, H, W, S]` stack S frames newest-last
@@ -48,7 +47,6 @@ producer was busy re-flattening pytrees):
 from __future__ import annotations
 
 import json
-import os
 import sys
 import threading
 from collections import namedtuple
@@ -56,6 +54,8 @@ from functools import lru_cache
 from typing import Any
 
 import numpy as np
+
+from distributed_reinforcement_learning_tpu.utils.environ import env_flag
 
 
 @lru_cache(maxsize=None)
@@ -276,65 +276,24 @@ def cache_stat(key: str) -> int:
 
 
 def clear_caches() -> None:
-    """Drop all cached plans and zero the counters (tests, benchmarks)."""
+    """Drop all cached plans and zero the counters (tests)."""
     _CACHES.clear()
 
 
 # -- feature gates ------------------------------------------------------------
 
-_VERDICT_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "benchmarks", "codec_verdict.json")
-
-_flag_lock = threading.Lock()
-_flags: dict[str, bool | None] = {"cache": None, "dedup": None}
-
-
-def _verdict_flag(key: str) -> bool:
-    try:
-        with open(_VERDICT_PATH) as f:
-            return bool(json.load(f).get(key, False))
-    except (OSError, ValueError):
-        return False
-
-
-def _resolve_flag(name: str, env_key: str, verdict_key: str) -> bool:
-    with _flag_lock:
-        cached = _flags[name]
-    if cached is not None:
-        return cached
-    env = os.environ.get(env_key, "").strip().lower()
-    if env in ("1", "true", "yes", "on"):
-        value = True
-    elif env in ("0", "false", "no", "off"):
-        value = False
-    else:
-        value = _verdict_flag(verdict_key)
-    with _flag_lock:
-        _flags[name] = value
-    return value
-
 
 def cache_enabled() -> bool:
-    """DRL_CODEC_CACHE=1 forces the schema cache on, =0 off; unset defers
-    to the committed `benchmarks/codec_verdict.json` adjudication
-    (`cache_auto_enable`) — the repo's no-un-adjudicated-fast-path rule.
-    Resolved once per process; `refresh_flags()` re-reads (tests/bench)."""
-    return _resolve_flag("cache", "DRL_CODEC_CACHE", "cache_auto_enable")
+    """`DRL_CODEC_CACHE`: the schema cache. Off by default; not measured
+    on the chip."""
+    return env_flag("DRL_CODEC_CACHE", False)
 
 
 def obs_dedup_enabled() -> bool:
-    """DRL_OBS_DEDUP=1 forces frame-stack dedup on the WIRE paths, =0
-    off; unset defers to the committed verdict (`dedup_auto_enable`).
-    Wire-only: in-process queues never see packed blobs."""
-    return _resolve_flag("dedup", "DRL_OBS_DEDUP", "dedup_auto_enable")
-
-
-def refresh_flags() -> None:
-    """Re-resolve the env/verdict gates (after monkeypatching env)."""
-    with _flag_lock:
-        _flags["cache"] = None
-        _flags["dedup"] = None
+    """`DRL_OBS_DEDUP`: frame-stack dedup on the WIRE paths (in-process
+    queues never see packed blobs). Off by default; not measured on the
+    chip."""
+    return env_flag("DRL_OBS_DEDUP", False)
 
 
 # -- frame-stack dedup plumbing ----------------------------------------------
@@ -522,12 +481,11 @@ def encode(tree: Any, dedup: bool = False, cache: bool | None = None) -> np.ndar
     overrides that gate per call (cache-hit blobs are byte-identical to
     cold encodes, so overriding changes cost, never bytes): the weight
     plane forces it on — its per-version publish encode has a stable
-    schema and is not what the committed trajectory-path verdict
-    adjudicated.
+    schema, whatever the trajectory path's gate says.
     """
     if not (cache_enabled() if cache is None else cache):
-        # Pre-cache behavior, kept as the adjudication baseline and the
-        # DRL_CODEC_CACHE=0 escape hatch.
+        # The uncached encode: the default, and the reference the
+        # cached path is pinned byte-identical to.
         pairs: list[tuple[str, np.ndarray]] = []
         skel = _flatten(tree, "$", pairs)
         leaves = [arr for _, arr in pairs]
@@ -808,8 +766,8 @@ def decode(blob: bytes | memoryview, copy: bool = False,
     overrides the layout-cache gate per call (see `encode`): the weight
     plane and the replay shards' decode-at-ingest
     (data/replay_service.py) both force it on — each sees ONE stable
-    schema per run, so the layout cache is a pure win there regardless
-    of the committed trajectory-path verdict.
+    schema per run, so the layout cache is a pure win there whatever
+    the trajectory path's gate says.
     """
     view = _skip_ext(memoryview(blob).cast("B"))
     plan = _layout_plan(view, cache)

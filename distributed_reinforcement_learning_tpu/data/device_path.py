@@ -1,10 +1,8 @@
 """Device-resident sample path: fused gather -> H2D -> scanned learn.
 
-Every committed bench run says the same thing: the learn kernel is
-~1000x faster than the host loop that feeds it (BENCH_r04 stage budget:
-learn 736k f/s vs 705-820 f/s e2e, h2d 0.87 GB/s serial). PR 6 moved
-prioritization to ingest; this module moves the REST of the per-update
-host round-trip off the learn thread — the device-side mirror of
+The learn kernel is far faster than the host loop that feeds it. PR 6
+moved prioritization to ingest; this module moves the REST of the
+per-update host round-trip off the learn thread — the device-side mirror of
 in-network experience sampling (arXiv:2110.13506) and the keep-it-on-
 device discipline of Podracer (arXiv:2104.06272). The host path the
 prioritized learners pay per train call is
@@ -25,8 +23,7 @@ bounds how many sampled calls sit device-resident beyond the one in
 use (classic double buffering at the default 1).
 
 The learn side (`runtime/replay_train.device_train_call`) runs the K
-steps as ONE jitted `lax.scan` (`agent.learn_many`, the `learn_scan`
-shape bench.py proved at per-step parity), materializes the `[K, B]`
+steps as ONE jitted `lax.scan` (`agent.learn_many`), materializes the `[K, B]`
 priority stack in a SINGLE D2H per K, and fans it back to the sharded
 writeback router through the existing packed (tag|epoch|shard|tree_idx)
 int64 indexes — a shard death mid-K drops only that shard's stale-epoch
@@ -50,9 +47,7 @@ that forces K=1 (allreduce merges per train step) RECONFIGURES the
 path instead: entries stacked at the old K are epoch-dropped, never
 fed to the K==1 learn seam — double-buffered H2D only, cleanly.
 
-Gate: `DRL_DEVICE_PATH` (0 off, 1 force; unset defers to the committed
-`benchmarks/device_path_verdict.json` adjudication — the repo's
-no-un-adjudicated-fast-path rule, bench.py `device_path_compare`).
+Gate: `DRL_DEVICE_PATH` (off by default; not measured on the chip).
 
 Concurrency model (no class-owned locks, so the `_GUARDED_BY` map is
 the documentation form): ONE gather thread produces, ONE learn thread
@@ -67,8 +62,6 @@ single-writer (noted per attribute in `_NOT_GUARDED`).
 
 from __future__ import annotations
 
-import json
-import os
 import queue as _queue
 import threading
 import time
@@ -77,51 +70,30 @@ from typing import Any, Callable
 import numpy as np
 
 from distributed_reinforcement_learning_tpu.observability import TELEMETRY as _OBS
+from distributed_reinforcement_learning_tpu.utils.environ import (
+    env_flag,
+    env_float,
+    env_int,
+)
 
-_VERDICT_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "benchmarks", "device_path_verdict.json")
 
-
-def device_path_enabled(verdict_path: str = _VERDICT_PATH) -> bool:
-    """Gate resolution: `DRL_DEVICE_PATH=1` forces on, `=0` forces off;
-    unset defers to the committed `device_path_compare` adjudication
-    (auto-enable only at >= 1.2x the host sample path — the repo's
-    Pallas-LSTM rule)."""
-    env = os.environ.get("DRL_DEVICE_PATH", "").strip()
-    if env:
-        return env != "0"
-    try:
-        with open(verdict_path) as f:
-            return bool(json.load(f).get("auto_enable", False))
-    except (OSError, ValueError):
-        return False
+def device_path_enabled() -> bool:
+    """`DRL_DEVICE_PATH`: the device-resident sample path. Off by
+    default; not measured on the chip."""
+    return env_flag("DRL_DEVICE_PATH", False)
 
 
 def path_depth() -> int:
     """`DRL_DEVICE_PATH_DEPTH`: device-resident sampled calls beyond the
     one in use (1 = classic double buffering)."""
-    env = os.environ.get("DRL_DEVICE_PATH_DEPTH", "").strip()
-    if not env:
-        return 1
-    try:
-        return max(1, int(env))
-    except ValueError as e:
-        raise ValueError(
-            f"DRL_DEVICE_PATH_DEPTH must be an integer, got {env!r}") from e
+    return max(1, env_int("DRL_DEVICE_PATH_DEPTH", 1))
 
 
 def path_max_bytes() -> int:
     """`DRL_DEVICE_PATH_MAX_MB`: stacked-call size past which the path
     demotes to the host loop instead of risking a device OOM."""
-    env = os.environ.get("DRL_DEVICE_PATH_MAX_MB", "").strip()
-    if not env:
-        return 256 * 1024 * 1024
-    try:
-        return max(1, int(float(env) * 1024 * 1024))
-    except ValueError as e:
-        raise ValueError(
-            f"DRL_DEVICE_PATH_MAX_MB must be a number, got {env!r}") from e
+    mb = env_float("DRL_DEVICE_PATH_MAX_MB", 256.0)
+    return max(1, int(mb * 1024 * 1024))
 
 
 # -- the gather (shared with the host path) -----------------------------------

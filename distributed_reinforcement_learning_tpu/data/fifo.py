@@ -15,7 +15,6 @@ the multi-process data plane.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from collections import deque
@@ -24,6 +23,7 @@ from typing import Any
 import numpy as np
 
 from distributed_reinforcement_learning_tpu.observability import TELEMETRY as _OBS
+from distributed_reinforcement_learning_tpu.utils.environ import env_int
 
 
 def stack_pytrees(items: list[Any]) -> Any:
@@ -81,10 +81,7 @@ def put_batch_size() -> int:
     the whole extract() round in one OP_PUT_TRAJ_N exchange (and, for
     the Ape-X actor's per-step puts, one unroll per put). Sizing
     guidance vs actor count: docs/performance.md ("PUT batch sizing")."""
-    try:
-        return max(0, int(os.environ.get("DRL_PUT_BATCH", "0") or 0))
-    except ValueError:
-        return 0
+    return max(0, env_int("DRL_PUT_BATCH", 0))
 
 
 def put_round(queue: Any, items: list[Any]) -> None:

@@ -4,9 +4,7 @@ Ape-X's core claim (arXiv:1803.00933) is that distributed prioritized
 replay scales when priority computation moves OFF the learner — yet the
 monolithic topology funnels every trajectory through the learner
 thread's own ingest loop (`apex_runner.ingest_many`: decode + TD forward
-+ sum-tree insert), and both committed honest-negative A/Bs
-(benchmarks/codec_verdict.json, transport_verdict.json) diagnosed
-exactly that learner-side path as the bound. "In-network experience
++ sum-tree insert), and that learner-side path is the bound. "In-network experience
 sampling" (arXiv:2110.13506) points the same way: compute priorities
 and store experience on the TRANSPORT path, not the train path.
 
@@ -44,9 +42,8 @@ marked dead and excluded from sampling; when every shard is dead the
 ingest facade (`runtime/replay_shard.ReplayIngestFifo`) demotes
 PERMANENTLY to the learner's monolithic queue+replay path.
 
-Gated by `DRL_REPLAY_SHARDS` (0 off, N>=1 forces N shards; unset defers
-to the committed `benchmarks/replay_verdict.json` adjudication — the
-repo's no-un-adjudicated-fast-path rule, bench.py `replay_compare`).
+Gated by `DRL_REPLAY_SHARDS` (0 off, N>=1 forces N shards; 2 by
+default; not measured on the chip).
 """
 
 from __future__ import annotations
@@ -255,8 +252,8 @@ class ReplayShard:
     def ingest_blob(self, blob) -> int:
         """Decode one wire blob and insert it; returns items inserted.
 
-        decode(cache=True) forces the layout cache regardless of the
-        trajectory-path codec verdict: shard ingest sees one stable
+        decode(cache=True) forces the layout cache whatever the
+        trajectory path's gate says: shard ingest sees one stable
         schema per run, the same argument that has the weight plane
         force its own encode cache (`runtime/weights.py`).
         """
@@ -853,7 +850,7 @@ class ShardedReplayService:
 
     def flush_tier(self, timeout: float | None = 10.0) -> bool:
         """Drive spill-tier maintenance to quiescence on the CALLING
-        thread (tests / benches / checkpoint barriers): safe alongside
+        thread (tests / checkpoint barriers): safe alongside
         the router — every job is planned and committed under its
         shard's lock, so two maintenance threads interleave cleanly."""
         if not self._tiered:

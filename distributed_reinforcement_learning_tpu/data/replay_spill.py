@@ -38,9 +38,8 @@ rewrite, PR 9 pattern) with a crc32 per segment file verified at promote
 time (PR 8 style): a corrupt file drops that one segment and counts it
 (`crc_dropped`), never poisons the shard.
 
-Gated by `DRL_REPLAY_SPILL*` (runtime/replay_shard.py) deferring to the
-committed `benchmarks/replay_spill_verdict.json` adjudication
-(bench.py `replay_spill_compare`), like every prior fast path.
+Gated by `DRL_REPLAY_SPILL*` (runtime/replay_shard.py): on by default;
+not measured on the chip.
 """
 
 from __future__ import annotations

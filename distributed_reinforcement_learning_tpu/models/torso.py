@@ -69,7 +69,7 @@ class ResNetTorso(nn.Module):
     contractions of 576/1152 and output channels of 64/128 that FILL the
     128-wide MXU, unlike Nature-CNN's 32/64-channel quarter-fills. SAME
     padding + pooling keep the spatial geometry analytically simple for
-    the roofline model (bench.py impala_roofline).
+    a roofline model.
 
     conv0 carries the folded `input_scale` exactly like `NatureConv`
     (declared params, conv(x*s) == conv_{k*s}(x)).

@@ -40,6 +40,7 @@ import time
 from typing import Any, Callable
 
 from distributed_reinforcement_learning_tpu.observability.trace import TraceEmitter
+from distributed_reinforcement_learning_tpu.utils.environ import env_flag, env_float
 
 # Weight-staleness histogram edges — the single source of truth for the
 # write side (transport server's observation-time `staleness_bucket/*`
@@ -156,7 +157,7 @@ class Telemetry:
         if self.enabled:
             return self
         if flush_interval is None:
-            flush_interval = float(os.environ.get("DRL_TELEMETRY_FLUSH_S", "1.0"))
+            flush_interval = env_float("DRL_TELEMETRY_FLUSH_S", 1.0)
         os.makedirs(out_dir, exist_ok=True)
         self.role, self.rank = role, int(rank)
         # "w", matching the trace: one shard file describes one process
@@ -314,8 +315,7 @@ def telemetry_dir(run_dir: str | None = None) -> str | None:
     out = os.environ.get("DRL_TELEMETRY_DIR")
     if out:
         return out
-    if run_dir and os.environ.get(
-            "DRL_TELEMETRY", "").strip().lower() in ("1", "true", "yes", "on"):
+    if run_dir and env_flag("DRL_TELEMETRY", False):
         return os.path.join(run_dir, "telemetry")
     return None
 

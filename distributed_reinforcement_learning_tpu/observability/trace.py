@@ -34,6 +34,8 @@ import threading
 import time
 from typing import Iterator
 
+from distributed_reinforcement_learning_tpu.utils.environ import env_int
+
 DEFAULT_MAX_EVENTS = 100_000
 
 
@@ -62,8 +64,7 @@ class TraceEmitter:
         self.label = label
         self.pid = os.getpid() if pid is None else pid
         if max_events is None:
-            max_events = int(os.environ.get("DRL_TRACE_MAX_EVENTS",
-                                            str(DEFAULT_MAX_EVENTS)))
+            max_events = env_int("DRL_TRACE_MAX_EVENTS", DEFAULT_MAX_EVENTS)
         self.max_events = max_events
         self.dropped = 0
         self._lock = threading.Lock()

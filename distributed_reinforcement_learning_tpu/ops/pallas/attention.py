@@ -24,7 +24,7 @@ the probabilities from q/k/lse, using the precomputed per-row
 `delta = rowsum(dout * out)` (a cheap XLA reduction outside).
 
 Numerics are validated against `ops/attention.dense_attention` (values
-and grads) in interpret mode on CPU and on TPU by tests/bench.
+and grads) in interpret mode on CPU and on TPU by tests.
 """
 
 from __future__ import annotations

@@ -78,8 +78,8 @@ def from_importance_weights(
     (`ops/pallas/vtrace.py`): the kernel runs the whole reverse
     recursion in one VMEM-resident launch, where this lax.scan's T
     while-loop iterations each round-trip their carries through HBM.
-    Its margin is not measured on the attached chip (bench.py
-    `bench_kernels` times both with an on-device loop); chip_smoke.py
+    Its margin over this scan is not measured on the attached chip
+    (the benchmark's IMPALA cell runs the kernel only); chip_smoke.py
     asserts that the compiled learn step really holds it.
     """
     from distributed_reinforcement_learning_tpu.ops.pallas import resolve_backend

@@ -60,7 +60,7 @@ each seat roundtrips its self-owned chunk so all seats still end
 bit-identical. A plan-less call is byte-for-byte today's f32 ring.
 
 This module is numpy + sockets only (no jax): the flatten/unflatten of
-gradient pytrees lives with the tier, and the bench/test children keep
+gradient pytrees lives with the tier, and the test children keep
 a jax-free import footprint.
 """
 
@@ -87,6 +87,7 @@ from distributed_reinforcement_learning_tpu.runtime.transport import (
     _recv_msg,
     _send_msg,
 )
+from distributed_reinforcement_learning_tpu.utils.environ import env_float
 
 # Collective op namespace (disjoint from runtime/transport's 1..9; the
 # endpoint below is the dispatcher, PeerClient._exchange the sender).
@@ -115,12 +116,7 @@ def wait_budget_s() -> float:
     """Bounded wait for one collective exchange (`DRL_LEARNER_WAIT_S`):
     past it the blocked seat probes the peer and either keeps waiting
     (peer alive, one extension) or declares it dead and re-forms."""
-    env = os.environ.get("DRL_LEARNER_WAIT_S", "").strip()
-    try:
-        return max(0.1, float(env)) if env else 10.0
-    except ValueError as e:
-        raise ValueError(
-            f"DRL_LEARNER_WAIT_S must be a number, got {env!r}") from e
+    return max(0.1, env_float("DRL_LEARNER_WAIT_S", 10.0))
 
 
 class CollectiveError(RuntimeError):

@@ -36,15 +36,11 @@ recorded trajectory semantics:
   `fleet.RetryLadder` re-promotes after transient causes clear
   (exhaustion latches the demotion permanent with one log line).
 
-Gate: `DRL_ACTOR_PIPE=1/0` forces; unset defers to the committed
-`benchmarks/actor_pipeline_verdict.json` written by bench.py's
-`actor_compare` A/B (the repo's 1.2x adjudication bar).
+Gate: `DRL_ACTOR_PIPE` (off by default; not measured on the chip).
 """
 
 from __future__ import annotations
 
-import json
-import os
 import sys
 import threading
 import time
@@ -56,16 +52,12 @@ import numpy as np
 
 from distributed_reinforcement_learning_tpu.data.fifo import put_round
 from distributed_reinforcement_learning_tpu.observability import TELEMETRY as _OBS
+from distributed_reinforcement_learning_tpu.utils.environ import env_flag, env_int
 
 # Per-slice RNG stream separation: slice 0 keeps the actor's own seed
 # (a 1-slice pipeline is exactly the plain actor), later slices stride
 # far enough that no launcher's seed+1+task layout can collide.
 _SLICE_SEED_STRIDE = 1_000_003
-
-_VERDICT_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "benchmarks", "actor_pipeline_verdict.json")
-
 
 def slice_seed(base_seed: int, index: int) -> int:
     """The per-slice RNG seed: deterministic and documented, so the
@@ -389,7 +381,7 @@ class ActorPipeline:
         self._queue = publisher_queue if publisher_queue is not None \
             else actor.queue
         self._slices = actor.pipeline_make_slices(max(2, int(num_slices)))
-        self._depth = (int(os.environ.get("DRL_ACTOR_PUB_DEPTH", "2"))
+        self._depth = (env_int("DRL_ACTOR_PUB_DEPTH", 2)
                        if publisher_depth is None else int(publisher_depth))
         self._publisher = UnrollPublisher(self._queue, self._depth).start()
         # One act worker: submission order == execution order, and the
@@ -406,12 +398,6 @@ class ActorPipeline:
         self._backlog: list = []  # payloads carried over by a demotion
         self.demotions = 0
         self.rounds = 0
-        # Bounded overlap samples (ms) for bench/obs introspection.
-        self.stage_samples: dict[str, deque] = {
-            "act_wait_ms": deque(maxlen=4096),
-            "env_step_ms": deque(maxlen=4096),
-            "put_wait_ms": deque(maxlen=4096),
-        }
 
     # -- actor-compatible surface -------------------------------------
     @property
@@ -475,25 +461,20 @@ class ActorPipeline:
         slices = self._slices
         k = len(slices)
         act = self._actor.slice_act
-        note = self.stage_samples
         total = steps * k
         fut, fut_idx = self._pool.submit(act, slices[0]), 0
         try:
             for j in range(total):
                 sl = slices[j % k]
-                t0 = time.perf_counter()
                 with _OBS.span("pipe_act_wait"):
                     out = fut.result()
-                note["act_wait_ms"].append((time.perf_counter() - t0) * 1e3)
                 if j + 1 < total:
                     fut, fut_idx = (self._pool.submit(act, slices[(j + 1) % k]),
                                     (j + 1) % k)
                 else:
                     fut = None
-                t0 = time.perf_counter()
                 with _OBS.span("pipe_env_step"):
                     payloads = self._actor.slice_step(sl, out)
-                note["env_step_ms"].append((time.perf_counter() - t0) * 1e3)
                 for p in payloads:
                     self._submit(p)
         finally:
@@ -553,10 +534,7 @@ class ActorPipeline:
 
     def _submit(self, payload) -> None:
         if not self._demoted:
-            t0 = time.perf_counter()
             if self._publisher.submit(payload):
-                self.stage_samples["put_wait_ms"].append(
-                    (time.perf_counter() - t0) * 1e3)
                 return
             self._demote("publisher thread died: "
                          + repr(self._publisher.error))
@@ -618,22 +596,6 @@ class ActorPipeline:
               "stepping resumes", file=sys.stderr)
         return True
 
-    def stage_stats(self) -> dict:
-        """p50/p99 of the bounded overlap samples (bench.actor_compare's
-        act/step/put overlap columns)."""
-        out: dict = {}
-        for name, samples in self.stage_samples.items():
-            if not samples:
-                continue
-            vals = sorted(samples)
-            out[name] = {
-                "p50": round(vals[len(vals) // 2], 3),
-                "p99": round(vals[min(int(0.99 * (len(vals) - 1) + 0.5),
-                                      len(vals) - 1)], 3),
-                "n": len(vals),
-            }
-        return out
-
     def close(self) -> None:
         """Drain the publisher and flush what it still held; best-effort
         (the transport may already be gone on the exit path)."""
@@ -656,28 +618,13 @@ class ActorPipeline:
         #   returns; don't hang the exit path behind it
 
 
-# -- adjudication gate -------------------------------------------------------
-
-def pipeline_auto_enabled(verdict_path: str | None = None) -> bool:
-    """The committed `actor_compare` verdict (bench.py): the pipeline
-    ships enabled-by-default only if the two-process A/B showed >= 1.2x
-    sequential actor frames/s, mirroring the repo's adjudication bar."""
-    try:
-        with open(verdict_path or _VERDICT_PATH) as f:
-            return bool(json.load(f).get("auto_enable", False))
-    except (OSError, ValueError):
-        return False
+# -- gate ---------------------------------------------------------------------
 
 
 def pipeline_enabled() -> bool:
-    """DRL_ACTOR_PIPE=1 forces the pipeline on, =0 off; unset defers to
-    the committed adjudication artifact."""
-    forced = os.environ.get("DRL_ACTOR_PIPE", "").strip()
-    if forced == "1":
-        return True
-    if forced == "0":
-        return False
-    return pipeline_auto_enabled()
+    """`DRL_ACTOR_PIPE`: the pipelined actor data plane. Off by default;
+    not measured on the chip."""
+    return env_flag("DRL_ACTOR_PIPE", False)
 
 
 def maybe_wrap(actor: Any, label: str = "actor",

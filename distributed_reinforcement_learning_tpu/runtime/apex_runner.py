@@ -16,7 +16,6 @@ Re-design of `train_apex.py:82-231`:
 
 from __future__ import annotations
 
-import os
 from typing import Any
 
 import numpy as np
@@ -43,6 +42,7 @@ from distributed_reinforcement_learning_tpu.runtime.actor_pipeline import (
 from distributed_reinforcement_learning_tpu.runtime.publishing import PublishCadenceMixin
 from distributed_reinforcement_learning_tpu.runtime.replay_train import ReplayTrainMixin
 from distributed_reinforcement_learning_tpu.runtime.weights import WeightStore
+from distributed_reinforcement_learning_tpu.utils.environ import env_int
 from distributed_reinforcement_learning_tpu.utils.logger import MetricsLogger
 from distributed_reinforcement_learning_tpu.utils.profiling import ProfilerSession, StageTimer
 
@@ -360,7 +360,7 @@ class ApexLearner(PublishCadenceMixin, ReplayTrainMixin):
         # note); resolved once here so the hot drain loops don't re-parse
         # the environment per call and a malformed value fails at
         # construction, not mid-training.
-        self.ingest_unrolls = int(os.environ.get("DRL_APEX_INGEST_UNROLLS", "1"))
+        self.ingest_unrolls = env_int("DRL_APEX_INGEST_UNROLLS", 1)
         if self.ingest_unrolls < 1:
             raise ValueError(
                 "DRL_APEX_INGEST_UNROLLS must be >= 1, got "
@@ -440,9 +440,9 @@ class ApexLearner(PublishCadenceMixin, ReplayTrainMixin):
         compiles at most log2(max_unrolls)+1 distinct shapes.
 
         DEFAULT = 1 (per-unroll), from `DRL_APEX_INGEST_UNROLLS`: the
-        batched path's gain is not measured on the attached chip
-        (bench.py `apex_ingest` prices it), so like the Pallas LSTM it
-        stays opt-in (`DRL_APEX_INGEST_UNROLLS=8`).
+        batched path's gain is not measured on the attached chip, so
+        like the Pallas LSTM it stays opt-in
+        (`DRL_APEX_INGEST_UNROLLS=8`).
         """
         if max_unrolls is None:
             max_unrolls = self.ingest_unrolls

@@ -60,31 +60,23 @@ from collections import deque
 from typing import Any
 
 from distributed_reinforcement_learning_tpu.observability import TELEMETRY as _OBS
+from distributed_reinforcement_learning_tpu.utils.environ import (
+    env_flag,
+    env_float,
+    env_int,
+)
 
 
 def fleet_enabled() -> bool:
     """DRL_FLEET=0 disables registration/heartbeats/re-promotion. The
     supervisor is control-plane (a few tiny json exchanges per member
-    per second), not a perf fast path, so unlike the ring/board gates
-    it defaults ON without an adjudication artifact — the committed
-    `benchmarks/chaos_verdict.json` documents its behavior under
-    kill/respawn instead."""
-    return os.environ.get("DRL_FLEET", "").strip().lower() not in (
-        "0", "false", "no", "off")
-
-
-def _env_float(name: str, default: float) -> float:
-    env = os.environ.get(name, "").strip()
-    if not env:
-        return default
-    try:
-        return float(env)
-    except ValueError as e:
-        raise ValueError(f"{name} must be a number, got {env!r}") from e
+    per second), not a perf fast path, so it defaults ON;
+    tests/test_fleet.py drills its behavior under kill/respawn."""
+    return env_flag("DRL_FLEET", True)
 
 
 def heartbeat_interval_s() -> float:
-    return max(0.05, _env_float("DRL_FLEET_HB_S", 2.0))
+    return max(0.05, env_float("DRL_FLEET_HB_S", 2.0))
 
 
 class ProbeContext:
@@ -148,12 +140,12 @@ class RetryLadder:
         # healthy, where "demotion is now permanent" would be a lie.
         self.exhausted_note = (exhausted_note or
                                "demotion is now permanent")
-        self.base_s = (_env_float("DRL_REATTACH_BASE_S", 2.0)
+        self.base_s = (env_float("DRL_REATTACH_BASE_S", 2.0)
                        if base_s is None else base_s)
-        self.max_s = (_env_float("DRL_REATTACH_MAX_S", 30.0)
+        self.max_s = (env_float("DRL_REATTACH_MAX_S", 30.0)
                       if max_s is None else max_s)
         if max_attempts is None:
-            max_attempts = int(_env_float("DRL_REATTACH_ATTEMPTS", 8))
+            max_attempts = env_int("DRL_REATTACH_ATTEMPTS", 8)
         self.max_attempts = max(1, max_attempts)
         self._lock = threading.Lock()
         self._attempts = 0
@@ -241,7 +233,7 @@ class ShmReattachMixin:
 
     def _on_reattached(self) -> None:
         """After a successful install: the surfaces' re-promotion log
-        lines (bench.py's chaos watcher greps "re-attached")."""
+        lines."""
 
     def reattach(self, ctx=None) -> None:
         """Probe the named segment while demoted (bounded ladder; fleet
@@ -378,9 +370,9 @@ class FleetSupervisor:
         # (the default) omits the field and ProbeContext falls back to
         # the learner's own pid (learner == board creator).
         self._board_pid_fn = board_pid_fn
-        self.suspect_s = _env_float("DRL_FLEET_SUSPECT_S",
+        self.suspect_s = env_float("DRL_FLEET_SUSPECT_S",
                                     self.SUSPECT_AFTER * self.heartbeat_s)
-        self.dead_s = _env_float("DRL_FLEET_DEAD_S",
+        self.dead_s = env_float("DRL_FLEET_DEAD_S",
                                  self.DEAD_AFTER * self.heartbeat_s)
         self.pid = os.getpid()
         # Incarnation identity: members detect a learner restart by the

@@ -1,10 +1,8 @@
 """Sharded learner tier: N cooperating learner seats, one publisher.
 
 The single learner process was the last singleton in the topology — the
-SPOF the fleet supervisor babysits and the host-data-plane ceiling every
-committed bench hits (BENCH_r04: learn kernel ~736k frames/s vs
-~700-820 frames/s end-to-end — the host plane around ONE learner is the
-whole gap). Following Podracer's Sebulba split (arXiv:2104.06272), this
+SPOF the fleet supervisor babysits and the host-data-plane ceiling of
+the host loop. Following Podracer's Sebulba split (arXiv:2104.06272), this
 module turns `--mode learner` into one SEAT of an N-seat tier:
 
 - each seat owns its own transport server (data port `server_port +
@@ -46,14 +44,12 @@ tests/test_learner_tier.py.
 
 Gate: the launcher spawns seats with `DRL_LEARNER_SEATS`/`DRL_LEARNER_RANK`/
 `DRL_LEARNER_PEERS` set (`launch_local_cluster --learners N` with seat
-mode); a learner process without them runs exactly as before. Unset
-seat counts defer to the committed `benchmarks/learner_verdict.json`
-adjudication (`bench.py learner_compare`), the repo's 1.2x rule.
+mode); a learner process without them runs exactly as before. No tier
+by default; not measured on the chip.
 """
 
 from __future__ import annotations
 
-import json
 import os
 import queue
 import threading
@@ -68,50 +64,17 @@ from distributed_reinforcement_learning_tpu.parallel.collective import (
     PeerLost,
     RoundAborted,
 )
-
-_VERDICT_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "benchmarks", "learner_verdict.json")
-
-_DEFAULT_SEATS = 2  # auto-enabled count when the verdict carries none
-
-
-def tier_auto_enabled(verdict_path: str = _VERDICT_PATH) -> bool:
-    """The committed `learner_compare` verdict (bench.py): the tier
-    ships enabled-by-default only if the two-process A/B showed >= 1.2x
-    one seat's ingest+train throughput — the repo's adjudication rule."""
-    try:
-        with open(verdict_path) as f:
-            return bool(json.load(f).get("auto_enable", False))
-    except (OSError, ValueError):
-        return False
+from distributed_reinforcement_learning_tpu.utils.environ import (
+    env_flag,
+    env_float,
+    env_int,
+)
 
 
-def seat_count(verdict_path: str = _VERDICT_PATH) -> int:
-    """Resolved seat count for the LAUNCHER (0/1 = no tier).
-    `DRL_LEARNER_SEATS=0|1` forces off, `=N` forces N seats; unset
-    defers to the committed adjudication (which may carry its own
-    `seats` count, default 2)."""
-    env = os.environ.get("DRL_LEARNER_SEATS", "").strip()
-    if env:
-        try:
-            return max(0, int(env))
-        except ValueError as e:
-            raise ValueError(
-                f"DRL_LEARNER_SEATS must be an integer, got {env!r}") from e
-    # ONE read serves both the enable flag and the seat count (no
-    # window for the file to change between two parses).
-    try:
-        with open(verdict_path) as f:
-            verdict = json.load(f)
-    except (OSError, ValueError):
-        return 0
-    if not verdict.get("auto_enable", False):
-        return 0
-    try:
-        return max(1, int(verdict.get("seats", _DEFAULT_SEATS)))
-    except (TypeError, ValueError):
-        return _DEFAULT_SEATS
+def seat_count() -> int:
+    """`DRL_LEARNER_SEATS`: the seat count the LAUNCHER resolves (0/1 =
+    no tier). 0 by default; not measured on the chip."""
+    return max(0, env_int("DRL_LEARNER_SEATS", 0))
 
 
 def sync_mode() -> str:
@@ -124,19 +87,9 @@ def sync_mode() -> str:
     return mode
 
 
-def _env_int(name: str, default: int, floor: int = 0) -> int:
-    env = os.environ.get(name, "").strip()
-    if not env:
-        return default
-    try:
-        return max(floor, int(env))
-    except ValueError as e:
-        raise ValueError(f"{name} must be an integer, got {env!r}") from e
-
-
 def merge_steps() -> int:
     """Async-mode merge cadence in train steps (`DRL_LEARNER_MERGE_STEPS`)."""
-    return _env_int("DRL_LEARNER_MERGE_STEPS", 8, floor=1)
+    return max(1, env_int("DRL_LEARNER_MERGE_STEPS", 8))
 
 
 def stale_max() -> int:
@@ -145,78 +98,34 @@ def stale_max() -> int:
     contribution within this many of the receiver's merge rounds ages
     out of the average until it pushes again (per-sender freshness —
     see LearnerTier._maybe_async_merge)."""
-    return _env_int("DRL_LEARNER_STALE_MAX", 4, floor=0)
+    return max(0, env_int("DRL_LEARNER_STALE_MAX", 4))
 
 
 # -- partition-aware collective gates ------------------------------------------
 
-_COLL_VERDICT_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "benchmarks", "collective_verdict.json")
-
-_coll_flag_lock = threading.Lock()
-_coll_flags: dict[str, Any] = {"partition": None, "quant": None,
-                               "overlap": None}
-
-
-def _coll_verdict() -> dict:
-    try:
-        with open(_COLL_VERDICT_PATH) as f:
-            return json.load(f)
-    except (OSError, ValueError):
-        return {}
-
-
-def _coll_resolve(name: str, compute) -> Any:
-    with _coll_flag_lock:
-        cached = _coll_flags[name]
-    if cached is not None:
-        return cached
-    value = compute()
-    with _coll_flag_lock:
-        _coll_flags[name] = value
-    return value
-
 
 def coll_partition() -> bool:
-    """DRL_COLL_PARTITION=0 forces every allreduce round through the
-    legacy whole-vector f32 ring (byte-for-byte today's path), =1 forces
-    the partition-aware exchange on; unset defaults ON — attach builds a
-    plan whenever the learner exposes a params schema, and a seat with
-    no schema falls back to the ring regardless. Resolved once per
-    process; `refresh_coll_flags()` re-reads (tests/bench)."""
-
-    def compute():
-        env = os.environ.get("DRL_COLL_PARTITION", "").strip().lower()
-        if env in ("1", "true", "yes", "on"):
-            return True
-        if env in ("0", "false", "no", "off"):
-            return False
-        return True
-
-    return _coll_resolve("partition", compute)
+    """`DRL_COLL_PARTITION`: on by default — attach builds a plan
+    whenever the learner exposes a params schema, and a seat with no
+    schema falls back to the ring regardless. `=0` forces every
+    allreduce round through the whole-vector f32 ring."""
+    return env_flag("DRL_COLL_PARTITION", True)
 
 
 def coll_quant() -> str:
     """Gradient transport encoding for partitioned rounds: "f32" (the
     default) or "bf16" (half the wire bytes through the shared RNE
-    codec, f32 master accumulation). `DRL_COLL_QUANT` forces a mode
-    (`1` means bf16, `0` f32); unset defers to the committed
-    `collective_verdict.json` adjudication (`quant_auto_enable`) — the
-    repo's 1.2x rule. The mode is folded into the plan hash, so seats
-    resolving differently refuse loudly instead of merging mixed
-    encodings."""
-
-    def compute():
-        env = os.environ.get("DRL_COLL_QUANT", "").strip().lower()
-        if env in ("bf16", "1", "true", "yes", "on"):
-            return "bf16"
-        if env in ("f32", "0", "false", "no", "off"):
-            return "f32"
-        return ("bf16" if _coll_verdict().get("quant_auto_enable", False)
-                else "f32")
-
-    return _coll_resolve("quant", compute)
+    codec, f32 master accumulation; not measured on the chip).
+    `DRL_COLL_QUANT` names a mode or is a flag (`1` means bf16). The
+    mode is folded into the plan hash, so seats resolving differently
+    refuse loudly instead of merging mixed encodings."""
+    env = os.environ.get("DRL_COLL_QUANT", "").strip().lower()
+    if env in ("bf16", "f32"):
+        return env
+    try:
+        return "bf16" if env_flag("DRL_COLL_QUANT", False) else "f32"
+    except ValueError as e:
+        raise ValueError(f"{e} (or a mode: bf16|f32)") from None
 
 
 def coll_overlap() -> int:
@@ -224,32 +133,12 @@ def coll_overlap() -> int:
     default) runs the exchange inline in the learn step; 1 hands it to
     the tier's collective worker so round t's wire time overlaps round
     t+1's backward (delayed apply — one-step-stale pipelined SGD, the
-    same staleness class the async mode already tolerates). Unset
-    defers to the committed verdict (`overlap_auto_enable`). Depth is
-    capped at 1: a deeper pipeline multiplies gradient staleness for no
-    additional overlap (one exchange already hides behind one
-    backward). Folded into the plan hash like the quant mode."""
-
-    def compute():
-        env = os.environ.get("DRL_COLL_OVERLAP", "").strip()
-        if env:
-            try:
-                return min(1, max(0, int(env)))
-            except ValueError as e:
-                raise ValueError(
-                    f"DRL_COLL_OVERLAP must be an integer, got {env!r}"
-                ) from e
-        return 1 if _coll_verdict().get("overlap_auto_enable", False) else 0
-
-    return _coll_resolve("overlap", compute)
-
-
-def refresh_coll_flags() -> None:
-    """Drop the cached gate resolutions (tests/bench re-resolve under a
-    mutated environment or verdict)."""
-    with _coll_flag_lock:
-        for key in _coll_flags:
-            _coll_flags[key] = None
+    same staleness class the async mode already tolerates; not
+    measured on the chip). Depth is capped at 1: a deeper pipeline
+    multiplies gradient staleness for no additional overlap (one
+    exchange already hides behind one backward). Folded into the plan
+    hash like the quant mode."""
+    return min(1, max(0, env_int("DRL_COLL_OVERLAP", 0)))
 
 
 # -- gradient pytree <-> flat f32 vector --------------------------------------
@@ -332,7 +221,7 @@ class LearnerTier:
                  probe_interval_s: float | None = None,
                  dead_after_s: float | None = None):
         from distributed_reinforcement_learning_tpu.runtime.fleet import (
-            _env_float, heartbeat_interval_s)
+            heartbeat_interval_s)
 
         self.rank = rank
         self.seats = len(addrs)
@@ -345,7 +234,7 @@ class LearnerTier:
                                  else probe_interval_s)
         # Same missed-beat vocabulary as the fleet supervisor: a peer
         # unreachable for the DEAD window is out of the membership.
-        self.dead_after_s = (_env_float("DRL_FLEET_DEAD_S",
+        self.dead_after_s = (env_float("DRL_FLEET_DEAD_S",
                                         10.0 * self.probe_interval_s)
                              if dead_after_s is None else dead_after_s)
         self._lock = threading.Lock()

@@ -15,6 +15,7 @@ import threading
 import time
 
 from distributed_reinforcement_learning_tpu.observability import TELEMETRY as _OBS
+from distributed_reinforcement_learning_tpu.utils.environ import env_flag
 
 
 def _async_publish(sync_default: bool) -> bool:
@@ -25,10 +26,7 @@ def _async_publish(sync_default: bool) -> bool:
     whose host snapshot doubles as a per-step device sync (useful when
     timing individual steps). An explicit env setting always wins;
     `sync_default` only flips the unset-env default (run_sync loops)."""
-    env = os.environ.get("DRL_ASYNC_PUBLISH")
-    if env is not None:
-        return env.strip().lower() not in ("0", "false", "no", "off", "")
-    return not sync_default
+    return env_flag("DRL_ASYNC_PUBLISH", not sync_default)
 
 
 class MetricsPump:
@@ -99,9 +97,8 @@ def _async_metrics(sync_default: bool) -> bool:
     with the very compute it is trying not to block (measured slower on
     a 1-core host); on TPU/GPU the compute is elsewhere and the float()
     it absorbs is a pure stall."""
-    env = os.environ.get("DRL_ASYNC_METRICS")
-    if env is not None:
-        return env.strip().lower() not in ("0", "false", "no", "off", "")
+    if os.environ.get("DRL_ASYNC_METRICS", "").strip():
+        return env_flag("DRL_ASYNC_METRICS", False)
     import jax
 
     return jax.default_backend() not in ("cpu",) and _async_publish(sync_default)

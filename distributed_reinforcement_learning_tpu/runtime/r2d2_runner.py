@@ -15,7 +15,6 @@ Re-design of `train_r2d2.py:86-238`:
 from __future__ import annotations
 
 import collections
-import os
 
 import numpy as np
 
@@ -41,6 +40,7 @@ from distributed_reinforcement_learning_tpu.runtime.actor_pipeline import (
 from distributed_reinforcement_learning_tpu.runtime.publishing import PublishCadenceMixin
 from distributed_reinforcement_learning_tpu.runtime.replay_train import ReplayTrainMixin
 from distributed_reinforcement_learning_tpu.runtime.weights import WeightStore
+from distributed_reinforcement_learning_tpu.utils.environ import env_float, env_int
 from distributed_reinforcement_learning_tpu.utils.logger import MetricsLogger
 from distributed_reinforcement_learning_tpu.utils.profiling import ProfilerSession, StageTimer
 
@@ -278,15 +278,13 @@ class R2D2Learner(PublishCadenceMixin, ReplayTrainMixin):
         # diversity, so the knob trades strict prioritized-IS semantics
         # for guaranteed fresh-data coverage). Forces the list-backed
         # replay so batch rows are replaceable pre-stack.
-        self.recent_fraction = float(
-            os.environ.get("DRL_R2D2_RECENT_FRACTION", "0"))
+        self.recent_fraction = env_float("DRL_R2D2_RECENT_FRACTION", 0.0)
         # Window clamped to the ring capacity: a deque entry's tree idx is
         # only valid until the ring overwrites that leaf (capacity ingests
         # after its write); with maxlen <= capacity the oldest cached
         # entry can never be a recycled slot.
         self._recent: collections.deque = collections.deque(
-            maxlen=min(int(os.environ.get("DRL_R2D2_RECENT_WINDOW",
-                                          str(8 * batch_size))),
+            maxlen=min(env_int("DRL_R2D2_RECENT_WINDOW", 8 * batch_size),
                        replay_capacity))
         # Monolithic replay is ALWAYS built: the normal path when
         # sharding is off, and the demotion target when a sharded

@@ -1,8 +1,7 @@
 """Runtime host for the sharded replay service (data/replay_service.py).
 
 Thin wiring layer, mirroring how runtime/shm_ring.py hosts its ring:
-the GATE (`DRL_REPLAY_SHARDS`, unset defers to the committed
-`benchmarks/replay_verdict.json` adjudication), the ingest FACADE that
+the GATE (`DRL_REPLAY_SHARDS`, 2 by default), the ingest FACADE that
 slots into the existing `fifo.blob_ingest` seam in place of the
 learner's trajectory queue, and the run_role builder + telemetry
 registration.
@@ -34,72 +33,31 @@ demotion, logged once.
 
 from __future__ import annotations
 
-import json
 import os
 import threading
 from typing import Any
 
 from distributed_reinforcement_learning_tpu.observability import TELEMETRY as _OBS
-
-_VERDICT_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "benchmarks", "replay_verdict.json")
-
-_SPILL_VERDICT_PATH = os.path.join(
-    os.path.dirname(_VERDICT_PATH), "replay_spill_verdict.json")
-
-_DEFAULT_SHARDS = 2  # auto-enabled count when the verdict carries none
+from distributed_reinforcement_learning_tpu.utils.environ import (
+    env_flag,
+    env_float,
+    env_int,
+)
 
 
-def shards_auto_enabled(verdict_path: str = _VERDICT_PATH) -> bool:
-    """The committed `replay_compare` verdict (bench.py): shards ship
-    enabled-by-default only if the two-process A/B showed >= 1.2x the
-    monolithic ingest+train throughput — the repo's Pallas-LSTM rule."""
-    try:
-        with open(verdict_path) as f:
-            return bool(json.load(f).get("auto_enable", False))
-    except (OSError, ValueError):
-        return False
-
-
-def shard_count(verdict_path: str = _VERDICT_PATH) -> int:
-    """Resolved shard count: 0 = sharding off.
-
-    `DRL_REPLAY_SHARDS=0` forces off, `=N` (N >= 1) forces N shards;
-    unset defers to the committed adjudication (which may carry its own
-    `shards` count, default 2)."""
-    env = os.environ.get("DRL_REPLAY_SHARDS", "").strip()
-    if env:
-        try:
-            return max(0, int(env))
-        except ValueError as e:
-            raise ValueError(
-                f"DRL_REPLAY_SHARDS must be an integer, got {env!r}") from e
-    if not shards_auto_enabled(verdict_path):
-        return 0
-    try:
-        with open(verdict_path) as f:
-            return max(1, int(json.load(f).get("shards", _DEFAULT_SHARDS)))
-    except (OSError, ValueError):
-        return _DEFAULT_SHARDS
+def shard_count() -> int:
+    """`DRL_REPLAY_SHARDS`: the replay shard count, 0 = sharding off.
+    2 by default; not measured on the chip."""
+    return max(0, env_int("DRL_REPLAY_SHARDS", 2))
 
 
 _ALGO_MODE = {"apex": "transition", "r2d2": "sequence", "xformer": "sequence"}
 
 
-def spill_auto_enabled(verdict_path: str = _SPILL_VERDICT_PATH) -> bool:
-    """The spill-tier gate: `DRL_REPLAY_SPILL=0` forces off, `=1` forces
-    on; unset defers to the committed `replay_spill_compare` verdict
-    (bench.py): the tier ships enabled-by-default only if the A/B showed
-    >= 4x stored-transitions-per-GB-RAM at sample-throughput parity."""
-    env = os.environ.get("DRL_REPLAY_SPILL", "").strip()
-    if env:
-        return env != "0"
-    try:
-        with open(verdict_path) as f:
-            return bool(json.load(f).get("auto_enable", False))
-    except (OSError, ValueError):
-        return False
+def spill_auto_enabled() -> bool:
+    """`DRL_REPLAY_SPILL`: the hot/cold spill tier. On by default; not
+    measured on the chip."""
+    return env_flag("DRL_REPLAY_SPILL", True)
 
 
 def spill_config(spill_dir: str | None = None):
@@ -118,8 +76,8 @@ def spill_config(spill_dir: str | None = None):
         import tempfile
 
         directory = tempfile.mkdtemp(prefix="drl_replay_spill_")
-    hot_mb = float(os.environ.get("DRL_REPLAY_SPILL_HOT_MB", "") or 256.0)
-    seg = int(os.environ.get("DRL_REPLAY_SPILL_SEG", "") or 512)
+    hot_mb = env_float("DRL_REPLAY_SPILL_HOT_MB", 256.0)
+    seg = env_int("DRL_REPLAY_SPILL_SEG", 512)
     return SpillConfig(directory=directory,
                        hot_bytes=int(hot_mb * 1024 * 1024),
                        seg_items=max(1, seg))

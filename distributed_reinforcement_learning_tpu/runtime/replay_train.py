@@ -91,9 +91,9 @@ class ReplayTrainMixin:
         self._last_target_sync = 0
         # Fused device sample path (data/device_path.py): built lazily
         # on the first gated train call — by then a learner tier has
-        # attached (it may force K=1) and the gate/verdict is readable.
-        # `device_path_force` overrides the env/verdict gate (bench A/B
-        # and tests set it; None = resolve DRL_DEVICE_PATH normally).
+        # attached (it may force K=1).
+        # `device_path_force` overrides the env gate (tests set it;
+        # None = resolve DRL_DEVICE_PATH normally).
         self._device_path = None
         self._device_path_demoted = False
         self.device_path_force: bool | None = None

@@ -35,24 +35,21 @@ off-policyness V-trace/TD already corrects. This module is that tier:
 
 Actor-side replica selection lives in `transport.RemoteActService`
 (round-robin with least-pending bias, permanent demote of dead replicas,
-fall back to the learner's in-process service) so existing topologies —
-and the bench's jax-free client children — never import jax.
+fall back to the learner's in-process service), so existing
+topologies never import this module.
 
 Equivalence: a replica's acts are pinned to the learner-hosted service's
 (identical params + rng -> identical action rows;
 tests/test_serving.py's two-process test), because both run the same
 adapters, the same PRNG split discipline, and the same bucketed shapes.
 
-Nothing ships by default without adjudication (the repo's Pallas-LSTM
-rule): `launch_local_cluster --inference_replicas N` forces a replica
-count, `DRL_INFER_REPLICAS` overrides, and unset defers to the committed
-`benchmarks/inference_verdict.json` written from bench.py's
-`inference_compare` client-swarm A/B.
+No replicas by default (not measured on the chip):
+`launch_local_cluster --inference_replicas N` or `DRL_INFER_REPLICAS`
+sets a replica count.
 """
 
 from __future__ import annotations
 
-import json
 import os
 import queue as _queuemod
 import threading
@@ -66,61 +63,16 @@ from distributed_reinforcement_learning_tpu.runtime.inference import (
     InferenceServer,
     make_act_adapter,
 )
+from distributed_reinforcement_learning_tpu.utils.environ import env_float, env_int
 
-# -- adjudication gate --------------------------------------------------------
-
-_VERDICT_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "benchmarks", "inference_verdict.json")
-
-_DEFAULT_REPLICAS = 2  # auto-enabled count when the verdict carries none
+# -- gate ---------------------------------------------------------------------
 
 
-def replicas_auto_enabled(verdict_path: str = _VERDICT_PATH) -> bool:
-    """The committed `inference_compare` verdict (bench.py): replicas
-    ship enabled-by-default for --remote_act topologies only if the
-    client-swarm A/B showed >= 1.2x the learner-hosted actions/s."""
-    try:
-        with open(verdict_path) as f:
-            return bool(json.load(f).get("auto_enable", False))
-    except (OSError, ValueError):
-        return False
-
-
-def replica_count(verdict_path: str = _VERDICT_PATH) -> int:
-    """Resolved replica count for --remote_act topologies: 0 = acts stay
-    on the learner's in-process service.
-
-    `DRL_INFER_REPLICAS=0` forces learner-hosted, `=N` forces N
-    replicas; unset defers to the committed adjudication (which may
-    carry its own `replicas` count, default 2)."""
-    env = os.environ.get("DRL_INFER_REPLICAS", "").strip()
-    if env:
-        try:
-            return max(0, int(env))
-        except ValueError as e:
-            raise ValueError(
-                f"DRL_INFER_REPLICAS must be an integer, got {env!r}") from e
-    if not replicas_auto_enabled(verdict_path):
-        return 0
-    try:
-        with open(verdict_path) as f:
-            return max(1, int(json.load(f).get("replicas", _DEFAULT_REPLICAS)))
-    except (OSError, ValueError):
-        return _DEFAULT_REPLICAS
-
-
-def _env_int(name: str, default: int) -> int:
-    """Integer knob with the replica_count-style error contract: a
-    malformed value fails with the knob's NAME, not a raw ValueError
-    traceback out of replica startup."""
-    env = os.environ.get(name, "").strip()
-    if not env:
-        return default
-    try:
-        return int(env)
-    except ValueError as e:
-        raise ValueError(f"{name} must be an integer, got {env!r}") from e
+def replica_count() -> int:
+    """`DRL_INFER_REPLICAS`: act-serving replicas for --remote_act
+    topologies; 0 = acts stay on the learner's in-process service.
+    0 by default; not measured on the chip."""
+    return max(0, env_int("DRL_INFER_REPLICAS", 0))
 
 
 def admission_budget(max_batch: int) -> int:
@@ -129,7 +81,7 @@ def admission_budget(max_batch: int) -> int:
     two-deep dispatch pipeline full at max occupancy, small enough that
     a rejected client's jittered retry lands in the next batch or two
     instead of minutes of queue)."""
-    return _env_int("DRL_INFER_BUDGET", 4 * max_batch)
+    return env_int("DRL_INFER_BUDGET", 4 * max_batch)
 
 
 # -- continuous batcher -------------------------------------------------------
@@ -185,7 +137,7 @@ class ContinuousInferenceServer(InferenceServer):
         # time), so accepting the knob would be dead configuration
         # surface that misleads tuning.
         if depth is None:
-            depth = _env_int("DRL_INFER_DEPTH", 2)
+            depth = env_int("DRL_INFER_DEPTH", 2)
         self._inflight: _queuemod.Queue = _queuemod.Queue(maxsize=max(1, depth))
         self._completer: threading.Thread | None = None
         # Base __init__ starts the dispatch thread (targeting our
@@ -315,7 +267,7 @@ def run_replica(
     task = max(task, 0)
     open_devices(f"infer {task}")
     agent_cfg, rt = load_config(config_path, section)
-    port = _env_int("DRL_INFER_PORT", 0) or (rt.server_port + 1000 + task)
+    port = env_int("DRL_INFER_PORT", 0) or (rt.server_port + 1000 + task)
     host, lport = resolve_learner_addr(rt)
     client = TransportClient(host, lport)
     # The initial connect above kept the client's generous 60-retry
@@ -373,7 +325,7 @@ def run_replica(
                 f"learner at {host}:{lport} published no weights in "
                 f"{grace:.0f}s")
         time.sleep(0.2)
-    max_batch = _env_int("DRL_INFER_MAX_BATCH", 256)
+    max_batch = env_int("DRL_INFER_MAX_BATCH", 256)
     inference = ContinuousInferenceServer.for_agent(
         algo, agent, local, max_batch=max_batch,
         admission_rows=admission_budget(max_batch),
@@ -419,7 +371,7 @@ def run_replica(
                             kind="counter")
         if heartbeats is not None:
             fleet_mod.register_member_telemetry(heartbeats)
-    pull_s = float(os.environ.get("DRL_INFER_PULL_S", "0.2"))
+    pull_s = env_float("DRL_INFER_PULL_S", 0.2)
     print(f"[infer {task}] serving acts on :{port} "
           f"(weights v{version} from {host}:{lport}, "
           f"max_batch {max_batch}, budget {inference.admission_rows} rows)")

@@ -61,16 +61,12 @@ into its `TrajectoryQueue`; the actor attaches by name
 queue when the ring never appears or dies mid-run; the local-cluster
 launcher additionally reaps the segments after the topology exits, so a
 SIGKILLed learner cannot leak /dev/shm. `DRL_SHM_RING` gates the whole
-feature: 1 forces on, 0 forces off, unset defers to the committed
-`benchmarks/transport_verdict.json` adjudication written from bench.py's
-`transport_compare` section (the repo's Pallas-LSTM rule: no
-un-adjudicated fast path ships enabled).
+feature: off by default; not measured on the chip.
 """
 
 from __future__ import annotations
 
 import atexit
-import json
 import os
 import struct
 import threading
@@ -80,6 +76,7 @@ from typing import Any
 from distributed_reinforcement_learning_tpu.observability import TELEMETRY as _OBS
 from distributed_reinforcement_learning_tpu.runtime.fleet import ShmReattachMixin
 from distributed_reinforcement_learning_tpu.runtime.transport import _LockedStatsMixin
+from distributed_reinforcement_learning_tpu.utils.environ import env_flag, env_float
 
 _MAGIC = 0x52494E47  # "RING"
 _VERSION = 1
@@ -503,44 +500,20 @@ class ShmRing:
             pass
 
 
-# -- adjudication gate -------------------------------------------------------
-
-_VERDICT_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "benchmarks", "transport_verdict.json")
-
-
-def ring_auto_enabled(verdict_path: str = _VERDICT_PATH) -> bool:
-    """The committed `transport_compare` verdict (bench.py): rings ship
-    enabled-by-default only if the A/B showed >= 1.2x TCP PUT
-    throughput, mirroring the repo's Pallas-LSTM adjudication bar."""
-    try:
-        with open(verdict_path) as f:
-            return bool(json.load(f).get("auto_enable", False))
-    except (OSError, ValueError):
-        return False
+# -- gate ---------------------------------------------------------------------
 
 
 def ring_enabled() -> bool:
-    """DRL_SHM_RING=1 forces rings on, =0 off; unset/auto defers to the
-    committed adjudication — but never auto-enables off x86-64, where
-    the ring's store-ordering argument does not hold (module docstring);
-    the corrupt-record check + TCP fallback make a forced =1 survivable
-    for single-machine experimentation there."""
-    env = os.environ.get("DRL_SHM_RING", "").strip().lower()
-    if env in ("1", "true", "yes", "on"):
-        return True
-    if env in ("0", "false", "no", "off"):
-        return False
-    import platform
-
-    if platform.machine().lower() not in ("x86_64", "amd64"):
-        return False
-    return ring_auto_enabled()
+    """`DRL_SHM_RING`: per-actor shm rings for co-hosted PUTs. Off by
+    default; not measured on the chip. The ring's store-ordering
+    argument holds on x86-64 only (module docstring); the
+    corrupt-record check + TCP fallback make a forced =1 survivable for
+    single-machine experimentation elsewhere."""
+    return env_flag("DRL_SHM_RING", False)
 
 
 def ring_capacity_bytes() -> int:
-    return int(float(os.environ.get("DRL_SHM_RING_MB", "64")) * 1e6)
+    return int(env_float("DRL_SHM_RING_MB", 64.0) * 1e6)
 
 
 # -- learner side: create + drain into the TrajectoryQueue -------------------
@@ -916,7 +889,7 @@ def attach_ring_queue(name: str, client,
     from distributed_reinforcement_learning_tpu.runtime import fleet
 
     if deadline_s is None:
-        deadline_s = float(os.environ.get("DRL_SHM_RING_ATTACH_S", "5"))
+        deadline_s = env_float("DRL_SHM_RING_ATTACH_S", 5.0)
     deadline = time.monotonic() + deadline_s
     while True:
         try:

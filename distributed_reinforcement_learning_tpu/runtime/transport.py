@@ -37,6 +37,7 @@ from distributed_reinforcement_learning_tpu.data.fifo import blob_ingest
 from distributed_reinforcement_learning_tpu.observability import TELEMETRY as _OBS
 from distributed_reinforcement_learning_tpu.observability import maybe_configure
 from distributed_reinforcement_learning_tpu.observability.metrics import stale_bucket
+from distributed_reinforcement_learning_tpu.utils.environ import env_float, env_int
 
 OP_PUT_TRAJ = 1
 OP_GET_WEIGHTS = 2
@@ -427,7 +428,7 @@ class TransportServer(_LockedStatsMixin):
         # stop() unable to join it.
         with self._conns_lock:
             self._threads.append(t)
-        stats_s = float(os.environ.get("DRL_TRANSPORT_STATS_S", "0"))
+        stats_s = env_float("DRL_TRANSPORT_STATS_S", 0.0)
         if stats_s > 0:
             t2 = threading.Thread(target=self._stats_loop, args=(stats_s,),
                                   daemon=True, name="transport-stats")
@@ -1759,8 +1760,7 @@ def resolve_learner_addr(rt) -> tuple[str, int]:
     if addr:
         host, _, p = addr.rpartition(":")
         return host, int(p)
-    return rt.server_ip, rt.server_port + int(
-        os.environ.get("DRL_LEARNER_INDEX", "0"))
+    return rt.server_ip, rt.server_port + env_int("DRL_LEARNER_INDEX", 0)
 
 
 def _make_queue(capacity: int):
@@ -1812,11 +1812,11 @@ def run_role(
     # --staleness_budget): the launcher derives a publish cadence from
     # the `learner/weight_staleness` semantics and exports it here,
     # replacing the config section's fixed per-recipe default.
-    interval_env = os.environ.get("DRL_PUBLISH_INTERVAL")
-    if interval_env:
+    interval = env_int("DRL_PUBLISH_INTERVAL", 0)
+    if interval:
         import dataclasses as _dc
 
-        rt = _dc.replace(rt, publish_interval=max(1, int(interval_env)))
+        rt = _dc.replace(rt, publish_interval=max(1, interval))
 
     if mode == "learner":
         # Sharded learner tier (runtime/learner_tier.py): when the
@@ -2253,8 +2253,7 @@ def run_role(
         # Pipelined actor data plane (runtime/actor_pipeline.py):
         # double-buffered env slices + an async bounded publisher, so
         # the jitted/remote act and the encode+PUT overlap the host env
-        # stepping. DRL_ACTOR_PIPE forces; unset defers to the
-        # committed benchmarks/actor_pipeline_verdict.json. On the TCP
+        # stepping. Off unless DRL_ACTOR_PIPE is set. On the TCP
         # data plane the publisher gets its OWN client: the shared
         # client's request/reply lock would otherwise serialize a
         # publisher PUT against remote acts and weight pulls — exactly
@@ -2353,7 +2352,7 @@ def run_role(
         client.connect_retries = 3
         frames = 0
         down_since: float | None = None
-        stats_s = float(os.environ.get("DRL_TRANSPORT_STATS_S", "0"))
+        stats_s = env_float("DRL_TRANSPORT_STATS_S", 0.0)
         next_stats = time.monotonic() + stats_s
         try:
             while True:

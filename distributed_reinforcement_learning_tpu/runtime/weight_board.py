@@ -56,10 +56,7 @@ at exit (atexit backstop; the local-cluster launcher additionally reaps
 leaked segments). Actors attach by name (`DRL_SHM_WEIGHTS_NAME`) with a
 bounded retry and FALL BACK to TCP pulls when the board never appears,
 the writer latches closed, or a read fails. `DRL_SHM_WEIGHTS` gates the
-feature: 1 forces on, 0 off, unset defers to the committed
-`benchmarks/weights_verdict.json` adjudication written from bench.py's
-`weights_compare` section (the repo's Pallas-LSTM rule: no
-un-adjudicated fast path ships enabled).
+feature: on by default on x86-64; not measured on the chip.
 """
 
 from __future__ import annotations
@@ -67,6 +64,7 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import platform
 import struct
 import threading
 import time
@@ -81,6 +79,7 @@ from distributed_reinforcement_learning_tpu.runtime.shm_ring import (
     create_or_reclaim_shm,
 )
 from distributed_reinforcement_learning_tpu.runtime.transport import _LockedStatsMixin
+from distributed_reinforcement_learning_tpu.utils.environ import env_flag, env_float
 
 _MAGIC = 0x44525742  # "DRWB"
 _MAGIC_SHARDED = 0x44525753  # "DRWS": segmented (per-shard) layout
@@ -735,47 +734,24 @@ def attach_any(name: str):
     return WeightBoard.attach(name)
 
 
-# -- adjudication gate -------------------------------------------------------
-
-_VERDICT_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "benchmarks", "weights_verdict.json")
-
-
-def board_auto_enabled(verdict_path: str = _VERDICT_PATH) -> bool:
-    """The committed `weights_compare` verdict (bench.py): the board
-    ships enabled-by-default only if the A/B showed >= 1.2x, mirroring
-    the repo's Pallas-LSTM adjudication bar."""
-    try:
-        with open(verdict_path) as f:
-            return bool(json.load(f).get("auto_enable", False))
-    except (OSError, ValueError):
-        return False
+# -- gate ---------------------------------------------------------------------
 
 
 def board_enabled() -> bool:
-    """DRL_SHM_WEIGHTS=1 forces the board on, =0 off; unset/auto defers
-    to the committed adjudication — but never auto-enables off x86-64,
-    where the seqlock's store-ordering argument does not hold (module
+    """`DRL_SHM_WEIGHTS`: the shm weight board. On by default on x86-64
+    only, where the seqlock's store-ordering argument holds (module
     docstring); the stabilization check + TCP fallback make a forced =1
-    survivable for single-machine experimentation there."""
-    env = os.environ.get("DRL_SHM_WEIGHTS", "").strip().lower()
-    if env in ("1", "true", "yes", "on"):
-        return True
-    if env in ("0", "false", "no", "off"):
-        return False
-    import platform
-
-    if platform.machine().lower() not in ("x86_64", "amd64"):
-        return False
-    return board_auto_enabled()
+    survivable for single-machine experimentation elsewhere. Not
+    measured on the chip."""
+    return env_flag("DRL_SHM_WEIGHTS",
+                    platform.machine().lower() in ("x86_64", "amd64"))
 
 
 def board_capacity_bytes() -> int:
     """Per-slot capacity. /dev/shm pages are committed on first touch,
     so a generous default costs address space, not memory, until a blob
     of that size is actually published."""
-    return int(float(os.environ.get("DRL_SHM_WEIGHTS_MB", "64")) * 1e6)
+    return int(env_float("DRL_SHM_WEIGHTS_MB", 64.0) * 1e6)
 
 
 # -- learner side: create + attach to the WeightStore -------------------------
@@ -1080,7 +1056,7 @@ def attach_board_weights(name: str, client,
     from distributed_reinforcement_learning_tpu.runtime import fleet
 
     if deadline_s is None:
-        deadline_s = float(os.environ.get("DRL_SHM_WEIGHTS_ATTACH_S", "5"))
+        deadline_s = env_float("DRL_SHM_WEIGHTS_ATTACH_S", 5.0)
     deadline = time.monotonic() + deadline_s
     while True:
         try:

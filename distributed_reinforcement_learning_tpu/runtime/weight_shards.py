@@ -27,14 +27,11 @@ the sharded plane:
   updates byte-stable; full blobs are sent whenever the delta would not
   pay (the encoder bails past 75% of the full size).
 
-Gates follow the repo's adjudication rule: `DRL_WEIGHTS_SHARDED` /
-`DRL_WEIGHTS_QUANT` / `DRL_WEIGHTS_DELTA` force; unset defers to the
-committed `benchmarks/weights_shard_verdict.json` written from
-bench.py's `weights_shard_compare` A/B (whole-blob vs sharded vs
-sharded+bf16 at CNN and xformer shapes, honest 1.2x bar).
+Gates: `DRL_WEIGHTS_SHARDED` / `DRL_WEIGHTS_QUANT` /
+`DRL_WEIGHTS_DELTA`, all off by default; not measured on the chip.
 
-Everything here is jax-free numpy: it runs on transport serve threads,
-board readers, and bench children.
+Everything here is jax-free numpy: it runs on transport serve threads
+and board readers.
 """
 
 from __future__ import annotations
@@ -42,13 +39,13 @@ from __future__ import annotations
 import json
 import os
 import struct
-import threading
 import zlib
 from typing import Any
 
 import numpy as np
 
 from distributed_reinforcement_learning_tpu.data import bf16 as bf16_codec
+from distributed_reinforcement_learning_tpu.utils.environ import env_flag
 
 MANIFEST_V = 1
 
@@ -73,97 +70,31 @@ def crc32(buf) -> int:
 
 
 # -- feature gates ------------------------------------------------------------
-
-_VERDICT_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "benchmarks", "weights_shard_verdict.json")
-
-_flag_lock = threading.Lock()
-_flags: dict[str, Any] = {"sharded": None, "quant": None, "delta": None}
-
-
-def _verdict() -> dict:
-    try:
-        with open(_VERDICT_PATH) as f:
-            return json.load(f)
-    except (OSError, ValueError):
-        return {}
-
-
-def _resolve(name: str, compute) -> Any:
-    with _flag_lock:
-        cached = _flags[name]
-    if cached is not None:
-        return cached
-    value = compute()
-    with _flag_lock:
-        _flags[name] = value
-    return value
+# All three are off by default; not measured on the chip.
 
 
 def sharded_enabled() -> bool:
-    """DRL_WEIGHTS_SHARDED=1 forces per-shard publication on, =0 off;
-    unset defers to the committed `weights_shard_verdict.json`
-    adjudication (`auto_enable`) — the repo's 1.2x rule. Resolved once
-    per process; `refresh_flags()` re-reads (tests/bench)."""
-
-    def compute():
-        env = os.environ.get("DRL_WEIGHTS_SHARDED", "").strip().lower()
-        if env in ("1", "true", "yes", "on"):
-            return True
-        if env in ("0", "false", "no", "off"):
-            return False
-        return bool(_verdict().get("auto_enable", False))
-
-    return _resolve("sharded", compute)
+    """`DRL_WEIGHTS_SHARDED`: per-shard publication."""
+    return env_flag("DRL_WEIGHTS_SHARDED", False)
 
 
 def quant_mode() -> str | None:
     """None (f32 broadcast), "bf16", or "int8". `DRL_WEIGHTS_QUANT`
-    forces a mode (`1` means bf16, `0` disables); unset defers to the
-    committed verdict (`quant_auto_enable` + its `quant_mode`). Only
-    meaningful when sharded publication is active — the whole-blob path
-    never quantizes."""
-
-    def compute():
-        env = os.environ.get("DRL_WEIGHTS_QUANT", "").strip().lower()
-        if env in QUANT_MODES:
-            return env
-        if env in ("1", "true", "yes", "on"):
-            return "bf16"
-        if env in ("0", "false", "no", "off"):
-            return "off"
-        v = _verdict()
-        if not v.get("quant_auto_enable", False):
-            return "off"
-        mode = str(v.get("quant_mode", "bf16")).lower()
-        return mode if mode in QUANT_MODES else "bf16"
-
-    mode = _resolve("quant", compute)
-    return None if mode == "off" else mode
+    names a mode or is a flag (`1` means bf16). Only meaningful when
+    sharded publication is active — the whole-blob path never
+    quantizes."""
+    env = os.environ.get("DRL_WEIGHTS_QUANT", "").strip().lower()
+    if env in QUANT_MODES:
+        return env
+    try:
+        return "bf16" if env_flag("DRL_WEIGHTS_QUANT", False) else None
+    except ValueError as e:
+        raise ValueError(f"{e} (or a mode: {'|'.join(QUANT_MODES)})") from None
 
 
 def delta_enabled() -> bool:
-    """DRL_WEIGHTS_DELTA=1 forces per-shard delta publication for TCP
-    pulls, =0 off; unset defers to the committed verdict
-    (`delta_auto_enable`)."""
-
-    def compute():
-        env = os.environ.get("DRL_WEIGHTS_DELTA", "").strip().lower()
-        if env in ("1", "true", "yes", "on"):
-            return True
-        if env in ("0", "false", "no", "off"):
-            return False
-        return bool(_verdict().get("delta_auto_enable", False))
-
-    return _resolve("delta", compute)
-
-
-def refresh_flags() -> None:
-    """Re-resolve the env/verdict gates (tests, bench variants)."""
-    with _flag_lock:
-        for k in _flags:
-            _flags[k] = None
+    """`DRL_WEIGHTS_DELTA`: per-shard delta publication for TCP pulls."""
+    return env_flag("DRL_WEIGHTS_DELTA", False)
 
 
 def role_keys() -> list[str] | None:
@@ -217,8 +148,7 @@ def quantize_leaves(leaves: list[np.ndarray], mode: str
 
 def dequantize_leaves(leaves: list[np.ndarray], meta: dict) -> list[np.ndarray]:
     """Inverse of `quantize_leaves` back to f32 (lossy by construction;
-    the bf16 policy-equivalence check in bench.py is the evidence the
-    loss does not move actions)."""
+    tests/test_weight_sharding.py pins the error bounds)."""
     mode = meta["mode"]
     out = list(leaves)
     for j, i in enumerate(meta["cast"]):

@@ -8,8 +8,8 @@ learner publishes a version-stamped params snapshot; actors poll
 off-policyness, corrected by V-trace), but publication is a single
 atomic reference swap instead of per-variable assigns.
 
-Publication is ENCODE-ONCE (the learner-side fix for the `publish` p99
-spikes both committed perf verdicts blamed on the copy path): the
+Publication is ENCODE-ONCE (the learner-side fix for `publish` p99
+spikes on the copy path): the
 background worker's D2H lands directly in a codec-layout host blob —
 one buffer allocation per publish with a schema-cached frozen layout
 (`data/codec.py`), not one fresh numpy array per leaf — and every
@@ -138,7 +138,7 @@ class WeightStore:
         self.sharded = (weight_shards.sharded_enabled()
                         if sharded is None else bool(sharded))
         # quant: None defers to the gate, "" forces off, "bf16"/"int8"
-        # force a mode (bench variants pin both knobs explicitly).
+        # force a mode.
         if not self.sharded:
             self._quant = None
         elif quant is None:
@@ -384,7 +384,7 @@ class WeightStore:
             except Exception as e:  # drop the item, keep the worker alive —
                 # a dead worker would freeze actor weights forever while
                 # training silently continues. (stderr: stdout may carry a
-                # machine-read JSON contract, e.g. bench.py's one line.)
+                # machine-read JSON contract.)
                 import sys
 
                 print(f"[weights] WARNING: async publish of version "

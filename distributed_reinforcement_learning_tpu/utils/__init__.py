@@ -1,6 +1,6 @@
-"""Config, logging, checkpointing, profiling utilities (reference layer L5)."""
+"""Config, logging, checkpointing, profiling utilities (reference layer L5).
 
-from distributed_reinforcement_learning_tpu.utils.config import RuntimeConfig, check_config, load_config
-from distributed_reinforcement_learning_tpu.utils.logger import MetricsLogger
-
-__all__ = ["RuntimeConfig", "check_config", "load_config", "MetricsLogger"]
+Import the submodule you need (`utils.config`, `utils.logger`, ...): this
+package imports none of them itself, so the lowest layers can read a knob
+through `utils.environ` without loading the agents or a metrics writer.
+"""

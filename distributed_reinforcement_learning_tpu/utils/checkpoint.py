@@ -29,6 +29,8 @@ from typing import Any
 
 from flax import serialization
 
+from distributed_reinforcement_learning_tpu.utils.environ import env_flag, env_float
+
 _CKPT_RE = re.compile(r"^ckpt_(\d{10})\.msgpack$")
 
 
@@ -41,9 +43,9 @@ def encode_replay_snapshot(replay) -> bytes | None:
     `DRL_CKPT_REPLAY_MAX_MB` (default 512) because a full Atari replay at
     capacity 1e5 is ~5 GB and would dominate every checkpoint write.
     """
-    if os.environ.get("DRL_CKPT_REPLAY", "1") == "0":
+    if not env_flag("DRL_CKPT_REPLAY", True):
         return None
-    cap_mb = float(os.environ.get("DRL_CKPT_REPLAY_MAX_MB", "512"))
+    cap_mb = env_float("DRL_CKPT_REPLAY_MAX_MB", 512.0)
 
     def over_cap(nbytes: int) -> bool:
         if nbytes > cap_mb * 1e6:

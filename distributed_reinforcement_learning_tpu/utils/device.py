@@ -2,7 +2,7 @@
 which device this process opened.
 
 Two calls, both made once per process by every entry point (the five
-`train_*.py` launchers, `bench.py`, `chip_smoke.py`, the profiling
+`train_*.py` launchers, `chip_smoke.py`, the profiling
 scripts):
 
 - `enable_compile_cache()` before the first jit. A cold IMPALA learn

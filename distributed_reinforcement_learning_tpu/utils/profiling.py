@@ -32,6 +32,7 @@ from typing import Iterator
 
 from distributed_reinforcement_learning_tpu.observability import TELEMETRY as _OBS
 from distributed_reinforcement_learning_tpu.observability import chip_span
+from distributed_reinforcement_learning_tpu.utils.environ import env_int
 from distributed_reinforcement_learning_tpu.utils.logger import MetricsLogger
 
 
@@ -122,8 +123,8 @@ class ProfilerSession:
         """DRL_PROFILE_DIR / DRL_PROFILE_START / DRL_PROFILE_STEPS."""
         return cls(
             os.environ.get("DRL_PROFILE_DIR") or None,
-            start_step=int(os.environ.get("DRL_PROFILE_START", "10")),
-            num_steps=int(os.environ.get("DRL_PROFILE_STEPS", "5")),
+            start_step=env_int("DRL_PROFILE_START", 10),
+            num_steps=env_int("DRL_PROFILE_STEPS", 5),
         )
 
     def on_step(self, step: int) -> None:

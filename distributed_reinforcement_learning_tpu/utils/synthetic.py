@@ -1,4 +1,4 @@
-"""Synthetic batch builders shared by bench.py, __graft_entry__.py and tests.
+"""Synthetic batch builders shared by __graft_entry__.py and tests.
 
 One parameterized constructor per batch type so a field change in the
 agents' Batch NamedTuples breaks every consumer at the same place.

@@ -62,7 +62,7 @@ def parse_args() -> argparse.Namespace:
                    help="conv torso: reference Nature-CNN, or the IMPALA "
                         "paper's deep ResNet (the MXU-dense variant)")
     p.add_argument("--torso-width", type=int, default=1,
-                   help="ResNet channel multiplier (bench's MXU-dense "
+                   help="ResNet channel multiplier (the MXU-dense "
                         "configuration uses 4)")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--platform", default=None,

@@ -23,9 +23,8 @@ restores from `--checkpoint_dir` when given, and the surviving actors'
 heartbeat-driven reattach ladders re-promote them off their TCP
 demotions. `--chaos` additionally KILLS roles mid-run on an escalation
 schedule (actor, then inference replica, then learner, every
-`--chaos_interval` seconds) — the launcher-level chaos drill
-`bench.py chaos_compare` adjudicates; it implies `--respawn chaos`
-(same respawn behavior as on-exit, plus the kill schedule).
+`--chaos_interval` seconds); it implies `--respawn chaos` (same
+respawn behavior as on-exit, plus the kill schedule).
 """
 
 from __future__ import annotations
@@ -34,54 +33,22 @@ import argparse
 import os
 import signal
 import socket
-import struct
 import subprocess
 import sys
 import threading
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
-
-# Segment-header creator-pid helpers, INLINED (mirroring
-# runtime/shm_ring.segment_owner_pid / pid_alive, the canonical
-# definitions) for the same reason as the gates below: importing the
-# package pulls jax into the launcher parent. Offset 24 carries the
-# creating pid in every ring/board layout.
-_SHM_PID_OFF = 24
-
-
-def _pid_alive(pid: int) -> bool:
-    if pid <= 0:
-        return False
-    try:
-        os.kill(pid, 0)
-        return True
-    except ProcessLookupError:
-        return False
-    except PermissionError:
-        return True
-
-
-def _segment_owner_pid(name: str) -> int:
-    from multiprocessing import shared_memory
-
-    try:
-        seg = shared_memory.SharedMemory(name=name)
-    except (FileNotFoundError, OSError, ValueError):
-        return 0
-    try:
-        from multiprocessing import resource_tracker
-
-        resource_tracker.unregister(seg._name, "shared_memory")  # noqa: SLF001
-    except Exception:  # noqa: BLE001 — tracker internals moved
-        pass
-    try:
-        if seg.size < _SHM_PID_OFF + 8:
-            return 0
-        return int(struct.unpack_from("<Q", seg.buf, _SHM_PID_OFF)[0])
-    finally:
-        seg.close()
+# The package owns every default this launcher plans by. Importing it
+# loads JAX but opens no device, so the chip stays free for the learner.
+from distributed_reinforcement_learning_tpu.runtime import (  # noqa: E402
+    learner_tier,
+    serving,
+    shm_ring,
+    weight_board,
+)
 
 
 def _reap_segments(names, why: str) -> None:
@@ -92,8 +59,7 @@ def _reap_segments(names, why: str) -> None:
     from multiprocessing import shared_memory
 
     for name in names:
-        owner = _segment_owner_pid(name)
-        if _pid_alive(owner):
+        if shm_ring.pid_alive(shm_ring.segment_owner_pid(name)):
             continue  # a live (respawned) owner: not ours to reap
         try:
             seg = shared_memory.SharedMemory(name=name)
@@ -136,10 +102,9 @@ def main() -> None:
                         "LEARNER TIER (independent seats exchanging "
                         "gradients over the host collective, "
                         "runtime/learner_tier.py) when seat mode "
-                        "resolves on (--learner_sync / DRL_LEARNER_SEATS "
-                        "/ the committed learner_verdict), else the "
-                        "jax.distributed multihost learners over one "
-                        "global mesh")
+                        "resolves on (--learner_sync / DRL_LEARNER_SEATS), "
+                        "else the jax.distributed multihost learners "
+                        "over one global mesh")
     p.add_argument("--learner_sync", choices=("allreduce", "async",
                                               "multihost"), default=None,
                    help="with --learners N>1: force the learner-tier "
@@ -148,8 +113,6 @@ def main() -> None:
                         "gradient exchange; async: bounded-staleness "
                         "parameter merging) or force the old multihost "
                         "pjit group. Unset defers to DRL_LEARNER_SEATS, "
-                        "then the committed "
-                        "benchmarks/learner_verdict.json adjudication, "
                         "then multihost; see docs/performance.md "
                         "'Learner tier'")
     p.add_argument("--updates", type=int, default=500)
@@ -170,18 +133,16 @@ def main() -> None:
                         "the learner's weight plane (shm board / TCP "
                         "fallback) and serves OP_ACT on its own port "
                         "with continuous batching + admission control "
-                        "(DRL_INFER_REPLICAS; 0 forces learner-hosted "
-                        "acts). Unset defers to the committed "
-                        "benchmarks/inference_verdict.json adjudication; "
-                        "see docs/performance.md 'Inference serving'")
+                        "(DRL_INFER_REPLICAS; 0, the default, keeps "
+                        "acts learner-hosted); see docs/performance.md "
+                        "'Inference serving'")
     p.add_argument("--replay_shards", type=int, default=None,
                    help="prioritized-replay learners (apex/r2d2/xformer): "
                         "N>=1 shards replay across the learner's ingest "
                         "threads with ingest-time prioritization "
                         "(DRL_REPLAY_SHARDS; 0 forces the monolithic "
-                        "path). Unset defers to the committed "
-                        "benchmarks/replay_verdict.json adjudication; "
-                        "see docs/performance.md 'Replay shards'")
+                        "path; 2 by default); see docs/performance.md "
+                        "'Replay shards'")
     p.add_argument("--weights_sharded", type=int, default=None,
                    choices=(0, 1),
                    help="force per-shard weight publication on (1) or "
@@ -189,10 +150,9 @@ def main() -> None:
                         "partition-keyed shard blobs + manifest on the "
                         "board and the shard-scoped TCP pull; pair with "
                         "DRL_WEIGHTS_QUANT=bf16|int8 / DRL_WEIGHTS_DELTA "
-                        "for the quantized/delta broadcast). Unset "
-                        "defers to the committed "
-                        "benchmarks/weights_shard_verdict.json; see "
-                        "docs/performance.md 'Sharded weight plane'")
+                        "for the quantized/delta broadcast). Off by "
+                        "default; see docs/performance.md 'Sharded "
+                        "weight plane'")
     p.add_argument("--respawn", choices=("off", "on-exit", "chaos"),
                    default=None,
                    help="elastic-fleet respawn policy: on-exit re-spawns "
@@ -207,8 +167,7 @@ def main() -> None:
                         "(actor, inference replica, learner — one each, "
                         "--chaos_interval apart) and respawn them; the "
                         "fleet supervisor + reattach ladders must carry "
-                        "the topology through (bench.py chaos_compare is "
-                        "the adjudicated version of this drill)")
+                        "the topology through")
     p.add_argument("--chaos_interval", type=float, default=20.0,
                    help="seconds between chaos kills (default 20)")
     p.add_argument("--max_respawns", type=int, default=5,
@@ -236,35 +195,24 @@ def main() -> None:
     if args.chaos and respawn == "off":
         p.error("--chaos needs a respawn policy; drop --respawn off")
 
+    def ask(resolve):
+        """What the package resolves a knob to; a malformed value is a
+        usage error of this command."""
+        try:
+            return resolve()
+        except ValueError as e:
+            p.error(str(e))
+
     # Learner-tier seat mode (runtime/learner_tier.py): with
     # --learners N>1, decide between N cooperating SEATS over the host
-    # collective and the old jax.distributed multihost pjit group. The
-    # gate is INLINED (canonical resolution: learner_tier.seat_count /
-    # sync_mode) for the same import-cost reason as shm_gate below.
+    # collective and the old jax.distributed multihost pjit group.
     def learner_tier_sync() -> str | None:
         if args.learners <= 1 or args.learner_sync == "multihost":
             return None
-        env_sync = os.environ.get("DRL_LEARNER_SYNC", "").strip().lower()
         if args.learner_sync in ("allreduce", "async"):
             return args.learner_sync
-        env_n = os.environ.get("DRL_LEARNER_SEATS", "").strip()
-        if env_n:
-            try:
-                n = int(env_n)
-            except ValueError:
-                p.error(f"DRL_LEARNER_SEATS must be an integer, got {env_n!r}")
-            return (env_sync or "allreduce") if n >= 2 else None
-        import json
-
-        try:
-            with open(os.path.join(REPO, "benchmarks",
-                                   "learner_verdict.json")) as f:
-                verdict = json.load(f)
-            if verdict.get("auto_enable", False):
-                return env_sync or str(verdict.get("sync", "allreduce"))
-        except (OSError, ValueError):
-            pass
-        return None
+        return (ask(learner_tier.sync_mode)
+                if ask(learner_tier.seat_count) >= 2 else None)
 
     tier_sync = learner_tier_sync()
     if tier_sync == "allreduce" and algo != "apex":
@@ -384,33 +332,11 @@ def main() -> None:
               file=sys.stderr)
     # Everything this launcher spawns shares one host, so every
     # actor/learner pair is co-hosted: wire one shm ring per actor
-    # (runtime/shm_ring.py) when rings are enabled — DRL_SHM_RING=1/0
-    # overrides, unset defers to the committed transport_compare verdict
-    # on x86-64 only. The gate is INLINED (mirroring
-    # shm_ring.ring_enabled, the canonical definition) because importing
-    # the package here pulls jax into the launcher parent — a measured
-    # ~2s tax on every launch just to read an env var and a JSON file.
-    def shm_gate(env_key: str, verdict_file: str) -> bool:
-        gate = os.environ.get(env_key, "").strip().lower()
-        if gate in ("1", "true", "yes", "on"):
-            return True
-        if gate in ("0", "false", "no", "off"):
-            return False
-        import json
-        import platform
-
-        if platform.machine().lower() not in ("x86_64", "amd64"):
-            return False
-        try:
-            with open(os.path.join(REPO, "benchmarks", verdict_file)) as f:
-                return bool(json.load(f).get("auto_enable", False))
-        except (OSError, ValueError):
-            return False
-
+    # (runtime/shm_ring.py) when rings are enabled.
     ring_names: dict[int, str] = {}
     board_names: dict[int, str] = {}
     tag = f"{os.getpid()}-{os.urandom(4).hex()}"
-    if shm_gate("DRL_SHM_RING", "transport_verdict.json"):
+    if ask(shm_ring.ring_enabled):
         ring_names = {task: f"drlring-{tag}-{task}"
                       for task in range(args.actors)}
         print(f"[cluster] shm rings enabled for {args.actors} co-hosted "
@@ -418,10 +344,8 @@ def main() -> None:
     # The weight plane's mirror: ONE board per learner, shared by every
     # actor partitioned to it (runtime/weight_board.py) — publish is one
     # memcpy + flip regardless of actor count, pulls are shared-memory
-    # reads. Same gate shape as the rings: env forces, unset defers to
-    # the committed weights_compare adjudication on x86-64 only (the
-    # gate is INLINED for the same import-cost reason as above).
-    if shm_gate("DRL_SHM_WEIGHTS", "weights_verdict.json"):
+    # reads.
+    if ask(weight_board.board_enabled):
         if tier_sync is not None:
             # Seat mode: ONE shared board name for the whole tier —
             # only the elected publisher seat creates/writes it
@@ -437,34 +361,15 @@ def main() -> None:
         print(f"[cluster] shm weight board(s) enabled for {args.actors} "
               f"co-hosted actor(s)", file=sys.stderr)
 
-    # Inference tier sizing: --inference_replicas forces, else the env /
-    # committed inference_compare adjudication decides (INLINED like
-    # shm_gate — the canonical resolution is runtime/serving.py's
-    # replica_count, but importing the package pulls jax into the
-    # launcher parent). Replicas only make sense for remote-act actors.
+    # Inference tier sizing: --inference_replicas forces, else
+    # DRL_INFER_REPLICAS decides. Replicas only make sense for
+    # remote-act actors.
     def infer_replicas() -> int:
         if args.inference_replicas is not None:
             return max(0, args.inference_replicas)
         if not args.remote_act:
             return 0
-        env_n = os.environ.get("DRL_INFER_REPLICAS", "").strip()
-        if env_n:
-            try:
-                return max(0, int(env_n))
-            except ValueError:
-                p.error(f"DRL_INFER_REPLICAS must be an integer, "
-                        f"got {env_n!r}")
-        import json
-
-        try:
-            with open(os.path.join(REPO, "benchmarks",
-                                   "inference_verdict.json")) as f:
-                verdict = json.load(f)
-            if not verdict.get("auto_enable", False):
-                return 0
-            return max(1, int(verdict.get("replicas", 2)))
-        except (OSError, ValueError):
-            return 0
+        return ask(serving.replica_count)
 
     n_infer = infer_replicas()
     if n_infer and not args.remote_act:
@@ -637,7 +542,7 @@ def main() -> None:
         except subprocess.TimeoutExpired:
             role.proc.kill()
             # Reap the SIGKILLed child: a zombie still passes the shm
-            # sweep's _pid_alive check below, which would skip every
+            # sweep's pid_alive check below, which would skip every
             # segment the dead learner owned.
             try:
                 role.proc.wait(timeout=10)

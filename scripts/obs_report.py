@@ -480,7 +480,7 @@ def build_report(tdir: str, merge: bool = True) -> str:
     # fill, RAM vs on-disk footprint, spill/promote traffic, and the
     # promote-wait latency parked cold draws paid before the pump
     # delivered their segment. Section only appears when a run had the
-    # spill tier on (DRL_REPLAY_SPILL / committed verdict).
+    # spill tier on (DRL_REPLAY_SPILL, on by default).
     spill_lines: list[str] = []
     for shard in shards:
         per = sorted(
@@ -993,7 +993,7 @@ def build_report(tdir: str, merge: bool = True) -> str:
     if not any_stale:
         out("  (no staleness gauges — actors may not have pulled weights)")
 
-    # Runtime sanitizer (tools/drlint/rt): a chaos/bench run executed
+    # Runtime sanitizer (tools/drlint/rt): a chaos run executed
     # under DRL_SANITIZE=1 leaves a sanitize*.jsonl artifact next to
     # the telemetry; render findings-by-rule and the hottest hold-time
     # sites so a sanitized run reads with the same tooling as a plain

@@ -4,7 +4,7 @@ A roofline says how far the flagship learn step is from its own
 attainable time, not WHERE the rest goes. This script measures the
 step's components independently on the device (lax.scan of K
 data-dependently-coupled iterations, two-window marginal, completion
-forced by materializing the carry — `bench.py`'s methodology), and
+forced by materializing the carry), and
 reports a breakdown that must sum to the measured step within ~10%.
 Not measured on the attached chip yet:
 
@@ -158,7 +158,7 @@ def main() -> None:
         results[f"{name}_ms"] = timed(name, fn)
 
     # Full learn step, scan-timed with the real state carry (the honest
-    # device time, same as bench_learn_scan).
+    # device time).
     def learn_scan(n):
         return jax.jit(lambda s: lax.scan(
             lambda s, _: (agent._learn(s, batch)[0], None), s, None,

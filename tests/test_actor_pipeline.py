@@ -10,7 +10,8 @@ The load-bearing pins:
 - FAILURE DRILLS: killing the publisher thread or erroring a slice
   mid-round demotes to the sequential per-slice loop with zero lost or
   corrupted unrolls, and the bounded RetryLadder re-promotes.
-- GATE: DRL_ACTOR_PIPE forces; unset defers to the committed verdict.
+- GATE: `maybe_wrap` follows DRL_ACTOR_PIPE (off by default;
+  tests/test_gate_defaults.py pins the resolution).
 """
 
 import json
@@ -600,25 +601,6 @@ def test_stuck_publisher_latches_wedge_instead_of_double_producing():
     for payload in leftover:
         pub.publish_one(payload)
     assert slow.size() == 3
-
-
-def test_gate_resolution(monkeypatch, tmp_path):
-    monkeypatch.setenv("DRL_ACTOR_PIPE", "1")
-    assert actor_pipeline.pipeline_enabled()
-    monkeypatch.setenv("DRL_ACTOR_PIPE", "0")
-    assert not actor_pipeline.pipeline_enabled()
-    monkeypatch.delenv("DRL_ACTOR_PIPE")
-    on = tmp_path / "on.json"
-    on.write_text(json.dumps({"auto_enable": True}))
-    off = tmp_path / "off.json"
-    off.write_text(json.dumps({"auto_enable": False}))
-    monkeypatch.setattr(actor_pipeline, "_VERDICT_PATH", str(on))
-    assert actor_pipeline.pipeline_enabled()
-    monkeypatch.setattr(actor_pipeline, "_VERDICT_PATH", str(off))
-    assert not actor_pipeline.pipeline_enabled()
-    monkeypatch.setattr(actor_pipeline, "_VERDICT_PATH",
-                        str(tmp_path / "missing.json"))
-    assert not actor_pipeline.pipeline_enabled()
 
 
 def test_maybe_wrap_respects_gate_and_sliceability(monkeypatch):

@@ -78,9 +78,6 @@ def td_proxy_env(monkeypatch):
     monkeypatch.setenv("DRL_ACTOR_PRIORITY", "1")
     monkeypatch.setenv("DRL_ADMISSION", "0")
     monkeypatch.delenv("DRL_ADMISSION_PRESSURE", raising=False)
-    admission.refresh_flags()
-    yield
-    admission.refresh_flags()
 
 
 class TestScorerBitEquality:
@@ -131,13 +128,9 @@ class TestScorerBitEquality:
     def test_max_scorer_cannot_stamp(self, monkeypatch):
         monkeypatch.setenv("DRL_ACTOR_PRIORITY", "1")
         monkeypatch.setenv("DRL_REPLAY_SCORER", "max")
-        admission.refresh_flags()
-        try:
-            assert admission.maybe_controller("apex") is None
-            with pytest.raises(ValueError):
-                AdmissionController("transition", "max")
-        finally:
-            admission.refresh_flags()
+        assert admission.maybe_controller("apex") is None
+        with pytest.raises(ValueError):
+            AdmissionController("transition", "max")
 
     def test_algo_modes_pin_matches_runtime_map(self):
         # data/ must not import runtime/: the mode map is mirrored, and
@@ -217,7 +210,6 @@ class TestAdmissionDistribution:
         monkeypatch.setenv("DRL_ACTOR_PRIORITY", "1")
         monkeypatch.setenv("DRL_ADMISSION", "1")
         monkeypatch.setenv("DRL_ADMISSION_PRESSURE", str(pressure))
-        admission.refresh_flags()
         ctrl = AdmissionController("transition", "td_proxy", seed=seed)
         ctrl._mu = mu  # pin the fleet mean: q_i is then analytic
         ctrl._mu_n = 1
@@ -340,7 +332,6 @@ class TestAdmissionDistribution:
 def finally_refresh(monkeypatch):
     """Re-resolve the gates after the monkeypatched env is gone."""
     monkeypatch.undo()
-    admission.refresh_flags()
 
 
 class TestLazyBlobDeferral:
@@ -408,7 +399,6 @@ class TestMixedFleetTcp:
         monkeypatch.setenv("DRL_ACTOR_PRIORITY", "1")
         monkeypatch.setenv("DRL_ADMISSION", "1")
         monkeypatch.setenv("DRL_ADMISSION_PRESSURE", "1.0")
-        admission.refresh_flags()
         svc = ShardedReplayService(1, 512, mode="transition",
                                    scorer="td_proxy", seed=0)
         fifo = ReplayIngestFifo(svc, TrajectoryQueue(16))
@@ -437,7 +427,6 @@ class TestMixedFleetTcp:
         finally:
             server.stop()
             svc.close()
-            admission.refresh_flags()
 
 
 class TestShmRingPath:
@@ -478,7 +467,6 @@ class TestBackpressure:
         monkeypatch.setenv("DRL_ACTOR_PRIORITY", "1")
         monkeypatch.setenv("DRL_ADMISSION", "1")
         monkeypatch.delenv("DRL_ADMISSION_PRESSURE", raising=False)
-        admission.refresh_flags()
         queue = TrajectoryQueue(capacity=10)
         server = TransportServer(queue, WeightStore(), host="127.0.0.1",
                                  port=_free_port()).start()
@@ -507,7 +495,6 @@ class TestBackpressure:
         finally:
             server.stop()
             queue.close()
-            admission.refresh_flags()
 
     def test_duty_meter_decays_idle(self):
         meter = DutyMeter()
@@ -530,12 +517,3 @@ class TestTransforms:
         np.testing.assert_allclose(
             inverse_transform(transform(errors)), errors, atol=1e-12)
 
-    def test_gates_follow_env_then_verdict(self, monkeypatch):
-        monkeypatch.setenv("DRL_ACTOR_PRIORITY", "1")
-        admission.refresh_flags()
-        assert admission.actor_priority_enabled()
-        monkeypatch.setenv("DRL_ACTOR_PRIORITY", "0")
-        admission.refresh_flags()
-        assert not admission.actor_priority_enabled()
-        monkeypatch.undo()
-        admission.refresh_flags()

@@ -34,13 +34,11 @@ from test_shm_ring import assert_trees_bit_identical  # noqa: E402
 @pytest.fixture(autouse=True)
 def _cache_on(monkeypatch):
     """Every test here runs with the schema cache forced ON and a clean
-    cache, independent of the committed verdict's default."""
+    cache, whatever the default."""
     monkeypatch.setenv("DRL_CODEC_CACHE", "1")
     monkeypatch.delenv("DRL_OBS_DEDUP", raising=False)
-    codec.refresh_flags()
     codec.clear_caches()
     yield
-    codec.refresh_flags()
     codec.clear_caches()
 
 
@@ -76,7 +74,6 @@ class TestSchemaCache:
         tree = mixed_tree()
         cached = bytes(codec.encode(tree))
         monkeypatch.setenv("DRL_CODEC_CACHE", "0")
-        codec.refresh_flags()
         assert bytes(codec.encode(tree)) == cached
 
     def test_mixed_schemas_interleaved(self):
@@ -270,10 +267,13 @@ class TestPutBatchKnob:
         put_round(Q(), [object()] * 10)
         assert calls == [4, 4, 2]
 
-    def test_invalid_value_keeps_default(self, monkeypatch):
+    def test_invalid_value_is_loud(self, monkeypatch):
         monkeypatch.setenv("DRL_PUT_BATCH", "banana")
         from distributed_reinforcement_learning_tpu.data.fifo import put_batch_size
 
+        with pytest.raises(ValueError, match="DRL_PUT_BATCH"):
+            put_batch_size()
+        monkeypatch.setenv("DRL_PUT_BATCH", "-3")
         assert put_batch_size() == 0
 
 
@@ -305,28 +305,3 @@ class TestDedupTwoProcessE2E:
         want = make_stacked_trajectories(seed, count)
         for g, w in zip(got, want):
             assert_trees_bit_identical(g, w)
-
-
-class TestGateResolution:
-    def test_env_forces_override_verdict(self, monkeypatch):
-        monkeypatch.setenv("DRL_OBS_DEDUP", "1")
-        codec.refresh_flags()
-        assert codec.obs_dedup_enabled() is True
-        monkeypatch.setenv("DRL_OBS_DEDUP", "0")
-        codec.refresh_flags()
-        assert codec.obs_dedup_enabled() is False
-
-    def test_unset_defers_to_committed_verdict(self, monkeypatch):
-        import json
-
-        monkeypatch.delenv("DRL_CODEC_CACHE", raising=False)
-        monkeypatch.delenv("DRL_OBS_DEDUP", raising=False)
-        codec.refresh_flags()
-        verdict_path = REPO / "benchmarks" / "codec_verdict.json"
-        if not verdict_path.exists():
-            assert codec.cache_enabled() is False  # conservative default
-            assert codec.obs_dedup_enabled() is False
-            return
-        verdict = json.loads(verdict_path.read_text())
-        assert codec.cache_enabled() is bool(verdict.get("cache_auto_enable"))
-        assert codec.obs_dedup_enabled() is bool(verdict.get("dedup_auto_enable"))

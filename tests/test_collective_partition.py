@@ -29,7 +29,6 @@ What this suite pins, seat by seat:
 
 from __future__ import annotations
 
-import json
 import socket
 import threading
 import time
@@ -438,48 +437,32 @@ class TestCollGates:
         for key in ("DRL_COLL_PARTITION", "DRL_COLL_QUANT",
                     "DRL_COLL_OVERLAP"):
             monkeypatch.delenv(key, raising=False)
-        learner_tier.refresh_coll_flags()
         yield monkeypatch
-        learner_tier.refresh_coll_flags()
 
     def test_partition_defaults_on_and_env_forces(self, monkeypatch):
         assert learner_tier.coll_partition() is True
         monkeypatch.setenv("DRL_COLL_PARTITION", "0")
-        learner_tier.refresh_coll_flags()
         assert learner_tier.coll_partition() is False
         monkeypatch.setenv("DRL_COLL_PARTITION", "1")
-        learner_tier.refresh_coll_flags()
         assert learner_tier.coll_partition() is True
 
     def test_quant_env_forces(self, monkeypatch):
         monkeypatch.setenv("DRL_COLL_QUANT", "bf16")
-        learner_tier.refresh_coll_flags()
         assert learner_tier.coll_quant() == "bf16"
         monkeypatch.setenv("DRL_COLL_QUANT", "0")
-        learner_tier.refresh_coll_flags()
         assert learner_tier.coll_quant() == "f32"
 
     def test_overlap_env_caps_depth_at_one(self, monkeypatch):
         monkeypatch.setenv("DRL_COLL_OVERLAP", "3")
-        learner_tier.refresh_coll_flags()
         assert learner_tier.coll_overlap() == 1
         monkeypatch.setenv("DRL_COLL_OVERLAP", "0")
-        learner_tier.refresh_coll_flags()
         assert learner_tier.coll_overlap() == 0
 
     def test_overlap_non_integer_is_loud(self, monkeypatch):
         monkeypatch.setenv("DRL_COLL_OVERLAP", "yes")
-        learner_tier.refresh_coll_flags()
         with pytest.raises(ValueError, match="DRL_COLL_OVERLAP"):
             learner_tier.coll_overlap()
 
-    def test_unset_follows_committed_verdict(self):
-        verdict = json.loads(
-            (REPO / "benchmarks" / "collective_verdict.json").read_text())
-        assert (learner_tier.coll_quant() == "bf16") \
-            is verdict["quant_auto_enable"]
-        assert (learner_tier.coll_overlap() == 1) \
-            is verdict["overlap_auto_enable"]
 
 
 # --------------------------------------------- backward-overlapped rounds

@@ -12,13 +12,12 @@ overlapping (slow-copy stub timing assertion), the demote ladder
 the host loop reclaims the RNG), tier-forced K=1 degradation with no
 shape crash and no silent K change, zero lost priority writebacks for
 the surviving shard across a shard death mid-K, gate resolution
-(env force > committed verdict > off), and a two-process e2e over a
+(the sizing knobs), and a two-process e2e over a
 real transport server + real replay shards.
 
 All CPU-only, tier-1 safe.
 """
 
-import json
 import os
 import subprocess
 import sys
@@ -38,7 +37,6 @@ from distributed_reinforcement_learning_tpu.agents.apex import (
 from distributed_reinforcement_learning_tpu.data import codec
 from distributed_reinforcement_learning_tpu.data.device_path import (
     DeviceSamplePath,
-    device_path_enabled,
     gather_scan_batch,
     gather_single_batch,
     path_depth,
@@ -346,19 +344,11 @@ class TestDemote:
         svc.close()
         queue.close()
 
-    def test_gate_resolution(self, monkeypatch, tmp_path):
-        monkeypatch.setenv("DRL_DEVICE_PATH", "1")
-        assert device_path_enabled("/nonexistent")
-        monkeypatch.setenv("DRL_DEVICE_PATH", "0")
-        assert not device_path_enabled("/nonexistent")
-        monkeypatch.delenv("DRL_DEVICE_PATH", raising=False)
-        verdict = tmp_path / "device_path_verdict.json"
-        verdict.write_text(json.dumps({"auto_enable": True}))
-        assert device_path_enabled(str(verdict))
-        verdict.write_text(json.dumps({"auto_enable": False}))
-        assert not device_path_enabled(str(verdict))
-        assert not device_path_enabled("/nonexistent")
-        # Knob parsing for the sizing knobs.
+    def test_sizing_knob_parsing(self, monkeypatch):
+        monkeypatch.delenv("DRL_DEVICE_PATH_DEPTH", raising=False)
+        monkeypatch.delenv("DRL_DEVICE_PATH_MAX_MB", raising=False)
+        assert path_depth() == 1
+        assert path_max_bytes() == 256 * 1024 * 1024
         monkeypatch.setenv("DRL_DEVICE_PATH_DEPTH", "3")
         assert path_depth() == 3
         monkeypatch.setenv("DRL_DEVICE_PATH_MAX_MB", "0.5")
@@ -366,20 +356,6 @@ class TestDemote:
         monkeypatch.setenv("DRL_DEVICE_PATH_DEPTH", "bogus")
         with pytest.raises(ValueError):
             path_depth()
-
-    def test_committed_verdict_consistent(self):
-        """The committed adjudication parses and the gate follows it
-        when DRL_DEVICE_PATH is unset."""
-        path = REPO / "benchmarks" / "device_path_verdict.json"
-        verdict = json.loads(path.read_text())
-        assert isinstance(verdict["auto_enable"], bool)
-        assert verdict["bar"] == 1.2 and verdict["ratio_runs"]
-        env = os.environ.pop("DRL_DEVICE_PATH", None)
-        try:
-            assert device_path_enabled(str(path)) is verdict["auto_enable"]
-        finally:
-            if env is not None:
-                os.environ["DRL_DEVICE_PATH"] = env
 
 
 # ------------------------------------------------------ tier interaction

@@ -387,13 +387,9 @@ class TestAllreduceEquivalence:
 
         from distributed_reinforcement_learning_tpu.parallel import (
             ShardedLearner, make_mesh)
-        from distributed_reinforcement_learning_tpu.runtime import (
-            learner_tier as lt)
-
         monkeypatch.setenv("DRL_COLL_PARTITION", "1")
         monkeypatch.setenv("DRL_COLL_QUANT", "f32")
         monkeypatch.setenv("DRL_COLL_OVERLAP", "0")
-        lt.refresh_coll_flags()
 
         agent, _, union, halves, isw = _apex_fixture()
         mesh = make_mesh(8, model_parallel=2)
@@ -459,7 +455,6 @@ class TestAllreduceEquivalence:
         finally:
             for t in tiers:
                 t.close()
-            lt.refresh_coll_flags()
 
 
 class TestLearnerTier:
@@ -741,17 +736,18 @@ class TestLearnerTier:
         monkeypatch.setenv("DRL_LEARNER_PEERS", "127.0.0.1:1")
         assert learner_tier.build_tier() is None  # one seat = no tier
 
-    def test_seat_count_and_sync_gates(self, monkeypatch, tmp_path):
+    def test_seat_count_and_sync_gates(self, monkeypatch):
+        monkeypatch.delenv("DRL_LEARNER_SEATS", raising=False)
+        assert learner_tier.seat_count() == 0  # no tier by default
         monkeypatch.setenv("DRL_LEARNER_SEATS", "3")
         assert learner_tier.seat_count() == 3
-        monkeypatch.setenv("DRL_LEARNER_SEATS", "0")
+        monkeypatch.setenv("DRL_LEARNER_SEATS", "-2")
         assert learner_tier.seat_count() == 0
-        monkeypatch.delenv("DRL_LEARNER_SEATS", raising=False)
-        verdict = tmp_path / "learner_verdict.json"
-        verdict.write_text(json.dumps({"auto_enable": True, "seats": 4}))
-        assert learner_tier.seat_count(str(verdict)) == 4
-        verdict.write_text(json.dumps({"auto_enable": False}))
-        assert learner_tier.seat_count(str(verdict)) == 0
+        monkeypatch.setenv("DRL_LEARNER_SEATS", "two")
+        with pytest.raises(ValueError, match="DRL_LEARNER_SEATS"):
+            learner_tier.seat_count()
+        monkeypatch.delenv("DRL_LEARNER_SYNC", raising=False)
+        assert learner_tier.sync_mode() == "allreduce"
         monkeypatch.setenv("DRL_LEARNER_SYNC", "async")
         assert learner_tier.sync_mode() == "async"
         monkeypatch.setenv("DRL_LEARNER_SYNC", "bogus")
@@ -809,3 +805,22 @@ class TestTwoProcessE2E:
         assert res["rounds"][-1]["solo"] is True
         assert res["publisher"] is True
         assert res["coll"]["peer_deaths"] == 1
+
+    def test_seat_drill_kill_one_of_two_learners(self):
+        """The kill-ONE-OF-N-learners drill (tests/seat_drill.py):
+        SIGKILL the publisher seat of a real 2-seat tier mid-run — the
+        survivor re-forms the collective solo, takes over publication
+        (board re-created under the same name; its actor observes
+        post-kill versions through the reattached board), and every
+        landed trajectory still crc-verifies."""
+        from seat_drill import seat_drill
+
+        r = seat_drill(secs=16.0, steps=4, obs_dim=8,
+                       repromote_deadline_s=12.0)
+        assert r["corrupt"] == 0 and r["verified"] > 0, r
+        assert r["survivor_solo"] and r["survivor_publisher"], r
+        assert r["reelected_s"] is not None \
+            and r["reelected_s"] <= r["repromote_deadline_s"], r
+        assert r["post_kill_versions_observed"] >= 1, r
+        assert r["survivor_board_reattaches"] >= 1, r
+        assert r["pass"] is True

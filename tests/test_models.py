@@ -2,7 +2,7 @@
 
 Conv-torso tests are gated behind DRL_TPU_SLOW_TESTS=1: XLA:CPU convolution
 is pathologically slow on the single-core CI host (minutes per compile).
-The conv path is exercised on real TPU by bench.py and __graft_entry__.
+The conv path is exercised on real TPU by chip_smoke.py and __graft_entry__.
 """
 
 import os
@@ -138,7 +138,7 @@ def test_models_have_gradients(rng):
 class TestResNetTorso:
     """IMPALA-paper deep torso (models/torso.py ResNetTorso): the
     MXU-dense variant (VERDICT r3 item 8). CPU tests run width 1 on
-    small frames; the width-4 84x84 geometry is bench-only."""
+    small frames; the width-4 84x84 geometry is left to the chip."""
 
     def _agent(self, **kw):
         from distributed_reinforcement_learning_tpu.agents.impala import (

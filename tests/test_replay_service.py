@@ -7,12 +7,11 @@ monolithic replay (chi-square over priorities), bit-identical trajectory
 contents through real TCP and shm-ring drainers (two-process), async
 priority-update routing (incl. the K-update writeback path), shard-death
 demote-to-monolithic fallback, and the DRL_REPLAY_SHARDS gate
-resolution (env force > committed verdict > off).
+resolution (tests/test_gate_defaults.py pins the default).
 
 All CPU-only, tier-1 safe.
 """
 
-import json
 import os
 import subprocess
 import sys
@@ -464,33 +463,9 @@ class TestShardDeathFallback:
 
 
 class TestGateResolution:
-    def test_env_force_wins(self, monkeypatch, tmp_path):
-        verdict = tmp_path / "replay_verdict.json"
-        verdict.write_text(json.dumps({"auto_enable": True, "shards": 6}))
-        monkeypatch.setenv("DRL_REPLAY_SHARDS", "3")
-        assert shard_count(str(verdict)) == 3
-        monkeypatch.setenv("DRL_REPLAY_SHARDS", "0")
-        assert shard_count(str(verdict)) == 0
-
-    def test_unset_defers_to_committed_verdict(self, monkeypatch, tmp_path):
-        monkeypatch.delenv("DRL_REPLAY_SHARDS", raising=False)
-        verdict = tmp_path / "replay_verdict.json"
-        verdict.write_text(json.dumps({"auto_enable": True, "shards": 4}))
-        assert shard_count(str(verdict)) == 4
-        verdict.write_text(json.dumps({"auto_enable": False}))
-        assert shard_count(str(verdict)) == 0
-
-    def test_unset_and_missing_verdict_is_off(self, monkeypatch, tmp_path):
-        monkeypatch.delenv("DRL_REPLAY_SHARDS", raising=False)
-        assert shard_count(str(tmp_path / "missing.json")) == 0
-
-    def test_committed_repo_state_consistent(self, monkeypatch):
-        """The committed verdict parses and the gate follows it when the
-        env is unset (same pin as the other adjudicated fast paths)."""
-        monkeypatch.delenv("DRL_REPLAY_SHARDS", raising=False)
-        path = REPO / "benchmarks" / "replay_verdict.json"
-        verdict = json.loads(path.read_text())
-        assert isinstance(verdict["auto_enable"], bool)
-        assert verdict["ratio_runs"] and verdict["bar"] == 1.2
-        enabled = shard_count(str(path)) > 0
-        assert enabled is verdict["auto_enable"]
+    def test_shard_count_clamps_and_rejects_non_integers(self, monkeypatch):
+        monkeypatch.setenv("DRL_REPLAY_SHARDS", "-1")
+        assert shard_count() == 0
+        monkeypatch.setenv("DRL_REPLAY_SHARDS", "two")
+        with pytest.raises(ValueError, match="DRL_REPLAY_SHARDS"):
+            shard_count()

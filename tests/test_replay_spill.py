@@ -12,13 +12,12 @@ poison-blob isolation (one corrupt segment file drops ONE segment, at
 promote time or at recovery time, never the shard), the shard restart
 clean-slate wipe, a live-service gather/update pass with the router
 thread doing the tier maintenance, and the DRL_REPLAY_SPILL gate
-resolution (env force > committed verdict > off).
+resolution (tests/test_gate_defaults.py pins the default).
 
 All CPU-only, tier-1 safe; spill directories are pytest tmp_path-scoped.
 """
 
 import dataclasses
-import json
 import sys
 from pathlib import Path
 
@@ -43,7 +42,6 @@ from distributed_reinforcement_learning_tpu.data.replay_spill import (
     TieredStore,
 )
 from distributed_reinforcement_learning_tpu.runtime.replay_shard import (
-    spill_auto_enabled,
     spill_config,
 )
 
@@ -448,24 +446,6 @@ class TestServiceWithSpill:
 
 
 class TestSpillGate:
-    def test_env_force_beats_verdict(self, tmp_path, monkeypatch):
-        vp = str(tmp_path / "replay_spill_verdict.json")
-        monkeypatch.setenv("DRL_REPLAY_SPILL", "0")
-        assert not spill_auto_enabled(vp)
-        monkeypatch.setenv("DRL_REPLAY_SPILL", "1")
-        assert spill_auto_enabled(vp)
-
-    def test_unset_defers_to_committed_verdict(self, tmp_path, monkeypatch):
-        monkeypatch.delenv("DRL_REPLAY_SPILL", raising=False)
-        vp = tmp_path / "replay_spill_verdict.json"
-        assert not spill_auto_enabled(str(vp))  # no verdict: off
-        vp.write_text(json.dumps({"auto_enable": True}))
-        assert spill_auto_enabled(str(vp))
-        vp.write_text(json.dumps({"auto_enable": False}))
-        assert not spill_auto_enabled(str(vp))
-        vp.write_text("not json")
-        assert not spill_auto_enabled(str(vp))
-
     def test_spill_config_resolves_knobs(self, tmp_path, monkeypatch):
         monkeypatch.setenv("DRL_REPLAY_SPILL", "1")
         monkeypatch.setenv("DRL_REPLAY_SPILL_DIR", str(tmp_path / "d"))

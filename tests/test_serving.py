@@ -36,7 +36,6 @@ from distributed_reinforcement_learning_tpu.runtime.inference import (
 from distributed_reinforcement_learning_tpu.runtime.serving import (
     ContinuousInferenceServer,
     replica_count,
-    replicas_auto_enabled,
 )
 from distributed_reinforcement_learning_tpu.runtime.weights import WeightStore
 
@@ -538,23 +537,13 @@ def test_replica_app_error_does_not_demote():
 
 
 class TestReplicaGate:
-    """replica_count / replicas_auto_enabled: env force > committed
-    verdict > off — the launcher's inlined gate mirrors this."""
+    """replica_count: the launcher asks it (scripts/launch_local_cluster.py)."""
 
-    def test_env_force_wins(self, monkeypatch):
-        monkeypatch.setenv("DRL_INFER_REPLICAS", "3")
-        assert replica_count() == 3
-        monkeypatch.setenv("DRL_INFER_REPLICAS", "0")
-        assert replica_count() == 0
-
-    def test_unset_defers_to_verdict(self, monkeypatch, tmp_path):
+    def test_unset_is_learner_hosted_and_malformed_is_loud(self, monkeypatch):
         monkeypatch.delenv("DRL_INFER_REPLICAS", raising=False)
-        on = tmp_path / "on.json"
-        on.write_text('{"auto_enable": true, "replicas": 4}')
-        off = tmp_path / "off.json"
-        off.write_text('{"auto_enable": false}')
-        assert replicas_auto_enabled(str(on)) is True
-        assert replica_count(str(on)) == 4
-        assert replicas_auto_enabled(str(off)) is False
-        assert replica_count(str(off)) == 0
-        assert replica_count(str(tmp_path / "missing.json")) == 0
+        assert replica_count() == 0
+        monkeypatch.setenv("DRL_INFER_REPLICAS", "-1")
+        assert replica_count() == 0
+        monkeypatch.setenv("DRL_INFER_REPLICAS", "two")
+        with pytest.raises(ValueError, match="DRL_INFER_REPLICAS"):
+            replica_count()

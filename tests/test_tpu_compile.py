@@ -119,7 +119,7 @@ def test_lstm_kernel_fwd_bwd_compiles(chip, T, B, H):
     assert _kernel_calls(compiled) == 2  # forward + BPTT
 
 
-# (batch*heads, T, head_dim): the bench's transformer (256 wide, 4
+# (batch*heads, T, head_dim): a transformer 256 wide (4
 # heads, T=32), a long-context row, and a bf16 row.
 @pytest.mark.parametrize("BH,T,D,dtype", [
     (128, 32, 64, jnp.float32),
@@ -212,7 +212,7 @@ def test_sharded_transformer_learn_step_compiles_for_four_chips(
         four_chips, kernels_as_on_chip):
     """The same for the flash-attention kernels (`auto` on TPU whenever
     T divides by a block): the data-parallel Transformer-R2D2 step at
-    the bench's width (256, 4 heads, T=32) over the four-chip mesh."""
+    width 256 (4 heads, T=32) over the four-chip mesh."""
     from distributed_reinforcement_learning_tpu.agents.xformer import (
         XformerAgent, XformerConfig)
 

@@ -24,7 +24,6 @@ from distributed_reinforcement_learning_tpu.runtime.weight_board import (
     BoardWeights,
     WeightBoard,
     attach_board_weights,
-    board_auto_enabled,
     board_enabled,
     serve_board,
 )
@@ -396,20 +395,17 @@ class TestBoardWeights:
 
 
 class TestGating:
-    def test_env_forces(self, monkeypatch):
-        monkeypatch.setenv("DRL_SHM_WEIGHTS", "1")
-        assert board_enabled() is True
-        monkeypatch.setenv("DRL_SHM_WEIGHTS", "0")
-        assert board_enabled() is False
+    def test_unset_is_on_only_where_the_seqlock_argument_holds(
+            self, monkeypatch):
+        import platform
 
-    def test_unset_defers_to_verdict(self, monkeypatch, tmp_path):
         monkeypatch.delenv("DRL_SHM_WEIGHTS", raising=False)
-        verdict = tmp_path / "weights_verdict.json"
-        verdict.write_text(json.dumps({"auto_enable": True}))
-        assert board_auto_enabled(str(verdict)) is True
-        verdict.write_text(json.dumps({"auto_enable": False}))
-        assert board_auto_enabled(str(verdict)) is False
-        assert board_auto_enabled(str(tmp_path / "missing.json")) is False
+        monkeypatch.setattr(platform, "machine", lambda: "x86_64")
+        assert board_enabled() is True
+        monkeypatch.setattr(platform, "machine", lambda: "aarch64")
+        assert board_enabled() is False
+        monkeypatch.setenv("DRL_SHM_WEIGHTS", "1")  # forced: survivable
+        assert board_enabled() is True
 
     def test_serve_board_failure_returns_none(self, monkeypatch):
         monkeypatch.setenv("DRL_SHM_WEIGHTS_MB", "64")

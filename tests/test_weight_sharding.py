@@ -99,14 +99,11 @@ def _whole(params):
 
 @pytest.fixture
 def fresh_gates(monkeypatch):
-    """Pin all three gates off in the environment, resolve, and restore
-    the process-cached flags afterwards."""
+    """Start from all three gates unset."""
     for key in ("DRL_WEIGHTS_SHARDED", "DRL_WEIGHTS_QUANT",
                 "DRL_WEIGHTS_DELTA", "DRL_WEIGHTS_KEYS"):
         monkeypatch.delenv(key, raising=False)
-    weight_shards.refresh_flags()
     yield monkeypatch
-    weight_shards.refresh_flags()
 
 
 class TestPartitionRules:
@@ -278,7 +275,6 @@ class TestStoreSharded:
 
     def test_unchanged_elision_and_delta(self, fresh_gates):
         fresh_gates.setenv("DRL_WEIGHTS_DELTA", "1")
-        weight_shards.refresh_flags()
         params = _xformer(8)
         ws = WeightStore(sharded=True)
         ws.publish(params, 0)
@@ -378,7 +374,6 @@ class TestTransportShardOp:
             ShardedRemoteWeights, TransportClient, TransportServer)
 
         fresh_gates.setenv("DRL_WEIGHTS_QUANT", "int8")
-        weight_shards.refresh_flags()
         params = _xformer(30)
         ws = WeightStore(sharded=True)
         ws.publish(params, 0)
@@ -678,31 +673,15 @@ class TestGating:
         fresh_gates.setenv("DRL_WEIGHTS_SHARDED", "1")
         fresh_gates.setenv("DRL_WEIGHTS_QUANT", "int8")
         fresh_gates.setenv("DRL_WEIGHTS_DELTA", "1")
-        weight_shards.refresh_flags()
         assert weight_shards.sharded_enabled() is True
         assert weight_shards.quant_mode() == "int8"
         assert weight_shards.delta_enabled() is True
         fresh_gates.setenv("DRL_WEIGHTS_SHARDED", "0")
         fresh_gates.setenv("DRL_WEIGHTS_QUANT", "0")
         fresh_gates.setenv("DRL_WEIGHTS_DELTA", "0")
-        weight_shards.refresh_flags()
         assert weight_shards.sharded_enabled() is False
         assert weight_shards.quant_mode() is None
         assert weight_shards.delta_enabled() is False
-
-    def test_quant_1_means_bf16(self, fresh_gates):
-        fresh_gates.setenv("DRL_WEIGHTS_QUANT", "1")
-        weight_shards.refresh_flags()
-        assert weight_shards.quant_mode() == "bf16"
-
-    def test_unset_defers_to_committed_verdict(self, fresh_gates):
-        committed = json.loads(
-            (Path(__file__).resolve().parent.parent / "benchmarks" /
-             "weights_shard_verdict.json").read_text())
-        assert weight_shards.sharded_enabled() is committed["auto_enable"]
-        assert (weight_shards.quant_mode() is not None) is \
-            committed["quant_auto_enable"]
-        assert weight_shards.delta_enabled() is committed["delta_auto_enable"]
 
     def test_role_keys_parsing(self, fresh_gates):
         assert weight_shards.role_keys() is None
@@ -715,7 +694,6 @@ class TestGating:
 
     def test_quantized_store_serves_f32_in_process(self, fresh_gates):
         fresh_gates.setenv("DRL_WEIGHTS_QUANT", "bf16")
-        weight_shards.refresh_flags()
         params = _xformer(17)
         ws = WeightStore(sharded=True)
         ws.publish(params, 1)

@@ -16,9 +16,9 @@ off:
   that stops being read is flagged as stale instead of rotting as
   documentation of nothing.
 
-Entry fields: `type` is the value vocabulary (`flag` 0/1, `verdict`
-1/0/unset-defers-to-the-committed-benchmark-verdict, `int`, `float`,
-`str`, `list` comma-separated, `enum`), `default` is the effective
+Entry fields: `type` is the value vocabulary (`flag` on/off as
+`utils/environ.env_flag` reads it, `int`, `float`, `str`, `list`
+comma-separated, `enum`), `default` is the effective
 unset behavior (free text where the default is computed), `owner` is
 the module that reads it (repo-relative).
 """
@@ -40,7 +40,7 @@ END_MARK = "<!-- END KNOB REGISTRY -->"
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 DOCS_PATH = os.path.join(_REPO_ROOT, "docs", "performance.md")
 
-_TYPES = ("flag", "verdict", "int", "float", "str", "list", "enum")
+_TYPES = ("flag", "int", "float", "str", "list", "enum")
 
 # Files whose DRL_* literals are NOT knob references: the registry's
 # own entries, and the linter test suite's deliberately-fake fixture
@@ -70,7 +70,7 @@ _PKG = "distributed_reinforcement_learning_tpu"
 
 _ENTRIES = [
     # -- data planes and fast-path gates ---------------------------------
-    Knob("DRL_SHM_RING", "verdict", "unset → committed verdict",
+    Knob("DRL_SHM_RING", "flag", "0",
          f"{_PKG}/runtime/shm_ring.py",
          "co-hosted actors PUT trajectories through a shared-memory SPSC "
          "ring instead of loopback TCP"),
@@ -84,7 +84,7 @@ _ENTRIES = [
          "learner side: comma-separated ring names to create and drain"),
     Knob("DRL_SHM_RING_NAME", "str", "unset", f"{_PKG}/runtime/transport.py",
          "actor side: ring segment name to attach"),
-    Knob("DRL_SHM_WEIGHTS", "verdict", "unset → committed verdict",
+    Knob("DRL_SHM_WEIGHTS", "flag", "1 on x86-64, else 0",
          f"{_PKG}/runtime/weight_board.py",
          "co-hosted actors pull weights from the seqlock shm board"),
     Knob("DRL_SHM_WEIGHTS_MB", "float", "64",
@@ -98,25 +98,25 @@ _ENTRIES = [
     Knob("DRL_SHM_WEIGHTS_NAME", "str", "unset",
          f"{_PKG}/runtime/transport.py",
          "actor/replica side: board segment name to attach"),
-    Knob("DRL_CODEC_CACHE", "verdict", "unset → committed verdict",
+    Knob("DRL_CODEC_CACHE", "flag", "0",
          f"{_PKG}/data/codec.py",
          "schema-keyed encode header cache + layout-keyed decode cache"),
-    Knob("DRL_OBS_DEDUP", "verdict", "unset → committed verdict",
+    Knob("DRL_OBS_DEDUP", "flag", "0",
          f"{_PKG}/data/codec.py",
          "frame-stack dedup on trajectory PUTs (base stack + new plane "
          "per step)"),
     Knob("DRL_PUT_BATCH", "int", "0 (off)", f"{_PKG}/data/fifo.py",
          "actor-side k-unroll batched PUT exchanges"),
     # -- weight plane -----------------------------------------------------
-    Knob("DRL_WEIGHTS_SHARDED", "verdict", "unset → committed verdict",
+    Knob("DRL_WEIGHTS_SHARDED", "flag", "0",
          f"{_PKG}/runtime/weight_shards.py",
          "partition-keyed per-shard weight publication + shard-scoped "
          "TCP pulls"),
-    Knob("DRL_WEIGHTS_QUANT", "enum(bf16|int8)", "unset → committed verdict",
+    Knob("DRL_WEIGHTS_QUANT", "enum(bf16|int8)", "off (`1` means bf16)",
          f"{_PKG}/runtime/weight_shards.py",
          "quantized actor-bound shard blobs (learner master copy stays "
          "f32)"),
-    Knob("DRL_WEIGHTS_DELTA", "verdict", "unset → committed verdict",
+    Knob("DRL_WEIGHTS_DELTA", "flag", "0",
          f"{_PKG}/runtime/weight_shards.py",
          "per-shard byte-range deltas between consecutive versions"),
     Knob("DRL_WEIGHTS_KEYS", "list", "unset (all shards)",
@@ -132,13 +132,13 @@ _ENTRIES = [
          f"{_PKG}/runtime/publishing.py",
          "metrics float()s absorbed off the learner thread"),
     # -- replay -----------------------------------------------------------
-    Knob("DRL_REPLAY_SHARDS", "int", "unset → committed verdict (2)",
+    Knob("DRL_REPLAY_SHARDS", "int", "2",
          f"{_PKG}/runtime/replay_shard.py",
          "N ingest-side prioritized replay shards (0 disables)"),
     Knob("DRL_REPLAY_SCORER", "enum(max|td_proxy)", "max",
          f"{_PKG}/runtime/replay_shard.py",
          "ingest-time initial-priority scorer"),
-    Knob("DRL_REPLAY_SPILL", "verdict", "unset → committed verdict (off)",
+    Knob("DRL_REPLAY_SPILL", "flag", "1",
          f"{_PKG}/runtime/replay_shard.py",
          "tiered replay: each shard keeps a hot RAM set and spills cold "
          "segments to disk by priority mass; stored experience scales "
@@ -156,12 +156,12 @@ _ENTRIES = [
     Knob("DRL_APEX_INGEST_UNROLLS", "int", "1",
          f"{_PKG}/runtime/apex_runner.py",
          "unrolls scored per TD forward + sum-tree batch add"),
-    Knob("DRL_ACTOR_PRIORITY", "verdict", "unset → committed verdict (off)",
+    Knob("DRL_ACTOR_PRIORITY", "flag", "0",
          f"{_PKG}/data/admission.py",
          "sample-at-source: actors compute the DRL_REPLAY_SCORER "
          "priorities and stamp them on PUT blobs; the learner "
          "fast-accepts the stamp instead of re-scoring"),
-    Knob("DRL_ADMISSION", "verdict", "unset → committed verdict (off)",
+    Knob("DRL_ADMISSION", "flag", "0",
          f"{_PKG}/data/admission.py",
          "priority-mass admission: under learner backpressure, thin "
          "low-priority unrolls at the actor (Horvitz-Thompson-corrected "
@@ -177,9 +177,9 @@ _ENTRIES = [
          "per-transition keep-probability floor under thinning"),
     Knob("DRL_ADMISSION_PRESSURE", "float", "unset → PUT-reply EWMA",
          f"{_PKG}/data/admission.py",
-         "pressure override 0..1 (tests/bench; beats both the TCP "
+         "pressure override 0..1 (tests; beats both the TCP "
          "PUT-reply EWMA and the shm-ring header's pressure word)"),
-    Knob("DRL_DEVICE_PATH", "verdict", "unset → committed verdict",
+    Knob("DRL_DEVICE_PATH", "flag", "0",
          f"{_PKG}/data/device_path.py",
          "fused device-resident sample path: background gather + "
          "double-buffered H2D + K-step scanned learn with one D2H per K"),
@@ -203,7 +203,7 @@ _ENTRIES = [
          f"{_PKG}/utils/checkpoint.py",
          "replay snapshot size cap per checkpoint"),
     # -- inference tier ---------------------------------------------------
-    Knob("DRL_INFER_REPLICAS", "int", "unset → committed verdict",
+    Knob("DRL_INFER_REPLICAS", "int", "0",
          f"{_PKG}/runtime/serving.py",
          "inference replica count for --remote_act (0 = learner-hosted)"),
     Knob("DRL_INFER_ADDRS", "list", "unset", f"{_PKG}/runtime/transport.py",
@@ -236,7 +236,7 @@ _ENTRIES = [
     Knob("DRL_REATTACH_ATTEMPTS", "int", "8", f"{_PKG}/runtime/fleet.py",
          "probes per outage before the demotion latches permanent"),
     # -- actor data plane -------------------------------------------------
-    Knob("DRL_ACTOR_PIPE", "verdict", "unset → committed verdict",
+    Knob("DRL_ACTOR_PIPE", "flag", "0",
          f"{_PKG}/runtime/actor_pipeline.py",
          "double-buffered env slices + async unroll publisher"),
     Knob("DRL_ACTOR_PUB_DEPTH", "int", "2",
@@ -244,7 +244,7 @@ _ENTRIES = [
          "publisher backpressure: max unpublished rounds before submit "
          "blocks"),
     # -- learner tier (runtime/learner_tier.py + parallel/collective.py) --
-    Knob("DRL_LEARNER_SEATS", "int", "unset → committed verdict (2)",
+    Knob("DRL_LEARNER_SEATS", "int", "0 (no tier)",
          f"{_PKG}/runtime/learner_tier.py",
          "N cooperating learner seats (launcher gate; 0/1 = the single "
          "pre-tier learner)"),
@@ -276,14 +276,13 @@ _ENTRIES = [
          "partition spec, replicated rides the ring, sharded classes "
          "go owner-scoped; 0 restores the plan-less full-vector ring "
          "byte-for-byte"),
-    Knob("DRL_COLL_QUANT", "enum(f32|bf16)", "unset → committed verdict "
-         "(f32)",
+    Knob("DRL_COLL_QUANT", "enum(f32|bf16)", "f32 (`1` means bf16)",
          f"{_PKG}/runtime/learner_tier.py",
          "gradient wire encoding for partitioned rounds: bf16 halves "
          "the bytes through the shared RNE codec (f32 master "
          "accumulation); folded into the plan hash — skewed seats "
          "refuse loudly"),
-    Knob("DRL_COLL_OVERLAP", "int", "unset → committed verdict (0)",
+    Knob("DRL_COLL_OVERLAP", "int", "0",
          f"{_PKG}/runtime/learner_tier.py",
          "in-flight exchange depth (capped at 1): 1 overlaps round t's "
          "wire time with round t+1's backward via the tier's collective "
@@ -468,10 +467,10 @@ def knob_literals(src: str) -> list[tuple[str, int]]:
 
 def scan_tree(paths: list[str] | None = None) -> dict[str, list[tuple[str, int]]]:
     """name -> [(repo-relative path, line)] across the given trees
-    (default: the package, scripts/, tests/, tools/ and bench.py)."""
+    (default: the package, scripts/, tests/ and tools/)."""
     if paths is None:
         paths = [os.path.join(_REPO_ROOT, p)
-                 for p in (_PKG, "scripts", "tests", "tools", "bench.py")]
+                 for p in (_PKG, "scripts", "tests", "tools")]
     from tools.drlint.core import iter_py_files
 
     out: dict[str, list[tuple[str, int]]] = {}

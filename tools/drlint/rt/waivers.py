@@ -44,8 +44,7 @@ GUARDED_WAIVERS: dict[tuple[str, str], str] = {
     ("NativePrioritizedReplay", "beta"):
         "native-lib-only path; exercised by test_data when cpp builds",
     ("_CodecCaches", "_dedup"):
-        "populated only under DRL_OBS_DEDUP=1 (parked opt-in fast path, "
-        "codec_verdict.json honest negative on this container)",
+        "populated only under DRL_OBS_DEDUP=1 (off by default)",
     ("ShardedReplayService", "updates_dropped"):
         "written only when the async priority-writeback ring overflows "
         "(latest-wins drop); the bounded suites never saturate it",
