@@ -11,6 +11,15 @@ functions over one flax model:
   the first/middle time views, sum-reduced losses, RMSProp + polynomial
   LR + global-norm clip (`agent/impala.py:63-100`).
 
+The loss is written once, time-major (`_vtrace_loss`), and has two
+entries that differ only in how their data lies. `_loss` / `_learn` /
+`learn` take an `ImpalaBatch`, `[B, T, ...]`, as a queue delivers it;
+`_loss_time_major` / `_learn_time_major` take an `ImpalaRollout`,
+`[T, B, ...]`, as the fused loop's scan writes it. Each flattens the
+frames as they lie (the network has no recurrence across its N rows),
+so neither transposes pixels; the batch-major entry swaps the network's
+`[B, T]` outputs and the batch's `[B, T]` scalars before the loss.
+
 Loss math parity (`agent/impala.py:63-93`):
     vs, rho     = vtrace(first view; next_values = middle values)
     vs_plus_1   = vtrace(middle view; next_values = last values)
@@ -68,7 +77,8 @@ class ImpalaConfig:
 
 
 class ImpalaBatch(NamedTuple):
-    """One learner batch: `[B, T, ...]` unrolls (queue payload, SURVEY §2 row 7)."""
+    """One learner batch, batch-major: `[B, T, ...]` unrolls (queue
+    payload, SURVEY §2 row 7). What `_loss` / `_learn` / `learn` take."""
 
     state: jax.Array  # [B, T, *obs] uint8 (or float for vector envs)
     reward: jax.Array  # [B, T] f32 raw rewards
@@ -78,6 +88,20 @@ class ImpalaBatch(NamedTuple):
     previous_action: jax.Array  # [B, T] i32
     initial_h: jax.Array  # [B, T, H] actor-recorded per-step LSTM h
     initial_c: jax.Array  # [B, T, H]
+
+
+class ImpalaRollout(NamedTuple):
+    """`ImpalaBatch`'s fields time-major, `[T, B, ...]`: a rollout as
+    `lax.scan` stacks it. What `_loss_time_major` / `_learn_time_major` take."""
+
+    state: jax.Array  # [T, B, *obs]
+    reward: jax.Array  # [T, B]
+    action: jax.Array  # [T, B]
+    done: jax.Array  # [T, B]
+    behavior_policy: jax.Array  # [T, B, A]
+    previous_action: jax.Array  # [T, B]
+    initial_h: jax.Array  # [T, B, H]
+    initial_c: jax.Array  # [T, B, H]
 
 
 class ActOutput(NamedTuple):
@@ -145,34 +169,38 @@ class ImpalaAgent:
         return ActOutput(action, out.policy, out.h, out.c)
 
     # -- learn -----------------------------------------------------------
-    @jax.named_scope(scopes.LOSS)
-    def _loss(self, params, batch: ImpalaBatch):
-        cfg = self.cfg
+    def _forward(self, params, data: ImpalaBatch | ImpalaRollout):
+        """Policy and value for every step of `data`, its two leading
+        axes kept as they are."""
         forward = functools.partial(apply_stored_state, self.model)
-        if cfg.remat:
+        if self.cfg.remat:
             forward = jax.checkpoint(forward)
-        policy, value = forward(
+        return forward(
             params,
-            self._prep_obs(batch.state),
-            batch.previous_action,
-            batch.initial_h,
-            batch.initial_c,
+            self._prep_obs(data.state),
+            data.previous_action,
+            data.initial_h,
+            data.initial_c,
         )
 
-        clipped_r = common.clip_rewards(batch.reward, cfg.reward_clipping)
-        discounts = (~batch.done).astype(jnp.float32) * cfg.discount_factor
+    def _vtrace_loss(self, policy, value, action, reward, done, behavior_policy):
+        """The V-trace actor-critic loss, time-major: `[T, B, A]`
+        policies, `[T, B]` everything else."""
+        cfg = self.cfg
+        clipped_r = common.clip_rewards(reward, cfg.reward_clipping)
+        discounts = (~done).astype(jnp.float32) * cfg.discount_factor
 
-        first_p, middle_p, _ = vtrace.split_data(policy)
-        first_v, middle_v, last_v = vtrace.split_data(value)
-        first_a, middle_a, _ = vtrace.split_data(batch.action)
-        first_r, middle_r, _ = vtrace.split_data(clipped_r)
-        first_d, middle_d, _ = vtrace.split_data(discounts)
-        first_b, middle_b, _ = vtrace.split_data(batch.behavior_policy)
+        first_p, middle_p, _ = vtrace.split_time_major(policy)
+        first_v, middle_v, last_v = vtrace.split_time_major(value)
+        first_a, middle_a, _ = vtrace.split_time_major(action)
+        first_r, middle_r, _ = vtrace.split_time_major(clipped_r)
+        first_d, middle_d, _ = vtrace.split_time_major(discounts)
+        first_b, middle_b, _ = vtrace.split_time_major(behavior_policy)
 
-        vs, rho = vtrace.from_softmax(
+        vs, rho = vtrace.from_softmax_time_major(
             behavior_policy=first_b, target_policy=first_p, actions=first_a,
             discounts=first_d, rewards=first_r, values=first_v, next_values=middle_v)
-        vs_plus_1, _ = vtrace.from_softmax(
+        vs_plus_1, _ = vtrace.from_softmax_time_major(
             behavior_policy=middle_b, target_policy=middle_p, actions=middle_a,
             discounts=middle_d, rewards=middle_r, values=middle_v, next_values=last_v)
 
@@ -190,9 +218,22 @@ class ImpalaAgent:
         }
         return total, metrics
 
-    @jax.named_scope(scopes.LEARN)
-    def _learn(self, state: common.TrainState, batch: ImpalaBatch):
-        grads, metrics = jax.grad(self._loss, has_aux=True)(state.params, batch)
+    @jax.named_scope(scopes.LOSS)
+    def _loss(self, params, batch: ImpalaBatch):
+        policy, value = self._forward(params, batch)
+        tm = lambda x: jnp.swapaxes(x, 0, 1)
+        return self._vtrace_loss(tm(policy), tm(value), tm(batch.action),
+                                 tm(batch.reward), tm(batch.done),
+                                 tm(batch.behavior_policy))
+
+    @jax.named_scope(scopes.LOSS)
+    def _loss_time_major(self, params, rollout: ImpalaRollout):
+        policy, value = self._forward(params, rollout)
+        return self._vtrace_loss(policy, value, rollout.action, rollout.reward,
+                                 rollout.done, rollout.behavior_policy)
+
+    def _learn_with(self, loss, state: common.TrainState, data):
+        grads, metrics = jax.grad(loss, has_aux=True)(state.params, data)
         with jax.named_scope(scopes.OPTIMIZER):
             updates, opt_state = self.tx.update(grads, state.opt_state, state.params)
             params = jax.tree.map(lambda p, u: p + u, state.params, updates)
@@ -200,3 +241,11 @@ class ImpalaAgent:
         metrics["learning_rate"] = self._schedule(state.step)
         new_state = state.replace(params=params, opt_state=opt_state, step=state.step + 1)
         return new_state, metrics
+
+    @jax.named_scope(scopes.LEARN)
+    def _learn(self, state: common.TrainState, batch: ImpalaBatch):
+        return self._learn_with(self._loss, state, batch)
+
+    @jax.named_scope(scopes.LEARN)
+    def _learn_time_major(self, state: common.TrainState, rollout: ImpalaRollout):
+        return self._learn_with(self._loss_time_major, state, rollout)

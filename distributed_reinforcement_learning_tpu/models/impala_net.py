@@ -9,7 +9,9 @@ stored-state semantics, no recurrence across learner timesteps.
 On TPU that collapses to a single application: flatten `[B, T, ...]` to
 `[B*T, ...]`, run the network once (big batched conv + one LSTM-cell
 matmul), and reshape back. The first/middle/last V-trace views become
-cheap slices of the one output (see `agents/impala.py`).
+cheap slices of the one output (see `agents/impala.py`). With no
+recurrence across the rows their order is free, so a `[T, B, ...]`
+rollout flattens to `[T*B, ...]` just as well and nobody transposes it.
 """
 
 from __future__ import annotations
@@ -79,20 +81,21 @@ class ImpalaActorCritic(nn.Module):
 def apply_stored_state(
     model: ImpalaActorCritic,
     params,
-    obs_seq: jax.Array,  # [B, T, ...obs]
-    prev_action_seq: jax.Array,  # [B, T]
-    h_seq: jax.Array,  # [B, T, lstm] actor-recorded per-step h
-    c_seq: jax.Array,  # [B, T, lstm]
+    obs_seq: jax.Array,  # [B, T, ...obs] or [T, B, ...obs]
+    prev_action_seq: jax.Array,  # [B, T] or [T, B]
+    h_seq: jax.Array,  # [B, T, lstm] actor-recorded per-step h, or [T, B, lstm]
+    c_seq: jax.Array,  # as h_seq
 ) -> tuple[jax.Array, jax.Array]:
     """Policy/value for all (b, t) at once via stored-state flattening.
 
     Replaces the 3*(T-2) replicated graphs of
-    `model/impala_actor_critic.py:73-114` with one `[B*T]` batched forward.
-    Returns (`policy` `[B, T, A]`, `value` `[B, T]`).
+    `model/impala_actor_critic.py:73-114` with one batched forward over
+    the two leading axes flattened AS THEY LIE: a batch-major `[B, T]`
+    input gives (`policy` `[B, T, A]`, `value` `[B, T]`), a time-major
+    `[T, B]` input gives (`[T, B, A]`, `[T, B]`). Either is a free
+    reshape; the layout is the caller's and comes back unchanged.
     """
-    B, T = obs_seq.shape[:2]
-    flat = lambda x: x.reshape((B * T,) + x.shape[2:])
+    lead = obs_seq.shape[:2]
+    flat = lambda x: x.reshape((lead[0] * lead[1],) + x.shape[2:])
     out = model.apply(params, flat(obs_seq), flat(prev_action_seq), flat(h_seq), flat(c_seq))
-    policy = out.policy.reshape(B, T, -1)
-    value = out.value.reshape(B, T)
-    return policy, value
+    return out.policy.reshape(*lead, -1), out.value.reshape(lead)

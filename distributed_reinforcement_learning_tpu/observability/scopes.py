@@ -27,7 +27,7 @@ import functools
 # of a jitted function is the HLO module's name, which IS in the key, so
 # the jitted steps that carry scopes go through `tagged`. Bump the tag
 # when a scope is added, renamed or moved; nothing else reads it.
-CACHE_TAG = "s2"
+CACHE_TAG = "s3"
 
 
 def tagged(fn):
@@ -47,7 +47,9 @@ ACT = "collect/act"  # obs prep, torso, LSTM, sampling (`agent._act`)
 ENV = "collect/env"  # env dynamics (`env.step` less rendering)
 RENDER = "collect/env/render"  # raw screen, 2-frame max, luma, resize, stack
 RECORD = "collect/record"  # the per-step record + carry of the rollout
-TO_BATCH_MAJOR = "to_batch_major"  # [T, B, ...] rollout -> [B, T, ...] batch
+# [T, B, ...] rollout -> [B, T, ...] batch: `AnakinImpala` under a mesh
+# only. The one-chip chunk learns time-major (PR 29): no op has this name.
+TO_BATCH_MAJOR = "to_batch_major"
 REPLAY = "replay"  # device ring: ingest, sample, priority write-back
 REPLAY_SCORE = "replay/score"  # TD error of the new sequences, both nets
 REPLAY_WRITE = "replay/write"  # the ring write at `ptr`
@@ -59,7 +61,7 @@ UNROLL = "learn/loss/unroll"  # the LSTM recurrence alone, learn step only
 VTRACE = "learn/vtrace"  # V-trace targets (Pallas kernel on the TPU)
 OPTIMIZER = "learn/optimizer"  # optimizer update + parameter add
 
-IMPALA_CHUNK_SCOPES = (COLLECT, ACT, ENV, RENDER, RECORD, TO_BATCH_MAJOR,
+IMPALA_CHUNK_SCOPES = (COLLECT, ACT, ENV, RENDER, RECORD,
                        LEARN, LOSS, VTRACE, OPTIMIZER)
 REPLAY_CHUNK_SCOPES = (COLLECT, REPLAY, LEARN)
 R2D2_CHUNK_SCOPES = (ACT, ENV, RECORD, REPLAY_SCORE, REPLAY_WRITE,

@@ -7,9 +7,10 @@ backward recursion is a `jax.lax.scan(reverse=True)` over time with the
 delta computation fused in front of it, all inside one XLA compilation.
 
 Conventions:
-- Batch-major public API: tensors are `[B, T, ...]` like the reference
-  (`optimizer/vtrace.py:29-44`). The time-major core (`[T, B]`) is also
-  exposed for callers that already hold time-major data.
+- Two layouts, one recursion. `split_data` / `from_softmax` take
+  `[B, T, ...]` like the reference (`optimizer/vtrace.py:29-44`);
+  `split_time_major` / `from_softmax_time_major` take `[T, B, ...]`, the
+  order a rollout scan writes and `from_importance_weights` runs in.
 - Loss reductions are **sums** over batch and time, matching the reference
   (`optimizer/vtrace.py:105-126`); IMPALA's gradient-clip/LR settings were
   tuned against sum-reduced losses.
@@ -41,6 +42,11 @@ def split_data(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     pass in the IMPALA loss.
     """
     return x[:, :-2], x[:, 1:-1], x[:, 2:]
+
+
+def split_time_major(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """`split_data` for a `[T, B, ...]` tensor: three `[T-2, B, ...]` views."""
+    return x[:-2], x[1:-1], x[2:]
 
 
 def action_log_probs(policy_probs: jax.Array, actions: jax.Array, eps: float = 0.0) -> jax.Array:
@@ -147,7 +153,9 @@ def from_softmax(
 
     Parity with `optimizer/vtrace.py:29-69`: inputs `[B, T, A]` policies and
     `[B, T]` trajectories; `next_values[:, -1]` supplies the bootstrap value.
-    Returns `[B, T]` vs and clipped rhos.
+    Returns `[B, T]` vs and clipped rhos. Swaps four `[B, T]` arrays to
+    `[T, B]` for the recursion and two back: a caller that holds
+    time-major data calls `from_softmax_time_major` instead.
     """
     log_rhos = action_log_probs(target_policy, actions) - action_log_probs(behavior_policy, actions)
     # Transpose to time-major for the scan, back to batch-major after.
@@ -162,6 +170,34 @@ def from_softmax(
         backend=backend,
     )
     return VTraceReturns(vs=tm(out.vs), clipped_rhos=tm(out.clipped_rhos))
+
+
+@jax.named_scope(scopes.VTRACE)
+def from_softmax_time_major(
+    behavior_policy: jax.Array,
+    target_policy: jax.Array,
+    actions: jax.Array,
+    discounts: jax.Array,
+    rewards: jax.Array,
+    values: jax.Array,
+    next_values: jax.Array,
+    clip_rho_threshold: float | None = 1.0,
+    backend: str = "auto",
+) -> VTraceReturns:
+    """`from_softmax` for `[T, B, A]` policies and `[T, B]` trajectories.
+
+    `[T, B]` vs and clipped rhos out, nothing transposed on the way;
+    `next_values[-1]` supplies the bootstrap value.
+    """
+    return from_importance_weights(
+        log_rhos=action_log_probs(target_policy, actions) - action_log_probs(behavior_policy, actions),
+        discounts=discounts,
+        rewards=rewards,
+        values=values,
+        bootstrap_value=next_values[-1],
+        clip_rho_threshold=clip_rho_threshold,
+        backend=backend,
+    )
 
 
 def policy_gradient_loss(

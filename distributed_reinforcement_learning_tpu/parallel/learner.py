@@ -19,7 +19,6 @@ per-model annotations.
 
 from __future__ import annotations
 
-import functools
 from typing import Any
 
 import jax
@@ -160,20 +159,9 @@ class ShardedLearner:
             )
 
     def _on_mesh(self, fn):
-        """`fn`, traced with this learner's mesh as JAX's context mesh.
-
-        GSPMD partitions everything in the step by itself except a
-        Pallas kernel, which has to be told the mesh to wrap itself in
-        a `shard_map` (`ops/pallas.batch_partitioned` asks
-        `jax.sharding.get_abstract_mesh()`); without this the step does
-        not lower on a multi-chip TPU host."""
-
-        @functools.wraps(fn)
-        def traced(*args):
-            with jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh):
-                return fn(*args)
-
-        return traced
+        """`fn`, traced with this learner's mesh as JAX's context mesh
+        (`mesh.traced_on`: what a Pallas kernel in the step needs)."""
+        return mesh_lib.traced_on(self.mesh, fn)
 
     def init_state(self, rng: jax.Array):
         """Initialize the TrainState directly into its mesh sharding."""

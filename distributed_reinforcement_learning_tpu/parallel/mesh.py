@@ -31,6 +31,8 @@ Everything here is plain `jax.sharding`; no torch-style process groups.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -89,6 +91,23 @@ def pcast_varying(x, axes: tuple[str, ...]):
     have = set(getattr(jax.typeof(x), "vma", ()))
     need = tuple(a for a in axes if a not in have)
     return jax.lax.pcast(x, need, to="varying") if need else x
+
+
+def traced_on(mesh: Mesh, fn):
+    """`fn`, traced with `mesh` as JAX's context mesh.
+
+    GSPMD partitions everything in a step jitted over a mesh by itself
+    except a Pallas kernel, which has to be told the mesh to wrap itself
+    in a `shard_map` (`ops/pallas.batch_partitioned` asks
+    `jax.sharding.get_abstract_mesh()`); without this the step does not
+    lower on a multi-chip TPU host."""
+
+    @functools.wraps(fn)
+    def traced(*args):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args)
+
+    return traced
 
 
 def replicated(mesh: Mesh) -> NamedSharding:

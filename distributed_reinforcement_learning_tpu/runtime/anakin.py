@@ -29,7 +29,8 @@ import jax
 import jax.numpy as jnp
 
 from distributed_reinforcement_learning_tpu.agents.common import TrainState
-from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent, ImpalaBatch
+from distributed_reinforcement_learning_tpu.agents.impala import (
+    ImpalaAgent, ImpalaBatch, ImpalaRollout)
 from distributed_reinforcement_learning_tpu.envs import cartpole_jax
 from distributed_reinforcement_learning_tpu.observability import scopes
 
@@ -73,8 +74,8 @@ class AnakinImpala:
         self.mesh = mesh
         # No donation: the freshly-init state's zero-filled leaves (env
         # counters, LSTM state, prev_action) can alias one deduped
-        # constant buffer, which donation rejects; the state is small
-        # (CartPole MLP+LSTM), so the copy is noise.
+        # constant buffer, which donation rejects. The undonated state is
+        # held twice: 0.154 GB each at 2,048 Breakout envs (PR 25).
         if mesh is None:
             self.train_chunk = jax.jit(scopes.tagged(self._train_chunk),
                                        static_argnums=(1,))
@@ -86,6 +87,7 @@ class AnakinImpala:
             # program, N chips, no host between them.
             from distributed_reinforcement_learning_tpu.parallel import (
                 data_sharding, replicated)
+            from distributed_reinforcement_learning_tpu.parallel.mesh import traced_on
             from distributed_reinforcement_learning_tpu.parallel.learner import (
                 train_state_sharding)
 
@@ -104,12 +106,27 @@ class AnakinImpala:
                 env=jax.tree.map(lambda _: data, env_abstract),
                 obs=data, prev_action=data, h=data, c=data, rng=repl,
             )
+            # The V-trace kernel wraps itself per device only under a
+            # context mesh; without one the chunk does not lower on a
+            # multi-chip TPU host.
             self.train_chunk = jax.jit(
-                scopes.tagged(self._train_chunk), static_argnums=(1,),
+                scopes.tagged(traced_on(mesh, self._train_chunk)),
+                static_argnums=(1,),
                 in_shardings=(self._state_sharding,),
                 out_shardings=(self._state_sharding, repl),
             )
         self._greedy_eval_jit = jax.jit(self._greedy_eval, static_argnums=(1, 2))
+
+    @property
+    def handoff(self) -> str:
+        """How `_update` hands the rollout to the learner, a static fact
+        of the compiled chunk. One chip: `time_major`, as the scan wrote
+        it, nothing transposed. A mesh shards B over `data`, and
+        `[T, B/n]` flattened to `T*B` is not contiguous per shard: the
+        partitioner all-gathers the whole frame batch (compiled for a
+        described v5e:2x2, PR 29), where `[B/n, T]` flattens in place; so
+        a mesh swaps every field to `[B, T, ...]`: `batch_major`."""
+        return "time_major" if self.mesh is None else "batch_major"
 
     def init(self, rng: jax.Array) -> AnakinState:
         # Three distinct streams: params init, env reset, and the ongoing
@@ -180,21 +197,16 @@ class AnakinImpala:
                 functools.partial(self._env_step, state.train.params), carry,
                 None, length=T)
         env, obs, prev_action, h, c, rng = carry
-        # rec fields are [T, B, ...]; the learner wants [B, T, ...].
-        bt = lambda name: jnp.swapaxes(rec[name], 0, 1)
-        with jax.named_scope(scopes.TO_BATCH_MAJOR):
-            batch = ImpalaBatch(
-                state=bt("state"),
-                reward=bt("reward"),
-                action=bt("action"),
-                done=bt("done"),
-                behavior_policy=bt("behavior_policy"),
-                previous_action=bt("previous_action"),
-                initial_h=bt("initial_h"),
-                initial_c=bt("initial_c"),
-            )
-        # `_learn` names itself (scopes.LEARN and below).
-        train, metrics = self.agent._learn(state.train, batch)
+        # rec fields are [T, B, ...]. The learners name themselves
+        # (scopes.LEARN and below).
+        if self.mesh is None:  # see `handoff`
+            rollout = ImpalaRollout(**{f: rec[f] for f in ImpalaRollout._fields})
+            train, metrics = self.agent._learn_time_major(state.train, rollout)
+        else:
+            with jax.named_scope(scopes.TO_BATCH_MAJOR):
+                batch = ImpalaBatch(**{f: jnp.swapaxes(rec[f], 0, 1)
+                                       for f in ImpalaBatch._fields})
+            train, metrics = self.agent._learn(state.train, batch)
         metrics["episode_return_sum"] = rec["episode_return"].sum()
         # Real episode ends; for life-loss envs rec["done"] also fires on
         # boundaries, which would skew a mean-return-per-episode metric.

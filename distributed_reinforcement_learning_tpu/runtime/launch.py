@@ -352,6 +352,7 @@ def train_anakin(config_path: str, section: str, num_updates: int,
     agent = ImpalaAgent(agent_cfg)
     anakin = AnakinImpala(agent, num_envs or rt.num_actors * rt.envs_per_actor,
                           env=env_mod)
+    print(f"[anakin] learn handoff: {anakin.handoff}")  # static, as compiled
     state = anakin.init(jax.random.PRNGKey(seed))
     ckpt, train = _restore_train(checkpoint_dir, state.train)
     state = state._replace(train=train)

@@ -52,6 +52,29 @@ def test_impala_chunk_carries_scope(impala_chunk_names, name):
     assert any(name in n for n in impala_chunk_names), name
 
 
+def test_one_chip_impala_chunk_swaps_nothing_to_batch_major(impala_chunk_names):
+    """The learner takes the rollout as the scan wrote it (ISSUE 29): the
+    readers that list `to_batch_major` sum a missing scope as 0."""
+    assert not any(scopes.TO_BATCH_MAJOR in n for n in impala_chunk_names)
+
+
+def test_mesh_impala_chunk_names_its_swap():
+    """Under a mesh the fused loop keeps the batch-major handoff, and
+    its swaps keep their name."""
+    from distributed_reinforcement_learning_tpu.agents.impala import (
+        ImpalaAgent, ImpalaConfig)
+    from distributed_reinforcement_learning_tpu.parallel import make_mesh
+    from distributed_reinforcement_learning_tpu.runtime.anakin import AnakinImpala
+
+    anakin = AnakinImpala(ImpalaAgent(ImpalaConfig(
+        obs_shape=(4,), num_actions=2, trajectory=4, lstm_size=16)), 8,
+        mesh=make_mesh(8))
+    # as lowered: XLA:CPU fuses these tiny swaps into their consumers
+    text = anakin.train_chunk.lower(
+        anakin.init(jax.random.PRNGKey(0)), 1).as_text(debug_info=True)
+    assert f"{scopes.TO_BATCH_MAJOR}/transpose" in text
+
+
 def test_impala_chunk_module_name_carries_the_cache_tag():
     """Metadata is not in the compile-cache key; the module's name is."""
     from distributed_reinforcement_learning_tpu.agents.impala import (
@@ -134,7 +157,8 @@ def _host_events(profile_dir: str) -> list[str]:
             for line in plane.lines for ev in line.events]
 
 
-def test_train_anakin_profile_holds_one_span_per_chunk(tmp_path, monkeypatch):
+def test_train_anakin_profile_holds_one_span_per_chunk(tmp_path, monkeypatch,
+                                                       capsys):
     """`DRL_PROFILE_DIR` on a fused loop: one profiler session (no
     Python tracer), and per chunk one dispatch / wait / report span on
     the host plane of the same `.xplane.pb` as the device ops."""
@@ -157,6 +181,8 @@ def test_train_anakin_profile_holds_one_span_per_chunk(tmp_path, monkeypatch):
     monkeypatch.setenv("DRL_PROFILE_START", "0")
     monkeypatch.setenv("DRL_PROFILE_STEPS", "1000")
     train_anakin("config.json", "impala_cartpole", num_updates=4, chunk=2)
+    # the static fact of the compiled chunk, once at start-up (ISSUE 29)
+    assert capsys.readouterr().out.count("[anakin] learn handoff: time_major") == 1
     assert calls == {"start": [0], "stop": 1}
     names = _host_events(str(tmp_path))
     for span in (scopes.DISPATCH, scopes.WAIT, scopes.REPORT):

@@ -7,7 +7,9 @@ tiling, a kernel over its VMEM budget, a program Mosaic refuses — at the
 shapes the main path really runs (`config.json` sections `impala`,
 `apex`, `r2d2_pixel`, `r2d2_atari`; the Anakin chunk `chip_smoke.py`
 drives), and
-costs no chip time.
+costs no chip time. It also shows what the compiler DID with a program:
+which layout copies and which collectives it put in (the fused IMPALA
+chunk's handoff, PR 29).
 
 A compile that passes is not a chip run: nothing executes here, so
 these tests say nothing about results or speed.
@@ -22,6 +24,7 @@ steps (10-25 s each) are marked slow and run before a chip call.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -339,3 +342,81 @@ def test_breakout_step_keeps_no_raster_and_one_luma(chip):
     assert f"u8[{n},210,160,3]" in text  # the frame exists, inside a fusion
     lumas = re.findall(rf"= \w+\[{n},210,160\]\S* reduce\(", text)
     assert len(lumas) == 1, lumas
+
+
+def _breakout_chunk_compiled(anakin, state_sharding):
+    """The fused IMPALA chunk (1 update) compiled for described devices;
+    `state_sharding(abstract state)` places its arguments."""
+    state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        state, state_sharding(state))
+    return anakin.train_chunk.lower(state, 1).compile()
+
+
+def _frame_batch_ops(text: str, frames: int, ops: str) -> list[str]:
+    """Lines of `text` whose op is one of `ops` (a regex alternation) and
+    whose RESULT holds `frames` 84x84x4 frames, whatever its shape."""
+    import math
+
+    hits = []
+    for m in re.finditer(
+            rf"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* (?:{ops})\(.*$", text, re.M):
+        if math.prod(int(d) for d in m[1].split(",")) == frames * 84 * 84 * 4:
+            hits.append(m[0].strip()[:300])
+    return hits
+
+
+def test_breakout_chunk_learns_from_the_rollout_where_the_scan_wrote_it(
+        chip, kernels_as_on_chip):
+    """The fused IMPALA chunk at 256 Breakout envs x T=20: the learner
+    takes the rollout time-major (`AnakinImpala._update`), so nothing is
+    named `to_batch_major`, and between the scan's `[20,256,84,84,4]`
+    output and conv0 ONE copy touches a frame-batch-sized operand (T moved
+    inside H and W in whole tiles, under `learn`). The batch-major handoff
+    (before PR 29) shows two: a true transposition through a W-minor
+    intermediate. Both V-trace passes stay Mosaic kernels."""
+    from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent
+    from distributed_reinforcement_learning_tpu.envs import breakout_jax
+    from distributed_reinforcement_learning_tpu.observability import scopes
+    from distributed_reinforcement_learning_tpu.runtime.anakin import AnakinImpala
+
+    cfg, _ = load_config(CONFIG, "impala")
+    n = 256
+    anakin = AnakinImpala(ImpalaAgent(cfg), n, env=breakout_jax)
+    compiled = _breakout_chunk_compiled(
+        anakin, lambda state: jax.tree.map(lambda _: chip, state))
+    text = compiled.as_text()
+    assert scopes.TO_BATCH_MAJOR not in text
+    copies = _frame_batch_ops(text, cfg.trajectory * n, "copy|transpose")
+    assert len(copies) <= 1, copies
+    assert all(f"/{scopes.LEARN}/" in c for c in copies), copies
+    assert _kernel_calls(compiled, "vtrace_pallas") == 2
+
+
+def test_mesh_breakout_chunk_moves_no_frames_between_chips(
+        four_chips, kernels_as_on_chip):
+    """`AnakinImpala(mesh=...)` over a described v5e:2x2, envs sharded
+    over `data`: the chunk lowers (each V-trace kernel per device, under
+    the context mesh) and no collective carries a frame batch: the
+    gradient all-reduce is all that crosses. That is why a mesh keeps the
+    batch-major handoff: `[B/4, T]` flattens in place, where the
+    time-major `[T, B/4]` made the partitioner all-gather the whole
+    `bf16[20,B,84,84,4]` batch five times (compiled so in PR 29)."""
+    from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent
+    from distributed_reinforcement_learning_tpu.envs import breakout_jax
+    from distributed_reinforcement_learning_tpu.parallel import make_mesh
+    from distributed_reinforcement_learning_tpu.runtime.anakin import AnakinImpala
+
+    cfg, _ = load_config(CONFIG, "impala")
+    n = 256
+    anakin = AnakinImpala(ImpalaAgent(cfg), n, mesh=make_mesh(devices=four_chips),
+                          env=breakout_jax)
+    assert anakin.handoff == "batch_major"
+    compiled = _breakout_chunk_compiled(anakin, lambda _: anakin._state_sharding)
+    text = compiled.as_text()
+    collectives = "all-gather|all-to-all|collective-permute|all-reduce|reduce-scatter"
+    for frames in (cfg.trajectory * n, cfg.trajectory * n // 4, n, n // 4):
+        assert _frame_batch_ops(text, frames, collectives + "|\\S*-start") == []
+    assert "all-reduce" in text and "all-gather" not in text
+    assert _kernel_calls(compiled, "vtrace_pallas") == 2
