@@ -77,6 +77,17 @@ def _sim_fallback(name: str, sim_mod, id_prefix: str, seed: int,
                              fire_reset=fire_reset)
 
 
+def make_jittable_env(name: str, **params):
+    """The on-device (fused-loop) envs that are built from a section's
+    own sizes rather than imported as a module: name -> env object of the
+    `cartpole_jax` contract."""
+    if name.startswith("TokenRecall"):
+        from distributed_reinforcement_learning_tpu.envs.token_recall_jax import TokenRecall
+
+        return TokenRecall(**params)
+    raise ValueError(f"unknown jittable env {name!r}")
+
+
 def make_env(name: str, seed: int = 0, num_actions: int = 18) -> Env:
     if name in _REGISTRY:
         return _REGISTRY[name](seed=seed)

@@ -57,15 +57,20 @@ def rope(x: jax.Array, positions: jax.Array | None = None, base: float = 10_000.
     attending k back" is the same computation wherever the window sits.
 
     `positions` overrides the default arange when the stream is held in
-    a permuted layout (zigzag sequence parallelism).
+    a permuted layout (zigzag sequence parallelism); `[B, T]` positions
+    give every row its own (the position inside its episode:
+    `models/looped_lm.py`, which also sets `base` from its section's
+    `rope_theta`).
     """
     d2 = x.shape[-1] // 2
     freqs = base ** (-jnp.arange(d2, dtype=jnp.float32) / d2)
     if positions is None:
         positions = jnp.arange(x.shape[1])
-    angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]
-    cos = jnp.cos(angles)[None, :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[None, :, None, :].astype(x.dtype)
+    angles = positions.astype(jnp.float32)[..., None] * freqs
+    if angles.ndim == 2:  # [T, d2]: the same positions for every row
+        angles = angles[None]
+    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
+    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
     x1, x2 = x[..., :d2], x[..., d2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 

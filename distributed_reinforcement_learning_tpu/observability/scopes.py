@@ -27,7 +27,7 @@ import functools
 # of a jitted function is the HLO module's name, which IS in the key, so
 # the jitted steps that carry scopes go through `tagged`. Bump the tag
 # when a scope is added, renamed or moved; nothing else reads it.
-CACHE_TAG = "s3"
+CACHE_TAG = "s4"
 
 
 def tagged(fn):
@@ -61,11 +61,21 @@ UNROLL = "learn/loss/unroll"  # the LSTM recurrence alone, learn step only
 VTRACE = "learn/vtrace"  # V-trace targets (Pallas kernel on the TPU)
 OPTIMIZER = "learn/optimizer"  # optimizer update + parameter add
 
+# The token loop (runtime/anakin_tokens.py, models/looped_lm.py):
+ACT_LOOP = "collect/act/loop"  # one decode step's R x L layer passes
+ACT_CACHE = "collect/act/cache"  # key/value writes at t and the cache reads
+ACT_HEAD = "collect/act/head"  # final norm, vocabulary head, sampling
+LOOP = "learn/loss/loop"  # the looped stack (backward: re-entered under transpose)
+HEADS = "learn/loss/heads"  # R x (norm, vocabulary, gate, value, log-softmax)
+LOSS_VTRACE = "learn/loss/vtrace"  # per-pass V-trace on taken-action log-probs
+
 IMPALA_CHUNK_SCOPES = (COLLECT, ACT, ENV, RENDER, RECORD,
                        LEARN, LOSS, VTRACE, OPTIMIZER)
 REPLAY_CHUNK_SCOPES = (COLLECT, REPLAY, LEARN)
 R2D2_CHUNK_SCOPES = (ACT, ENV, RECORD, REPLAY_SCORE, REPLAY_WRITE,
                      REPLAY_SAMPLE, REPLAY_PRIORITIES, LOSS, UNROLL, OPTIMIZER)
+TOKENS_CHUNK_SCOPES = (ENV, ACT, ACT_LOOP, ACT_CACHE, ACT_HEAD, LOOP, HEADS,
+                       LOSS_VTRACE, OPTIMIZER)
 
 # -- host spans of the fused loops (runtime/launch.py) ---------------------
 STEP_READ = "anakin/step_read"  # int(state.train.step) at the loop head

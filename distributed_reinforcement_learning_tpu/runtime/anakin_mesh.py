@@ -79,6 +79,23 @@ def state_shardings(mesh, specs_tree):
                         is_leaf=lambda x: isinstance(x, P))
 
 
+def own_buffers(state):
+    """`state` with every leaf in a buffer of its own: a donated state
+    cannot hold one buffer twice, and freshly made zeros may share one."""
+    seen = set()
+
+    def own(x):
+        if isinstance(x, jax.core.Tracer):  # `jax.eval_shape(init)`
+            return x
+        buffer = x.addressable_shards[0].data.unsafe_buffer_pointer()
+        if buffer in seen:
+            return jnp.copy(x)
+        seen.add(buffer)
+        return x
+
+    return jax.tree.map(own, state)
+
+
 class DataMeshReplayMixin:
     """Shared ctor/init plumbing for the mesh-capable replay runtimes.
 
@@ -123,18 +140,7 @@ class DataMeshReplayMixin:
             state = state._replace(rng=jax.random.split(k_run, self.dshard))
             state = jax.device_put(state,
                                    state_shardings(self.mesh, self._specs))
-        seen = set()
-
-        def own(x):
-            if isinstance(x, jax.core.Tracer):  # `jax.eval_shape(init)`
-                return x
-            buffer = x.addressable_shards[0].data.unsafe_buffer_pointer()
-            if buffer in seen:
-                return jnp.copy(x)
-            seen.add(buffer)
-            return x
-
-        return jax.tree.map(own, state)
+        return own_buffers(state)
 
     def _psum(self, tree):
         return jax.lax.psum(tree, self._axis) if self._axis else tree

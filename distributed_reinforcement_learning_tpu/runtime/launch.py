@@ -552,3 +552,62 @@ def train_local(config_path: str, section: str, num_updates: int,
 
         result["mean_return_last20"] = float(np.mean(returns[-20:]))
     return result
+
+
+def train_anakin_tokens(config_path: str, section: str, num_updates: int,
+                        chunk: int = 2, seed: int = 0,
+                        num_envs: int | None = None,
+                        checkpoint_dir: str | None = None,
+                        run_dir: str | None = None) -> dict:
+    """Fully on-device token-level IMPALA on a looped language model
+    (runtime/anakin_tokens.py; `train_ximpala.py --mode anakin`): N token
+    envs each play one episode by decode through a per-pass key/value
+    cache, then one V-trace learn step, in compiled chunks of `chunk`
+    updates on the same `_run_chunk` as the other fused loops."""
+    open_devices("anakin-tokens")
+    agent_cfg, rt = load_config(config_path, section)
+    from distributed_reinforcement_learning_tpu.agents.looplm import (
+        LoopLMAgent, LoopLMConfig)
+    from distributed_reinforcement_learning_tpu.envs.registry import make_jittable_env
+    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import AnakinTokens
+
+    if not isinstance(agent_cfg, LoopLMConfig):
+        raise ValueError("anakin-tokens mode runs the looplm family "
+                         "(a section with \"algorithm\": \"looplm\")")
+    env = make_jittable_env(
+        rt.envs[0], vocab=agent_cfg.vocab_size,
+        episode_len=agent_cfg.trajectory, distance=agent_cfg.recall_distance)
+    anakin = AnakinTokens(LoopLMAgent(agent_cfg),
+                          num_envs or rt.num_actors * rt.envs_per_actor, env)
+    print(f"[anakin-tokens] {anakin.static_facts}")  # static, as compiled
+    state = anakin.init(jax.random.PRNGKey(seed))
+    ckpt, train = _restore_train(checkpoint_dir, state.train)
+    state = state._replace(train=train)
+    chunk = max(1, min(chunk, num_updates))
+    maybe_configure("anakin-tokens", 0, run_dir)  # env-gated run-wide telemetry
+    last = {"loss": None}
+
+    def log(step: int, mean_ret: float, eps: float, m) -> str:
+        last["loss"] = float(m["total_loss"][-1])
+        cdf = [round(float(m[f"exit_cdf_pass{i}"][-1]), 3)
+               for i in range(1, agent_cfg.total_ut_steps)]
+        return (f"[anakin-tokens] step {step}: mean_return {mean_ret:.2f} "
+                f"({eps:.0f} episodes, loss {last['loss']:.2f}, exit cdf {cdf}, "
+                f"rho clipped {float(m['rho_clipped_share'][-1]):.3f})")
+
+    returns = []
+    profiler = ProfilerSession.from_env()  # DRL_PROFILE_DIR: one on_step a chunk
+    try:
+        while (step := _read_step(state)) < num_updates:
+            profiler.on_step(step)
+            state, mean_ret = _run_chunk(
+                anakin, state, min(chunk, num_updates - step), 1, log, ckpt)
+            returns.append(mean_ret)
+    finally:
+        profiler.close()
+    return {
+        "frames": int(state.train.step) * anakin.num_envs * agent_cfg.trajectory,
+        "last_loss": last["loss"],
+        "chunk_mean_returns": [round(r, 2) for r in returns],
+        "mean_return_last_chunk": round(returns[-1], 2) if returns else None,
+    }

@@ -83,6 +83,21 @@ def _runtime_from_section(algo: str, d: dict[str, Any]) -> RuntimeConfig:
     )
 
 
+_DTYPES = ("float32", "bfloat16")
+
+
+def _compute_dtype(d: dict[str, Any], default: str):
+    """A section's `dtype` key (the transformer families' matmul
+    operands and activations): `float32` or `bfloat16`, anything else is
+    an error naming the key."""
+    import jax.numpy as jnp
+
+    name = d.get("dtype", default)
+    if name not in _DTYPES:
+        raise ValueError(f"dtype {name!r}: one of {_DTYPES}")
+    return jnp.dtype(name).type
+
+
 def load_config(path: str | Path, section: str):
     """Load one config section -> (agent_config, runtime_config).
 
@@ -203,6 +218,38 @@ def load_config(path: str | Path, section: str):
             pipeline_microbatches=d.get("pipeline_microbatches", 2),
             pipeline_stages=d.get("pipeline_stages", 0),
             remat=d.get("remat", False),
+            dtype=_compute_dtype(d, "float32"),
+        )
+    elif algorithm == "looplm":
+        from distributed_reinforcement_learning_tpu.agents.looplm import LoopLMConfig
+
+        # The model's keys are the source's own (`config.json` of the
+        # published model); a key the section lacks is an error, not a
+        # default: a width is never guessed.
+        agent_cfg = LoopLMConfig(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            num_attention_heads=d["num_attention_heads"],
+            head_dim=d["head_dim"],
+            intermediate_size=d["intermediate_size"],
+            num_hidden_layers=d["num_hidden_layers"],
+            total_ut_steps=d["total_ut_steps"],
+            early_exit_threshold=d.get("early_exit_threshold", 1.0),
+            rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+            rope_theta=d.get("rope_theta", 1e6),
+            trajectory=d.get("trajectory", 128),
+            recall_distance=d.get("recall_distance", 8),
+            discount_factor=d.get("discount_factor", 0.99),
+            baseline_loss_coef=d.get("baseline_loss_coef", 1.0),
+            entropy_coef=d.get("entropy_coef", 0.05),
+            exit_entropy_coef=d.get("exit_entropy_coef", 0.05),
+            gradient_clip_norm=d.get("gradient_clip_norm", 40.0),
+            reward_clipping=d.get("reward_clipping", "abs_one"),
+            start_learning_rate=d.get("start_learning_rate", 1e-5),
+            end_learning_rate=d.get("end_learning_rate", 0.0),
+            learning_frame=int(d.get("learning_frame", 1e9)),
+            dtype=_compute_dtype(d, "bfloat16"),
+            init_std=d.get("initializer_range", 0.02),
         )
     else:
         raise ValueError(f"unknown algorithm {algorithm!r}")

@@ -29,13 +29,56 @@ class TestReferenceSchema:
         ("impala", "impala"), ("apex", "apex"), ("r2d2", "r2d2"),
         ("impala_cartpole", "impala"), ("xformer", "xformer"),
         ("impala_invaders", "impala"), ("r2d2_pixel", "r2d2"),
-        ("r2d2_atari", "r2d2"),
+        ("r2d2_atari", "r2d2"), ("ximpala", "ximpala"),
+        ("ouro_looplm", "looplm"),
     ])
     def test_repo_config_sections_load(self, section, algo):
         agent_cfg, rt = load_config("config.json", section)
         assert rt.algorithm == algo
         assert agent_cfg.num_actions >= 2
         assert rt.num_actors == len(rt.envs) == len(rt.available_action)
+
+    def test_looplm_section_carries_the_published_widths(self):
+        import jax.numpy as jnp
+
+        cfg, rt = load_config("config.json", "ouro_looplm")
+        assert (cfg.hidden_size, cfg.num_attention_heads, cfg.head_dim,
+                cfg.intermediate_size, cfg.vocab_size) == (2048, 16, 128, 5632, 49152)
+        assert (cfg.total_ut_steps, cfg.early_exit_threshold, cfg.rope_theta,
+                cfg.rms_norm_eps) == (4, 1, 1_000_000, 1e-6)
+        assert cfg.num_hidden_layers == 8 and cfg.trajectory == 128
+        assert cfg.dtype == jnp.bfloat16 and cfg.start_learning_rate == 1e-5
+        assert rt.num_actors * rt.envs_per_actor == 32
+        assert rt.envs == ("TokenRecall-v0",)
+
+    @pytest.mark.parametrize("section,default", [("ximpala", "float32"),
+                                                 ("ouro_looplm", "bfloat16")])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16", None])
+    def test_dtype_key_reaches_the_agent_config(self, tmp_path, section,
+                                                default, dtype):
+        import jax.numpy as jnp
+
+        with open("config.json") as f:
+            d = dict(json.load(f)[section])
+        d.pop("dtype", None)
+        if dtype is not None:
+            d["dtype"] = dtype
+        cfg, _ = load_config(_write(tmp_path, section, d), section)
+        assert cfg.dtype == jnp.dtype(dtype or default).type
+
+    @pytest.mark.parametrize("section", ["ximpala", "ouro_looplm"])
+    def test_unknown_dtype_is_an_error(self, tmp_path, section):
+        with open("config.json") as f:
+            d = dict(json.load(f)[section], dtype="float16")
+        with pytest.raises(ValueError, match="dtype 'float16'"):
+            load_config(_write(tmp_path, section, d), section)
+
+    def test_looplm_section_without_a_width_is_an_error(self, tmp_path):
+        with open("config.json") as f:
+            d = dict(json.load(f)["ouro_looplm"])
+        del d["intermediate_size"]
+        with pytest.raises(KeyError, match="intermediate_size"):
+            load_config(_write(tmp_path, "ouro_looplm", d), "ouro_looplm")
 
     def test_vestigial_keys_accepted(self, tmp_path):
         """Unknown/vestigial reference keys (`config.json:66,105`

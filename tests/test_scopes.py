@@ -133,6 +133,47 @@ def test_r2d2_chunk_carries_scope(r2d2_chunk_names, name):
     assert any(name in n for n in r2d2_chunk_names), name
 
 
+@pytest.fixture(scope="module")
+def tokens_chunk():
+    import jax.numpy as jnp
+
+    from distributed_reinforcement_learning_tpu.agents.looplm import (
+        LoopLMAgent, LoopLMConfig)
+    from distributed_reinforcement_learning_tpu.envs.token_recall_jax import TokenRecall
+    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import AnakinTokens
+
+    cfg = LoopLMConfig(vocab_size=128, hidden_size=32, num_attention_heads=2,
+                       head_dim=16, intermediate_size=48, num_hidden_layers=2,
+                       trajectory=8, dtype=jnp.float32, head_block=16)
+    anakin = AnakinTokens(LoopLMAgent(cfg), 4, TokenRecall(128, 8))
+    return anakin, anakin.init(jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def tokens_chunk_names(tokens_chunk):
+    anakin, state = tokens_chunk
+    return _op_names(anakin.train_chunk, state, 1)
+
+
+@pytest.mark.parametrize("name", scopes.TOKENS_CHUNK_SCOPES)
+def test_tokens_chunk_carries_scope(tokens_chunk_names, name):
+    """The names `perfbench/layer_metrics/looplm_*` read (ISSUE 30)."""
+    assert any(name in n for n in tokens_chunk_names), name
+
+
+def test_tokens_backward_stack_keeps_the_loop_name(tokens_chunk_names):
+    """The rematerialised stack is entered again under the transpose:
+    `transpose(jvp(learn/loss))/.../learn/loss/loop/...`."""
+    assert any(f"transpose(jvp({scopes.LOSS}))" in n and scopes.LOOP in n
+               for n in tokens_chunk_names)
+
+
+def test_tokens_chunk_module_name_carries_the_cache_tag(tokens_chunk):
+    anakin, state = tokens_chunk
+    text = anakin.train_chunk.lower(state, 1).as_text()
+    assert f"_train_chunk_{scopes.CACHE_TAG}" in text.split("\n", 1)[0]
+
+
 def test_r2d2_backward_recurrence_keeps_the_unroll_name(r2d2_chunk_names):
     """The inner scope is entered again inside the transposed outer one:
     `transpose(jvp(learn/loss))/.../learn/loss/unroll/...`."""

@@ -5,8 +5,8 @@ described, not opened (`/opt/skills/guides/on-chip-measurement`, section
 2). That catches what interpret mode cannot — a slice not aligned to the
 tiling, a kernel over its VMEM budget, a program Mosaic refuses — at the
 shapes the main path really runs (`config.json` sections `impala`,
-`apex`, `r2d2_pixel`, `r2d2_atari`; the Anakin chunk `chip_smoke.py`
-drives), and
+`apex`, `r2d2_pixel`, `r2d2_atari`, `ouro_looplm`; the Anakin chunk
+`chip_smoke.py` drives), and
 costs no chip time. It also shows what the compiler DID with a program:
 which layout copies and which collectives it put in (the fused IMPALA
 chunk's handoff, PR 29).
@@ -317,6 +317,42 @@ def test_r2d2_atari_chunk_fits_with_its_ring_donated(chip, kernels_as_on_chip,
     # the word ring shows here first (PERF.md, PRs 26-27).
     assert mem.temp_size_in_bytes < 4e9, mem.temp_size_in_bytes
     assert _kernel_calls(compiled) == 0
+
+
+def test_ouro_looplm_chunk_fits_and_holds_its_six_kernels(chip,
+                                                          kernels_as_on_chip):
+    """The fused token chunk at the `ouro_looplm` section's sizes (32 envs
+    x 128 tokens, 8 layers x 4 passes at 2048 wide, chunk of 2): it
+    compiles for a described v5e, the donated state (parameters +
+    RMSProp's second moments, 8 B a parameter) is aliased whole, and
+    arguments + scratch stay under the chip's `bytes_limit`. Six Mosaic
+    kernels whatever L and R: flash attention in the scanned layer body
+    (forward, rematerialised forward, dq, dkv) and V-trace's two views
+    with the four passes in the kernel's batch."""
+    from distributed_reinforcement_learning_tpu.agents.looplm import LoopLMAgent
+    from distributed_reinforcement_learning_tpu.envs.registry import (
+        make_jittable_env)
+    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import (
+        AnakinTokens)
+
+    cfg, rt = load_config(CONFIG, "ouro_looplm")
+    env = make_jittable_env(rt.envs[0], vocab=cfg.vocab_size,
+                            episode_len=cfg.trajectory,
+                            distance=cfg.recall_distance)
+    anakin = AnakinTokens(LoopLMAgent(cfg), rt.num_actors * rt.envs_per_actor,
+                          env)
+    state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+    lowered = anakin.train_chunk.lower(_on(chip, state), 2)
+    assert len(re.findall("tpu_custom_call", lowered.as_text())) == 6
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    params = 8 * (4 * 2048 ** 2 + 3 * 2048 * 5632 + 4 * 2048) + 2 * 49152 * 2048
+    assert mem.alias_size_in_bytes == mem.argument_size_in_bytes > 8 * params
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert held < 15.0e9 < 16_909_336_064, held  # 14.15 GB when written
+    assert anakin.static_facts == {"loop_passes": 4, "compute_dtype": "bfloat16",
+                                   "kv_cache_bytes": 2 ** 30}
 
 
 def test_breakout_step_keeps_no_raster_and_one_luma(chip):

@@ -6,6 +6,13 @@ composes V-trace with the framework's long-context machinery (ring
 sequence parallelism, MoE, pipelining, remat all apply).
 
     python train_ximpala.py --section ximpala --updates 300
+
+`--mode anakin` is the fused on-device loop of the `looplm` sections
+(runtime/anakin_tokens.py): a looped language model as the policy of a
+token-level IMPALA, generation through a per-pass key/value cache and
+the learn step in one compiled chunk.
+
+    python train_ximpala.py --mode anakin --section ouro_looplm --updates 8
 """
 
 from __future__ import annotations
@@ -17,7 +24,7 @@ def main() -> None:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--config", default="config.json")
     p.add_argument("--section", default="ximpala")
-    p.add_argument("--mode", default="local", choices=["local", "learner", "actor", "inference"])
+    p.add_argument("--mode", default="local", choices=["local", "learner", "actor", "inference", "anakin"])
     p.add_argument("--task", type=int, default=-1)
     p.add_argument("--updates", type=int, default=1000)
     p.add_argument("--run_dir", default=None)
@@ -30,6 +37,11 @@ def main() -> None:
     p.add_argument("--platform", default=None,
                    help="force a jax platform (e.g. 'cpu'); actors default to cpu "
                         "so they never grab the TPU chip")
+    p.add_argument("--anakin_envs", type=int, default=None,
+                   help="anakin mode: parallel on-device envs (default "
+                        "num_actors * envs_per_actor from the section)")
+    p.add_argument("--anakin_chunk", type=int, default=2,
+                   help="anakin mode: updates per compiled chunk")
     p.add_argument("--serve_inference", action="store_true",
                    help="learner mode: serve SEED-style centralized inference")
     p.add_argument("--remote_act", action="store_true",
@@ -48,6 +60,16 @@ def main() -> None:
 
     enable_compile_cache()
 
+    if args.mode == "anakin":
+        # Fully on-device decode + learn (runtime/anakin_tokens.py).
+        from distributed_reinforcement_learning_tpu.runtime.launch import train_anakin_tokens
+
+        print(train_anakin_tokens(args.config, args.section, args.updates,
+                                  chunk=args.anakin_chunk, seed=args.seed,
+                                  num_envs=args.anakin_envs,
+                                  checkpoint_dir=args.checkpoint_dir,
+                                  run_dir=args.run_dir))
+        return
     if args.mode == "local":
         from distributed_reinforcement_learning_tpu.runtime.launch import train_local
 
