@@ -134,11 +134,13 @@ class LoopLMAgent:
     def for_acting(self, params):
         return looped_lm.for_acting(params, self.cfg.dtype)
 
-    def _act(self, act_params, tokens, t, cache, rng):
+    def _act(self, act_params, tokens, t, cache, rng, span=None):
         """-> (action `[N]`, log mu(action) `[N]`, cache): sample from
-        softmax(logits^(R)) (threshold 1: every pass is run)."""
+        softmax(logits^(R)) (threshold 1: every pass is run). `span`:
+        the static prefix of the cache that covers t (`LoopedLM.decode`)."""
         model = self.model
-        h, cache = model.apply(act_params, tokens, t, cache, method=model.decode)
+        h, cache = model.apply(act_params, tokens, t, cache, span,
+                               method=model.decode)
         with jax.named_scope(scopes.ACT_HEAD):
             logits, _, _ = model.apply(act_params, h, method=model.logits)
             action = jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)

@@ -23,7 +23,11 @@ Two entries:
 - `LoopedLM.decode`: one token a row through a key/value cache `[R, L,
   N, T, H, d]` x 2, ONE PER LOOP PASS: pass r's keys are projections of
   h^(r-1), so the passes cannot share a cache, and a looped decoder
-  carries R times the cache of a plain one of the same depth.
+  carries R times the cache of a plain one of the same depth. Step t
+  writes position t and attends over positions <= t, reading a static
+  prefix that covers them (`span`; `decode_spans` cuts an episode into
+  the segments that share one): the read is bound by the bytes of the
+  row, and past t the row holds only `init_cache`'s zeros.
 
 Precision (`dtype`, bfloat16 as the configuration states it): matmul
 operands and activations in `dtype` with float32 accumulation; norm
@@ -56,6 +60,21 @@ class KVCache(NamedTuple):
 
     k: jax.Array
     v: jax.Array
+
+
+def decode_spans(length: int, segments: int | None = None) -> tuple[int, ...]:
+    """The static cache prefixes an episode of `length` decode steps
+    reads: segment i is the steps `[spans[i-1], spans[i])` (from 0) and
+    every step of it reads the first `spans[i]` positions of its cache
+    row. `segments` follows from the shape: 8 from 128 steps on (a mean
+    read of 9/16 of the row; 1/2 is the least any scheme reads), fewer
+    for short episodes (segments of at least 16 steps), one under 32."""
+    if segments is None:
+        segments = min(8, max(1, length // 16))
+    if not 1 <= segments <= length:
+        raise ValueError(f"{segments} segments of {length} decode steps")
+    step = -(-length // segments)
+    return tuple(range(step, length, step)) + (length,)
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -194,10 +213,11 @@ class LoopedLM(nn.Module):
         return (self.loop_passes, self.num_layers, num_rows, length,
                 self.num_heads, self.head_dim)
 
-    def _decode_layer(self, carry, xs, slot, t):
+    def _decode_layer(self, carry, xs, slot, t, span=None):
         """One layer of decode pass `slot`, which reads and writes cache
-        `slot`: its own."""
+        `slot`: its own, as far as `span` (the whole row by default)."""
         h, cache = carry
+        span = cache.k.shape[3] if span is None else span
         lp, layer_index = xs
         n = h.shape[0]
         y = rms_norm(h, lp["norms"][0], self.rms_eps)
@@ -213,12 +233,12 @@ class LoopedLM(nn.Module):
                 jax.lax.dynamic_update_slice(cache.v, v.astype(self.dtype)[None, None],
                                              where))
             row = (slot, layer_index, 0, 0, 0, 0)
-            size = (1, 1, *cache.k.shape[2:])
+            size = (1, 1, cache.k.shape[2], span, *cache.k.shape[4:])
             keys = jax.lax.dynamic_slice(cache.k, row, size)[0, 0]
             values = jax.lax.dynamic_slice(cache.v, row, size)[0, 0]
         s = jnp.einsum("nqhd,nkhd->nhqk", q, keys,
                        preferred_element_type=F32) * self.head_dim ** -0.5
-        seen = (jnp.arange(keys.shape[1]) <= t)[None, None, None, :]
+        seen = (jnp.arange(span) <= t)[None, None, None, :]
         p = jnp.where(seen, jax.nn.softmax(jnp.where(seen, s, _MASK_VALUE), -1), 0.0)
         att = jnp.einsum("nhqk,nkhd->nqhd", p.astype(self.dtype), values,
                          preferred_element_type=F32)
@@ -227,18 +247,32 @@ class LoopedLM(nn.Module):
              ).astype(self.dtype)
         return (self._mlp(u, lp), cache), None
 
-    def decode(self, tokens: jax.Array, t: jax.Array, cache: KVCache):
+    def decode(self, tokens: jax.Array, t: jax.Array, cache: KVCache,
+               span: int | None = None):
         """One decode step at batch N: `tokens [N]` shown at step `t` of
         the episode (the same for every row: an episode is one unroll,
         `ximpala`'s rule, so every cached position <= t is of this
         episode). All `loop_passes` passes x L layers, each writing its
         key and value at position t of ITS cache and attending over
-        positions <= t. -> (h^(R) `[N, D]`, cache)."""
+        positions <= t, reading a static prefix that covers them: the
+        first `span` positions of the row (a Python int, the whole row by
+        default). `t` is traced, so that `t < span` is the CALLER's to
+        hold; past `span` the step would read nothing it wrote.
+        -> (h^(R) `[N, D]`, cache)."""
+        length = cache.k.shape[3]
+        span = length if span is None else span
+        if not 0 < span <= length:
+            raise ValueError(f"span {span} of a cache of {length} positions")
         stack = self._stack()
         index = jnp.arange(self.num_layers)
 
+        # The whole row is the call as it was before there were spans: a
+        # subclass that overrides `_decode_layer(carry, xs, slot, t)` (the
+        # benchmark plants a shared cache that way) keeps working.
+        prefix = () if span == length else (span,)
+
         def one_pass(carry, loop_pass):
-            step = lambda c, xs: self._decode_layer(c, xs, loop_pass, t)
+            step = lambda c, xs: self._decode_layer(c, xs, loop_pass, t, *prefix)
             return jax.lax.scan(step, carry, (stack, index))[0], None
 
         with jax.named_scope(scopes.ACT_LOOP):

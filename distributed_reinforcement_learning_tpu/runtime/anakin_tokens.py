@@ -13,6 +13,13 @@ start of every update: episode = unroll, so the learner's `[N, T]`
 forward sees exactly the context the actor saw (`ximpala`'s rule). Then
 one learn step over the N x T rollout (`LoopLMAgent._learn`).
 
+The T steps are S scans, not one (`looped_lm.decode_spans`: 8 of 16 at
+T = 128): a decode step is bound by the bytes it reads, and a cache row
+holds nothing past t, so the steps of segment i read the static prefix
+`spans[i]` of their rows: 9/16 of the cache on average where one scan
+read all of it at every step. The carry passes from scan to scan in
+place; the compiled chunk holds S decode bodies of one layer each.
+
 The rollout is five `[T, N]` scalars a step, so its swap to the `[N, T]`
 that the attention wants moves 80 KB: there is no layout question here
 (PR 29's was about frames). The chunk DONATES its state (PR 26): the
@@ -34,6 +41,7 @@ import jax.numpy as jnp
 from distributed_reinforcement_learning_tpu.agents.common import TrainState
 from distributed_reinforcement_learning_tpu.agents.looplm import (
     LoopLMAgent, LoopLMBatch)
+from distributed_reinforcement_learning_tpu.models import looped_lm
 from distributed_reinforcement_learning_tpu.observability import scopes
 from distributed_reinforcement_learning_tpu.runtime.anakin_mesh import own_buffers
 
@@ -56,16 +64,22 @@ class AnakinTokens:
                 f"{agent.cfg.trajectory}: the cache is reset every update, so "
                 f"an episode has to be exactly one unroll")
         self.agent, self.env, self.num_envs = agent, env, num_envs
+        self.decode_spans = looped_lm.decode_spans(agent.cfg.trajectory)
         self.train_chunk = jax.jit(scopes.tagged(self._train_chunk),
                                    static_argnums=(1,), donate_argnums=(0,))
 
     @property
     def static_facts(self) -> dict:
         """What the compiled chunk is, said once at start-up."""
-        cfg = self.agent.cfg
+        cfg, spans = self.agent.cfg, self.decode_spans
+        steps = [hi - lo for lo, hi in zip((0, *spans), spans)]
         return {"loop_passes": cfg.total_ut_steps,
                 "kv_cache_bytes": self.agent.kv_cache_bytes * self.num_envs,
-                "compute_dtype": jnp.dtype(cfg.dtype).name}
+                "compute_dtype": jnp.dtype(cfg.dtype).name,
+                "decode_spans": spans,
+                # mean over the T steps of the share of its row a step reads
+                "cache_read_share": sum(n * span for n, span in zip(steps, spans))
+                / cfg.trajectory ** 2}
 
     def init(self, rng: jax.Array) -> TokensState:
         k_train, k_env, k_run = jax.random.split(rng, 3)
@@ -74,28 +88,45 @@ class AnakinTokens:
             train=self.agent.init_state(k_train), env=env, obs=obs, rng=k_run))
 
     # -- one env step = one decode step (scanned T times per update) -----
-    def _env_step(self, act_params, carry, t):
+    def _env_step(self, act_params, span, carry, t):
         env, obs, cache, rng = carry
         rng, k_act, k_env = jax.random.split(rng, 3)
         with jax.named_scope(scopes.ACT):
-            action, logp, cache = self.agent._act(act_params, obs, t, cache, k_act)
+            action, logp, cache = self.agent._act(act_params, obs, t, cache,
+                                                  k_act, span)
         with jax.named_scope(scopes.ENV):
             env, next_obs, reward, done, ep_ret = self.env.step(env, action, k_env)
         record = dict(tokens=obs, action=action, behaviour_logp=logp,
                       reward=reward, done=done, episode_return=ep_ret)
         return (env, next_obs, cache, rng), record
 
+    def _collect(self, act_params, carry, lo: int, hi: int, span: int):
+        """Steps `[lo, hi)` of the episode, each reading the first `span`
+        positions of its cache rows -> (carry, `[hi - lo, N]` records)."""
+        if not lo < hi <= span:
+            raise ValueError(
+                f"decode steps {lo}..{hi - 1} under a cache prefix of {span} "
+                f"positions: a step past its prefix cannot see what it wrote")
+        return jax.lax.scan(
+            functools.partial(self._env_step, act_params, span), carry,
+            jnp.arange(lo, hi, dtype=jnp.int32))
+
     # -- one update: a T-step episode by decode, then learn --------------
     def _update(self, state: TokensState, _):
-        agent = self.agent
+        agent, spans = self.agent, self.decode_spans
+        if spans[-1] != agent.cfg.trajectory:
+            raise ValueError(f"spans {spans} do not end at the episode's "
+                             f"{agent.cfg.trajectory} steps")
         with jax.named_scope(scopes.COLLECT):
             with jax.named_scope(scopes.ACT_CACHE):
                 cache = agent.init_cache(self.num_envs)
             act_params = agent.for_acting(state.train.params)
-            (env, obs, _, rng), rec = jax.lax.scan(
-                functools.partial(self._env_step, act_params),
-                (state.env, state.obs, cache, state.rng),
-                jnp.arange(agent.cfg.trajectory, dtype=jnp.int32))
+            carry, recs = (state.env, state.obs, cache, state.rng), []
+            for lo, span in zip((0, *spans), spans):
+                carry, rec = self._collect(act_params, carry, lo, span, span)
+                recs.append(rec)
+            env, obs, _, rng = carry
+            rec = jax.tree.map(lambda *xs: jnp.concatenate(xs), *recs)
         batch = LoopLMBatch(**{f: rec[f].swapaxes(0, 1)
                                for f in LoopLMBatch._fields})
         train, metrics = agent._learn(state.train, batch)

@@ -9,6 +9,7 @@ R 4, T 16, N 4; float32 so that the agreement is the arithmetic's.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -218,15 +219,18 @@ def test_threshold_one_runs_every_pass():
 # -- acting as decode ---------------------------------------------------------
 
 
-def _decode_all(agent, params, tokens, model=None):
-    """Token by token through the cache -> last-pass logits `[N, T, V]`."""
+def _decode_all(agent, params, tokens, model=None, spans=None):
+    """Token by token through the cache -> last-pass logits `[N, T, V]`;
+    step t reads the first of `spans` that covers it (default: the
+    full-length path, `decode` without `span`)."""
     model = model or agent.model
     cache = agent.init_cache(tokens.shape[0])
     out = []
-    step = jax.jit(lambda tok, t, c: model.apply(params, tok, t, c,
-                                                 method=model.decode))
+    step = jax.jit(lambda tok, t, c, span: model.apply(
+        params, tok, t, c, span, method=model.decode), static_argnums=3)
     for t in range(tokens.shape[1]):
-        h, cache = step(tokens[:, t], jnp.int32(t), cache)
+        span = spans and next(s for s in spans if s > t)
+        h, cache = step(tokens[:, t], jnp.int32(t), cache, span)
         out.append(model.apply(params, h, method=model.logits)[0])
     return jnp.stack(out, axis=1)
 
@@ -248,6 +252,66 @@ def test_decode_through_per_pass_cache_equals_full_forward(
     np.testing.assert_allclose(got, want["logits"][-1], rtol=2e-4, atol=2e-4)
 
 
+def _model_as(cls, model):
+    return cls(**{f.name: getattr(model, f.name)
+                  for f in dataclasses.fields(model)
+                  if f.name not in ("parent", "name")})
+
+
+class ShortRead(looped_lm.LoopedLM):
+    """The planted fault: a prefix one position short, so that the last
+    step of every segment does not see the key it has just written."""
+
+    def _decode_layer(self, carry, xs, slot, t, span=None):
+        span = carry[1].k.shape[3] if span is None else span
+        return super()._decode_layer(carry, xs, slot, t, span - 1)
+
+
+# 16 steps: every segment whole; 14: the last of four is cut to 2 steps
+@pytest.mark.parametrize("length", [16, 14])
+@pytest.mark.parametrize("segments", [1, 2, 4])
+def test_decode_through_spans_equals_full_length_decode(
+        params, whole_episode_forward, segments, length):
+    nb, full = whole_episode_forward
+    agent = LoopLMAgent(dataclasses.replace(CFG, trajectory=length))
+    tokens = jnp.asarray(nb["tokens"][:, :length])
+    spans = looped_lm.decode_spans(length, segments)
+    assert len(spans) == segments and spans[-1] == length
+    whole = _decode_all(agent, params, tokens)
+    got = _decode_all(agent, params, tokens, spans=spans)
+    # What a span drops are exact zeros of the float32 sums; what is left
+    # is the ORDER of the sums over the prefix, which the CPU changes with
+    # the length: 0 at spans of 8 and 16, at most 1.4e-5 on logits up to
+    # 5.9 at spans of 4 and under (a last bit, through 8 layer passes).
+    np.testing.assert_allclose(got, whole, rtol=0, atol=5e-5)
+    # causal: the first `length` positions of the 16-token forward
+    np.testing.assert_allclose(got, full[:, :length], rtol=2e-4, atol=2e-4)
+    want = ref.forward(ref.rekey(params), nb["tokens"], nb["done"], hyper(CFG))
+    np.testing.assert_allclose(got, want["logits"][-1][:, :length],
+                               rtol=2e-4, atol=2e-4)
+    short = _decode_all(agent, params, tokens, spans=spans,
+                        model=_model_as(ShortRead, agent.model))
+    ends = [s - 1 for s in spans]  # each segment's last step
+    assert float(jnp.min(jnp.max(jnp.abs(short - whole)[:, ends],
+                                 axis=(0, 2)))) > 1e-2
+
+
+@pytest.mark.parametrize("length, spans, share", [
+    (16, (16,), 1.0), (31, (31,), 1.0), (32, (16, 32), 0.75),
+    (100, (17, 34, 51, 68, 85, 100), 0.5835),
+    (128, (16, 32, 48, 64, 80, 96, 112, 128), 0.5625),
+    (2048, tuple(range(256, 2049, 256)), 0.5625)])
+def test_spans_follow_from_the_episode_length(length, spans, share):
+    """S = 8 from 128 steps on, segments of at least 16 below, one span
+    under 32; `static_facts` says what the chunk was compiled with."""
+    assert looped_lm.decode_spans(length) == spans
+    facts = AnakinTokens(
+        LoopLMAgent(dataclasses.replace(CFG, trajectory=length)), N,
+        TokenRecall(V, length, 8)).static_facts
+    assert facts["decode_spans"] == spans
+    assert facts["cache_read_share"] == pytest.approx(share, abs=1e-9)
+
+
 def test_cache_shared_between_passes_is_wrong(agent, params,
                                               whole_episode_forward):
     class Shared(looped_lm.LoopedLM):
@@ -255,9 +319,7 @@ def test_cache_shared_between_passes_is_wrong(agent, params,
             return super()._decode_layer(carry, xs, slot * 0, t)
 
     nb, full = whole_episode_forward
-    m = agent.model
-    shared = Shared(**{f.name: getattr(m, f.name) for f in dataclasses.fields(m)
-                       if f.name not in ("parent", "name")})
+    shared = _model_as(Shared, agent.model)
     got = _decode_all(agent, params, jnp.asarray(nb["tokens"]), model=shared)
     # position 0 attends only to itself; from position 1 on the passes
     # read each other's keys
@@ -356,14 +418,23 @@ def test_env_is_deterministic_in_its_seed(seed):
 # -- one fused chunk ----------------------------------------------------------------
 
 
-@pytest.fixture(scope="module")
-def chunk(agent):
+@functools.lru_cache(maxsize=None)
+def _chunk_of(agent, segments):
+    """Two updates of the fused loop from one seed, collected through
+    `segments` spans (None: what `decode_spans` derives from T = 16, one)."""
     env = TokenRecall(vocab=V, episode_len=T, distance=8)
     anakin = AnakinTokens(agent, N, env)
+    if segments is not None:
+        anakin.decode_spans = looped_lm.decode_spans(T, segments)
     state = anakin.init(jax.random.PRNGKey(7))
     before = jax.tree.map(np.asarray, state.train.params)
     state, metrics = anakin.train_chunk(state, 2)
     return anakin, before, state, jax.device_get(metrics)
+
+
+@pytest.fixture(scope="module", params=[None, 4], ids=["one_span", "four_spans"])
+def chunk(agent, request):
+    return _chunk_of(agent, request.param)
 
 
 def test_fused_chunk_losses_are_finite_and_parameters_move(chunk):
@@ -393,9 +464,55 @@ def test_collect_logp_equals_learn_logp_with_unchanged_weights(chunk):
     want = ref.taken_logp(ref.rekey(before), roll["tokens"], roll["action"],
                           roll["done"], hyper(CFG))
     np.testing.assert_allclose(roll["behaviour_logp"], want, rtol=1e-4, atol=1e-4)
+    spans = anakin.decode_spans
+    assert spans in ((16,), (4, 8, 12, 16))
     assert anakin.static_facts == {
         "loop_passes": 4, "compute_dtype": "float32",
-        "kv_cache_bytes": 2 * 4 * 2 * N * T * 4 * 16 * 4}
+        "kv_cache_bytes": 2 * 4 * 2 * N * T * 4 * 16 * 4,
+        "decode_spans": spans,
+        "cache_read_share": {1: 1.0, 4: 0.625}[len(spans)]}
     # four times a plain decoder's of the same depth
     plain = LoopLMAgent(dataclasses.replace(CFG, total_ut_steps=1))
     assert anakin.agent.kv_cache_bytes == 4 * plain.kv_cache_bytes
+
+
+@pytest.mark.parametrize("segments", [2, 4])
+def test_rollout_is_the_same_through_spans_and_through_one(agent, segments):
+    """One seed, two updates: the segmented collection shows, answers and
+    rewards what the one-scan collection does, update for update."""
+    _, _, one_state, one = _chunk_of(agent, None)
+    anakin, _, state, got = _chunk_of(agent, segments)
+    assert len(anakin.decode_spans) == segments
+    for field in ("tokens", "action", "reward", "done"):
+        np.testing.assert_array_equal(got["rollout"][field],
+                                      one["rollout"][field], err_msg=field)
+    np.testing.assert_allclose(got["rollout"]["behaviour_logp"],
+                               one["rollout"]["behaviour_logp"],
+                               rtol=0, atol=5e-5)  # the order of the sums
+    np.testing.assert_allclose(got["total_loss"], one["total_loss"], rtol=1e-5)
+    np.testing.assert_array_equal(got["episode_return_sum"],
+                                  one["episode_return_sum"])
+    for a, b in zip(jax.tree.leaves(state.train.params),
+                    jax.tree.leaves(one_state.train.params)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("what", ["segment_past_its_span", "span_past_the_cache",
+                                  "spans_short_of_the_episode"])
+def test_a_span_that_does_not_cover_its_steps_is_refused(agent, params, what):
+    """At trace time, not as a wrong answer: `t` is traced, so the static
+    bounds are held where they are known."""
+    anakin = AnakinTokens(agent, N, TokenRecall(vocab=V, episode_len=T, distance=8))
+    if what == "segment_past_its_span":
+        with pytest.raises(ValueError, match="cannot see what it wrote"):
+            anakin._collect(params, None, 0, 16, 8)
+    elif what == "span_past_the_cache":
+        with pytest.raises(ValueError, match="span 17 of a cache of 16"):
+            jax.eval_shape(lambda: agent.model.apply(
+                params, jnp.zeros(N, jnp.int32), jnp.int32(0),
+                agent.init_cache(N), T + 1, method=agent.model.decode))
+    else:
+        anakin.decode_spans = (8, 12)
+        state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+        with pytest.raises(ValueError, match="do not end at the episode"):
+            jax.eval_shape(anakin._update, state, None)

@@ -23,6 +23,7 @@ The kernels and the float32 IMPALA step run in tier-1; the other whole
 steps (10-25 s each) are marked slow and run before a chip call.
 """
 
+import collections
 import os
 import re
 
@@ -328,7 +329,11 @@ def test_ouro_looplm_chunk_fits_and_holds_its_six_kernels(chip,
     arguments + scratch stay under the chip's `bytes_limit`. Six Mosaic
     kernels whatever L and R: flash attention in the scanned layer body
     (forward, rematerialised forward, dq, dkv) and V-trace's two views
-    with the four passes in the kernel's batch."""
+    with the four passes in the kernel's batch. The 128 decode steps are
+    8 scans (PR 31): each reads its keys and its values as a static
+    prefix `bf16[1,1,32,P,16,128]` of a cache row, P = 16, 32, ..., 128,
+    and the cache passes from scan to scan in place (a `copy` of it is
+    1.07 GB, 2.7 ms, eight times an update)."""
     from distributed_reinforcement_learning_tpu.agents.looplm import LoopLMAgent
     from distributed_reinforcement_learning_tpu.envs.registry import (
         make_jittable_env)
@@ -350,9 +355,17 @@ def test_ouro_looplm_chunk_fits_and_holds_its_six_kernels(chip,
     assert mem.alias_size_in_bytes == mem.argument_size_in_bytes > 8 * params
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
-    assert held < 15.0e9 < 16_909_336_064, held  # 14.15 GB when written
+    assert held < 15.0e9 < 16_909_336_064, held  # 14.16 GB when written
+    spans = tuple(range(16, 129, 16))
     assert anakin.static_facts == {"loop_passes": 4, "compute_dtype": "bfloat16",
-                                   "kv_cache_bytes": 2 ** 30}
+                                   "kv_cache_bytes": 2 ** 30,
+                                   "decode_spans": spans,
+                                   "cache_read_share": 0.5625}
+    text = compiled.as_text()
+    reads = collections.Counter(int(p) for p in re.findall(
+        r"= bf16\[1,1,32,(\d+),16,128\]\S* dynamic-slice\(", text))
+    assert reads == {p: 2 for p in spans}, reads  # keys and values, per segment
+    assert not re.findall(r"= bf16\[4,8,32,128,16,128\]\S* copy\(", text)
 
 
 def test_breakout_step_keeps_no_raster_and_one_luma(chip):
