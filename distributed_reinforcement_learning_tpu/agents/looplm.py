@@ -154,6 +154,7 @@ class LoopLMAgent:
         `value` of every pass."""
         model, cfg = self.model, self.cfg
         hs = model.apply(params, batch.tokens, batch.done, method=model.trunk)
+        hs, counters = hs if isinstance(hs, tuple) else (hs, {})  # a trunk's own
         r, b, t, d = hs.shape
         block = min(cfg.head_block, b * t)
         if (b * t) % block:
@@ -164,9 +165,8 @@ class LoopLMAgent:
         actions = jnp.broadcast_to(batch.action.reshape(1, blocks, block),
                                    (r, blocks, block)).reshape(r * blocks, block)
         with jax.named_scope(scopes.HEADS):
-            out = jax.lax.map(lambda xs: heads(*xs),
-                              (hs.reshape(r * blocks, block, d), actions))
-        return {k: v.reshape(r, b, t) for k, v in out.items()}
+            out = jax.lax.map(lambda xs: heads(*xs), (hs.reshape(-1, block, d), actions))
+        return {"counters": counters, **{k: v.reshape(r, b, t) for k, v in out.items()}}
 
     def _loss(self, params, batch: LoopLMBatch):
         cfg = self.cfg
@@ -215,7 +215,7 @@ class LoopLMAgent:
             **{f"exit_cdf_pass{i + 1}": cdf[i] for i in range(r - 1)},
             # positions of the LAST pass whose raw rho was cut to rho-bar = 1
             "rho_clipped_share": jnp.mean((first(log_rho)[-1] > 0).astype(F32)),
-            "behaviour_logp_mean": jnp.mean(batch.behaviour_logp),
+            "behaviour_logp_mean": jnp.mean(batch.behaviour_logp), **stats["counters"],
         }
         return total, metrics
 
@@ -230,3 +230,12 @@ class LoopLMAgent:
         metrics["learning_rate"] = self._schedule(state.step)
         return state.replace(params=params, opt_state=opt_state,
                              step=state.step + 1), metrics
+
+    # -- what a subclass with another model replaces (agents/hybridlm.py) --
+    def state_facts(self, num_rows: int) -> dict:
+        """Bytes of the act-time state of `num_rows` rows, by kind."""
+        return {"kv_cache_bytes": self.kv_cache_bytes * num_rows}
+
+    def state_counters(self, cache) -> dict:
+        """Counters read from the act-time state an episode ended with."""
+        return {}

@@ -96,11 +96,11 @@ def episode_positions(done: jax.Array) -> jax.Array:
 
 def exit_distribution(gate: jax.Array) -> jax.Array:
     """`gate [R, ...]` -> the probability of leaving after pass r,
-    `[R, ...]`: p(r) = lambda^(r) prod_{j<r}(1 - lambda^(j)) for r < R and
-    what is left for r = R. Sums to 1 over passes."""
+    p(r) = lambda^(r) prod_{j<r}(1 - lambda^(j)), what is left for r = R."""
     stay = jnp.cumprod(1.0 - gate[:-1], axis=0)  # prod_{j<=r}
     before = jnp.concatenate([jnp.ones_like(gate[:1]), stay[:-1]], axis=0)
-    return jnp.concatenate([gate[:-1] * before, stay[-1:]], axis=0)
+    # what is left after the last pass; all of it where there is one pass
+    return jnp.concatenate([gate[:-1] * before, (stay if len(stay) else before)[-1:]], axis=0)
 
 
 class LoopedLM(nn.Module):

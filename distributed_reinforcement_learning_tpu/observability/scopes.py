@@ -69,6 +69,17 @@ LOOP = "learn/loss/loop"  # the looped stack (backward: re-entered under transpo
 HEADS = "learn/loss/heads"  # R x (norm, vocabulary, gate, value, log-softmax)
 LOSS_VTRACE = "learn/loss/vtrace"  # per-pass V-trace on taken-action log-probs
 
+# The hybrid state-space / attention model in the same loop
+# (models/hybrid_lm.py, ops/ssd.py); heads, cache, V-trace and optimizer
+# under the token loop's names above. No bump of CACHE_TAG for these: no
+# older commit compiled a chunk of this model, so no cache directory
+# holds one without them, and a bump would start every other chunk cold.
+ACT_LAYERS = "collect/act/layers"  # a decode step's projections and MLPs
+ACT_SSM = "collect/act/ssm"  # window shift, state update and read-out, nine layers
+LAYERS = "learn/loss/layers"  # the stack (backward: re-entered under transpose)
+SSD = "learn/loss/layers/ssd"  # the chunked scan alone
+ATTENTION = "learn/loss/layers/attention"  # the one attention mixer
+
 IMPALA_CHUNK_SCOPES = (COLLECT, ACT, ENV, RENDER, RECORD,
                        LEARN, LOSS, VTRACE, OPTIMIZER)
 REPLAY_CHUNK_SCOPES = (COLLECT, REPLAY, LEARN)
@@ -76,6 +87,8 @@ R2D2_CHUNK_SCOPES = (ACT, ENV, RECORD, REPLAY_SCORE, REPLAY_WRITE,
                      REPLAY_SAMPLE, REPLAY_PRIORITIES, LOSS, UNROLL, OPTIMIZER)
 TOKENS_CHUNK_SCOPES = (ENV, ACT, ACT_LOOP, ACT_CACHE, ACT_HEAD, LOOP, HEADS,
                        LOSS_VTRACE, OPTIMIZER)
+HYBRID_CHUNK_SCOPES = (ENV, ACT, ACT_LAYERS, ACT_SSM, ACT_CACHE, ACT_HEAD,
+                       LAYERS, SSD, ATTENTION, HEADS, LOSS_VTRACE, OPTIMIZER)
 
 # -- host spans of the fused loops (runtime/launch.py) ---------------------
 STEP_READ = "anakin/step_read"  # int(state.train.step) at the loop head

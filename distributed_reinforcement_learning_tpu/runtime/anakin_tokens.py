@@ -73,8 +73,8 @@ class AnakinTokens:
         """What the compiled chunk is, said once at start-up."""
         cfg, spans = self.agent.cfg, self.decode_spans
         steps = [hi - lo for lo, hi in zip((0, *spans), spans)]
-        return {"loop_passes": cfg.total_ut_steps,
-                "kv_cache_bytes": self.agent.kv_cache_bytes * self.num_envs,
+        return {"loop_passes": cfg.total_ut_steps,  # and the act-time state by kind
+                **self.agent.state_facts(self.num_envs),
                 "compute_dtype": jnp.dtype(cfg.dtype).name,
                 "decode_spans": spans,
                 # mean over the T steps of the share of its row a step reads
@@ -125,15 +125,15 @@ class AnakinTokens:
             for lo, span in zip((0, *spans), spans):
                 carry, rec = self._collect(act_params, carry, lo, span, span)
                 recs.append(rec)
-            env, obs, _, rng = carry
+            env, obs, cache, rng = carry
             rec = jax.tree.map(lambda *xs: jnp.concatenate(xs), *recs)
         batch = LoopLMBatch(**{f: rec[f].swapaxes(0, 1)
                                for f in LoopLMBatch._fields})
         train, metrics = agent._learn(state.train, batch)
+        metrics.update(agent.state_counters(cache))  # of the episode's last step
         metrics["episode_return_sum"] = rec["episode_return"].sum()
         metrics["episodes_done"] = rec["done"].sum().astype(jnp.float32)
-        # The rollout itself (80 KB at 32 x 128): what a reader needs to
-        # replay this update, collect and learn, with a reference.
+        # The rollout itself (80 KB at 32 x 128): what a replay of this update needs.
         metrics["rollout"] = batch._asdict()
         return TokensState(train, env, obs, rng), metrics
 

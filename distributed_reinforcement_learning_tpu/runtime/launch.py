@@ -566,18 +566,18 @@ def train_anakin_tokens(config_path: str, section: str, num_updates: int,
     updates on the same `_run_chunk` as the other fused loops."""
     open_devices("anakin-tokens")
     agent_cfg, rt = load_config(config_path, section)
-    from distributed_reinforcement_learning_tpu.agents.looplm import (
-        LoopLMAgent, LoopLMConfig)
+    # One token-level actor-critic, two models: a looped decoder (`looplm`)
+    # or a hybrid state-space / attention stack (`hybridlm`).
     from distributed_reinforcement_learning_tpu.envs.registry import make_jittable_env
     from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import AnakinTokens
 
-    if not isinstance(agent_cfg, LoopLMConfig):
-        raise ValueError("anakin-tokens mode runs the looplm family "
-                         "(a section with \"algorithm\": \"looplm\")")
+    agent = _token_agent(agent_cfg)  # at the end of this file; refuses the rest
+    if agent is None:
+        raise ValueError("anakin-tokens mode runs the looplm and hybridlm families")
     env = make_jittable_env(
         rt.envs[0], vocab=agent_cfg.vocab_size,
         episode_len=agent_cfg.trajectory, distance=agent_cfg.recall_distance)
-    anakin = AnakinTokens(LoopLMAgent(agent_cfg),
+    anakin = AnakinTokens(agent,
                           num_envs or rt.num_actors * rt.envs_per_actor, env)
     print(f"[anakin-tokens] {anakin.static_facts}")  # static, as compiled
     state = anakin.init(jax.random.PRNGKey(seed))
@@ -611,3 +611,18 @@ def train_anakin_tokens(config_path: str, section: str, num_updates: int,
         "chunk_mean_returns": [round(r, 2) for r in returns],
         "mean_return_last_chunk": round(returns[-1], 2) if returns else None,
     }
+
+
+def _token_agent(agent_cfg):
+    """The token-level agent of a `looplm` or `hybridlm` section's
+    configuration, None for any other family's."""
+    from distributed_reinforcement_learning_tpu.agents.hybridlm import (
+        HybridLMAgent, HybridLMConfig)
+    from distributed_reinforcement_learning_tpu.agents.looplm import (
+        LoopLMAgent, LoopLMConfig)
+
+    for config, agent in ((LoopLMConfig, LoopLMAgent),
+                          (HybridLMConfig, HybridLMAgent)):
+        if isinstance(agent_cfg, config):
+            return agent(agent_cfg)
+    return None

@@ -251,6 +251,59 @@ def load_config(path: str | Path, section: str):
             dtype=_compute_dtype(d, "bfloat16"),
             init_std=d.get("initializer_range", 0.02),
         )
+    elif algorithm == "hybridlm":
+        from distributed_reinforcement_learning_tpu.agents.hybridlm import (
+            HybridLMConfig)
+        from distributed_reinforcement_learning_tpu.models.hybrid_lm import (
+            layer_runs)
+
+        # As `looplm`: the source's own keys, none of them guessed. What
+        # the family's config can say and this program does not compute
+        # is refused by name.
+        layer_types = tuple(d["layer_types"])
+        layer_runs(layer_types)  # an unknown layer type raises here
+        if len(layer_types) != d["num_hidden_layers"]:
+            raise ValueError(f"{len(layer_types)} layer_types for "
+                             f"num_hidden_layers {d['num_hidden_layers']}")
+        for key, only in (("mamba_n_groups", 1), ("num_local_experts", 0),
+                          ("position_embedding_type", "nope"),
+                          ("tie_word_embeddings", True), ("mamba_expand", 2),
+                          ("mamba_conv_bias", True), ("mamba_proj_bias", False),
+                          ("attention_bias", False)):
+            if d.get(key, only) != only:
+                raise ValueError(f"{key} {d[key]!r}: only {only!r} is computed")
+        if d["mamba_n_heads"] * d["mamba_d_head"] != 2 * d["hidden_size"]:
+            raise ValueError("mamba_n_heads x mamba_d_head is not twice hidden_size")
+        agent_cfg = HybridLMConfig(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            layer_types=layer_types,
+            num_attention_heads=d["num_attention_heads"],
+            num_key_value_heads=d["num_key_value_heads"],
+            shared_intermediate_size=d["shared_intermediate_size"],
+            mamba_n_heads=d["mamba_n_heads"],
+            mamba_d_head=d["mamba_d_head"],
+            mamba_d_state=d["mamba_d_state"],
+            mamba_d_conv=d["mamba_d_conv"],
+            mamba_chunk_size=d["mamba_chunk_size"],
+            embedding_multiplier=d["embedding_multiplier"],
+            residual_multiplier=d["residual_multiplier"],
+            attention_multiplier=d["attention_multiplier"],
+            logits_scaling=d["logits_scaling"],
+            rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+            trajectory=d.get("trajectory", 1024),
+            recall_distance=d.get("recall_distance", 8),
+            discount_factor=d.get("discount_factor", 0.99),
+            baseline_loss_coef=d.get("baseline_loss_coef", 1.0),
+            entropy_coef=d.get("entropy_coef", 0.05),
+            gradient_clip_norm=d.get("gradient_clip_norm", 40.0),
+            reward_clipping=d.get("reward_clipping", "abs_one"),
+            start_learning_rate=d.get("start_learning_rate", 1e-5),
+            end_learning_rate=d.get("end_learning_rate", 0.0),
+            learning_frame=int(d.get("learning_frame", 1e9)),
+            dtype=_compute_dtype(d, "bfloat16"),
+            init_std=d.get("initializer_range", 0.02),
+        )
     else:
         raise ValueError(f"unknown algorithm {algorithm!r}")
 

@@ -174,6 +174,37 @@ def test_tokens_chunk_module_name_carries_the_cache_tag(tokens_chunk):
     assert f"_train_chunk_{scopes.CACHE_TAG}" in text.split("\n", 1)[0]
 
 
+@pytest.fixture(scope="module")
+def hybrid_chunk_names():
+    import jax.numpy as jnp
+
+    from distributed_reinforcement_learning_tpu.agents.hybridlm import (
+        HybridLMAgent, HybridLMConfig)
+    from distributed_reinforcement_learning_tpu.envs.token_recall_jax import TokenRecall
+    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import AnakinTokens
+
+    cfg = HybridLMConfig(
+        vocab_size=64, hidden_size=32, layer_types=("mamba", "attention", "mamba"),
+        num_attention_heads=4, num_key_value_heads=2, shared_intermediate_size=48,
+        mamba_n_heads=4, mamba_d_head=16, mamba_d_state=8, mamba_chunk_size=8,
+        trajectory=16, dtype=jnp.float32, head_block=16, row_block=2)
+    anakin = AnakinTokens(HybridLMAgent(cfg), 4, TokenRecall(64, 16))
+    return _op_names(anakin.train_chunk, anakin.init(jax.random.PRNGKey(0)), 1)
+
+
+@pytest.mark.parametrize("name", scopes.HYBRID_CHUNK_SCOPES)
+def test_hybrid_chunk_carries_scope(hybrid_chunk_names, name):
+    """The names `perfbench/layer_metrics/hybridlm_*` read (ISSUE 32)."""
+    assert any(name in n for n in hybrid_chunk_names), name
+
+
+@pytest.mark.parametrize("name", [scopes.LAYERS, scopes.SSD, scopes.ATTENTION])
+def test_hybrid_backward_stack_keeps_its_names(hybrid_chunk_names, name):
+    """The rematerialised blocks are entered again under the transpose."""
+    assert any(f"transpose(jvp({scopes.LOSS}))" in n and name in n
+               for n in hybrid_chunk_names)
+
+
 def test_r2d2_backward_recurrence_keeps_the_unroll_name(r2d2_chunk_names):
     """The inner scope is entered again inside the transposed outer one:
     `transpose(jvp(learn/loss))/.../learn/loss/unroll/...`."""

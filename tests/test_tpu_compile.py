@@ -5,7 +5,7 @@ described, not opened (`/opt/skills/guides/on-chip-measurement`, section
 2). That catches what interpret mode cannot — a slice not aligned to the
 tiling, a kernel over its VMEM budget, a program Mosaic refuses — at the
 shapes the main path really runs (`config.json` sections `impala`,
-`apex`, `r2d2_pixel`, `r2d2_atari`, `ouro_looplm`; the Anakin chunk
+`apex`, `r2d2_pixel`, `r2d2_atari`, `ouro_looplm`, `granite_hybrid`; the Anakin chunk
 `chip_smoke.py` drives), and
 costs no chip time. It also shows what the compiler DID with a program:
 which layout copies and which collectives it put in (the fused IMPALA
@@ -366,6 +366,55 @@ def test_ouro_looplm_chunk_fits_and_holds_its_six_kernels(chip,
         r"= bf16\[1,1,32,(\d+),16,128\]\S* dynamic-slice\(", text))
     assert reads == {p: 2 for p in spans}, reads  # keys and values, per segment
     assert not re.findall(r"= bf16\[4,8,32,128,16,128\]\S* copy\(", text)
+
+
+def test_granite_hybrid_chunk_fits_and_updates_its_state_in_place(
+        chip, kernels_as_on_chip):
+    """The fused token chunk at the `granite_hybrid` section's sizes (32
+    envs x 1,024 tokens, 9 Mamba-2 layers and 1 attention layer at 2048
+    wide, chunk of 1): it compiles for a described v5e, the donated state
+    (772.2 M parameters + their second moments, 8 B each) is aliased
+    whole, and arguments + scratch stay under the chip's `bytes_limit` by
+    `memory_analysis`, whose scratch reads 1.5 times the chip's own
+    (PERF.md, PRs 30 and 32). Six Mosaic kernels: flash attention in the
+    one attention layer (forward, rematerialised forward, dq, dkv) and
+    V-trace's two views; the state-space scan is plain XLA. No copy of a
+    layer's recurrent state `f32[32,64,64,128]` in any decode body: the
+    step updates it in place (as slices of one stacked array under a scan
+    over layers it was copied twice a layer a step)."""
+    from distributed_reinforcement_learning_tpu.agents.hybridlm import (
+        HybridLMAgent)
+    from distributed_reinforcement_learning_tpu.envs.registry import (
+        make_jittable_env)
+    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import (
+        AnakinTokens)
+
+    cfg, rt = load_config(CONFIG, "granite_hybrid")
+    env = make_jittable_env(rt.envs[0], vocab=cfg.vocab_size,
+                            episode_len=cfg.trajectory,
+                            distance=cfg.recall_distance)
+    anakin = AnakinTokens(HybridLMAgent(cfg), rt.num_actors * rt.envs_per_actor,
+                          env)
+    state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+    lowered = anakin.train_chunk.lower(_on(chip, state), 1)
+    assert len(re.findall("tpu_custom_call", lowered.as_text())) == 6
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    params = 772_162_497
+    assert mem.alias_size_in_bytes == mem.argument_size_in_bytes > 8 * params
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert held < 16.5e9 < 16_909_336_064, held  # 16.18 GB when written
+    facts = anakin.static_facts
+    assert facts["layer_order"] == ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+    assert (facts["ssm_state_bytes"], facts["conv_state_bytes"],
+            facts["kv_cache_bytes"]) == (9 * 32 * 64 * 64 * 128 * 4,
+                                         9 * 32 * 3 * 4352 * 4,
+                                         2 * 32 * 1024 * 8 * 64 * 2)
+    assert facts["decode_spans"] == tuple(range(128, 1025, 128))
+    text = compiled.as_text()
+    assert not re.findall(r"= f32\[32,64,64,128\]\S* copy\(", text)
+    assert not re.findall(r"= f32\[\d+,32,64,64,128\]\S* copy\(", text)
 
 
 def test_breakout_step_keeps_no_raster_and_one_luma(chip):

@@ -7,12 +7,13 @@ sequence parallelism, MoE, pipelining, remat all apply).
 
     python train_ximpala.py --section ximpala --updates 300
 
-`--mode anakin` is the fused on-device loop of the `looplm` sections
-(runtime/anakin_tokens.py): a looped language model as the policy of a
-token-level IMPALA, generation through a per-pass key/value cache and
-the learn step in one compiled chunk.
+`--mode anakin` is the fused on-device loop of the `looplm` and
+`hybridlm` sections (runtime/anakin_tokens.py): a language model as the
+policy of a token-level IMPALA, generation by decode through its
+act-time state and the learn step in one compiled chunk.
 
     python train_ximpala.py --mode anakin --section ouro_looplm --updates 8
+    python train_ximpala.py --mode anakin --section granite_hybrid --updates 2 --anakin_chunk 1
 """
 
 from __future__ import annotations
