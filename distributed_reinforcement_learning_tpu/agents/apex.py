@@ -41,9 +41,6 @@ class ApexConfig:
     end_learning_rate: float = 0.0
     learning_frame: int = 100_000_000_000_000
     dtype: Any = jnp.float32
-    # Fold /255 into conv0's kernel; uint8 frames feed the model raw
-    # (see ImpalaConfig.fold_normalize / models.torso.NatureConv).
-    fold_normalize: bool = False
 
 
 class ApexBatch(NamedTuple):
@@ -63,10 +60,7 @@ class ApexAgent:
         if len(cfg.obs_shape) == 1:
             self.model = SimpleQNetwork(num_actions=cfg.num_actions, dtype=cfg.dtype)
         else:
-            self.model = DuelingQNetwork(
-                num_actions=cfg.num_actions, dtype=cfg.dtype,
-                fold_normalize=cfg.fold_normalize,
-            )
+            self.model = DuelingQNetwork(num_actions=cfg.num_actions, dtype=cfg.dtype)
         self._schedule = common.polynomial_lr(
             cfg.start_learning_rate, cfg.end_learning_rate, cfg.learning_frame
         )
@@ -95,14 +89,8 @@ class ApexAgent:
         return common.TargetTrainState.create(params, self.tx)
 
     def _prep_obs(self, obs):
-        """Normalize frames — or pass integer frames raw under `fold_normalize`."""
-        if (
-            self.cfg.fold_normalize
-            and len(self.cfg.obs_shape) == 3
-            and jnp.issubdtype(obs.dtype, jnp.integer)
-        ):
-            return obs
-        return common.normalize_obs(obs, self.cfg.dtype)
+        """Integer frames go to the model raw (conv0 owns their /255)."""
+        return common.prep_obs(obs, self.cfg.obs_shape, self.cfg.dtype)
 
     # -- act -------------------------------------------------------------
     def _act(self, params, obs, prev_action, epsilon, rng):

@@ -131,6 +131,8 @@ def normalize_obs(obs: jax.Array, dtype: Any = jnp.float32) -> jax.Array:
 
     The reference normalizes `/255` at every feed (`agent/impala.py:119,133`);
     keeping frames uint8 until this point minimizes host->HBM bandwidth.
+    Integer IMAGES do not come here any more (`prep_obs`): this is for
+    integer vector observations and for floats.
 
     `dtype` should be the model's compute dtype: normalizing straight
     into bf16 (a bf16 multiply by the constant 1/255) avoids
@@ -144,6 +146,16 @@ def normalize_obs(obs: jax.Array, dtype: Any = jnp.float32) -> jax.Array:
     if jnp.issubdtype(obs.dtype, jnp.integer):
         return obs.astype(dtype) * jnp.asarray(1.0 / 255.0, dtype)
     return obs.astype(dtype)
+
+
+def prep_obs(obs: jax.Array, obs_shape: tuple[int, ...], dtype: Any) -> jax.Array:
+    """What an agent hands its network: integer IMAGE observations raw
+    (they stay bytes until conv0, whose kernel carries the /255: see
+    `models.torso.NatureConv`), everything else through `normalize_obs`.
+    Decided by the observation's own dtype, not by a configuration key."""
+    if len(obs_shape) == 3 and jnp.issubdtype(obs.dtype, jnp.integer):
+        return obs
+    return normalize_obs(obs, dtype)
 
 
 def global_norm(tree) -> jax.Array:

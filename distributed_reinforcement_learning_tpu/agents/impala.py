@@ -64,11 +64,6 @@ class ImpalaConfig:
     # conv/LSTM activations of B*T frames in HBM — the knob that lets
     # batch size keep scaling once activations, not params, bound memory.
     remat: bool = False
-    # Fold the /255 frame normalization into conv0's kernel (NatureConv
-    # input_scale): uint8 frames feed the model raw, skipping the
-    # full-frame elementwise normalize pass. Exact same math modulo one
-    # rounding on the kernel; no-op for vector observations.
-    fold_normalize: bool = False
     # "nature" (reference parity, model/impala_actor_critic.py:4-10) or
     # "resnet" — the IMPALA paper's deep torso, `torso_width`-multiplied
     # channels (models/torso.py ResNetTorso, the MXU-dense variant).
@@ -118,7 +113,6 @@ class ImpalaAgent:
         self.cfg = cfg
         self.model = ImpalaActorCritic(
             num_actions=cfg.num_actions, lstm_size=cfg.lstm_size, dtype=cfg.dtype,
-            fold_normalize=cfg.fold_normalize,
             torso=cfg.torso, torso_width=cfg.torso_width,
         )
         self._schedule = common.polynomial_lr(
@@ -146,15 +140,8 @@ class ImpalaAgent:
         return z, z
 
     def _prep_obs(self, obs: jax.Array) -> jax.Array:
-        """Normalize frames — or pass integer frames raw when the model
-        folds the /255 into conv0 (`fold_normalize`)."""
-        if (
-            self.cfg.fold_normalize
-            and len(self.cfg.obs_shape) == 3
-            and jnp.issubdtype(obs.dtype, jnp.integer)
-        ):
-            return obs
-        return common.normalize_obs(obs, self.cfg.dtype)
+        """Integer frames go to the model raw (conv0 owns their /255)."""
+        return common.prep_obs(obs, self.cfg.obs_shape, self.cfg.dtype)
 
     # -- act -------------------------------------------------------------
     def _act(self, params, obs, prev_action, h, c, rng) -> ActOutput:

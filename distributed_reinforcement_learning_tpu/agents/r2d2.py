@@ -54,13 +54,17 @@ class R2D2Config:
     # configuration — see models/r2d2_net.py).
     torso: str = "mlp"
     torso_width: int = 1
-    # Fold /255 into conv0 (conv torsos): uint8 frames feed the model raw.
-    fold_normalize: bool = False
     # n-step double-Q targets (paper: 5); 1 = the reference's 1-step.
     n_step: int = 1
     # None = the reference's Dense(128) head with a learned mean; an
     # integer = the paper's two dueling streams of that width (512).
     dueling_hidden: int | None = None
+
+    @property
+    def fold_normalize(self) -> bool:
+        """Not a field: uint8 frames always reach the model raw. Read by
+        `perfbench/families/r2d2.py`, which a `benchmark` PR owns."""
+        return True
 
 
 class R2D2Batch(NamedTuple):
@@ -81,7 +85,6 @@ class R2D2Agent(common.SequenceReplayLearnMixin):
         self.model = R2D2Net(num_actions=cfg.num_actions, lstm_size=cfg.lstm_size,
                              dtype=cfg.dtype, torso=cfg.torso,
                              torso_width=cfg.torso_width,
-                             fold_normalize=cfg.fold_normalize,
                              dueling_hidden=cfg.dueling_hidden)
         self.tx = common.adam_with_clip(cfg.learning_rate,
                                         clip_norm=cfg.gradient_clip_norm)
@@ -94,7 +97,8 @@ class R2D2Agent(common.SequenceReplayLearnMixin):
         self.sync_target = jax.jit(lambda s: s.sync_target())
 
     def init_state(self, rng: jax.Array) -> common.TargetTrainState:
-        dtype = jnp.uint8 if self.cfg.fold_normalize else jnp.float32
+        # Pixel observations are stored and fed as bytes.
+        dtype = jnp.uint8 if len(self.cfg.obs_shape) == 3 else jnp.float32
         obs = jnp.zeros((1, *self.cfg.obs_shape), dtype)
         pa = jnp.zeros((1,), jnp.int32)
         h = c = jnp.zeros((1, self.cfg.lstm_size), jnp.float32)
@@ -102,15 +106,8 @@ class R2D2Agent(common.SequenceReplayLearnMixin):
         return common.TargetTrainState.create(params, self.tx)
 
     def _prep_obs(self, obs):
-        """Normalize frames — or pass integer frames raw under
-        `fold_normalize` (the conv owns the /255; ApexAgent._prep_obs)."""
-        if (
-            self.cfg.fold_normalize
-            and len(self.cfg.obs_shape) == 3
-            and jnp.issubdtype(obs.dtype, jnp.integer)
-        ):
-            return obs
-        return common.normalize_obs(obs, self.cfg.dtype)
+        """Integer frames go to the model raw (conv0 owns their /255)."""
+        return common.prep_obs(obs, self.cfg.obs_shape, self.cfg.dtype)
 
     def initial_lstm_state(self, batch_size: int) -> tuple[jax.Array, jax.Array]:
         z = jnp.zeros((batch_size, self.cfg.lstm_size), jnp.float32)

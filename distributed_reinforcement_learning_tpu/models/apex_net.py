@@ -16,7 +16,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from distributed_reinforcement_learning_tpu.models.torso import MLP, ActionEmbedding, NatureConv
+from distributed_reinforcement_learning_tpu.models.torso import (
+    MLP, ActionEmbedding, NatureConv, frame_scale)
 
 
 class DuelingQNetwork(nn.Module):
@@ -25,17 +26,10 @@ class DuelingQNetwork(nn.Module):
     num_actions: int
     hidden_sizes: Sequence[int] = (256, 256)
     dtype: jnp.dtype = jnp.float32
-    # Fold /255 into conv0's kernel; integer frames flow in raw (NatureConv).
-    fold_normalize: bool = False
 
     @nn.compact
     def __call__(self, obs: jax.Array, prev_action: jax.Array) -> jax.Array:
-        scale = (
-            1.0 / 255.0
-            if self.fold_normalize and jnp.issubdtype(obs.dtype, jnp.integer)
-            else None
-        )
-        img = NatureConv(dtype=self.dtype, input_scale=scale, name="torso")(obs)
+        img = NatureConv(dtype=self.dtype, input_scale=frame_scale(obs), name="torso")(obs)
         act = ActionEmbedding(self.num_actions, dtype=self.dtype, name="action_embed")(prev_action)
         z = jnp.concatenate([img, act], axis=-1)
         value = MLP(self.hidden_sizes, self.num_actions, dtype=self.dtype, name="value")(z)

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from distributed_reinforcement_learning_tpu.models.recurrent import LSTMCell
 from distributed_reinforcement_learning_tpu.models.torso import (
-    MLP, ActionEmbedding, NatureConv, ResNetTorso)
+    MLP, ActionEmbedding, NatureConv, ResNetTorso, frame_scale)
 
 
 class ImpalaOutput(NamedTuple):
@@ -44,9 +44,6 @@ class ImpalaActorCritic(nn.Module):
     num_actions: int
     lstm_size: int = 256
     dtype: jnp.dtype = jnp.float32
-    # Fold the /255 frame normalization into conv0's kernel: integer
-    # frames flow in raw and the model owns the scaling (see NatureConv).
-    fold_normalize: bool = False
     # "nature" (reference parity) or "resnet" (the IMPALA paper's deep
     # torso, width-multiplied — the MXU-dense variant; models/torso.py).
     torso: str = "nature"
@@ -59,11 +56,7 @@ class ImpalaActorCritic(nn.Module):
                 obs.astype(self.dtype)
             )
         else:
-            scale = (
-                1.0 / 255.0
-                if self.fold_normalize and jnp.issubdtype(obs.dtype, jnp.integer)
-                else None
-            )
+            scale = frame_scale(obs)
             if self.torso == "resnet":
                 img = ResNetTorso(dtype=self.dtype, width=self.torso_width,
                                   input_scale=scale, name="torso")(obs)

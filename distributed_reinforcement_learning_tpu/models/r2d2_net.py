@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from distributed_reinforcement_learning_tpu.models.recurrent import LSTMCell
 from distributed_reinforcement_learning_tpu.models.torso import (
-    ActionEmbedding, NatureConv, ResNetTorso)
+    ActionEmbedding, NatureConv, ResNetTorso, frame_scale)
 
 _glorot = nn.initializers.xavier_uniform()
 
@@ -50,24 +50,12 @@ class R2D2Net(nn.Module):
     cell_backend: str = "auto"  # LSTM recursion backend (pallas on TPU)
     torso: str = "mlp"  # "mlp" | "nature" | "resnet"
     torso_width: int = 1  # ResNet channel multiplier
-    # Fold /255 into conv0's kernel; integer frames flow in raw
-    # (see NatureConv). Conv torsos only.
-    fold_normalize: bool = False
     dueling_hidden: int | None = None
 
     def setup(self):
         if self.torso == "mlp":
             self.state_fc1 = nn.Dense(256, kernel_init=_glorot, dtype=self.dtype)
             self.state_fc2 = nn.Dense(256, kernel_init=_glorot, dtype=self.dtype)
-        else:
-            scale = 1.0 / 255.0 if self.fold_normalize else None
-            if self.torso == "resnet":
-                self.conv_torso = ResNetTorso(
-                    dtype=self.dtype, width=self.torso_width,
-                    input_scale=scale, name="torso")
-            else:
-                self.conv_torso = NatureConv(
-                    dtype=self.dtype, input_scale=scale, name="torso")
         self.action_embed = ActionEmbedding(self.num_actions, dtype=self.dtype)
         self.cell = LSTMCell(self.lstm_size, dtype=self.dtype, backend=self.cell_backend)
         dense = lambda n: nn.Dense(n, kernel_init=_glorot, dtype=self.dtype)
@@ -92,19 +80,21 @@ class R2D2Net(nn.Module):
             q = v + adv - jnp.mean(adv, axis=-1, keepdims=True)
         return q.astype(jnp.float32)
 
+    @nn.compact
     def _torso(self, x: jax.Array) -> jax.Array:
-        """[N, ...obs] -> [N, F] features."""
+        """[N, ...obs] -> [N, F] features.
+
+        The conv torso is made here, not in `setup`, because what it is
+        made with follows from the frames' dtype (`frame_scale`).
+        """
         if self.torso == "mlp":
             x = nn.relu(self.state_fc1(x.astype(self.dtype)))
             return nn.relu(self.state_fc2(x))
-        if self.fold_normalize and not jnp.issubdtype(x.dtype, jnp.integer):
-            # The folded conv0 kernel scales by 1/255; already-normalized
-            # float frames would be scaled twice. Trace-time contract
-            # error, same guard as ApexAgent._prep_obs's dtype check.
-            raise ValueError(
-                "fold_normalize expects raw integer frames; got "
-                f"{x.dtype} — feed uint8 or disable fold_normalize")
-        return self.conv_torso(x)
+        scale = frame_scale(x)
+        if self.torso == "resnet":
+            return ResNetTorso(dtype=self.dtype, width=self.torso_width,
+                               input_scale=scale, name="torso")(x)
+        return NatureConv(dtype=self.dtype, input_scale=scale, name="torso")(x)
 
     def step(self, obs: jax.Array, prev_action: jax.Array, h: jax.Array, c: jax.Array):
         x = self._torso(obs)

@@ -17,6 +17,14 @@ import jax.numpy as jnp
 _glorot = nn.initializers.xavier_uniform()
 
 
+def frame_scale(frames: jax.Array) -> float | None:
+    """The `input_scale` a pixel network makes its torso with for these
+    frames. Integer frames come in raw and conv0's kernel carries their
+    /255; float frames are the caller's and go through unscaled. The
+    frames' dtype decides, not a configuration key."""
+    return 1.0 / 255.0 if jnp.issubdtype(frames.dtype, jnp.integer) else None
+
+
 class NatureConv(nn.Module):
     """Nature-DQN conv torso: 8x8/4 x32, 4x4/2 x64, 3x3/1 x64, flatten.
 

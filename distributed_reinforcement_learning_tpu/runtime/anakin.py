@@ -125,8 +125,14 @@ class AnakinImpala:
         `[T, B/n]` flattened to `T*B` is not contiguous per shard: the
         partitioner all-gathers the whole frame batch (compiled for a
         described v5e:2x2, PR 29), where `[B/n, T]` flattens in place; so
-        a mesh swaps every field to `[B, T, ...]`: `batch_major`."""
-        return "time_major" if self.mesh is None else "batch_major"
+        a mesh swaps every field to `[B, T, ...]`: `batch_major`.
+        Then the dtype the learner's network gets the frames in: `uint8`
+        says they stay bytes until conv0 (`agents/common.prep_obs`)."""
+        layout = "time_major" if self.mesh is None else "batch_major"
+        _, obs = jax.eval_shape(
+            lambda k: self.env.reset(k, self.num_envs), jax.random.PRNGKey(0))
+        frames = jax.eval_shape(self.agent._prep_obs, obs).dtype
+        return f"{layout}, frames {frames}"
 
     def init(self, rng: jax.Array) -> AnakinState:
         # Three distinct streams: params init, env reset, and the ongoing

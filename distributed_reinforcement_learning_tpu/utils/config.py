@@ -110,6 +110,14 @@ def load_config(path: str | Path, section: str):
     d = data[section]
     algorithm = d.get("algorithm", section.split("_")[0])
     rt = _runtime_from_section(algorithm, d)
+    # Not a switch any more: integer frames always stay bytes until conv0
+    # (`agents/common.prep_obs`). Sections written before that carry the
+    # key; `true` is what the program does, `false` cannot be had.
+    if not d.get("fold_normalize", True):
+        raise ValueError(
+            f"section {section!r}: \"fold_normalize\": false is refused: the "
+            "agent-side /255 pass is gone (integer frames go to the model raw "
+            "and conv0's kernel carries the 1/255); drop the key")
 
     if algorithm == "impala":
         agent_cfg = ImpalaConfig(
@@ -125,7 +133,6 @@ def load_config(path: str | Path, section: str):
             start_learning_rate=d.get("start_learning_rate", 6e-4),
             end_learning_rate=d.get("end_learning_rate", 0.0),
             learning_frame=int(d.get("learning_frame", 1e9)),
-            fold_normalize=d.get("fold_normalize", False),
             torso=d.get("torso", "nature"),
             torso_width=d.get("torso_width", 1),
         )
@@ -139,7 +146,6 @@ def load_config(path: str | Path, section: str):
             start_learning_rate=d.get("start_learning_rate", 1e-4),
             end_learning_rate=d.get("end_learning_rate", 0.0),
             learning_frame=int(d.get("learning_frame", 1e9)),
-            fold_normalize=d.get("fold_normalize", False),
         )
     elif algorithm == "r2d2":
         agent_cfg = R2D2Config(
@@ -162,7 +168,6 @@ def load_config(path: str | Path, section: str):
             # counterpart.
             torso=d.get("torso", "mlp"),
             torso_width=d.get("torso_width", 1),
-            fold_normalize=d.get("fold_normalize", False),
             # The paper's Atari configuration (section `r2d2_atari`);
             # absent = the reference's 1-step targets and Dense(128) head.
             n_step=d.get("n_step", 1),

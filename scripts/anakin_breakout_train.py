@@ -138,7 +138,6 @@ def main() -> None:
         torso=args.torso,
         torso_width=args.torso_width,
         dtype=dtype,
-        fold_normalize=True,  # frames stay uint8 through the whole loop
     )
     agent = ImpalaAgent(cfg)
     anakin = AnakinImpala(agent, num_envs=args.num_envs, env=env_mod)

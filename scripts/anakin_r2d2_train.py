@@ -120,7 +120,6 @@ def main() -> None:
         priority_eta=None if args.priority_eta < 0 else args.priority_eta,
         gradient_clip_norm=args.adam_clip,
         torso="nature",
-        fold_normalize=True,  # frames stay uint8 through the whole loop
         dtype=dtype,
     )
     agent = R2D2Agent(cfg)

@@ -80,7 +80,7 @@ def main() -> None:
           f"{np.bincount(Y, minlength=4) / len(Y)}", file=sys.stderr)
 
     cfg = ImpalaConfig(obs_shape=bj.OBS_SHAPE, num_actions=4, trajectory=20,
-                       lstm_size=256, dtype=jnp.float32, fold_normalize=True)
+                       lstm_size=256, dtype=jnp.float32)
     agent = ImpalaAgent(cfg)
     params = agent.init_state(jax.random.PRNGKey(1)).params
     tx = optax.adam(args.lr)

@@ -87,8 +87,7 @@ class TestPixelSmoke:
         compiled update runs and stays finite."""
         from distributed_reinforcement_learning_tpu.envs import breakout_jax
 
-        cfg = ApexConfig(obs_shape=(84, 84, 4), num_actions=4,
-                         fold_normalize=True)
+        cfg = ApexConfig(obs_shape=(84, 84, 4), num_actions=4)
         an = AnakinApex(ApexAgent(cfg), num_envs=2, steps_per_collect=3,
                         capacity=12, batch_size=4, env=breakout_jax)
         st = an.init(jax.random.PRNGKey(0))

@@ -151,7 +151,7 @@ class TestPixelR2D2:
 
         cfg = R2D2Config(obs_shape=(84, 84, 4), num_actions=4, seq_len=4,
                          burn_in=2, lstm_size=16, torso="nature",
-                         fold_normalize=True, priority_eta=0.9)
+                         priority_eta=0.9)
         an = AnakinR2D2(R2D2Agent(cfg), num_envs=2, capacity=8,
                         batch_size=2, env=breakout_jax)
         st = an.init(jax.random.PRNGKey(0))

@@ -219,8 +219,7 @@ class TestAnakinBreakout:
     def cfg(self, **kw):
         base = dict(obs_shape=(84, 84, 4), num_actions=4, trajectory=5,
                     lstm_size=16, entropy_coef=0.01,
-                    start_learning_rate=1e-3, end_learning_rate=1e-3,
-                    fold_normalize=True)
+                    start_learning_rate=1e-3, end_learning_rate=1e-3)
         base.update(kw)
         return ImpalaConfig(**base)
 

@@ -2,7 +2,10 @@
 
 Both paths exist purely for TPU throughput; their contract is exact (up
 to float rounding) equivalence with the plain paths, checked here on CPU
-in fp32 with small image shapes.
+in fp32 with small image shapes. Integer frames stay bytes until conv0,
+whose kernel carries the /255 (no switch: the frames' dtype decides), so
+the plain path of the fold is the same agent fed the same frames
+pre-divided as float32.
 """
 
 import jax
@@ -48,6 +51,12 @@ def impala_image_batch(cfg, key, B=2):
     )
 
 
+def as_float_frames(batch):
+    """The batch with its uint8 frames divided by 255 as float32: what
+    the agent-side pass used to hand the model."""
+    return batch._replace(state=batch.state.astype(jnp.float32) / 255.0)
+
+
 class TestFoldNormalize:
     def test_nature_conv_input_scale_exact(self):
         """conv_{k/255}(x) == conv_k(x/255) on the same params."""
@@ -61,37 +70,54 @@ class TestFoldNormalize:
                                    rtol=2e-5, atol=2e-5)
 
     def test_impala_fold_normalize_same_params_and_loss(self):
-        plain = ImpalaAgent(small_impala_cfg())
-        folded = ImpalaAgent(small_impala_cfg(fold_normalize=True))
-        s0 = plain.init_state(jax.random.PRNGKey(1))
-        s1 = folded.init_state(jax.random.PRNGKey(1))
-        # identical param trees: the fold changes no parameter, only the call
+        """uint8 frames through the model == the same frames divided by
+        255 and passed as float32: same parameters, same loss."""
+        agent = ImpalaAgent(small_impala_cfg())
+        s0 = agent.init_state(jax.random.PRNGKey(1))
+        # the parameters do not depend on how the frames will arrive
+        u8 = agent.model.init(
+            jax.random.PRNGKey(1), jnp.zeros((1, *OBS), jnp.uint8),
+            jnp.zeros((1,), jnp.int32), *agent.initial_lstm_state(1))
         jax.tree.map(lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
-                     s0.params, s1.params)
-        batch = impala_image_batch(plain.cfg, jax.random.PRNGKey(2))
-        l0, _ = plain._loss(s0.params, batch)
-        l1, _ = folded._loss(s1.params, batch)
+                     s0.params, u8)
+        batch = impala_image_batch(agent.cfg, jax.random.PRNGKey(2))
+        l0, _ = agent._loss(s0.params, as_float_frames(batch))
+        l1, _ = agent._loss(s0.params, batch)
         np.testing.assert_allclose(float(l0), float(l1), rtol=1e-5)
 
     def test_impala_fold_normalize_act_parity(self):
-        plain = ImpalaAgent(small_impala_cfg())
-        folded = ImpalaAgent(small_impala_cfg(fold_normalize=True))
-        state = plain.init_state(jax.random.PRNGKey(1))
+        agent = ImpalaAgent(small_impala_cfg())
+        state = agent.init_state(jax.random.PRNGKey(1))
         obs = np.random.default_rng(1).integers(0, 256, (2, *OBS)).astype(np.uint8)
         pa = np.zeros(2, np.int32)
-        h, c = plain.initial_lstm_state(2)
+        h, c = agent.initial_lstm_state(2)
         rng = jax.random.PRNGKey(3)
-        a0 = plain.act(state.params, obs, pa, h, c, rng)
-        a1 = folded.act(state.params, obs, pa, h, c, rng)
+        a0 = agent.act(state.params, obs.astype(np.float32) / 255.0, pa, h, c, rng)
+        a1 = agent.act(state.params, obs, pa, h, c, rng)
         np.testing.assert_allclose(np.asarray(a0.policy), np.asarray(a1.policy),
                                    rtol=1e-5, atol=1e-6)
         np.testing.assert_array_equal(np.asarray(a0.action), np.asarray(a1.action))
 
+    def test_impala_learn_step_from_bytes_and_from_floats_same_params(self):
+        """One learn step from uint8 frames and one from the same frames
+        pre-divided as float32 end in the same parameters: the gradient
+        passes through the kernel's constant 1/255 (float32, CPU)."""
+        agent = ImpalaAgent(small_impala_cfg())
+        batch = impala_image_batch(agent.cfg, jax.random.PRNGKey(2))
+        init = agent.init_state(jax.random.PRNGKey(1))  # `_learn` donates nothing
+        s0, m0 = agent._learn(init, as_float_frames(batch))
+        s1, m1 = agent._learn(init, batch)
+        np.testing.assert_allclose(float(m0["grad_norm"]), float(m1["grad_norm"]),
+                                   rtol=1e-5)
+        moved = 0.0
+        for a, b, start in zip(*(jax.tree.leaves(s.params) for s in (s0, s1, init))):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0, atol=1e-5)
+            moved = max(moved, float(jnp.max(jnp.abs(a - start))))
+        assert moved > 1e-4  # the step is not a no-op that any path would match
+
     def test_apex_fold_normalize_td_parity(self):
-        cfg = dict(obs_shape=OBS, num_actions=4)
-        plain = ApexAgent(ApexConfig(**cfg))
-        folded = ApexAgent(ApexConfig(**cfg, fold_normalize=True))
-        state = plain.init_state(jax.random.PRNGKey(0))
+        agent = ApexAgent(ApexConfig(obs_shape=OBS, num_actions=4))
+        state = agent.init_state(jax.random.PRNGKey(0))
         rng = np.random.default_rng(2)
         B = 3
         batch = ApexBatch(
@@ -102,14 +128,19 @@ class TestFoldNormalize:
             reward=rng.random(B).astype(np.float32),
             done=rng.random(B) < 0.2,
         )
-        td0 = plain.td_error(state, batch)
-        td1 = folded.td_error(state, batch)
+        floats = batch._replace(
+            state=batch.state.astype(np.float32) / 255.0,
+            next_state=batch.next_state.astype(np.float32) / 255.0)
+        td0 = agent.td_error(state, floats)
+        td1 = agent.td_error(state, batch)
         np.testing.assert_allclose(np.asarray(td0), np.asarray(td1), rtol=1e-4, atol=1e-5)
 
     def test_fold_normalize_ignores_vector_obs(self):
-        """Vector observations keep the normalize/cast path untouched."""
+        """Vector observations keep the normalize/cast path untouched:
+        float ones are cast, integer ones (the reference's x255
+        quantization) are still divided by the agent."""
         cfg = ImpalaConfig(obs_shape=(4,), num_actions=2, trajectory=4,
-                           lstm_size=8, fold_normalize=True)
+                           lstm_size=8)
         agent = ImpalaAgent(cfg)
         state = agent.init_state(jax.random.PRNGKey(0))
         obs = np.random.default_rng(0).random((2, 4)).astype(np.float32)
@@ -117,6 +148,9 @@ class TestFoldNormalize:
         out = agent.act(state.params, obs, np.zeros(2, np.int32), h, c,
                         jax.random.PRNGKey(1))
         assert out.policy.shape == (2, 2)
+        q = np.round(obs * 255).astype(np.int32)
+        np.testing.assert_allclose(np.asarray(agent._prep_obs(jnp.asarray(q))),
+                                   q / 255.0, rtol=1e-6)
 
 
 def test_upgrade_nature_conv_params_maps_old_layout():

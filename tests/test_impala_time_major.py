@@ -167,7 +167,7 @@ def test_anakin_update_matches_collect_swap_learn():
                        lstm_size=32, learning_frame=10 ** 9)
     agent = ImpalaAgent(cfg)
     anakin = AnakinImpala(agent, num_envs=8)
-    assert anakin.handoff == "time_major"
+    assert anakin.handoff == "time_major, frames float32"  # CartPole: floats
     state = anakin.init(jax.random.PRNGKey(3))
 
     def parent_update(state):
@@ -206,4 +206,4 @@ def test_mesh_keeps_the_batch_major_handoff():
     cfg = ImpalaConfig(obs_shape=(4,), num_actions=2, trajectory=4,
                        lstm_size=16)
     assert AnakinImpala(ImpalaAgent(cfg), 8, mesh=make_mesh(8)).handoff \
-        == "batch_major"
+        == "batch_major, frames float32"

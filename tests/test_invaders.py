@@ -210,7 +210,7 @@ class TestAnakinInvaders:
         from distributed_reinforcement_learning_tpu.runtime.anakin import AnakinImpala
 
         cfg = ImpalaConfig(obs_shape=(84, 84, 4), num_actions=6,
-                           trajectory=4, lstm_size=16, fold_normalize=True)
+                           trajectory=4, lstm_size=16)
         an = AnakinImpala(ImpalaAgent(cfg), num_envs=2, env=invaders_jax)
         state = an.init(jax.random.PRNGKey(0))
         state, m = an.train_chunk(state, 1)

@@ -176,13 +176,12 @@ class TestResNetTorso:
         from distributed_reinforcement_learning_tpu.utils.synthetic import (
             synthetic_impala_batch)
 
-        plain = self._agent(fold_normalize=False)
-        folded = self._agent(fold_normalize=True)
-        state = plain.init_state(jax.random.PRNGKey(0))
+        agent = self._agent()
         batch = jax.tree.map(jnp.asarray, synthetic_impala_batch(2, 4, (16, 16, 4), 4, 32))
-        _, m_plain = plain.learn(state, batch)
-        state_f = folded.init_state(jax.random.PRNGKey(0))
-        _, m_fold = folded.learn(state_f, batch)
+        assert batch.state.dtype == jnp.uint8
+        floats = batch._replace(state=batch.state.astype(jnp.float32) / 255.0)
+        _, m_plain = agent.learn(agent.init_state(jax.random.PRNGKey(0)), floats)
+        _, m_fold = agent.learn(agent.init_state(jax.random.PRNGKey(0)), batch)
         np.testing.assert_allclose(float(m_plain["total_loss"]),
                                    float(m_fold["total_loss"]), rtol=2e-4)
 
@@ -205,7 +204,29 @@ class TestResNetTorso:
 
         cfg, rt = load_config("config.json", "impala_resnet")
         assert cfg.torso == "resnet" and cfg.torso_width == 4
-        assert cfg.fold_normalize is True
+        # the section still says `"fold_normalize": true`: accepted, and
+        # no field is left for it to set
+        assert not hasattr(cfg, "fold_normalize")
+
+    @pytest.mark.parametrize("algorithm", ["impala", "apex", "r2d2"])
+    def test_fold_normalize_key_true_accepted_false_refused(self, tmp_path, algorithm):
+        """`true` is what the program always does; `false` asks for the
+        agent-side /255 pass, which is gone."""
+        import json as _json
+
+        from distributed_reinforcement_learning_tpu.utils.config import load_config
+
+        section = {"model_input": [84, 84, 4], "model_output": 4,
+                   "env": ["BreakoutDeterministic-v4"], "available_action": [4],
+                   "num_actors": 1, "torso": "nature"}
+        p = tmp_path / "c.json"
+        p.write_text(_json.dumps({
+            algorithm: section,
+            f"{algorithm}_on": dict(section, fold_normalize=True),
+            f"{algorithm}_off": dict(section, fold_normalize=False)}))
+        assert load_config(str(p), f"{algorithm}_on")[0] == load_config(str(p), algorithm)[0]
+        with pytest.raises(ValueError, match="fold_normalize.*agent-side /255 pass is gone"):
+            load_config(str(p), f"{algorithm}_off")
 
 
 def test_r2d2_conv_torso_step_and_unroll_consistency(rng):
@@ -214,8 +235,7 @@ def test_r2d2_conv_torso_step_and_unroll_consistency(rng):
     + fused LSTM unroll matches a per-step Python loop with done-masked
     resets, on raw uint8 frames."""
     B, T, A, H = 2, 4, 4, 8
-    model = R2D2Net(num_actions=A, lstm_size=H, torso="nature",
-                    fold_normalize=True)
+    model = R2D2Net(num_actions=A, lstm_size=H, torso="nature")
     key = jax.random.PRNGKey(5)
     obs = jax.random.randint(key, (B, T, 84, 84, 4), 0, 256, dtype=jnp.uint8)
     pa = jax.random.randint(key, (B, T), 0, A)

@@ -156,8 +156,7 @@ class TestAnakinPong:
     def test_train_chunk_runs_and_is_finite(self):
         cfg = ImpalaConfig(obs_shape=(84, 84, 4), num_actions=6, trajectory=5,
                            lstm_size=16, entropy_coef=0.01,
-                           start_learning_rate=1e-3, end_learning_rate=1e-3,
-                           fold_normalize=True)
+                           start_learning_rate=1e-3, end_learning_rate=1e-3)
         anakin = AnakinImpala(ImpalaAgent(cfg), num_envs=2, env=pong_jax)
         st = anakin.init(jax.random.PRNGKey(0))
         st, m = anakin.train_chunk(st, 2)

@@ -29,7 +29,7 @@ B, T, A, BURN = 3, 12, 6, 4
 # paths (uint8 frames, folded 1/255, conv torso) the published ones.
 SMALL = dict(obs_shape=(44, 44, 4), num_actions=A, seq_len=T, burn_in=BURN,
              lstm_size=32, dueling_hidden=16, torso="nature",
-             fold_normalize=True, priority_eta=0.9)
+             priority_eta=0.9)
 
 
 def _batch(seed: int = 0) -> dict:
@@ -404,7 +404,7 @@ def test_section_r2d2_atari_is_the_published_configuration():
         lstm_size=512, discount_factor=0.997, learning_rate=1e-4,
         rescale_eps=1e-3, dtype=None, priority_eta=0.9,
         gradient_clip_norm=None, torso="nature", torso_width=1,
-        fold_normalize=True, n_step=5, dueling_hidden=512)
+        n_step=5, dueling_hidden=512)
     assert (rt.batch_size, rt.target_sync_interval, rt.updates_per_call,
             rt.train_start_factor, rt.replay_capacity) == (64, 2500, 4, 32, 2048)
     assert rt.num_actors * rt.envs_per_actor == 256

@@ -254,7 +254,8 @@ def test_train_anakin_profile_holds_one_span_per_chunk(tmp_path, monkeypatch,
     monkeypatch.setenv("DRL_PROFILE_STEPS", "1000")
     train_anakin("config.json", "impala_cartpole", num_updates=4, chunk=2)
     # the static fact of the compiled chunk, once at start-up (ISSUE 29)
-    assert capsys.readouterr().out.count("[anakin] learn handoff: time_major") == 1
+    assert capsys.readouterr().out.count(
+        "[anakin] learn handoff: time_major, frames float32\n") == 1
     assert calls == {"start": [0], "stop": 1}
     names = _host_events(str(tmp_path))
     for span in (scopes.DISPATCH, scopes.WAIT, scopes.REPORT):

@@ -66,8 +66,7 @@ def chip(four_chips):
     return SingleDeviceSharding(four_chips[0])
 
 
-@pytest.fixture
-def kernels_as_on_chip(monkeypatch):
+def _kernels_as_on_chip(monkeypatch):
     """`auto` kernel selection answers as on the chip: the real
     resolver (env gates included) with `jax.default_backend()` reading
     "tpu" for the duration of that one call."""
@@ -80,6 +79,11 @@ def kernels_as_on_chip(monkeypatch):
 
     monkeypatch.setattr(pallas_pkg, "resolve_backend", resolve)
     monkeypatch.setattr(lstm_ops, "resolve_backend", resolve)
+
+
+@pytest.fixture
+def kernels_as_on_chip(monkeypatch):
+    _kernels_as_on_chip(monkeypatch)
 
 
 def _on(chip, tree):
@@ -465,8 +469,28 @@ def _frame_batch_ops(text: str, frames: int, ops: str) -> list[str]:
     return hits
 
 
+@pytest.fixture(scope="module")
+def breakout_chunk(chip):
+    """The fused IMPALA chunk at 256 Breakout envs x T=20 from the
+    UNCHANGED `impala` section, compiled once for the tests below:
+    (compiled, its text, frames an update)."""
+    from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent
+    from distributed_reinforcement_learning_tpu.envs import breakout_jax
+    from distributed_reinforcement_learning_tpu.runtime.anakin import AnakinImpala
+
+    cfg, _ = load_config(CONFIG, "impala")
+    n = 256
+    with pytest.MonkeyPatch.context() as m:
+        _kernels_as_on_chip(m)
+        anakin = AnakinImpala(ImpalaAgent(cfg), n, env=breakout_jax)
+        assert anakin.handoff == "time_major, frames uint8"
+        compiled = _breakout_chunk_compiled(
+            anakin, lambda state: jax.tree.map(lambda _: chip, state))
+    return compiled, compiled.as_text(), cfg.trajectory * n
+
+
 def test_breakout_chunk_learns_from_the_rollout_where_the_scan_wrote_it(
-        chip, kernels_as_on_chip):
+        breakout_chunk):
     """The fused IMPALA chunk at 256 Breakout envs x T=20: the learner
     takes the rollout time-major (`AnakinImpala._update`), so nothing is
     named `to_batch_major`, and between the scan's `[20,256,84,84,4]`
@@ -474,22 +498,39 @@ def test_breakout_chunk_learns_from_the_rollout_where_the_scan_wrote_it(
     inside H and W in whole tiles, under `learn`). The batch-major handoff
     (before PR 29) shows two: a true transposition through a W-minor
     intermediate. Both V-trace passes stay Mosaic kernels."""
-    from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent
-    from distributed_reinforcement_learning_tpu.envs import breakout_jax
     from distributed_reinforcement_learning_tpu.observability import scopes
-    from distributed_reinforcement_learning_tpu.runtime.anakin import AnakinImpala
 
-    cfg, _ = load_config(CONFIG, "impala")
-    n = 256
-    anakin = AnakinImpala(ImpalaAgent(cfg), n, env=breakout_jax)
-    compiled = _breakout_chunk_compiled(
-        anakin, lambda state: jax.tree.map(lambda _: chip, state))
-    text = compiled.as_text()
+    compiled, text, frames = breakout_chunk
     assert scopes.TO_BATCH_MAJOR not in text
-    copies = _frame_batch_ops(text, cfg.trajectory * n, "copy|transpose")
+    copies = _frame_batch_ops(text, frames, "copy|transpose")
     assert len(copies) <= 1, copies
     assert all(f"/{scopes.LEARN}/" in c for c in copies), copies
     assert _kernel_calls(compiled, "vtrace_pallas") == 2
+
+
+def test_breakout_chunk_frames_stay_bytes_until_conv0(breakout_chunk):
+    """The same chunk: the frames are uint8 from the scan's output to
+    conv0. No op between them has a float result of T x N x 84 x 84 x 4
+    elements (the agent-side /255 pass, `multiply_bitcast_fusion` before
+    PR 33, wrote the batch as bfloat16: twice the bytes, a pass of its
+    own), the one frame-batch copy moves bytes, and conv0's forward and
+    weight-gradient fusions read the `u8[T*N,84,84,4]` batch themselves:
+    the convert happens inside the convolution, whose kernel carries the
+    1/255 (`models/torso.NatureConv`)."""
+    _, text, frames = breakout_chunk
+    dtype = lambda line: re.search(r"= (\w+)\[", line)[1]
+    ops = _frame_batch_ops(text, frames, "copy|transpose|convert|multiply|fusion")
+    assert ops and {dtype(o) for o in ops} == {"u8"}, ops
+    copies = _frame_batch_ops(text, frames, "copy|transpose")
+    assert [dtype(c) for c in copies] == ["u8"], copies
+    batch = re.findall(rf"^\s*%(\S+) = u8\[{frames},84,84,4\]\S* bitcast\(%copy",
+                       text, re.M)
+    assert len(batch) == 1, batch
+    readers = [line for line in text.splitlines()
+               if re.search(rf" fusion\([^)]*%{re.escape(batch[0])}[,)]", line)
+               and "torso/conv_general_dilated" in line]
+    assert any("/jvp(learn/loss)/" in r for r in readers), readers  # conv0 forward
+    assert any("/transpose(jvp(learn/loss))/" in r for r in readers), readers  # dL/dk
 
 
 def test_mesh_breakout_chunk_moves_no_frames_between_chips(
@@ -510,7 +551,7 @@ def test_mesh_breakout_chunk_moves_no_frames_between_chips(
     n = 256
     anakin = AnakinImpala(ImpalaAgent(cfg), n, mesh=make_mesh(devices=four_chips),
                           env=breakout_jax)
-    assert anakin.handoff == "batch_major"
+    assert anakin.handoff == "batch_major, frames uint8"
     compiled = _breakout_chunk_compiled(anakin, lambda _: anakin._state_sharding)
     text = compiled.as_text()
     collectives = "all-gather|all-to-all|collective-permute|all-reduce|reduce-scatter"
