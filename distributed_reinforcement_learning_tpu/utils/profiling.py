@@ -21,6 +21,9 @@ profiling is first-class:
   host-loop learners and of the fused loops (one `on_step` per chunk).
   Enabled via env vars so any launcher/run picks it up:
       DRL_PROFILE_DIR=/tmp/trace DRL_PROFILE_START=50 DRL_PROFILE_STEPS=5
+  `close()` then writes `scope_ledger.json` beside the profile and prints
+  it: the device's self time per scope, the ops the compiler made
+  resolved to the scope they serve (observability/attribution.py).
 """
 
 from __future__ import annotations
@@ -117,6 +120,7 @@ class ProfilerSession:
         self.num_steps = num_steps
         self._active = False
         self._done = out_dir is None
+        self._traced = False
 
     @classmethod
     def from_env(cls) -> "ProfilerSession":
@@ -151,8 +155,32 @@ class ProfilerSession:
         jax.profiler.stop_trace()
         self._active = False
         self._done = True
+        self._traced = True
         print(f"[profiler] device trace written to {self.out_dir}", flush=True)
 
     def close(self) -> None:
+        """Stop a trace still running, then (once, after the loop: it
+        takes seconds) write the profile's scope ledger beside it."""
         if self._active:
             self._stop()
+        if self._traced:
+            self._traced = False
+            self._write_ledger()
+
+    def _write_ledger(self) -> None:
+        # imported here: a run without DRL_PROFILE_DIR loads neither the
+        # resolver nor xprof
+        from distributed_reinforcement_learning_tpu.observability import attribution
+
+        try:
+            led = attribution.ledger(self.out_dir, attribution.program_vocabulary())
+        except Exception as e:  # noqa: BLE001 - a report must not end a run
+            print(f"[profiler] no scope ledger: {type(e).__name__}: {e}", flush=True)
+            return
+        if led is None:  # no device op line (a CPU run)
+            print("[profiler] no scope ledger: the profile holds no device op",
+                  flush=True)
+            return
+        path = attribution.write(led, self.out_dir)
+        print(f"[profiler] scope ledger written to {path}\n"
+              f"{attribution.table(led)}", flush=True)

@@ -27,6 +27,15 @@ grow with flush count, not record count.
 
     python scripts/obs_report.py /tmp/run
     python scripts/obs_report.py /tmp/run --no-merge
+
+`--profile <dir>` reads a `jax.profiler` trace instead (what
+`DRL_PROFILE_DIR` wrote, or any directory above an `.xplane.pb`): the
+device's self time per scope of `observability/scopes.py`, the ops the
+compiler made resolved to the scope they serve
+(`observability/attribution.py`), and the fused loop's `anakin/*` host
+spans on the same clock.
+
+    python scripts/obs_report.py --profile /tmp/trace
 """
 
 from __future__ import annotations
@@ -1079,13 +1088,38 @@ def sanitizer_section(tdir: str, top: int = 5) -> list[str]:
     return lines
 
 
+def profile_report(profile_dir: str) -> str:
+    """The scope ledger of the newest profile under `profile_dir` and the
+    host spans of the same file."""
+    from distributed_reinforcement_learning_tpu.observability import attribution
+
+    led = attribution.ledger(profile_dir, attribution.program_vocabulary())
+    if led is None:
+        return (f"no device op in a profile under {profile_dir} "
+                f"(none written, or a CPU run)")
+    lines = [f"== Scope ledger: {attribution.xplane_path(profile_dir)} ==",
+             attribution.table(led), "", "-- Host spans (same clock) --"]
+    for name, (count, seconds) in sorted(
+            attribution.host_spans(profile_dir).items()):
+        lines.append(f"  {name:24s} {count:5d} x {1e3 * seconds / count:10.3f} ms")
+    return "\n".join(lines)
+
+
 def main(argv: list[str] | None = None) -> int:
     p = argparse.ArgumentParser(description=__doc__,
                                 formatter_class=argparse.RawDescriptionHelpFormatter)
-    p.add_argument("run_dir", help="run directory (or the telemetry dir itself)")
+    p.add_argument("run_dir", nargs="?",
+                   help="run directory (or the telemetry dir itself)")
     p.add_argument("--no-merge", action="store_true",
                    help="skip writing trace-merged.json")
+    p.add_argument("--profile", metavar="DIR",
+                   help="print the scope ledger of a jax.profiler trace instead")
     args = p.parse_args(argv)
+    if args.profile:
+        print(profile_report(args.profile))
+        return 0
+    if not args.run_dir:
+        p.error("a run directory, or --profile DIR")
     print(build_report(find_telemetry_dir(args.run_dir), merge=not args.no_merge))
     return 0
 
