@@ -37,11 +37,19 @@ an 84x210 matrix by pre-cropping the row weights) -> [84, 84] uint8 ->
 4-frame newest-last stack. The resize is two small matmuls per frame —
 MXU work, which is the point of doing it on-device.
 
-No picture is state. The 2-frame max needs the previous raw frame, and
-that frame is `_render` of five small fields (`_DRAWN`: the 6x18 board,
-paddle x, ball dead / x / y; 121 bytes an env), so `step` renders it again
-from the fields it was handed instead of carrying 100,800 bytes of RGB
-per env from step to step.
+No picture is state, and `step` makes no RGB picture at all. A pixel
+shows one of three things (wall or background, a brick of its row's
+colour, the sprite colour), told by two masks per frame (`_classes`). The
+2-frame max of a pixel is then one of 3 x 3 colour pairs, whose luma is a
+constant of its position: `_luma_tables` makes those nine planes from the
+colours `_render` draws, by `pixel_jax.luma`, and `step` selects among
+them with the masks of the new frame and of the frame the last step drew.
+That frame's masks come from five small fields (`_DRAWN`: the 6x18 board,
+paddle x, ball dead / x / y; 121 bytes an env) of the state `step` was
+handed, so nothing carries a frame from step to step. Only the scanlines
+the crop reads (`pixel_jax.CROP_ROWS`) are made; the rest go to the resize
+as the zeros its weights would have made of them. `_render` gives the RGB
+frame of the same masks, for `reset` and for whoever wants a picture.
 """
 
 from __future__ import annotations
@@ -55,7 +63,6 @@ import numpy as np
 
 from distributed_reinforcement_learning_tpu.envs import breakout_sim as sim
 from distributed_reinforcement_learning_tpu.envs import pixel_jax
-from distributed_reinforcement_learning_tpu.envs.pixel_jax import preprocess as _preprocess
 from distributed_reinforcement_learning_tpu.observability import scopes
 
 NUM_ACTIONS = sim.BreakoutCore.num_actions  # NOOP / FIRE / RIGHT / LEFT
@@ -91,7 +98,7 @@ class BreakoutState(NamedTuple):
     """Batched game + observation-pipeline state (`[N, ...]` leaves).
 
     The observation stack is the only picture here. The last raw frame
-    is not: it always equals `_render` of this state's `_DRAWN` fields
+    is not: it always shows `_classes` of this state's `_DRAWN` fields
     (true after `reset`, kept by every `step`), so nothing has to store it.
     """
 
@@ -108,19 +115,19 @@ class BreakoutState(NamedTuple):
     returns: jax.Array     # [N] f32 raw (unclipped) episode return
 
 
-# -- rendering + preprocessing (single env; vmapped) ------------------------
+# -- rendering (single env; vmapped) ----------------------------------------
 
 
-def _render(bricks, paddle_x, ball_dead, ball_x, ball_y) -> jax.Array:
-    """`[210, 160, 3]` uint8 frame, `breakout_sim.render` draw order.
+def _classes(bricks, paddle_x, ball_dead, ball_x, ball_y, rows=(0, H)):
+    """What each pixel of scanlines `rows` shows, `breakout_sim.render`
+    draw order: -> (`sprite`, `brick`) bool `[rows, 160]`; `brick` is a
+    live brick no sprite covers, a pixel in neither shows `_BASE`.
 
-    Written for the TPU compiler, which keeps whatever feeds a broadcast
-    over the colour channels out of the luma reduction that consumes the
-    frame: two masks (live brick, sprite) are all that reaches HBM per
-    frame, made in one pass from the 6x18 board by `repeat` + `pad` (no
-    per-pixel gather), and the selects below fuse into the reduction.
+    The one definition of what is drawn where. Made from the 6x18 board
+    by `repeat` + `pad` (no per-pixel gather) and from row and column
+    comparisons, so it fuses into whatever selects by it.
     """
-    ys, xs = jnp.asarray(_YS), jnp.asarray(_XS)
+    ys, xs = jnp.asarray(_YS[slice(*rows)]), jnp.asarray(_XS)
     px = paddle_x.astype(jnp.int32)
     paddle = (
         (ys >= sim.PADDLE_Y) & (ys < sim.PADDLE_Y + sim.PADDLE_H)
@@ -135,20 +142,55 @@ def _render(bricks, paddle_x, ball_dead, ball_x, ball_y) -> jax.Array:
     )
     sprite = paddle | ball
     field = jnp.repeat(jnp.repeat(bricks, sim.BRICK_H, axis=0), sim.BRICK_W, axis=1)
-    brick = jnp.pad(field, _FIELD_PAD) & ~sprite  # sprites are drawn over bricks
+    brick = jnp.pad(field, _FIELD_PAD)[slice(*rows)] & ~sprite  # sprites are drawn over bricks
+    return sprite, brick
 
+
+def _render(bricks, paddle_x, ball_dead, ball_x, ball_y) -> jax.Array:
+    """`[210, 160, 3]` uint8 frame of `_classes`: `reset`'s picture. `step`
+    selects lumas by the same masks instead (`_luma_batch`)."""
+    sprite, brick = _classes(bricks, paddle_x, ball_dead, ball_x, ball_y)
     f = jnp.where(brick[:, :, None], jnp.asarray(_ROW_RGB_Y)[:, None, :],
                   jnp.asarray(_BASE))
     return jnp.where(sprite[:, :, None], jnp.asarray(_SPRITE), f)
 
 
-# The state fields `_render` draws: 108 + 1 booleans and three float32.
+# The state fields `_classes` reads: 108 + 1 booleans and three float32.
 _DRAWN = ("bricks", "paddle_x", "ball_dead", "ball_x", "ball_y")
 
 
 def _render_batch(fields) -> jax.Array:
     """`[N, 210, 160, 3]` uint8 frames of a mapping of `[N, ...]` fields."""
     return jax.vmap(_render)(*(fields[k] for k in _DRAWN))
+
+
+def _luma_tables(rows) -> jax.Array:
+    """`[3, 3, rows, 160]` f32: the luma of `maximum(colour a, colour b)`
+    at every position, for a and b in (`_BASE`, the row's brick colour,
+    `_SPRITE`), `_render`'s three colours.
+
+    Through `pixel_jax.luma`, inside the program: a table holds what
+    `preprocess` makes of that colour on the device it runs on.
+    """
+    shown = jnp.stack([jnp.broadcast_to(jnp.asarray(c), _BASE.shape) for c in
+                       (_BASE, _ROW_RGB_Y[:, None, :], _SPRITE)])[:, slice(*rows)]
+    return pixel_jax.luma(jnp.maximum(shown[:, None], shown[None, :]))
+
+
+def _luma_batch(fields, entered) -> jax.Array:
+    """`[N, 210, 160]` f32 luma of the 2-frame max of the frames of
+    `fields` and `entered` (mappings of `[N, ...]` fields), without the
+    frames: a select among `_luma_tables` by both frames' `_classes`,
+    made for `pixel_jax.CROP_ROWS` and zero elsewhere."""
+    rows = pixel_jax.CROP_ROWS
+    classes = jax.vmap(functools.partial(_classes, rows=rows))
+    sprite, brick = classes(*(fields[k] for k in _DRAWN))
+    sprite0, brick0 = classes(*(entered[k] for k in _DRAWN))
+    base, bricked, sprited = (
+        jnp.where(sprite0, t[2], jnp.where(brick0, t[1], t[0]))
+        for t in _luma_tables(rows))
+    plane = jnp.where(sprite, sprited, jnp.where(brick, bricked, base))
+    return jnp.pad(plane, ((0, 0), (rows[0], H - rows[1]), (0, 0)))
 
 
 # -- physics (single env; vmapped) ------------------------------------------
@@ -294,12 +336,12 @@ def step(
     reference's shaping (`train_impala.py:149-154`).
 
     Order: emulate, auto-reset the game FIELDS of game-over slots, then
-    one render -> 2-frame max -> luma -> resize pass for every slot. The
-    max's previous frame is `_render` of the `_DRAWN` fields `state` came
-    in with, because that is what the last step drew (the invariant on
-    `BreakoutState`); a game-over slot takes its fresh fields for both
-    frames and zeros for the older stack slots, which is the reset
-    observation. No frame outlives the pass.
+    one pass for every slot: the luma of the 2-frame max (`_luma_batch`;
+    no RGB frame is made) -> resize. The max's previous frame shows the
+    `_DRAWN` fields `state` came in with, because that is what the last
+    step drew (the invariant on `BreakoutState`); a game-over slot takes
+    its fresh fields for both frames and zeros for the older stack slots,
+    which is the reset observation.
     """
     n = state.lives.shape[0]
     lives_before = state.lives
@@ -347,8 +389,7 @@ def step(
     entered = {k: pick(fresh[k], getattr(state, k)) for k in _DRAWN}
 
     with jax.named_scope(scopes.RENDER):
-        maxed = jnp.maximum(_render_batch(fields), _render_batch(entered))
-        frame = jax.vmap(_preprocess)(maxed)
+        frame = jax.vmap(pixel_jax.resize)(_luma_batch(fields, entered))
         older = jnp.where(game_over[:, None, None, None], jnp.uint8(0),
                           state.stack[..., 1:])
         stack = jnp.concatenate([older, frame[..., None]], axis=-1)

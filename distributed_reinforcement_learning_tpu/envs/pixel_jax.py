@@ -7,6 +7,12 @@ resize as two matmuls (the separable overlap weights of
 newest-last stacking — used by both `breakout_jax` and `pong_jax` so the
 subtle parts (crop window, stack shift, reset-stack semantics,
 auto-reset merge) cannot diverge between games.
+
+`preprocess` is `resize(luma(rgb))`. Pong and Invaders hand it their RGB
+frames. Breakout's `step` makes no RGB frame: it selects the luma plane
+from tables that `luma` made of its constant colours, over `CROP_ROWS`
+(the scanlines `_WH_CROP` gives a weight), and hands `resize` that plane
+with zeros in the rows the crop never reads.
 """
 
 from __future__ import annotations
@@ -25,12 +31,26 @@ _WH_CROP = np.asarray(_area_weights(H, 110))[18:102, :]  # [84, 210]
 _WW_T = np.asarray(_area_weights(W, 84)).T  # [160, 84]
 _LUMA = np.array([0.299, 0.587, 0.114], np.float32)
 
+# The scanlines the crop reads, [first, last + 1): every other column of
+# `_WH_CROP` is zero, so what a frame shows there reaches no observation.
+_READ = np.flatnonzero(_WH_CROP.any(axis=0))
+CROP_ROWS = (int(_READ[0]), int(_READ[-1]) + 1)  # (34, 195)
+
+
+def luma(rgb: jax.Array) -> jax.Array:
+    """`[..., 3]` u8 -> `[...]` f32 luma."""
+    return rgb.astype(jnp.float32) @ jnp.asarray(_LUMA)
+
+
+def resize(plane: jax.Array) -> jax.Array:
+    """`[210, 160]` f32 luma -> `[84, 84]` u8 (area-resize, crop)."""
+    resized = jnp.asarray(_WH_CROP) @ plane @ jnp.asarray(_WW_T)  # [84, 84]
+    return resized.astype(jnp.uint8)
+
 
 def preprocess(rgb: jax.Array) -> jax.Array:
     """`[210, 160, 3]` u8 -> `[84, 84]` u8 (luma, area-resize, crop)."""
-    luma = rgb.astype(jnp.float32) @ jnp.asarray(_LUMA)  # [210, 160]
-    resized = jnp.asarray(_WH_CROP) @ luma @ jnp.asarray(_WW_T)  # [84, 84]
-    return resized.astype(jnp.uint8)
+    return resize(luma(rgb))
 
 
 def observe(raw: jax.Array, prev_raw: jax.Array, stack: jax.Array) -> jax.Array:

@@ -101,7 +101,7 @@ class TestRenderParity:
         launched(core)
         frame = core.render()
         want = preprocess_frame(frame).astype(np.int32)
-        got = np.asarray(breakout_jax._preprocess(jnp.asarray(frame))).astype(np.int32)
+        got = np.asarray(pixel_jax.preprocess(jnp.asarray(frame))).astype(np.int32)
         assert np.abs(got - want).max() <= 1
 
 
@@ -327,6 +327,20 @@ def _raster_step(rs, actions, rng, max_frames):
             done, episode_return)
 
 
+def _assert_same_step(out, out_r, t):
+    """`breakout_jax.step`'s results against `_raster_step`'s, bit for bit:
+    obs, reward, done, episode return and every leaf of the state."""
+    (state, *rest), (rs, *rest_r) = out, out_r
+    for name, got, want in zip(("obs", "reward", "done", "episode_return"),
+                               rest, rest_r):
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(want), err_msg=f"{name}, step {t}")
+    for name in state._fields:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(state, name)), np.asarray(getattr(rs.game, name)),
+            err_msg=f"state.{name}, step {t}")
+
+
 class TestNoRasterInState:
     """`step` selects the game state on auto-reset and renders once; the
     parent selected the pictures and carried the last RGB frame."""
@@ -372,16 +386,8 @@ class TestNoRasterInState:
                     after_reset=0)
         was_over = np.zeros(self.N, bool)
         for t, out, out_r in self._rollout():
-            (state, obs, reward, done, ep), (rs, obs_r, *rest_r) = out, out_r
-            for name, got, want in zip(("obs", "reward", "done", "episode_return"),
-                                       (obs, reward, done, ep), (obs_r, *rest_r)):
-                np.testing.assert_array_equal(
-                    np.asarray(got), np.asarray(want), err_msg=f"{name}, step {t}")
-            for name in state._fields:
-                np.testing.assert_array_equal(
-                    np.asarray(getattr(state, name)),
-                    np.asarray(getattr(rs.game, name)),
-                    err_msg=f"state.{name}, step {t}")
+            _assert_same_step(out, out_r, t)
+            state, _, _, done, _ = out
             over = np.asarray(breakout_jax.completed_episode_mask(done, state))
             seen["life_loss"] += int((np.asarray(done) & ~over).sum())
             seen["game_over"] += int(over[4:6].sum())
@@ -397,3 +403,173 @@ class TestNoRasterInState:
         assert "prev_raw" not in state._fields
         for name, leaf in state._asdict().items():
             assert leaf.size * leaf.dtype.itemsize <= obs.size * obs.dtype.itemsize, name
+
+
+# -- the luma plane `step` selects from tables (PR 35) ------------------------
+
+
+def _class_colours():
+    """`[3, 210, 160, 3]` u8: what a pixel of each class shows, from the
+    module's constants by plain numpy broadcasting."""
+    shape = (breakout_sim.H, breakout_sim.W, 3)
+    return np.stack([
+        breakout_jax._BASE,
+        np.broadcast_to(breakout_jax._ROW_RGB_Y[:, None, :], shape),
+        np.broadcast_to(breakout_jax._SPRITE, shape)])
+
+
+def _parent_preprocess(rgb):
+    """`pixel_jax.preprocess` as it was written before `luma` and `resize`
+    were factored out of it."""
+    luma = rgb.astype(jnp.float32) @ jnp.asarray(pixel_jax._LUMA)
+    resized = jnp.asarray(pixel_jax._WH_CROP) @ luma @ jnp.asarray(pixel_jax._WW_T)
+    return resized.astype(jnp.uint8)
+
+
+class TestLumaPlane:
+    @pytest.mark.parametrize("a", range(3))
+    @pytest.mark.parametrize("b", range(3))
+    def test_table_is_the_luma_preprocess_makes_of_that_colour_pair(self, a, b):
+        colours = _class_colours()
+        want = pixel_jax.luma(jnp.maximum(jnp.asarray(colours[a]),
+                                          jnp.asarray(colours[b])))
+        full = breakout_jax._luma_tables((0, breakout_sim.H))
+        np.testing.assert_array_equal(np.asarray(full[a, b]), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(full[b, a]), np.asarray(want))
+        lo, hi = pixel_jax.CROP_ROWS
+        np.testing.assert_array_equal(
+            np.asarray(breakout_jax._luma_tables((lo, hi))[a, b]),
+            np.asarray(want[lo:hi]))
+
+    def test_scanline_window_is_the_nonzero_columns_of_the_crop_weights(self):
+        lo, hi = pixel_jax.CROP_ROWS
+        read = pixel_jax._WH_CROP.any(axis=0)
+        assert read[lo:hi].all() and not read[:lo].any() and not read[hi:].any()
+        assert (lo, hi) == (34, 195)  # today's weights: 161 of 210 scanlines
+        # Nothing the window leaves out is drawn differently from frame to
+        # frame but the ball on its way out, which the crop never showed.
+        assert lo < breakout_sim.BRICK_TOP and hi > breakout_sim.PADDLE_Y + breakout_sim.PADDLE_H
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_plane_is_the_luma_of_the_maxed_rasters_inside_the_window(self, seed):
+        rng = np.random.default_rng(seed)
+        n = 16
+
+        def fields():
+            dead = rng.random(n) < 0.25
+            ball_y = rng.uniform(breakout_sim.WALL_TOP, breakout_sim.H - 2, n)
+            ball_y[:6] = rng.uniform(breakout_sim.BRICK_TOP - 2,
+                                     breakout_sim.BRICK_TOP + 36, 6)
+            return dict(
+                bricks=jnp.asarray(rng.random((n, 6, 18)) < 0.5),
+                paddle_x=jnp.asarray(rng.integers(8, 137, n), jnp.float32),
+                ball_dead=jnp.asarray(dead),
+                ball_x=jnp.asarray(rng.uniform(0, breakout_sim.W - 2, n), jnp.float32),
+                ball_y=jnp.asarray(ball_y, jnp.float32))
+
+        new, old = fields(), fields()
+        got = np.asarray(breakout_jax._luma_batch(new, old))
+        want = np.asarray(pixel_jax.luma(jnp.maximum(
+            breakout_jax._render_batch(new), breakout_jax._render_batch(old))))
+        lo, hi = pixel_jax.CROP_ROWS
+        np.testing.assert_array_equal(got[:, lo:hi], want[:, lo:hi])
+        assert not got[:, :lo].any() and not got[:, hi:].any()
+
+    @pytest.mark.parametrize("game", ["pong", "invaders"])
+    def test_other_games_preprocess_is_unchanged(self, game):
+        from distributed_reinforcement_learning_tpu.envs import invaders_jax, pong_jax
+
+        env = dict(pong=pong_jax, invaders=invaders_jax)[game]
+        state, obs = env.reset(jax.random.PRNGKey(0), 4)
+        rng = np.random.default_rng(5)
+        for t in range(6):
+            prev = state.prev_raw
+            a = jnp.asarray(rng.integers(0, env.NUM_ACTIONS, 4))
+            state, obs, *_ = env.step(state, a, jax.random.PRNGKey(t))
+            frames = jnp.maximum(state.prev_raw, prev)
+            want = np.asarray(jax.vmap(_parent_preprocess)(frames))
+            np.testing.assert_array_equal(
+                np.asarray(jax.vmap(pixel_jax.preprocess)(frames)), want)
+            if not np.asarray(state.frames == 0).any():  # no slot was reset
+                np.testing.assert_array_equal(np.asarray(obs[..., -1]), want)
+        assert want.any()
+
+
+class TestDrawnCases:
+    """A seeded rollout whose frames hold every case the masks tell apart,
+    held to the raster formulation bit for bit."""
+
+    N, STEPS, MAX_FRAMES = 8, 28, 10_000
+
+    def _enter(self):
+        """0: LEFT to the wall under a dead ball; 1: RIGHT to the other
+        wall; 2: a ball at rest in a cleared cell with a live brick under
+        its right column; 3: a ball over the side wall; 4: one life left
+        and a falling ball; 5: three lives and a falling ball; 6: one brick
+        left right above a rising ball; 7: plays at random."""
+        n = self.N
+        state, _ = breakout_jax.reset(jax.random.PRNGKey(0), n)
+        i = jnp.arange(n)
+        resting, walled, dying, clearing = i == 2, i == 3, (i == 4) | (i == 5), i == 6
+        live = resting | walled | dying | clearing
+        last = jnp.zeros((6, 18), bool).at[5, 9].set(True)
+        holed = jnp.ones((6, 18), bool).at[3, 5].set(False)
+        bricks = jnp.where(clearing[:, None, None], last, state.bricks)
+        bricks = jnp.where(resting[:, None, None], holed, bricks)
+        x = jnp.select([resting, walled, dying | clearing],
+                       [8.0 + 5 * 8 + 7, 7.0, 83.0], state.ball_x)
+        y = jnp.select([resting, walled, dying, clearing],
+                       [57.0 + 3 * 6 + 2, 120.0, 190.0, 100.0], state.ball_y)
+        vy = jnp.select([dying, clearing], [3.0, -3.0], state.vy)
+        state = state._replace(
+            bricks=bricks, ball_dead=state.ball_dead & ~live, ball_x=x, ball_y=y,
+            vy=vy, lives=jnp.where(i == 4, 1, jnp.where(i == 5, 3, state.lives)))
+        return state, _RasterState(state, breakout_jax._render_batch(state._asdict()))
+
+    @staticmethod
+    def _drawn(state):
+        """Which cases the frame of `state` holds, per env."""
+        s = {k: np.asarray(getattr(state, k)) for k in breakout_jax._DRAWN}
+        bx, by = s["ball_x"].astype(int), s["ball_y"].astype(int)
+        live = ~s["ball_dead"]
+        over_brick = np.zeros(len(bx), bool)
+        for dy in range(2):
+            for dx in range(2):
+                r = (by + dy - breakout_sim.BRICK_TOP) // breakout_sim.BRICK_H
+                c = (bx + dx - breakout_sim.WALL_SIDE) // breakout_sim.BRICK_W
+                inside = (r >= 0) & (r < 6) & (c >= 0) & (c < 18)
+                over_brick |= inside & s["bricks"][
+                    np.arange(len(bx)), r.clip(0, 5), c.clip(0, 17)]
+        return dict(
+            ball_over_brick=live & over_brick,
+            ball_over_side_wall=live & ((bx < breakout_sim.WALL_SIDE) | (
+                bx + 2 > breakout_sim.W - breakout_sim.WALL_SIDE)),
+            paddle_at_left_wall=s["paddle_x"] == breakout_sim.WALL_SIDE,
+            paddle_at_right_wall=s["paddle_x"] == (
+                breakout_sim.W - breakout_sim.WALL_SIDE - breakout_sim.PADDLE_W),
+            dead_ball=s["ball_dead"])
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_rollout_equals_the_raster_formulation(self, seed):
+        state, rs = self._enter()
+        seen = {k: int(v.sum()) for k, v in self._drawn(state).items()}
+        seen.update(life_loss=0, game_over=0, cleared_board=0)
+        rng = np.random.default_rng(seed)
+        for t in range(self.STEPS):
+            a = rng.integers(0, 4, size=self.N)
+            a[0], a[1], a[2:7] = breakout_sim.LEFT, breakout_sim.RIGHT, breakout_sim.NOOP
+            a, key = jnp.asarray(a), jax.random.PRNGKey(1000 * seed + t)
+            out = breakout_jax.step(state, a, key, max_frames=self.MAX_FRAMES)
+            out_r = _raster_step(rs, a, key, max_frames=self.MAX_FRAMES)
+            _assert_same_step(out, out_r, t)
+            (state, _, _, done, _), rs = out, out_r[0]
+            for k, v in self._drawn(state).items():
+                seen[k] += int(v.sum())
+            over = np.asarray(breakout_jax.completed_episode_mask(done, state))
+            seen["life_loss"] += int((np.asarray(done) & ~over).sum())
+            seen["game_over"] += int(over[4])
+            # A cleared board is reset in the step that clears it, so no
+            # state shows one: env 6 has five lives and no frame limit, and
+            # only clearing its last brick ends its game.
+            seen["cleared_board"] += int(over[6])
+        assert all(seen.values()), f"rollout never covered: {seen}"

@@ -422,16 +422,18 @@ def test_granite_hybrid_chunk_fits_and_updates_its_state_in_place(
 
 
 def test_breakout_step_keeps_no_raster_and_one_luma(chip):
-    """`breakout_jax.step` at 64 envs: no RGB raster `u8[N,210,160,3]` is
-    a buffer of the compiled step (inside a fusion it is never written),
-    and one reduction makes the `[N,210,160]` luma. The step that carried
-    the last frame as state and rendered the reset board beside the live
-    one (before PR 25) shows ten such buffers and two reductions."""
-    import re
-
-    from distributed_reinforcement_learning_tpu.envs import breakout_jax
+    """`breakout_jax.step` at 64 envs makes no RGB raster: `u8[N,210,160,3]`
+    is nowhere in the compiled step, fusions included; no class mask
+    `pred[N,210,160]` (or of the crop's rows) is a buffer of the entry
+    computation; ONE fusion writes the luma plane, over the scanlines the
+    crop reads, and the nine tables it selects from are made on the device
+    (a constant folded by the compiler would hold the host's arithmetic,
+    not the chip's). The step before PR 35 shows four `pred[N,210,160]`
+    buffers, the raster inside a fusion and a `[N,210,160]` reduction."""
+    from distributed_reinforcement_learning_tpu.envs import breakout_jax, pixel_jax
 
     n = 64
+    lo, hi = pixel_jax.CROP_ROWS
     state = jax.eval_shape(
         lambda: breakout_jax.reset(jax.random.PRNGKey(0), n)[0])
     text = breakout_jax.step.lower(
@@ -440,10 +442,12 @@ def test_breakout_step_keeps_no_raster_and_one_luma(chip):
         jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=chip),
     ).compile().as_text()
     buffers = text[text.index("\nENTRY"):]  # fused computations come before
-    assert f"u8[{n},210,160,3]" not in buffers
-    assert f"u8[{n},210,160,3]" in text  # the frame exists, inside a fusion
-    lumas = re.findall(rf"= \w+\[{n},210,160\]\S* reduce\(", text)
-    assert len(lumas) == 1, lumas
+    assert f"u8[{n},210,160,3]" not in text
+    assert not re.findall(rf"= \(?pred\[{n},(?:210|{hi - lo}),160\]", buffers)
+    planes = re.findall(rf"= \w+\[{n},(?:210|{hi - lo}),160\]\S* (\w+)\(", buffers)
+    assert planes == ["fusion"], planes
+    tables = re.findall(rf"= \w+\[3,3,{hi - lo},160\]\S* (\w+)\(", buffers)
+    assert tables == ["fusion"], tables
 
 
 def _breakout_chunk_compiled(anakin, state_sharding):
