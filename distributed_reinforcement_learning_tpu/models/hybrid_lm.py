@@ -87,13 +87,13 @@ class HybridState(NamedTuple):
     v: tuple
 
 
-def layer_runs(layer_types) -> tuple:
+def layer_runs(layer_types, kinds=LAYER_KINDS) -> tuple:
     """`("mamba",) * 5 + ("attention",) + ...` -> `(("mamba", 5),
     ("attention", 1), ...)`; a kind this file does not compute is refused."""
     runs: list = []
     for kind in layer_types:
-        if kind not in LAYER_KINDS:
-            raise ValueError(f"unknown layer type {kind!r}: {LAYER_KINDS}")
+        if kind not in kinds:
+            raise ValueError(f"unknown layer type {kind!r}: {kinds}")
         if runs and runs[-1][0] == kind:
             runs[-1][1] += 1
         else:
@@ -416,7 +416,7 @@ class HybridLM:
         return h, HybridState(tuple(ssm), tuple(conv), tuple(keys), tuple(values))
 
 
-def per_layer(p: dict, dtype=None) -> list:
+def per_layer(p: dict, dtype=None, run_matrices=RUN_MATRICES) -> list:
     """The runs' `[n, ...]`-stacked parameters as one dict per layer, in
     the published order; with `dtype`, the matrices cast to it."""
     layers = []
@@ -424,7 +424,7 @@ def per_layer(p: dict, dtype=None) -> list:
                        key=lambda k: int(k[3:])):
         for i in range(p[name]["norms"].shape[0]):
             layers.append({k: v[i].astype(dtype)
-                           if dtype is not None and k in RUN_MATRICES else v[i]
+                           if dtype is not None and k in run_matrices else v[i]
                            for k, v in p[name].items()})
     return layers
 

@@ -80,6 +80,21 @@ LAYERS = "learn/loss/layers"  # the stack (backward: re-entered under transpose)
 SSD = "learn/loss/layers/ssd"  # the chunked scan alone
 ATTENTION = "learn/loss/layers/attention"  # the one attention mixer
 
+# The sparse-expert hybrid model in the same loop (models/moe_lm.py,
+# ops/gated_delta.py, ops/expert_share.py); layers, attention, cache,
+# heads, V-trace and optimizer under the names above. No bump of
+# CACHE_TAG, for the reason given there.
+ACT_GDN = "collect/act/gdn"  # window shift, delta-rule state update and read-out
+ACT_MOE = "collect/act/moe"  # a decode step's expert MLPs: norm, shared expert, the sum
+ACT_MOE_ROUTE = "collect/act/moe/route"  # router product, softmax, top-k; the record
+ACT_MOE_EXPERTS = "collect/act/moe/experts"  # sort, gather, grouped products, add
+GDN = "learn/loss/layers/gdn"  # the chunked delta rule alone
+MOE_ROUTE = "learn/loss/layers/moe/route"  # router product, softmax, top-k
+MOE_EXPERTS = "learn/loss/layers/moe/experts"  # sort, gather, grouped products, add
+MOE_SHARED = "learn/loss/layers/moe/shared"  # the gated shared expert
+MOE_ACT = {"route": ACT_MOE_ROUTE, "experts": ACT_MOE_EXPERTS, "shared": ACT_MOE}
+MOE_LEARN = {"route": MOE_ROUTE, "experts": MOE_EXPERTS, "shared": MOE_SHARED}
+
 IMPALA_CHUNK_SCOPES = (COLLECT, ACT, ENV, RENDER, RECORD,
                        LEARN, LOSS, VTRACE, OPTIMIZER)
 REPLAY_CHUNK_SCOPES = (COLLECT, REPLAY, LEARN)
@@ -89,6 +104,11 @@ TOKENS_CHUNK_SCOPES = (ENV, ACT, ACT_LOOP, ACT_CACHE, ACT_HEAD, LOOP, HEADS,
                        LOSS_VTRACE, OPTIMIZER)
 HYBRID_CHUNK_SCOPES = (ENV, ACT, ACT_LAYERS, ACT_SSM, ACT_CACHE, ACT_HEAD,
                        LAYERS, SSD, ATTENTION, HEADS, LOSS_VTRACE, OPTIMIZER)
+
+MOE_CHUNK_SCOPES = (ENV, ACT, ACT_LAYERS, ACT_GDN, ACT_MOE, ACT_MOE_ROUTE,
+                    ACT_MOE_EXPERTS, ACT_CACHE, ACT_HEAD, LAYERS, GDN, ATTENTION,
+                    MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, HEADS, LOSS_VTRACE,
+                    OPTIMIZER)
 
 # -- host spans of the fused loops (runtime/launch.py) ---------------------
 STEP_READ = "anakin/step_read"  # int(state.train.step) at the loop head

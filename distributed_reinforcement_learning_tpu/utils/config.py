@@ -309,6 +309,62 @@ def load_config(path: str | Path, section: str):
             dtype=_compute_dtype(d, "bfloat16"),
             init_std=d.get("initializer_range", 0.02),
         )
+    elif algorithm == "moelm":
+        from distributed_reinforcement_learning_tpu.agents.moelm import MoELMConfig
+        from distributed_reinforcement_learning_tpu.models.hybrid_lm import (
+            layer_runs)
+        from distributed_reinforcement_learning_tpu.models.moe_lm import (
+            LAYER_KINDS)
+
+        # As `hybridlm`: the source's own keys, none of them guessed, and
+        # what the family's config can say and this program does not
+        # compute refused by name. `num_experts` is the chip's share of a
+        # layer's `router_width` experts, from `first_expert` on.
+        layer_types = tuple(d["layer_types"])
+        layer_runs(layer_types, LAYER_KINDS)  # an unknown layer type raises here
+        if len(layer_types) != d["num_hidden_layers"]:
+            raise ValueError(f"{len(layer_types)} layer_types for "
+                             f"num_hidden_layers {d['num_hidden_layers']}")
+        for key, only in (("decoder_sparse_step", 1), ("mlp_only_layers", []),
+                          ("norm_topk_prob", True), ("tie_word_embeddings", False),
+                          ("use_sliding_window", False), ("rope_scaling", None),
+                          ("attention_bias", False), ("hidden_act", "silu")):
+            if d.get(key, only) != only:
+                raise ValueError(f"{key} {d[key]!r}: only {only!r} is computed")
+        agent_cfg = MoELMConfig(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            layer_types=layer_types,
+            num_attention_heads=d["num_attention_heads"],
+            num_key_value_heads=d["num_key_value_heads"],
+            head_dim=d["head_dim"],
+            partial_rotary_factor=d["partial_rotary_factor"],
+            rope_theta=d["rope_theta"],
+            linear_num_key_heads=d["linear_num_key_heads"],
+            linear_num_value_heads=d["linear_num_value_heads"],
+            linear_key_head_dim=d["linear_key_head_dim"],
+            linear_value_head_dim=d["linear_value_head_dim"],
+            linear_conv_kernel_dim=d["linear_conv_kernel_dim"],
+            num_experts=d["num_experts"],
+            router_width=d["router_width"],
+            first_expert=d["first_expert"],
+            num_experts_per_tok=d["num_experts_per_tok"],
+            moe_intermediate_size=d["moe_intermediate_size"],
+            shared_expert_intermediate_size=d["shared_expert_intermediate_size"],
+            rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+            trajectory=d.get("trajectory", 1024),
+            recall_distance=d.get("recall_distance", 8),
+            discount_factor=d.get("discount_factor", 0.99),
+            baseline_loss_coef=d.get("baseline_loss_coef", 1.0),
+            entropy_coef=d.get("entropy_coef", 0.05),
+            gradient_clip_norm=d.get("gradient_clip_norm", 40.0),
+            reward_clipping=d.get("reward_clipping", "abs_one"),
+            start_learning_rate=d.get("start_learning_rate", 1e-5),
+            end_learning_rate=d.get("end_learning_rate", 0.0),
+            learning_frame=int(d.get("learning_frame", 1e9)),
+            dtype=_compute_dtype(d, "bfloat16"),
+            init_std=d.get("initializer_range", 0.02),
+        )
     else:
         raise ValueError(f"unknown algorithm {algorithm!r}")
 

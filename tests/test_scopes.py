@@ -205,6 +205,41 @@ def test_hybrid_backward_stack_keeps_its_names(hybrid_chunk_names, name):
                for n in hybrid_chunk_names)
 
 
+@pytest.fixture(scope="module")
+def moe_chunk_names():
+    import jax.numpy as jnp
+
+    from distributed_reinforcement_learning_tpu.agents.moelm import (
+        MoELMAgent, MoELMConfig)
+    from distributed_reinforcement_learning_tpu.envs.token_recall_jax import TokenRecall
+    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import AnakinTokens
+
+    cfg = MoELMConfig(
+        vocab_size=64, hidden_size=32, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=8, linear_value_head_dim=8,
+        num_experts=4, router_width=16, first_expert=4, num_experts_per_tok=3,
+        moe_intermediate_size=16, shared_expert_intermediate_size=16,
+        trajectory=16, gdn_chunk=8, dtype=jnp.float32, head_block=16, row_block=2)
+    anakin = AnakinTokens(MoELMAgent(cfg), 4, TokenRecall(64, 16))
+    return _op_names(anakin.train_chunk, anakin.init(jax.random.PRNGKey(0)), 1)
+
+
+@pytest.mark.parametrize("name", scopes.MOE_CHUNK_SCOPES)
+def test_moe_chunk_carries_scope(moe_chunk_names, name):
+    """The names `perfbench/layer_metrics/moelm_*` read (ISSUE 36)."""
+    assert any(name in n for n in moe_chunk_names), name
+
+
+@pytest.mark.parametrize("name", [scopes.LAYERS, scopes.GDN, scopes.ATTENTION,
+                                  scopes.MOE_ROUTE, scopes.MOE_EXPERTS,
+                                  scopes.MOE_SHARED])
+def test_moe_backward_stack_keeps_its_names(moe_chunk_names, name):
+    """The rematerialised blocks are entered again under the transpose."""
+    assert any(f"transpose(jvp({scopes.LOSS}))" in n and name in n
+               for n in moe_chunk_names)
+
+
 def test_r2d2_backward_recurrence_keeps_the_unroll_name(r2d2_chunk_names):
     """The inner scope is entered again inside the transposed outer one:
     `transpose(jvp(learn/loss))/.../learn/loss/unroll/...`."""

@@ -5,7 +5,7 @@ described, not opened (`/opt/skills/guides/on-chip-measurement`, section
 2). That catches what interpret mode cannot — a slice not aligned to the
 tiling, a kernel over its VMEM budget, a program Mosaic refuses — at the
 shapes the main path really runs (`config.json` sections `impala`,
-`apex`, `r2d2_pixel`, `r2d2_atari`, `ouro_looplm`, `granite_hybrid`; the Anakin chunk
+`apex`, `r2d2_pixel`, `r2d2_atari`, `ouro_looplm`, `granite_hybrid`, `qwen3_next`; the Anakin chunk
 `chip_smoke.py` drives), and
 costs no chip time. It also shows what the compiler DID with a program:
 which layout copies and which collectives it put in (the fused IMPALA
@@ -419,6 +419,61 @@ def test_granite_hybrid_chunk_fits_and_updates_its_state_in_place(
     text = compiled.as_text()
     assert not re.findall(r"= f32\[32,64,64,128\]\S* copy\(", text)
     assert not re.findall(r"= f32\[\d+,32,64,64,128\]\S* copy\(", text)
+
+
+def test_qwen3_next_chunk_fits_and_holds_its_six_kernels(chip, kernels_as_on_chip):
+    """The fused token chunk at the `qwen3_next` section's sizes (32 envs x
+    1,024 tokens; three gated-delta-rule layers and one gated attention
+    layer at 2048 wide, a 512-way router over 32 held experts in every
+    layer; chunk of 1): it compiles for a described v5e, the donated state
+    (625.7 M parameters + their second moments, 8 B each) is aliased
+    whole, and arguments + scratch stay under the chip's `bytes_limit` by
+    `memory_analysis` (14.96 GB when written). Six Mosaic kernels in the
+    LOWERED chunk, the configuration file's count: flash attention in the
+    one attention layer (forward, rematerialised forward, dq, dkv) and
+    V-trace's two views; the chunked delta rule and the expert layer are
+    plain XLA there (the compiler makes the grouped products its own
+    kernels afterwards). No copy of a layer's delta-rule state
+    `f32[32,32,128,128]` in any decode body: the step updates it in
+    place."""
+    import json
+
+    from distributed_reinforcement_learning_tpu.agents.moelm import MoELMAgent
+    from distributed_reinforcement_learning_tpu.envs.registry import (
+        make_jittable_env)
+    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import (
+        AnakinTokens)
+
+    cfg, rt = load_config(CONFIG, "qwen3_next")
+    env = make_jittable_env(rt.envs[0], vocab=cfg.vocab_size,
+                            episode_len=cfg.trajectory,
+                            distance=cfg.recall_distance)
+    anakin = AnakinTokens(MoELMAgent(cfg), rt.num_actors * rt.envs_per_actor, env)
+    state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+    lowered = anakin.train_chunk.lower(_on(chip, state), 1)
+    with open(os.path.join(os.path.dirname(CONFIG), "perfbench", "configs",
+                           "qwen3_next.json")) as f:
+        named = json.load(f)["kernels"]["tpu_custom_call"]
+    assert len(re.findall("tpu_custom_call", lowered.as_text())) == named == 6
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    params = 625_669_185
+    assert mem.alias_size_in_bytes == mem.argument_size_in_bytes > 8 * params
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert held < 16.5e9 < 16_909_336_064, held
+    facts = anakin.static_facts
+    assert facts["layer_order"] == ("linear_attention",) * 3 + ("full_attention",)
+    assert (facts["gdn_state_bytes"], facts["conv_state_bytes"],
+            facts["kv_cache_bytes"]) == (3 * 32 * 32 * 128 * 128 * 4,
+                                         3 * 32 * 3 * 8192 * 4,
+                                         2 * 32 * 1024 * 2 * 256 * 2)
+    assert (facts["experts_held"], facts["router_width"]) == (32, 512)
+    assert facts["decode_spans"] == tuple(range(128, 1025, 128))
+    text = compiled.as_text()
+    assert not re.findall(r"= f32\[32,32,128,128\]\S* copy\(", text)
+    # no array with the router's width AND a capacity beside the tokens
+    assert not re.findall(r"\[4096,512,\d+\]|\[32768,512,\d+\]", text)
 
 
 def test_breakout_step_keeps_no_raster_and_one_luma(chip):
