@@ -19,20 +19,61 @@ k_t u_t^T, and inside a chunk of C steps, c_i the running sum of g:
     o_i = exp(c_i) S_0^T q_i + sum_{j<=i} exp(c_i - c_j) (k_j . q_i) u_j
     S_C = exp(c_C) S_0 + sum_j exp(c_C - c_j) k_j u_j^T
 
-so T steps are T / C steps of a scan whose body is matrix products and
-one triangular solve, where the step-by-step form (`models/moe_lm.py`
-decode, the plain reference) is T rank-one updates. Every exponent is
-<= 0. An episode boundary inside a chunk cuts every sum at it, as in
-`ops/ssd.py`: a pair (i, j) counts only if both steps are of one episode
-(`seg`), and S_0 reaches only the steps of the episode the previous
-chunk ended in.
+so T steps are T / C steps of a scan where the step-by-step form
+(`models/moe_lm.py` decode, the plain reference) is T rank-one updates.
+Every exponent is <= 0. An episode boundary inside a chunk cuts every
+sum at it, as in `ops/ssd.py`: a pair (i, j) counts only if both steps
+are of one episode (`seg`), and S_0 reaches only the steps of the
+episode the previous chunk ended in.
 
-Plain `jax.numpy`, no kernel (ISSUE 36: the chunked rule as a Pallas
-kernel is `perf_opt` work this cell will judge). g, b, the cumulative
-sums, L, the solve and the state are float32; the matrix products take
-operands in `dtype` with float32 accumulation. The body is
-rematerialised: the backward, which is autodiff's (through the solve
-too), keeps one state `[B, H, K, V]` a chunk.
+Only S_0 depends on the chunk before, so `gated_delta_chunked` runs in
+three phases (PR 37), n = T / C chunks, N = n B H matrices:
+
+1. All chunks at once, `[n, B, H, C, ...]` (chunk-major so that the scan
+   slices rows, head-major by the one transposition of q, k, v): the
+   running sums, the masked decays, which episode each chunk's S_0
+   belongs to (-1 before the first, then the chunk before's last `seg`),
+   `k k^T`, L, the right-hand side `b [V | exp(c) K]`, ONE call of
+   `jax.scipy.linalg.solve_triangular` for U~ and W, `q k^T`, the keys
+   scaled to the chunk's end.
+2. A `lax.scan` over the chunks that keeps what touches S and nothing
+   else: `u = U~ - W S`, the past's part of the read-out `(q S)
+   exp(c)`, `S' = exp(c_C) S + (k exp(c_C - c))^T u`. Three products;
+   it emits `u` and the past's read-out.
+3. All chunks at once: `o = past + (q k^T . decay) u`, back to `[B, T,
+   H, V]`.
+
+The solve is called with its batch FLAT, `[N, C, C]` and `[N, C, V +
+K]`: the TPU's triangular inversion (`InvertDiagBlocksLowerTriangular`,
+what XLA lowers the call to, then one product with the right-hand side)
+lays the LAST batch dimension along the 128 lanes, so at `[n, B, H] =
+[16, 4, 32]` three lanes in four are empty and every array in that
+layout is padded fourfold: 5.46 ms a call against 1.38 flat, and with
+it the whole rule 9.5 + 17.5 ms against 4.7 + 11.2 (forward; forward
+and backward under `trunk`'s checkpoint; a row block on a v5e, PR 37).
+Two things `perfbench/tests/test_qwen3_next_faults.py`
+holds the call to: it is reached as the attribute
+`jax.scipy.linalg.solve_triangular(matrix, rhs, ...)` at trace time (a
+planted fault swaps that function), and the right-hand side is `[V | K]`
+on the last axis, V first (the fault slices `rhs[..., :V]`).
+
+Plain `jax.numpy`, no kernel. g, b, the cumulative sums, L, the solve
+and the state are float32; the matrix products take operands in `dtype`
+with float32 accumulation; the state crosses a chunk boundary in
+`carry_dtype`. What phase 1 makes lives only inside one call, which
+`models/moe_lm.trunk` makes a row block at a time: at the published
+sizes (4 rows x 1,024 steps x 32 heads of 128 x 128, N = 2,048) k and v
+head-major 67 MB each and q 34 (bfloat16), `k k^T`, L, `q k^T` and each
+masked decay 34 MB, the right-hand side and the solve's result 134 MB
+each (U~ 67 of it, W kept as 34 in bfloat16), the scaled keys 34, `u`,
+the past's read-out and `o` 67 each: 338 MB of temporaries forward and
+982 MB forward and backward by the compiler's count. The scan's BODY is
+rematerialised and nothing else: the backward keeps one state `[B, H,
+K, V]` a chunk (16 x 8.4 MB) and recomputes three products, which reads
+0.4 ms and 100 MB under keeping the body's own residuals; phases 1 and 3
+are differentiated as they stand, so under `trunk`'s per-row-block
+`jax.checkpoint` they run twice an update (forward, recomputed forward),
+not three times.
 """
 
 from __future__ import annotations
@@ -61,6 +102,7 @@ def gated_delta_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     dtype the state crosses a chunk boundary in (float32; a test plants
     another)."""
     b, t, h, dk = q.shape
+    dv = v.shape[-1]
     c = min(chunk, t)
     pad = -t % c
     if pad:
@@ -75,48 +117,55 @@ def gated_delta_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     lower = steps[:, None] > steps[None, :]
     causal = steps[:, None] >= steps[None, :]
 
-    @jax.checkpoint
-    def one_chunk(carry, xs):
-        state, seg_before = carry  # S_0 and the episode it belongs to
-        q_c, k_c, v_c, g_c, b_c, seg_c = xs
-        cs = jnp.moveaxis(jnp.cumsum(g_c.astype(F32), axis=1), 2, 1)  # [B, H, C]
-        b_h = jnp.moveaxis(b_c.astype(F32), 2, 1)  # [B, H, C]
-        same = (seg_c[:, :, None] == seg_c[:, None, :])[:, None]  # [B, 1, C, C]
-        decay = lambda pairs: jnp.exp(jnp.where(
-            pairs, cs[..., :, None] - cs[..., None, :], -jnp.inf))
-        live = (seg_c == seg_before[:, None])[:, None]  # [B, 1, C]: S_0 reaches them
-        from_past = jnp.where(live, jnp.exp(cs), 0.0)  # [B, H, C]
-        # (I + L) [U~ | W] = b [V | exp(c) K]: one unit-lower-triangular
-        # solve of K + V right-hand sides a head, float32.
-        kk = mm("bihd,bjhd->bhij", k_c, k_c)
-        lmat = b_h[..., None] * decay(lower & same) * kk
-        k_h, v_h = (jnp.moveaxis(x.astype(F32), 2, 1) for x in (k_c, v_c))
-        rhs = jnp.concatenate([b_h[..., None] * v_h,
-                               (b_h * from_past)[..., None] * k_h], axis=-1)
-        solved = jax.scipy.linalg.solve_triangular(
-            lmat + jnp.eye(c, dtype=F32), rhs, lower=True, unit_diagonal=True)
-        u, w = solved[..., :v_h.shape[-1]], solved[..., v_h.shape[-1]:]
-        u = u - mm("bhik,bhkv->bhiv", w, state)
-        # read-out: what the past hands on, and the chunk's own writes
-        qk = mm("bihd,bjhd->bhij", q_c, k_c) * decay(causal & same)
-        o = (mm("bihk,bhkv->bhiv", q_c, state) * from_past[..., None]
-             + mm("bhij,bhjv->bhiv", qk, u))
-        # the state the chunk leaves: its last episode's writes, and S_0
-        # if that episode is the one the chunk began in
-        ends = seg_c[:, -1]
-        to_end = jnp.where((seg_c == ends[:, None])[:, None],
-                           jnp.exp(cs[..., -1:] - cs), 0.0)  # [B, H, C]
-        kept = jnp.where((ends == seg_before)[:, None], jnp.exp(cs[..., -1]), 0.0)
-        state = (kept[..., None, None] * state.astype(F32)
-                 + mm("bhjk,bhjv->bhkv", k_h * to_end[..., None], u))
-        return (state.astype(carry_dtype), ends), jnp.moveaxis(o, 1, 2)
+    # -- 1. all chunks at once: everything the state does not reach ------
+    # chunk-major, head-major: [n, B, H, C, ...], so the scan slices rows
+    heads = lambda x: jnp.transpose(x.reshape(b, n, c, h, -1), (1, 0, 3, 2, 4))
+    q_h, k_h, v_h = heads(q.astype(dtype)), heads(k.astype(F32)), heads(v.astype(F32))
+    cs = jnp.cumsum(heads(g.astype(F32))[..., 0], axis=-1)  # [n, B, H, C]
+    b_h = heads(beta.astype(F32))  # [n, B, H, C, 1]
+    seg_c = jnp.moveaxis(seg.reshape(b, n, c), 1, 0)  # [n, B, C]
+    ends = seg_c[..., -1]
+    # the episode S_0 belongs to; no episode is -1: S before step 0 is 0
+    seg_before = jnp.concatenate([jnp.full((1, b), -1, seg.dtype), ends[:-1]])
+    same = (seg_c[..., :, None] == seg_c[..., None, :])[:, :, None]  # [n, B, 1, C, C]
+    decay = lambda pairs: jnp.exp(jnp.where(
+        pairs, cs[..., :, None] - cs[..., None, :], -jnp.inf))
+    live = (seg_c == seg_before[..., None])[:, :, None]  # [n, B, 1, C]: S_0 reaches them
+    from_past = jnp.where(live, jnp.exp(cs), 0.0)[..., None]  # [n, B, H, C, 1]
+    # (I + L) [U~ | W] = b [V | exp(c) K]: one unit-lower-triangular
+    # solve of V + K right-hand sides a head and chunk, float32.
+    lmat = b_h * decay(lower & same) * mm("nbhid,nbhjd->nbhij", k_h, k_h)
+    rhs = jnp.concatenate([b_h * v_h, b_h * from_past * k_h], axis=-1)
+    solved = jax.scipy.linalg.solve_triangular(
+        (lmat + jnp.eye(c, dtype=F32)).reshape(-1, c, c),
+        rhs.reshape(-1, c, rhs.shape[-1]), lower=True,
+        unit_diagonal=True).reshape(rhs.shape)
+    u_alone, w = solved[..., :dv], solved[..., dv:].astype(dtype)
+    qk = mm("nbhid,nbhjd->nbhij", q_h, k_h) * decay(causal & same)
+    # what a chunk leaves: its last episode's writes, and S_0 if that
+    # episode is the one the chunk began in
+    to_end = jnp.where((seg_c == ends[..., None])[:, :, None],
+                       jnp.exp(cs[..., -1:] - cs), 0.0)  # [n, B, H, C]
+    k_end = (k_h * to_end[..., None]).astype(dtype)
+    kept = jnp.where((ends == seg_before)[..., None], jnp.exp(cs[..., -1]), 0.0)
 
-    chunks = lambda x: jnp.moveaxis(x.reshape(b, n, c, *x.shape[2:]), 1, 0)
-    carry = (jnp.zeros((b, h, dk, v.shape[-1]), carry_dtype),
-             jnp.full((b,), -1, seg.dtype))  # no episode is -1: S before step 0 is 0
-    (state, _), o = jax.lax.scan(
-        one_chunk, carry, tuple(chunks(x) for x in (q, k, v, g, beta, seg)))
-    o = jnp.moveaxis(o, 0, 1).reshape(b, t + pad, h, -1)[:, :t]
+    # -- 2. chunk after chunk: the three products that touch S -----------
+    @jax.checkpoint
+    def one_chunk(state, xs):
+        u_c, w_c, q_c, past_c, k_c, kept_c = xs
+        u = u_c - mm("bhik,bhkv->bhiv", w_c, state)
+        read = mm("bhik,bhkv->bhiv", q_c, state) * past_c
+        state = (kept_c[..., None, None] * state.astype(F32)
+                 + mm("bhjk,bhjv->bhkv", k_c, u))
+        return state.astype(carry_dtype), (u, read)
+
+    state, (u, read) = jax.lax.scan(
+        one_chunk, jnp.zeros((b, h, dk, dv), carry_dtype),
+        (u_alone, w, q_h, from_past, k_end, kept))
+
+    # -- 3. all chunks at once: the chunk's own writes, read out ----------
+    o = read + mm("nbhij,nbhjv->nbhiv", qk, u)
+    o = jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(b, t + pad, h, dv)[:, :t]
     return o, state.astype(F32)
 
 

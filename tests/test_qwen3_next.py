@@ -144,19 +144,21 @@ def _rel(got, want) -> float:
 # -- the chunked delta rule against the step-by-step recurrence ------------
 
 
-def _rule_inputs(seed, steps, boundary):
+def _rule_inputs(seed, steps, ends, rows=2, h=3, dk=8, dv=6):
+    """Inputs of the rule: `ends` is one step of row 0, a list of `(row,
+    step)` at which an episode ends, or None."""
     r = np.random.RandomState(seed)
-    b, h, dk, dv = 2, 3, 8, 6
     f = lambda *shape: jnp.asarray(r.normal(size=shape), jnp.float32)
-    done = np.zeros((b, steps), bool)
-    if boundary is not None:
-        done[0, boundary] = True
+    done = np.zeros((rows, steps), bool)
+    for row, step in ([] if ends is None else
+                      [(0, ends)] if isinstance(ends, int) else ends):
+        done[row, step] = True
     seg, pos = ref.episode_positions(jnp.asarray(done))
-    q = gated_delta.l2_normalize(f(b, steps, h, dk)) * dk ** -0.5
-    k = gated_delta.l2_normalize(f(b, steps, h, dk) + 0.7)  # keys that overlap
-    g = -jnp.asarray(r.uniform(0.02, 0.4, (b, steps, h)), jnp.float32)
-    beta = jnp.asarray(r.uniform(0.2, 0.9, (b, steps, h)), jnp.float32)
-    return (q, k, f(b, steps, h, dv), g, beta), seg, pos == 0
+    q = gated_delta.l2_normalize(f(rows, steps, h, dk)) * dk ** -0.5
+    k = gated_delta.l2_normalize(f(rows, steps, h, dk) + 0.7)  # keys that overlap
+    g = -jnp.asarray(r.uniform(0.02, 0.4, (rows, steps, h)), jnp.float32)
+    beta = jnp.asarray(r.uniform(0.2, 0.9, (rows, steps, h)), jnp.float32)
+    return (q, k, f(rows, steps, h, dv), g, beta), seg, pos == 0
 
 
 @pytest.mark.parametrize("boundary", [None, 2, 7, 8, 11],
@@ -221,6 +223,155 @@ def test_a_wrong_delta_rule_is_seen(fault):
                 *(x[:, i:i + 8] for x in (q, k, v, g, beta)), seg[:, i:i + 8], 8,
                 jnp.float32)[0] for i in range(0, 24, 8)], axis=1)
     assert _rel(got, want) > 0.02
+
+
+# -- the hoisted form against the form it replaced (PR 37) -------------------
+
+
+def _parent_chunked(q, k, v, g, beta, seg, chunk=64, dtype=jnp.bfloat16,
+                    carry_dtype=jnp.float32):
+    """`gated_delta_chunked` as it was before PR 37, kept here as the plain
+    version: EVERYTHING inside the scan over chunks, the solve included."""
+    F32 = jnp.float32
+    b, t, h, dk = q.shape
+    c = min(chunk, t)
+    pad = -t % c
+    if pad:
+        tail = lambda x, mode="constant": jnp.pad(
+            x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2), mode=mode)
+        q, k, v, g, beta = (tail(x) for x in (q, k, v, g, beta))
+        seg = tail(seg, "edge")
+    n = (t + pad) // c
+    mm = lambda spec, x, y: jnp.einsum(spec, x.astype(dtype), y.astype(dtype),
+                                       preferred_element_type=F32)
+    steps = jnp.arange(c)
+    lower = steps[:, None] > steps[None, :]
+    causal = steps[:, None] >= steps[None, :]
+
+    @jax.checkpoint
+    def one_chunk(carry, xs):
+        state, seg_before = carry
+        q_c, k_c, v_c, g_c, b_c, seg_c = xs
+        cs = jnp.moveaxis(jnp.cumsum(g_c.astype(F32), axis=1), 2, 1)
+        b_h = jnp.moveaxis(b_c.astype(F32), 2, 1)
+        same = (seg_c[:, :, None] == seg_c[:, None, :])[:, None]
+        decay = lambda pairs: jnp.exp(jnp.where(
+            pairs, cs[..., :, None] - cs[..., None, :], -jnp.inf))
+        live = (seg_c == seg_before[:, None])[:, None]
+        from_past = jnp.where(live, jnp.exp(cs), 0.0)
+        kk = mm("bihd,bjhd->bhij", k_c, k_c)
+        lmat = b_h[..., None] * decay(lower & same) * kk
+        k_h, v_h = (jnp.moveaxis(x.astype(F32), 2, 1) for x in (k_c, v_c))
+        rhs = jnp.concatenate([b_h[..., None] * v_h,
+                               (b_h * from_past)[..., None] * k_h], axis=-1)
+        solved = jax.scipy.linalg.solve_triangular(
+            lmat + jnp.eye(c, dtype=F32), rhs, lower=True, unit_diagonal=True)
+        u, w = solved[..., :v_h.shape[-1]], solved[..., v_h.shape[-1]:]
+        u = u - mm("bhik,bhkv->bhiv", w, state)
+        qk = mm("bihd,bjhd->bhij", q_c, k_c) * decay(causal & same)
+        o = (mm("bihk,bhkv->bhiv", q_c, state) * from_past[..., None]
+             + mm("bhij,bhjv->bhiv", qk, u))
+        ends = seg_c[:, -1]
+        to_end = jnp.where((seg_c == ends[:, None])[:, None],
+                           jnp.exp(cs[..., -1:] - cs), 0.0)
+        kept = jnp.where((ends == seg_before)[:, None], jnp.exp(cs[..., -1]), 0.0)
+        state = (kept[..., None, None] * state.astype(F32)
+                 + mm("bhjk,bhjv->bhkv", k_h * to_end[..., None], u))
+        return (state.astype(carry_dtype), ends), jnp.moveaxis(o, 1, 2)
+
+    chunks = lambda x: jnp.moveaxis(x.reshape(b, n, c, *x.shape[2:]), 1, 0)
+    carry = (jnp.zeros((b, h, dk, v.shape[-1]), carry_dtype),
+             jnp.full((b,), -1, seg.dtype))
+    (state, _), o = jax.lax.scan(
+        one_chunk, carry, tuple(chunks(x) for x in (q, k, v, g, beta, seg)))
+    o = jnp.moveaxis(o, 0, 1).reshape(b, t + pad, h, -1)[:, :t]
+    return o, state.astype(F32)
+
+
+_SMALL = (2, 3, 8, 6, 8)  # rows, heads, K, V, chunk
+_PUBLISHED = (1, 32, 128, 128, 64)
+_HOISTED_CASES = [
+    # T whole chunks / a ragged tail / under one chunk, each with an episode
+    # end inside a chunk, one exactly on a chunk edge, and none
+    ("whole_end_inside", _SMALL, 24, [(0, 11), (1, 3)], jnp.float32),
+    ("whole_end_on_edge", _SMALL, 24, [(0, 7), (1, 15)], jnp.float32),
+    ("whole_no_end", _SMALL, 24, [], jnp.float32),
+    ("ragged_end_inside", _SMALL, 27, [(0, 20), (1, 25)], jnp.float32),
+    ("ragged_end_on_edge", _SMALL, 27, [(0, 7), (1, 23)], jnp.float32),
+    ("ragged_no_end", _SMALL, 27, [], jnp.float32),
+    ("under_one_chunk_end_inside", _SMALL, 5, [(0, 2)], jnp.float32),
+    ("under_one_chunk_end_on_edge", _SMALL, 5, [(0, 4)], jnp.float32),
+    ("under_one_chunk_no_end", _SMALL, 5, [], jnp.float32),
+    ("whole_bfloat16_carry", _SMALL, 24, [(0, 11)], jnp.bfloat16),
+    ("ragged_bfloat16_carry", _SMALL, 27, [(0, 7)], jnp.bfloat16),
+    ("published_whole_ends_inside_and_on_edge", _PUBLISHED, 256,
+     [(0, 70), (0, 127)], jnp.float32),
+    ("published_ragged_end_on_edge", _PUBLISHED, 200, [(0, 63)], jnp.float32),
+    ("published_no_end", _PUBLISHED, 128, [], jnp.float32),
+]
+
+
+@pytest.mark.parametrize("sizes,steps,ends,carry", [c[1:] for c in _HOISTED_CASES],
+                         ids=[c[0] for c in _HOISTED_CASES])
+def test_hoisted_delta_rule_equals_the_form_it_replaced(sizes, steps, ends, carry):
+    """Outputs, final state and the gradients of all five inputs, at
+    float32 and `highest`: the two forms differ by the order of float32
+    sums alone (readings when written: at most 3e-7)."""
+    rows, h, dk, dv, chunk = sizes
+    xs, seg, _ = _rule_inputs(steps + len(ends), steps, ends, rows, h, dk, dv)
+    form = lambda f: lambda *xs: f(*xs, seg, chunk, jnp.float32, carry)
+    weigh = lambda f: lambda *xs: sum(
+        jnp.sum(out * jnp.cos(jnp.arange(out.size).reshape(out.shape)))
+        for out in f(*xs))
+    new, old = form(gated_delta.gated_delta_chunked), form(_parent_chunked)
+    with jax.default_matmul_precision("highest"):
+        got, want = new(*xs), old(*xs)
+        g_got = jax.grad(weigh(new), argnums=range(5))(*xs)
+        g_want = jax.grad(weigh(old), argnums=range(5))(*xs)
+    assert got[0].shape == (rows, steps, h, dv) and got[1].shape == (rows, h, dk, dv)
+    assert _rel(got[0], want[0]) < 2e-6
+    assert _rel(got[1], want[1]) < 2e-6
+    for a, b in zip(g_got, g_want):
+        assert _rel(a, b) < 2e-6
+
+
+def _primitives(jaxpr, counts=None):
+    """How often each primitive occurs in a jaxpr, sub-jaxprs included."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] = counts.get(eqn.primitive.name, 0) + 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, counts)
+    return counts
+
+
+def _scans_with_a_carry(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" and eqn.params["num_carry"]:
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans_with_a_carry(sub)
+
+
+_SEQUENTIAL = ("dot_general", "triangular_solve", "exp", "cumsum", "transpose")
+
+
+@pytest.mark.parametrize("form,counts", [
+    ("hoisted", (3, 0, 0, 0, 0)), ("replaced", (6, 1, 5, 1, 5))])
+def test_only_the_state_stays_in_the_scan_over_chunks(form, counts):
+    """The FORWARD jaxpr at the published head sizes: the scan over chunks
+    holds the three products that touch S and no solve, `exp`, `cumsum` or
+    transposition (the form it replaced: 6, 1, 5, 1, 5: the walker sees
+    them), and the one solve of the whole rule stands outside it."""
+    xs, seg, _ = _rule_inputs(0, 256, 70, 1, 32, 128, 128)
+    rule = {"hoisted": gated_delta.gated_delta_chunked,
+            "replaced": _parent_chunked}[form]
+    whole = jax.make_jaxpr(lambda *xs: rule(*xs, seg, 64))(*xs).jaxpr
+    (scan,) = _scans_with_a_carry(whole)
+    assert scan.params["length"] == 4
+    body = _primitives(scan.params["jaxpr"].jaxpr)
+    assert tuple(body.get(name, 0) for name in _SEQUENTIAL) == counts
+    assert _primitives(whole)["triangular_solve"] == 1
 
 
 def test_one_step_of_the_rule_is_the_recurrence():

@@ -428,7 +428,8 @@ def test_qwen3_next_chunk_fits_and_holds_its_six_kernels(chip, kernels_as_on_chi
     layer; chunk of 1): it compiles for a described v5e, the donated state
     (625.7 M parameters + their second moments, 8 B each) is aliased
     whole, and arguments + scratch stay under the chip's `bytes_limit` by
-    `memory_analysis` (14.96 GB when written). Six Mosaic kernels in the
+    `memory_analysis` (14.87 GB at PR 36, 15.75 since PR 37 hoisted the delta
+    rule's chunk-independent work out of its scan). Six Mosaic kernels in the
     LOWERED chunk, the configuration file's count: flash attention in the
     one attention layer (forward, rematerialised forward, dq, dkv) and
     V-trace's two views; the chunked delta rule and the expert layer are
