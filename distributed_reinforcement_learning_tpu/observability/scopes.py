@@ -95,6 +95,25 @@ MOE_SHARED = "learn/loss/layers/moe/shared"  # the gated shared expert
 MOE_ACT = {"route": ACT_MOE_ROUTE, "experts": ACT_MOE_EXPERTS, "shared": ACT_MOE}
 MOE_LEARN = {"route": MOE_ROUTE, "experts": MOE_EXPERTS, "shared": MOE_SHARED}
 
+# The latent-attention sparse-expert model in the same loop
+# (models/latent_moe_lm.py, ops/latent_attention.py, ops/expert_share.py);
+# layers, cache, the expert scopes, heads, V-trace and optimizer under
+# the names above. No bump of CACHE_TAG, for the reason given there.
+ACT_MLA = "collect/act/mla"  # a decode step's latent attention, every layer
+ACT_MLA_PROJECT = "collect/act/mla/project"  # q and latent down/up-projections, norms, rotary, W_o
+ACT_MLA_ATTEND = "collect/act/mla/attend"  # absorb W^UK, scores and weighted sum on the cache, W^UV
+MLA_PROJECT = "learn/loss/layers/mla/project"  # W_qa, W_qb, W_kva, their norms, W_o
+MLA_ATTEND = "learn/loss/layers/mla/attend"  # W_kvb, rotary, the causal attention core
+DENSE = "learn/loss/layers/dense"  # the leading layer's dense SwiGLU
+MTP = "learn/loss/mtp"  # the multi-token-prediction module, its layer and its head
+MLA_ACT = {"project": ACT_MLA_PROJECT, "attend": ACT_MLA_ATTEND,
+           "dense": ACT_LAYERS, **MOE_ACT}
+MLA_LEARN = {"project": MLA_PROJECT, "attend": MLA_ATTEND, "dense": DENSE,
+             **MOE_LEARN}
+MLA_MTP = {"project": f"{MTP}/mla/project", "attend": f"{MTP}/mla/attend",
+           "route": f"{MTP}/moe/route", "experts": f"{MTP}/moe/experts",
+           "shared": f"{MTP}/moe/shared"}
+
 IMPALA_CHUNK_SCOPES = (COLLECT, ACT, ENV, RENDER, RECORD,
                        LEARN, LOSS, VTRACE, OPTIMIZER)
 REPLAY_CHUNK_SCOPES = (COLLECT, REPLAY, LEARN)
@@ -109,6 +128,12 @@ MOE_CHUNK_SCOPES = (ENV, ACT, ACT_LAYERS, ACT_GDN, ACT_MOE, ACT_MOE_ROUTE,
                     ACT_MOE_EXPERTS, ACT_CACHE, ACT_HEAD, LAYERS, GDN, ATTENTION,
                     MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, HEADS, LOSS_VTRACE,
                     OPTIMIZER)
+
+MLA_CHUNK_SCOPES = (ENV, ACT, ACT_LAYERS, ACT_MLA_PROJECT, ACT_MLA_ATTEND,
+                    ACT_MOE, ACT_MOE_ROUTE, ACT_MOE_EXPERTS, ACT_CACHE, ACT_HEAD,
+                    LAYERS, MLA_PROJECT, MLA_ATTEND, DENSE, MOE_ROUTE,
+                    MOE_EXPERTS, MOE_SHARED, MTP, *MLA_MTP.values(), HEADS,
+                    LOSS_VTRACE, OPTIMIZER)
 
 # -- host spans of the fused loops (runtime/launch.py) ---------------------
 STEP_READ = "anakin/step_read"  # int(state.train.step) at the loop head

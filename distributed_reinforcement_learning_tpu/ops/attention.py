@@ -60,7 +60,8 @@ def dense_attention(
 ) -> jax.Array:
     """Plain softmax(QKᵀ/√d)V — the golden reference the blockwise and
     ring paths are tested against, and the fast path for short sequences
-    where one fused XLA softmax beats any blocking."""
+    where one fused XLA softmax beats any blocking. `v` may have a
+    width of its own."""
     dim = q.shape[-1]
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (dim**-0.5)
     q_pos = q_offset + jnp.arange(q.shape[1])
@@ -76,7 +77,7 @@ def dense_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
 
 
-def attention_block_init(q: jax.Array):
+def attention_block_init(q: jax.Array, v_dim: int | None = None):
     """(m, l, o) accumulator for online-softmax over KV blocks.
 
     m: running row max of logits `[B, H, Tq]` (f32); l: running softmax
@@ -87,7 +88,7 @@ def attention_block_init(q: jax.Array):
     b, t, h, _ = q.shape
     m = jnp.full((b, h, t), _MASK_VALUE, jnp.float32)
     l = jnp.zeros((b, h, t), jnp.float32)
-    o = jnp.zeros(q.shape, jnp.float32)
+    o = jnp.zeros(q.shape if v_dim is None else (*q.shape[:-1], v_dim), jnp.float32)
     return m, l, o
 
 
@@ -153,7 +154,8 @@ def causal_attention(
     block — VMEM holds only per-block operands, so T is HBM-bound —
     else to plain dense softmax for short sequences or the blockwise
     online-softmax path for long ones. All paths share the same
-    numerics contract (validated against dense in tests).
+    numerics contract (validated against dense in tests). `v` may have
+    a width of its own (`[B, T, H, Dv]`, the output's), on every path.
     """
     from distributed_reinforcement_learning_tpu.ops.pallas import resolve_backend
     from distributed_reinforcement_learning_tpu.ops.pallas.attention import flash_blocks
@@ -170,14 +172,14 @@ def causal_attention(
         zeros = jnp.zeros((b, t), jnp.int32)
         qs = zeros if q_seg is None else q_seg.astype(jnp.int32)
         ks = zeros if k_seg is None else k_seg.astype(jnp.int32)
-        flat = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+        flat = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, x.shape[-1])
         seg_flat = lambda s: jnp.repeat(s, h, axis=0)
         out = flash_attention_bhtd(
             flat(q), flat(k), flat(v), seg_flat(qs), seg_flat(ks),
             block_q=min(block, 128), block_kv=min(block, 128),
             interpret=(resolved == "pallas_interpret"),
         )
-        return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+        return out.reshape(b, h, t, v.shape[-1]).transpose(0, 2, 1, 3)
     if t <= 1024:
         return dense_attention(q, k, v, causal=True, q_seg=q_seg, k_seg=k_seg)
     return blockwise_attention(
@@ -236,5 +238,5 @@ def blockwise_attention(
         None if segb is None else segb.swapaxes(0, 1),
         jnp.arange(n_blocks),
     )
-    acc, _ = jax.lax.scan(step, attention_block_init(q), xs)
+    acc, _ = jax.lax.scan(step, attention_block_init(q, v.shape[-1]), xs)
     return attention_block_finish(acc, q.dtype)

@@ -5,6 +5,7 @@ part of the result that the experts it HOLDS give, for the (token,
 expert) pairs routed to them:
 
     p = softmax(x W_r) over all E;   I = the top_k largest;   w_i = p_i / sum_{j in I} p_j
+    (or, `scoring="sigmoid"`: s = sigmoid(x W_r), I = the top_k of s + b, w_i = c s_i / sum_{j in I} s_j)
     out(x) = sum_{i in I, first <= i < first + held} w_i E_i(x),
     E(x) = W_d (silu(W_g x) * W_u x)
 
@@ -34,15 +35,36 @@ import jax.numpy as jnp
 F32 = jnp.float32
 
 
-def route(x: jax.Array, w_router: jax.Array, top_k: int):
+def route(x: jax.Array, w_router: jax.Array, top_k: int,
+          scoring: str = "softmax", select_bias: jax.Array | None = None,
+          scale: float = 1.0):
     """`x [N, D]`, `w_router [D, E]` -> (`probs [N, E]`, `chosen [N,
     top_k]` int32 expert ids in order of decreasing probability, `weight
-    [N, top_k]` renormalised over the chosen), float32."""
+    [N, top_k]` renormalised over the chosen), float32.
+
+    `scoring="sigmoid"` (the auxiliary-loss-free router of DeepSeek-V3,
+    arXiv:2412.19437 section 2.1.2): `probs` are the sigmoid scores of
+    ALL experts, the set is CHOSEN by score + `select_bias [E]` (a bias
+    no gradient reaches), the weights are the UNBIASED scores of the
+    chosen, renormalised and times `scale`; a fourth result, `load [E]`
+    int32, counts the tokens that chose each expert (what moves the bias
+    after the step)."""
     logits = jnp.dot(x.astype(F32), w_router.astype(F32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top, chosen = jax.lax.top_k(probs, top_k)
-    return probs, chosen.astype(jnp.int32), top / jnp.sum(top, -1, keepdims=True)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        top, chosen = jax.lax.top_k(probs, top_k)
+        return probs, chosen.astype(jnp.int32), top / jnp.sum(top, -1, keepdims=True)
+    if scoring != "sigmoid":
+        raise ValueError(f"unknown scoring {scoring!r}: softmax or sigmoid")
+    scores = jax.nn.sigmoid(logits)
+    _, chosen = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(select_bias.astype(F32)), top_k)
+    top = jnp.take_along_axis(scores, chosen, axis=-1)
+    load = jnp.sum(chosen[..., None] == jnp.arange(scores.shape[-1]),
+                   axis=(0, 1), dtype=jnp.int32)
+    return (scores, chosen.astype(jnp.int32),
+            scale * top / (jnp.sum(top, -1, keepdims=True) + 1e-20), load)
 
 
 def held_pairs(chosen: jax.Array, first_expert: int, held: int):

@@ -201,8 +201,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _qkv_specs(d: int, bq: int, bkv: int):
-    """3-D-grid (b, i_q, j_kv) block specs: q-indexed, kv-indexed rows.
+def _qkv_specs(d: int, bq: int, bkv: int, dv: int):
+    """3-D-grid (b, i_q, j_kv) block specs: q-indexed, kv-indexed rows
+    at q/k's width `d`, then the output (and its cotangent) and the
+    value rows at the value's width `dv`.
 
     The kv index is CLAMPED to the last causally-visible block for the
     current q block: past it the index map repeats the same block, which
@@ -220,25 +222,29 @@ def _qkv_specs(d: int, bq: int, bkv: int):
         (1, bkv, d), lambda b, i, j: (b, jcap(i, j), 0), memory_space=pltpu.VMEM)
     krow3 = pl.BlockSpec(
         (1, bkv, 1), lambda b, i, j: (b, jcap(i, j), 0), memory_space=pltpu.VMEM)
-    return q3, qrow3, kv3, krow3
+    o3 = pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM)
+    v3 = pl.BlockSpec(
+        (1, bkv, dv), lambda b, i, j: (b, jcap(i, j), 0), memory_space=pltpu.VMEM)
+    return q3, qrow3, kv3, krow3, o3, v3
 
 
 def _fwd_call(q, k, v, qs, ks, bq, bkv, interpret):
     bh, t, d = q.shape
-    q3, qrow3, kv3, krow3 = _qkv_specs(d, bq, bkv)
+    dv = v.shape[2]
+    q3, qrow3, kv3, krow3, o3, v3 = _qkv_specs(d, bq, bkv, dv)
     return pl.pallas_call(
         _fwd_kernel,
         grid=(bh, t // bq, t // bkv),
-        in_specs=[q3, kv3, kv3, qrow3, krow3],
-        out_specs=[q3, qrow3],
+        in_specs=[q3, kv3, v3, qrow3, krow3],
+        out_specs=[o3, qrow3],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v, qs, ks)
@@ -246,11 +252,12 @@ def _fwd_call(q, k, v, qs, ks, bq, bkv, interpret):
 
 def _bwd_call(q, k, v, qs, ks, do, lse, delta, bq, bkv, interpret):
     bh, t, d = q.shape
-    q3, qrow3, kv3, krow3 = _qkv_specs(d, bq, bkv)
+    dv = v.shape[2]
+    q3, qrow3, kv3, krow3, o3, v3 = _qkv_specs(d, bq, bkv, dv)
     dq = pl.pallas_call(
         _dq_kernel,
         grid=(bh, t // bq, t // bkv),
-        in_specs=[q3, kv3, kv3, qrow3, krow3, q3, qrow3, qrow3],
+        in_specs=[q3, kv3, v3, qrow3, krow3, o3, qrow3, qrow3],
         out_specs=[q3],
         out_shape=[jax.ShapeDtypeStruct((bh, t, d), q.dtype)],
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
@@ -264,27 +271,30 @@ def _bwd_call(q, k, v, qs, ks, do, lse, delta, bq, bkv, interpret):
         return jnp.maximum(i, (j * bkv) // bq)
 
     kv3 = pl.BlockSpec((1, bkv, d), lambda b, j, i: (b, j, 0), memory_space=pltpu.VMEM)
+    v3 = pl.BlockSpec((1, bkv, dv), lambda b, j, i: (b, j, 0), memory_space=pltpu.VMEM)
     krow3 = pl.BlockSpec((1, bkv, 1), lambda b, j, i: (b, j, 0), memory_space=pltpu.VMEM)
     q3 = pl.BlockSpec(
         (1, bq, d), lambda b, j, i: (b, icap(j, i), 0), memory_space=pltpu.VMEM)
+    o3 = pl.BlockSpec(
+        (1, bq, dv), lambda b, j, i: (b, icap(j, i), 0), memory_space=pltpu.VMEM)
     qrow3 = pl.BlockSpec(
         (1, bq, 1), lambda b, j, i: (b, icap(j, i), 0), memory_space=pltpu.VMEM)
-    dk, dv = pl.pallas_call(
+    dk, dv_ = pl.pallas_call(
         functools.partial(_dkv_kernel, block_q=bq),
         grid=(bh, t // bkv, t // bq),
-        in_specs=[q3, kv3, kv3, qrow3, krow3, q3, qrow3, qrow3],
-        out_specs=[kv3, kv3],
+        in_specs=[q3, kv3, v3, qrow3, krow3, o3, qrow3, qrow3],
+        out_specs=[kv3, v3],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, t, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, t, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bkv, d), jnp.float32),
-            pltpu.VMEM((bkv, d), jnp.float32),
+            pltpu.VMEM((bkv, dv), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v, qs, ks, do, lse, delta)
-    return dq, dk, dv
+    return dq, dk, dv_
 
 
 @functools.cache
@@ -335,7 +345,9 @@ def flash_attention_bhtd(
     """Causal flash attention on `[BH, T, D]` with `[BH, T]` segment ids.
 
     T must divide by both block sizes (choose blocks via
-    `flash_blocks`); differentiable via the fused dq/dkv kernels.
+    `flash_blocks`); differentiable via the fused dq/dkv kernels. `v`
+    may have a width of its own (`[BH, T, Dv]`: the output's); the
+    scores are scaled by q/k's `D ** -0.5`.
     """
     bh, t, d = q.shape
     if t % block_q or t % block_kv:

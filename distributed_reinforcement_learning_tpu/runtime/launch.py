@@ -573,7 +573,7 @@ def train_anakin_tokens(config_path: str, section: str, num_updates: int,
 
     agent = _token_agent(agent_cfg)  # at the end of this file; refuses the rest
     if agent is None:
-        raise ValueError("anakin-tokens mode runs the looplm, hybridlm and moelm families")
+        raise ValueError("anakin-tokens mode runs the looplm, hybridlm, moelm and mlalm families")
     env = make_jittable_env(
         rt.envs[0], vocab=agent_cfg.vocab_size,
         episode_len=agent_cfg.trajectory, distance=agent_cfg.recall_distance)
@@ -614,18 +614,20 @@ def train_anakin_tokens(config_path: str, section: str, num_updates: int,
 
 
 def _token_agent(agent_cfg):
-    """The token-level agent of a `looplm`, `hybridlm` or `moelm`
+    """The token-level agent of a `looplm`, `hybridlm`, `moelm` or `mlalm`
     section's configuration, None for any other family's."""
     from distributed_reinforcement_learning_tpu.agents.hybridlm import (
         HybridLMAgent, HybridLMConfig)
     from distributed_reinforcement_learning_tpu.agents.looplm import (
         LoopLMAgent, LoopLMConfig)
+    from distributed_reinforcement_learning_tpu.agents.mlalm import (
+        MLALMAgent, MLALMConfig)
     from distributed_reinforcement_learning_tpu.agents.moelm import (
         MoELMAgent, MoELMConfig)
 
     for config, agent in ((LoopLMConfig, LoopLMAgent),
                           (HybridLMConfig, HybridLMAgent),
-                          (MoELMConfig, MoELMAgent)):
+                          (MoELMConfig, MoELMAgent), (MLALMConfig, MLALMAgent)):
         if isinstance(agent_cfg, config):
             return agent(agent_cfg)
     return None

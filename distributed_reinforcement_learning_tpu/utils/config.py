@@ -365,6 +365,62 @@ def load_config(path: str | Path, section: str):
             dtype=_compute_dtype(d, "bfloat16"),
             init_std=d.get("initializer_range", 0.02),
         )
+    elif algorithm == "mlalm":
+        from distributed_reinforcement_learning_tpu.agents.mlalm import MLALMConfig
+
+        # As `moelm`: the source's own keys, none of them guessed, and what
+        # the family's config can say and this program does not compute
+        # refused by name. `n_routed_experts` is the chip's share of a
+        # layer's `router_width` experts, from `first_expert` on.
+        for key, only in (("scoring_func", "sigmoid"), ("topk_method", "noaux_tc"),
+                          ("n_group", 1), ("topk_group", 1), ("moe_layer_freq", 1),
+                          ("norm_topk_prob", True), ("rope_interleave", True),
+                          ("rope_scaling", None), ("attention_bias", False),
+                          ("tie_word_embeddings", False), ("hidden_act", "silu"),
+                          ("num_nextn_predict_layers", 1), ("ep_size", 1)):
+            if d.get(key, only) != only:
+                raise ValueError(f"{key} {d[key]!r}: only {only!r} is computed")
+        if d["qk_head_dim"] != d["qk_nope_head_dim"] + d["qk_rope_head_dim"]:
+            raise ValueError("qk_head_dim is not qk_nope_head_dim + qk_rope_head_dim")
+        if d["num_key_value_heads"] != d["num_attention_heads"]:
+            raise ValueError("latent attention rebuilds a key and a value for "
+                             "every query head: num_key_value_heads differs")
+        agent_cfg = MLALMConfig(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            num_hidden_layers=d["num_hidden_layers"],
+            first_k_dense_replace=d["first_k_dense_replace"],
+            num_attention_heads=d["num_attention_heads"],
+            q_lora_rank=d["q_lora_rank"],
+            kv_lora_rank=d["kv_lora_rank"],
+            qk_nope_head_dim=d["qk_nope_head_dim"],
+            qk_rope_head_dim=d["qk_rope_head_dim"],
+            v_head_dim=d["v_head_dim"],
+            rope_theta=d["rope_theta"],
+            intermediate_size=d["intermediate_size"],
+            n_routed_experts=d["n_routed_experts"],
+            router_width=d["router_width"],
+            first_expert=d["first_expert"],
+            num_experts_per_tok=d["num_experts_per_tok"],
+            moe_intermediate_size=d["moe_intermediate_size"],
+            n_shared_experts=d["n_shared_experts"],
+            routed_scaling_factor=d["routed_scaling_factor"],
+            rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+            bias_update_speed=d.get("bias_update_speed", 1e-3),
+            mtp_loss_coef=d.get("mtp_loss_coef", 0.3),
+            trajectory=d.get("trajectory", 2048),
+            recall_distance=d.get("recall_distance", 8),
+            discount_factor=d.get("discount_factor", 0.99),
+            baseline_loss_coef=d.get("baseline_loss_coef", 1.0),
+            entropy_coef=d.get("entropy_coef", 0.05),
+            gradient_clip_norm=d.get("gradient_clip_norm", 40.0),
+            reward_clipping=d.get("reward_clipping", "abs_one"),
+            start_learning_rate=d.get("start_learning_rate", 1e-5),
+            end_learning_rate=d.get("end_learning_rate", 0.0),
+            learning_frame=int(d.get("learning_frame", 1e9)),
+            dtype=_compute_dtype(d, "bfloat16"),
+            init_std=d.get("initializer_range", 0.02),
+        )
     else:
         raise ValueError(f"unknown algorithm {algorithm!r}")
 

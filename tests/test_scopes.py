@@ -240,6 +240,50 @@ def test_moe_backward_stack_keeps_its_names(moe_chunk_names, name):
                for n in moe_chunk_names)
 
 
+@pytest.fixture(scope="module")
+def mla_chunk_names():
+    import jax.numpy as jnp
+
+    from distributed_reinforcement_learning_tpu.agents.mlalm import (
+        MLALMAgent, MLALMConfig)
+    from distributed_reinforcement_learning_tpu.envs.token_recall_jax import TokenRecall
+    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import AnakinTokens
+
+    cfg = MLALMConfig(
+        vocab_size=64, hidden_size=32, num_hidden_layers=3, num_attention_heads=4,
+        q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+        v_head_dim=8, intermediate_size=48, n_routed_experts=4, router_width=16,
+        first_expert=4, num_experts_per_tok=3, moe_intermediate_size=16,
+        trajectory=16, dtype=jnp.float32, head_block=16, row_block=2)
+    anakin = AnakinTokens(MLALMAgent(cfg), 4, TokenRecall(64, 16))
+    return _op_names(anakin.train_chunk, anakin.init(jax.random.PRNGKey(0)), 1)
+
+
+@pytest.mark.parametrize("name", scopes.MLA_CHUNK_SCOPES)
+def test_mla_chunk_carries_scope(mla_chunk_names, name):
+    """The names `perfbench/layer_metrics/mlalm_*` read (ISSUE 40)."""
+    assert any(name in n for n in mla_chunk_names), name
+
+
+@pytest.mark.parametrize("name", [scopes.LAYERS, scopes.MLA_PROJECT,
+                                  scopes.MLA_ATTEND, scopes.DENSE, scopes.MOE_ROUTE,
+                                  scopes.MOE_EXPERTS, scopes.MOE_SHARED, scopes.MTP,
+                                  scopes.MLA_MTP["attend"], scopes.MLA_MTP["experts"]])
+def test_mla_backward_stack_keeps_its_names(mla_chunk_names, name):
+    """The rematerialised blocks are entered again under the transpose,
+    the prediction module's under its own names."""
+    assert any(f"transpose(jvp({scopes.LOSS}))" in n and name in n
+               for n in mla_chunk_names)
+
+
+def test_the_prediction_modules_layer_is_not_named_for_the_stack(mla_chunk_names):
+    """Its ops end under `learn/loss/mtp/...`: a reader of
+    `learn/loss/layers` does not count them."""
+    module = [n for n in mla_chunk_names if scopes.MLA_MTP["attend"] in n]
+    assert module and not any(scopes.MLA_ATTEND in n for n in module)
+    assert not any(scopes.ACT in n and scopes.MTP in n for n in mla_chunk_names)
+
+
 def test_r2d2_backward_recurrence_keeps_the_unroll_name(r2d2_chunk_names):
     """The inner scope is entered again inside the transposed outer one:
     `transpose(jvp(learn/loss))/.../learn/loss/unroll/...`."""

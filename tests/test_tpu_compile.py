@@ -5,7 +5,7 @@ described, not opened (`/opt/skills/guides/on-chip-measurement`, section
 2). That catches what interpret mode cannot — a slice not aligned to the
 tiling, a kernel over its VMEM budget, a program Mosaic refuses — at the
 shapes the main path really runs (`config.json` sections `impala`,
-`apex`, `r2d2_pixel`, `r2d2_atari`, `ouro_looplm`, `granite_hybrid`, `qwen3_next`; the Anakin chunk
+`apex`, `r2d2_pixel`, `r2d2_atari`, `ouro_looplm`, `granite_hybrid`, `qwen3_next`, `joyai_flash`; the Anakin chunk
 `chip_smoke.py` drives), and
 costs no chip time. It also shows what the compiler DID with a program:
 which layout copies and which collectives it put in (the fused IMPALA
@@ -475,6 +475,78 @@ def test_qwen3_next_chunk_fits_and_holds_its_six_kernels(chip, kernels_as_on_chi
     assert not re.findall(r"= f32\[32,32,128,128\]\S* copy\(", text)
     # no array with the router's width AND a capacity beside the tokens
     assert not re.findall(r"\[4096,512,\d+\]|\[32768,512,\d+\]", text)
+
+
+# (batch*heads, T, q/k width, value width): the `joyai_flash` learner's
+# row block (2 rows x 32 heads, 192 | 128, no padding), and today's call.
+@pytest.mark.parametrize("BH,T,D,DV", [(64, 2048, 192, 128), (64, 512, 64, 64)])
+def test_flash_attention_with_its_own_value_width_compiles(chip, BH, T, D, DV):
+    def loss(q, k, v, seg):
+        out = flash_attention_bhtd(q, k, v, seg, seg, block_q=128, block_kv=128)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    qk = jax.ShapeDtypeStruct((BH, T, D), jnp.bfloat16, sharding=chip)
+    v = jax.ShapeDtypeStruct((BH, T, DV), jnp.bfloat16, sharding=chip)
+    seg = jax.ShapeDtypeStruct((BH, T), jnp.int32, sharding=chip)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        qk, qk, v, seg).compile()
+    assert _kernel_calls(compiled) == 3  # forward, dq, dkv
+    text = compiled.as_text()
+    assert f"bf16[{BH},{T},{DV}]" in text  # the output and dv at the value's width
+
+
+def test_joyai_flash_chunk_fits_and_holds_its_fourteen_kernels(chip,
+                                                              kernels_as_on_chip):
+    """The fused token chunk at the `joyai_flash` section's sizes (16 envs x
+    2,048 tokens; latent attention in a dense layer, four expert layers
+    and the prediction module at 2048 wide, a 256-way router over 16 held
+    experts; chunk of 1): it compiles for a described v5e, the donated
+    state (680.4 M parameters + their second moments, 8 B each) is
+    aliased whole, and arguments + scratch stay under the chip's
+    `bytes_limit` by `memory_analysis` (16.25 GB at a row block of 2,
+    which reads about 1.2 times the chip's own). Fourteen Mosaic kernels
+    in the LOWERED chunk, the configuration file's count: flash attention
+    with q/k of 192 and v of 128 in the learner's three runs of layers
+    (forward, rematerialised forward, dq, dkv each) and V-trace's two
+    views. The cache is the latent's: no array of expanded keys or values
+    a layer (`[16, 2048, 32, 192]`) in any decode body."""
+    import json
+
+    from distributed_reinforcement_learning_tpu.agents.mlalm import MLALMAgent
+    from distributed_reinforcement_learning_tpu.envs.registry import (
+        make_jittable_env)
+    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import (
+        AnakinTokens)
+
+    cfg, rt = load_config(CONFIG, "joyai_flash")
+    env = make_jittable_env(rt.envs[0], vocab=cfg.vocab_size,
+                            episode_len=cfg.trajectory,
+                            distance=cfg.recall_distance)
+    anakin = AnakinTokens(MLALMAgent(cfg), rt.num_actors * rt.envs_per_actor, env)
+    state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+    lowered = anakin.train_chunk.lower(_on(chip, state), 1)
+    with open(os.path.join(os.path.dirname(CONFIG), "perfbench", "configs",
+                           "joyai_flash.json")) as f:
+        named = json.load(f)["kernels"]["tpu_custom_call"]
+    assert len(re.findall("tpu_custom_call", lowered.as_text())) == named == 14
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    params = 680_443_137
+    assert mem.alias_size_in_bytes == mem.argument_size_in_bytes > 8 * params
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert held < 16.5e9 < 16_909_336_064, held
+    facts = anakin.static_facts
+    assert facts["layer_order"] == ("dense",) + ("moe",) * 4
+    assert facts["latent_cache_bytes"] == 16 * 2048 * 5 * 576 * 2
+    assert (facts["cache_bytes_per_token"],
+            facts["expanded_cache_bytes_per_token"]) == (5_760, 102_400)
+    assert (facts["experts_held"], facts["router_width"]) == (16, 256)
+    assert facts["decode_spans"] == tuple(range(256, 2049, 256))
+    text = compiled.as_text()
+    assert not re.findall(r"bf16\[16,\d+,32,(192|128)\]", text)
+    # no array with the router's width AND a capacity beside the tokens
+    assert not re.findall(r"\[4096,256,\d+\]|\[32768,256,\d+\]", text)
 
 
 def test_breakout_step_keeps_no_raster_and_one_luma(chip):

@@ -7,14 +7,15 @@ sequence parallelism, MoE, pipelining, remat all apply).
 
     python train_ximpala.py --section ximpala --updates 300
 
-`--mode anakin` is the fused on-device loop of the `looplm`, `hybridlm`
-and `moelm` sections (runtime/anakin_tokens.py): a language model as the
+`--mode anakin` is the fused on-device loop of the `looplm`, `hybridlm`,
+`moelm` and `mlalm` sections (runtime/anakin_tokens.py): a language model as the
 policy of a token-level IMPALA, generation by decode through its
 act-time state and the learn step in one compiled chunk.
 
     python train_ximpala.py --mode anakin --section ouro_looplm --updates 8
     python train_ximpala.py --mode anakin --section granite_hybrid --updates 2 --anakin_chunk 1
     python train_ximpala.py --mode anakin --section qwen3_next --updates 2 --anakin_chunk 1
+    python train_ximpala.py --mode anakin --section joyai_flash --updates 2 --anakin_chunk 1
 """
 
 from __future__ import annotations
