@@ -151,9 +151,10 @@ def causal_attention(
 
     `auto` resolves to the fused Pallas flash kernels on TPU
     (`ops/pallas/attention.py`) when T divides by a >=8 power-of-two
-    block — VMEM holds only per-block operands, so T is HBM-bound —
-    else to plain dense softmax for short sequences or the blockwise
-    online-softmax path for long ones. All paths share the same
+    block — VMEM holds only per-block operands, so T is HBM-bound, and
+    the kernels take their tile from T, the widths and the dtype
+    (`flash_blocks`) — else to plain dense softmax for short sequences
+    or the blockwise online-softmax path for long ones. All paths share the same
     numerics contract (validated against dense in tests). `v` may have
     a width of its own (`[B, T, H, Dv]`, the output's), on every path.
     """
@@ -164,8 +165,8 @@ def causal_attention(
         raise ValueError("q_seg and k_seg must be provided together")
     b, t, h, d = q.shape
     resolved = resolve_backend(backend)
-    block = flash_blocks(t)
-    if resolved in ("pallas", "pallas_interpret") and block > 0:
+    if (resolved in ("pallas", "pallas_interpret")
+            and flash_blocks(t, d, v.shape[-1], q.dtype.itemsize)[0]):
         from distributed_reinforcement_learning_tpu.ops.pallas.attention import (
             flash_attention_bhtd)
 
@@ -176,7 +177,6 @@ def causal_attention(
         seg_flat = lambda s: jnp.repeat(s, h, axis=0)
         out = flash_attention_bhtd(
             flat(q), flat(k), flat(v), seg_flat(qs), seg_flat(ks),
-            block_q=min(block, 128), block_kv=min(block, 128),
             interpret=(resolved == "pallas_interpret"),
         )
         return out.reshape(b, h, t, v.shape[-1]).transpose(0, 2, 1, 3)

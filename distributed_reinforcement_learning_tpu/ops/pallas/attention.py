@@ -11,12 +11,31 @@ Layout: inputs are flattened to `[BH, T, D]` (batch*heads leading); the
 grid is (BH, q-blocks, kv-blocks) with ONLY one block of each operand
 VMEM-resident per step (online-softmax / gradient accumulators live in
 scratch across the innermost kv/q walk), so T is bounded by HBM, not by
-the 16MB scoped VMEM — a whole-K/V-resident design capped out at T~8k.
-Per-row vectors (segment ids, logsumexp, delta) travel as `[BH, T, 1]`
-so their blocks satisfy the TPU (8, 128)-tiling rule on the last two
-dims. Segment ids confine attention within episodes exactly like the
+VMEM: a whole-K/V-resident design capped out at T~8k.
+Per-query-row vectors (segment ids, logsumexp, delta) travel as
+`[BH, T, 1]`, the keys' segment ids as `[BH, 1, T]`: a block of either
+satisfies the TPU (8, 128)-tiling rule on the last two dims, and the
+`[bq, 1] == [1, bkv]` compare needs no relayout in the kernel (turning
+a `[bkv, 1]` column into a row cost a step more than its products).
+Segment ids confine attention within episodes exactly like the
 XLA paths; "no segments" is the all-zeros id vector (same segment
 everywhere), so one kernel serves both cases.
+
+The tile follows the input (`flash_blocks`): a side is the largest power
+of two up to 512 that divides T, so a row of 2,048 is a grid of 4 x 4
+steps of `[512, D] x [D, 512]` products where a fixed 128 x 128 tile made
+16 x 16 steps of one 128-cube each (6.5 % of the matrix unit's peak in
+the cell that runs it, 34 % since: PERF.md section 6, PR 41); a
+row of 128 keeps its single 128 x 128 tile by the same rule. The rule
+reckons the working set of the largest kernel (double-buffered blocks at
+their lane-padded widths, the `[bq, bkv]` float32 scores / p / dp / ds,
+the accumulators) and halves the longer side while it overflows the
+scoped VMEM a kernel gets (`_SCOPED_VMEM_BYTES`, the compiler's default
+16 MB: it holds 512 x 512 at the widths the cells run, not at every
+width). Every product takes its operands in the dtype the caller sent
+and accumulates in float32: p and ds are computed in float32 (scores,
+max, exp, sums, `dp - delta`) and cast AT the product, as every other
+matmul operand of a bfloat16 model is; float32 callers lose nothing.
 
 Backward follows the standard flash decomposition: the forward saves
 only (out, logsumexp); dq and (dk, dv) are two kernels that recompute
@@ -39,8 +58,12 @@ from jax.experimental.pallas import tpu as pltpu
 from distributed_reinforcement_learning_tpu.ops.attention import _MASK_VALUE as _NEG
 from distributed_reinforcement_learning_tpu.ops.pallas import batch_partitioned
 
-_BLOCK_Q = 128
-_BLOCK_KV = 128
+# The largest tile a side (the sweep of PERF.md section 6, PR 41) and the
+# scoped VMEM a kernel may use: the compiler's default, which the calls do
+# not raise (asking for 32 MB moved ops nobody touched in the same program,
+# decode up and the update's tail down: PERF.md section 6, PR 41).
+_MAX_BLOCK = 512
+_SCOPED_VMEM_BYTES = 16 << 20
 
 
 def _pos(start, rows, cols, axis):
@@ -83,7 +106,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, o_ref, lse_ref,
         qs = qs_ref[0]
         k_blk = k_ref[0]
         v_blk = v_ref[0]
-        ks_row = ks_ref[0].reshape(1, bkv)
+        ks_row = ks_ref[0]
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -96,7 +119,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, o_ref, lse_ref,
         m_scr[:] = m_new
         l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v_blk.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(jk == pl.num_programs(2) - 1)
@@ -126,12 +149,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
     def _():
         q = q_ref[0]
         qs = qs_ref[0]
-        do = do_ref[0].astype(jnp.float32)
+        do = do_ref[0]
         lse = lse_ref[0]
         delta = delta_ref[0]
         k_blk = k_ref[0]
         v_blk = v_ref[0]
-        ks_row = ks_ref[0].reshape(1, bkv)
+        ks_row = ks_ref[0]
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -142,7 +165,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * scale
         dq_scr[:] += jax.lax.dot_general(
-            ds, k_blk.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(jk == pl.num_programs(2) - 1)
@@ -173,9 +196,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
     def _():
         k_blk = k_ref[0]
         v_blk = v_ref[0]
-        ks_row = ks_ref[0].reshape(1, bkv)
+        ks_row = ks_ref[0]
         q_i = q_ref[0]
-        do_i = do_ref[0].astype(jnp.float32)
+        do_i = do_ref[0]
         lse_i = lse_ref[0]
         delta_i = delta_ref[0]
         qs_i = qs_ref[0]
@@ -185,14 +208,14 @@ def _dkv_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
         msk = _block_mask(iq * block_q, jk * bkv, block_q, bkv, qs_i, ks_row)
         p = jnp.where(msk, jnp.exp(s - lse_i), 0.0)
         dv_scr[:] += jax.lax.dot_general(
-            p, do_i, (((0,), (0,)), ((), ())),
+            p.astype(do_i.dtype), do_i, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
             do_i, v_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         ds = p * (dp - delta_i) * scale
         dk_scr[:] += jax.lax.dot_general(
-            ds, q_i.astype(jnp.float32), (((0,), (0,)), ((), ())),
+            ds.astype(q_i.dtype), q_i, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(iq == n_q - 1)
@@ -221,7 +244,7 @@ def _qkv_specs(d: int, bq: int, bkv: int, dv: int):
     kv3 = pl.BlockSpec(
         (1, bkv, d), lambda b, i, j: (b, jcap(i, j), 0), memory_space=pltpu.VMEM)
     krow3 = pl.BlockSpec(
-        (1, bkv, 1), lambda b, i, j: (b, jcap(i, j), 0), memory_space=pltpu.VMEM)
+        (1, 1, bkv), lambda b, i, j: (b, 0, jcap(i, j)), memory_space=pltpu.VMEM)
     o3 = pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM)
     v3 = pl.BlockSpec(
         (1, bkv, dv), lambda b, i, j: (b, jcap(i, j), 0), memory_space=pltpu.VMEM)
@@ -272,7 +295,7 @@ def _bwd_call(q, k, v, qs, ks, do, lse, delta, bq, bkv, interpret):
 
     kv3 = pl.BlockSpec((1, bkv, d), lambda b, j, i: (b, j, 0), memory_space=pltpu.VMEM)
     v3 = pl.BlockSpec((1, bkv, dv), lambda b, j, i: (b, j, 0), memory_space=pltpu.VMEM)
-    krow3 = pl.BlockSpec((1, bkv, 1), lambda b, j, i: (b, j, 0), memory_space=pltpu.VMEM)
+    krow3 = pl.BlockSpec((1, 1, bkv), lambda b, j, i: (b, 0, j), memory_space=pltpu.VMEM)
     q3 = pl.BlockSpec(
         (1, bq, d), lambda b, j, i: (b, icap(j, i), 0), memory_space=pltpu.VMEM)
     o3 = pl.BlockSpec(
@@ -338,31 +361,72 @@ def flash_attention_bhtd(
     v: jax.Array,
     q_seg: jax.Array,
     k_seg: jax.Array,
-    block_q: int = _BLOCK_Q,
-    block_kv: int = _BLOCK_KV,
+    block_q: int | None = None,
+    block_kv: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Causal flash attention on `[BH, T, D]` with `[BH, T]` segment ids.
 
-    T must divide by both block sizes (choose blocks via
-    `flash_blocks`); differentiable via the fused dq/dkv kernels. `v`
-    may have a width of its own (`[BH, T, Dv]`: the output's); the
-    scores are scaled by q/k's `D ** -0.5`.
+    The tile is `flash_blocks`' for these shapes unless a test names its
+    own (T must divide by both sides); differentiable via the fused
+    dq/dkv kernels. `v` may have a width of its own (`[BH, T, Dv]`: the
+    output's); the scores are scaled by q/k's `D ** -0.5`. Every product
+    takes its operands in the dtype they arrive in and accumulates in
+    float32; the softmax is float32 throughout.
     """
     bh, t, d = q.shape
-    if t % block_q or t % block_kv:
+    if block_q is None or block_kv is None:
+        block_q, block_kv = flash_blocks(t, d, v.shape[2], q.dtype.itemsize)
+    if not block_q or t % block_q or t % block_kv:
         raise ValueError(f"T={t} not divisible by blocks ({block_q}, {block_kv})")
     f = _make_flash(block_q, block_kv, interpret)
     return f(q, k, v,
              q_seg.astype(jnp.int32).reshape(bh, t, 1),
-             k_seg.astype(jnp.int32).reshape(bh, t, 1))
+             k_seg.astype(jnp.int32).reshape(bh, 1, t))
 
 
-def flash_blocks(t: int, cap: int = _BLOCK_Q) -> int:
-    """Largest power-of-two block <= cap dividing t (>= 8), or 0 if none."""
-    b = cap
-    while b >= 8:
-        if t % b == 0:
-            return b
-        b //= 2
-    return 0
+def _lanes(width: int) -> int:
+    return -(-width // 128) * 128
+
+
+def flash_working_set_bytes(bq: int, bkv: int, d: int, dv: int, itemsize: int) -> int:
+    """VMEM a `(bq, bkv)` tile needs in the largest of the three kernels
+    (dkv): every operand and result block twice (the pipeline's double
+    buffer) at its lane-padded width, a `[bq, 1]` row vector as one
+    128-lane word a row and the `[1, bkv]` ids as eight sublanes, the
+    float32 accumulators, and the `[bq, bkv]` float32 scores, p, dp and ds
+    with the two operand-dtype copies the products take. An upper
+    reckoning: the compiler reuses what this counts apart."""
+    width = _lanes(d) + _lanes(dv)  # a row of q + do, of k + v, of dk + dv
+    blocks = 2 * ((bq + 2 * bkv) * width * itemsize
+                  + 3 * bq * 128 * 4 + 8 * _lanes(bkv) * 4)
+    accumulators = bkv * width * 4
+    scores = bq * bkv * (4 * 4 + 2 * itemsize)
+    return blocks + accumulators + scores
+
+
+def flash_blocks(t: int, d: int, dv: int, itemsize: int) -> tuple[int, int]:
+    """`(block_q, block_kv)` for a `[BH, t, d]` x `[BH, t, dv]` call.
+
+    A side is the largest power of two (>= 8) up to the sweep's best that
+    divides t; the kv side, which lies along the lanes of the scores and of
+    the keys' segment ids, is a multiple of 128 or the whole row. The longer
+    side is halved while the working set overflows a kernel's scoped VMEM.
+    `(0, 0)` if no such tile exists (the caller keeps to XLA)."""
+
+    def side(cap, least):
+        while cap >= least and t % cap:
+            cap //= 2
+        return cap if cap >= least else 0
+
+    bq, bkv = side(_MAX_BLOCK, 8), side(_MAX_BLOCK, 128) or t
+    if not bq:
+        return 0, 0
+    while flash_working_set_bytes(bq, bkv, d, dv, itemsize) > _SCOPED_VMEM_BYTES:
+        if bkv >= bq and bkv % 256 == 0:
+            bkv //= 2
+        elif bq > 8:
+            bq //= 2
+        else:
+            return 0, 0
+    return bq, bkv

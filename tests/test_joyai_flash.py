@@ -31,6 +31,7 @@ from distributed_reinforcement_learning_tpu.envs.token_recall_jax import TokenRe
 from distributed_reinforcement_learning_tpu.models import latent_moe_lm, looped_lm
 from distributed_reinforcement_learning_tpu.ops import (
     attention, expert_share, latent_attention)
+from distributed_reinforcement_learning_tpu.ops.pallas import attention as flash
 from distributed_reinforcement_learning_tpu.ops.pallas.attention import (
     flash_attention_bhtd)
 from distributed_reinforcement_learning_tpu.reference import joyai_flash as ref
@@ -241,6 +242,16 @@ def test_a_wrong_attention_is_seen(fault):
 # -- the flash kernels with a value width of their own -------------------------
 
 
+def _flash_bthd(q, k, v, seg, bq, bkv):
+    """The kernels (interpret mode) on `[B, T, H, D]` with `[B, T]` ids."""
+    b, t, h, _ = q.shape
+    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, x.shape[-1])
+    out = flash_attention_bhtd(flat(q), flat(k), flat(v), jnp.repeat(seg, h, 0),
+                               jnp.repeat(seg, h, 0), block_q=bq, block_kv=bkv,
+                               interpret=True)
+    return out.reshape(b, h, t, v.shape[-1]).transpose(0, 2, 1, 3)
+
+
 @pytest.mark.parametrize("d,dv", [(24, 16), (24, 8), (16, 16), (32, 8)])
 def test_flash_attention_with_its_own_value_width_matches_dense(d, dv):
     """Interpret mode: forward and all three gradients against the dense
@@ -251,14 +262,7 @@ def test_flash_attention_with_its_own_value_width_matches_dense(d, dv):
     f = lambda *s: jnp.asarray(r.normal(size=s), jnp.float32)
     q, k, v = f(b, t, h, d), f(b, t, h, d), f(b, t, h, dv)
     seg = jnp.asarray(np.cumsum(r.rand(b, t) < 0.1, axis=1), jnp.int32)
-    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, x.shape[-1])
-
-    def kernel(q, k, v):
-        out = flash_attention_bhtd(flat(q), flat(k), flat(v), jnp.repeat(seg, h, 0),
-                                   jnp.repeat(seg, h, 0), block_q=8, block_kv=8,
-                                   interpret=True)
-        return out.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
-
+    kernel = lambda q, k, v: _flash_bthd(q, k, v, seg, 8, 8)
     dense = lambda q, k, v: attention.dense_attention(
         q, k, v, causal=True, q_seg=seg, k_seg=seg)
     w = f(b, t, h, dv)
@@ -268,6 +272,86 @@ def test_flash_attention_with_its_own_value_width_matches_dense(d, dv):
         want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), argnums=(0, 1, 2))(q, k, v)
     for g, wnt in zip(got, want):
         assert g.shape == wnt.shape and _rel(g, wnt) < 1e-4
+
+
+# Rows of 64 in tiles that differ a side, both orders, and more than one a
+# side; episodes end inside a tile (5, 41) and on a tile's edge (32, 16).
+_SEG = np.zeros((2, 64), np.int32)
+_SEG[0, 5:] += 1
+_SEG[0, 32:] += 1
+_SEG[1, 16:] += 1
+_SEG[1, 41:] += 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("d,dv", [(192, 128), (32, 32)])
+@pytest.mark.parametrize("bq,bkv", [(16, 32), (32, 16), (16, 16)])
+def test_flash_tiles_match_dense_in_the_callers_dtype(bq, bkv, d, dv, dtype):
+    """Interpret mode: values and all three gradients against the dense
+    softmax. Float32 inputs agree to float32 rounding (no product rounds
+    what the caller sent); bfloat16 inputs agree with the float32 answer
+    as closely as `dense_attention` does with its own bfloat16 `probs`."""
+    r = np.random.RandomState(0)
+    b, t, h = 2, 64, 2
+    f = lambda *s: jnp.asarray(r.normal(size=s), jnp.float32).astype(dtype)
+    q, k, v, w = f(b, t, h, d), f(b, t, h, d), f(b, t, h, dv), f(b, t, h, dv)
+    seg = jnp.asarray(_SEG)
+    kernel = lambda q, k, v: _flash_bthd(q, k, v, seg, bq, bkv)
+    dense = lambda q, k, v: attention.dense_attention(
+        q, k, v, causal=True, q_seg=seg, k_seg=seg)
+
+    def both(fn, *x):
+        loss = lambda *a: jnp.sum((fn(*a) * w).astype(jnp.float32))
+        return (fn(*x), *jax.grad(loss, argnums=(0, 1, 2))(*x))
+
+    with jax.default_matmul_precision("highest"):
+        got = both(kernel, q, k, v)
+        same = both(dense, q, k, v)
+        exact = both(dense, *(x.astype(jnp.float32) for x in (q, k, v)))
+    for g, s, e, limit in zip(got, same, exact, (1e-5, 1e-4, 1e-4, 1e-4)):
+        assert g.shape == e.shape and g.dtype == dtype
+        if dtype == jnp.float32:
+            assert _rel(g, e) < limit
+        else:
+            assert _rel(g, e) < 1.5 * _rel(s, e)
+
+
+def test_the_tile_follows_t_the_widths_and_the_itemsize():
+    """The four token cells' learner shapes (PERF.md section 6, PR 41), a
+    row of one tile, a T that only 8 divides, none; and the working set the
+    rule reckons stays under a kernel's scoped VMEM."""
+    assert flash.flash_blocks(2048, 192, 128, 2) == (512, 512)   # joyai_flash
+    assert flash.flash_blocks(1024, 256, 256, 2) == (512, 512)   # qwen3_next
+    assert flash.flash_blocks(1024, 64, 64, 2) == (512, 512)     # granite_hybrid
+    assert flash.flash_blocks(128, 128, 128, 2) == (128, 128)    # ouro_looplm
+    assert flash.flash_blocks(32, 64, 64, 4) == (32, 32)
+    assert flash.flash_blocks(1000, 64, 64, 4) == (8, 1000)  # kv: 128s or the row
+    assert flash.flash_blocks(1001, 64, 64, 4) == (0, 0)
+    for t, d, dv, itemsize in [(2048, 192, 128, 2), (1024, 256, 256, 4),
+                               (4096, 512, 512, 4), (8192, 1024, 1024, 4)]:
+        bq, bkv = flash.flash_blocks(t, d, dv, itemsize)
+        assert bq >= 8 and t % bq == 0 and t % bkv == 0 and bkv % 128 == 0
+        assert flash.flash_working_set_bytes(bq, bkv, d, dv, itemsize) \
+            <= flash._SCOPED_VMEM_BYTES
+    # wide float32 rows: the longer side gives way first
+    assert flash.flash_blocks(8192, 1024, 1024, 4) != (512, 512)
+
+
+def test_the_kernel_microbenchmark_prints_one_line_a_tile():
+    import pathlib
+    import subprocess
+    import sys
+
+    script = pathlib.Path(__file__).parent.parent / "scripts" / "flash_kernel_bench.py"
+    done = subprocess.run(
+        [sys.executable, str(script), "--bh", "2", "--t", "32", "--d", "24",
+         "--dv", "16", "--dtype", "float32", "--block-q", "8", "16",
+         "--block-kv", "16", "--iters", "1", "--interpret"],
+        capture_output=True, text=True, timeout=300, check=True)
+    lines = [json.loads(l) for l in done.stdout.splitlines()]
+    assert [(l["block_q"], l["block_kv"]) for l in lines] == [(8, 16), (16, 16)]
+    assert all(l["platform"] == "cpu" and l["fwd_ms"] > 0 and l["fwd_bwd_ms"] > 0
+               and l["rule"] == [32, 32] for l in lines)
 
 
 @pytest.mark.parametrize("backend", ["reference", "pallas_interpret"])

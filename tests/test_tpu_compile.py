@@ -127,20 +127,24 @@ def test_lstm_kernel_fwd_bwd_compiles(chip, T, B, H):
     assert _kernel_calls(compiled) == 2  # forward + BPTT
 
 
-# (batch*heads, T, head_dim): a transformer 256 wide (4
-# heads, T=32), a long-context row, and a bf16 row.
-@pytest.mark.parametrize("BH,T,D,dtype", [
-    (128, 32, 64, jnp.float32),
-    (16, 2048, 64, jnp.bfloat16),
-    (64, 512, 64, jnp.bfloat16),
+# (batch*heads, T, head_dim, dtype, the rule's tile): a transformer 256 wide
+# (4 heads, T=32), a long-context row, a bf16 row, and the learner's rows of
+# the Qwen3-Next, granite and Ouro cells (q/k = v there), and a T that only
+# 8 divides (its kv side is the whole row: 128s or nothing along the lanes).
+@pytest.mark.parametrize("BH,T,D,dtype,tile", [
+    (128, 32, 64, jnp.float32, (32, 32)),
+    (16, 2048, 64, jnp.bfloat16, (512, 512)),
+    (64, 512, 64, jnp.bfloat16, (512, 512)),
+    (64, 1024, 256, jnp.bfloat16, (512, 512)),
+    (128, 1024, 64, jnp.bfloat16, (512, 512)),
+    (512, 128, 128, jnp.bfloat16, (128, 128)),
+    (8, 1000, 64, jnp.float32, (8, 1000)),
 ])
-def test_flash_attention_fwd_bwd_compiles(chip, BH, T, D, dtype):
-    block = flash_blocks(T)
-    assert block > 0
+def test_flash_attention_fwd_bwd_compiles(chip, BH, T, D, dtype, tile):
+    assert flash_blocks(T, D, D, jnp.dtype(dtype).itemsize) == tile
 
     def loss(q, k, v, seg):
-        out = flash_attention_bhtd(q, k, v, seg, seg, block_q=block,
-                                   block_kv=block)
+        out = flash_attention_bhtd(q, k, v, seg, seg)  # the rule's tile
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     qkv = jax.ShapeDtypeStruct((BH, T, D), dtype, sharding=chip)
@@ -477,12 +481,16 @@ def test_qwen3_next_chunk_fits_and_holds_its_six_kernels(chip, kernels_as_on_chi
     assert not re.findall(r"\[4096,512,\d+\]|\[32768,512,\d+\]", text)
 
 
-# (batch*heads, T, q/k width, value width): the `joyai_flash` learner's
-# row block (2 rows x 32 heads, 192 | 128, no padding), and today's call.
-@pytest.mark.parametrize("BH,T,D,DV", [(64, 2048, 192, 128), (64, 512, 64, 64)])
-def test_flash_attention_with_its_own_value_width_compiles(chip, BH, T, D, DV):
+# (batch*heads, T, q/k width, value width, the rule's tile): the
+# `joyai_flash` learner's row block (2 rows x 32 heads, 192 | 128, no
+# padding), and a row of equal widths.
+@pytest.mark.parametrize("BH,T,D,DV,tile", [(64, 2048, 192, 128, (512, 512)),
+                                            (64, 512, 64, 64, (512, 512))])
+def test_flash_attention_with_its_own_value_width_compiles(chip, BH, T, D, DV, tile):
+    assert flash_blocks(T, D, DV, 2) == tile
+
     def loss(q, k, v, seg):
-        out = flash_attention_bhtd(q, k, v, seg, seg, block_q=128, block_kv=128)
+        out = flash_attention_bhtd(q, k, v, seg, seg)  # the rule's tile
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     qk = jax.ShapeDtypeStruct((BH, T, D), jnp.bfloat16, sharding=chip)
