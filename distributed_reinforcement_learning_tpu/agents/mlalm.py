@@ -209,8 +209,8 @@ class MLALMAgent(LoopLMAgent):
     # -- the act-time state ------------------------------------------------
     def state_facts(self, num_rows: int) -> dict:
         """Bytes of the latent cache of `num_rows` rows and a token, the
-        order of the layers that hold it, and this chip's share of the
-        experts."""
+        order of the layers that hold it, this chip's share of the
+        experts, and the rows of a slab of the learner's sorted pairs."""
         state = jax.eval_shape(lambda: self.init_cache(num_rows))
         cfg = self.cfg
         size = sum(x.size * x.dtype.itemsize for x in state.cache)
@@ -223,7 +223,8 @@ class MLALMAgent(LoopLMAgent):
                 "layer_order": tuple(cfg.layer_types),
                 "experts_held": cfg.n_routed_experts,
                 "router_width": cfg.router_width,
-                "first_expert": cfg.first_expert}
+                "first_expert": cfg.first_expert,
+                "pair_slab_rows": self.model.pair_slab_rows(num_rows, cfg.trajectory)}
 
     def state_counters(self, cache: latent_moe_lm.LatentState) -> dict:
         """`act_routes`: the experts every decode step chose, which a

@@ -119,8 +119,8 @@ class MoELMAgent(LoopLMAgent):
 
     def state_facts(self, num_rows: int) -> dict:
         """Bytes of the act-time state of `num_rows` rows, by kind, the
-        order of the layers that hold it, and this chip's share of the
-        experts."""
+        order of the layers that hold it, this chip's share of the
+        experts, and the rows of a slab of the learner's sorted pairs."""
         state = jax.eval_shape(lambda: self.init_cache(num_rows))
         size = lambda part: sum(x.size * x.dtype.itemsize
                                 for x in jax.tree.leaves(part))
@@ -130,7 +130,8 @@ class MoELMAgent(LoopLMAgent):
                 "conv_state_bytes": size(state.conv),
                 "layer_order": tuple(cfg.layer_types),
                 "experts_held": cfg.num_experts, "router_width": cfg.router_width,
-                "first_expert": cfg.first_expert}
+                "first_expert": cfg.first_expert,
+                "pair_slab_rows": self.model.pair_slab_rows(num_rows, cfg.trajectory)}
 
     def state_counters(self, cache: moe_lm.MoEState) -> dict:
         """`state_norm_mean`: the mean over rows, layers and heads of the

@@ -212,13 +212,13 @@ class LatentMoELM:
         with jax.named_scope(scope["experts"]):
             routed, counters = expert_share.held_experts(
                 x, chosen, weight, lp["expert_wgu"], lp["expert_wd"],
-                self.first_expert, self.dtype)
+                self.first_expert, self.num_experts, self.dtype)
         with jax.named_scope(scope["shared"]):
             shared = self._swiglu(x, lp["shared_wgu"], lp["shared_wd"])
         picked = jnp.take_along_axis(scores, chosen, axis=-1)
         stats = jax.lax.stop_gradient({
-            "expert_pairs": counters["expert_pairs"],
-            "dropped_pairs": counters["dropped_pairs"], "router_load": load,
+            **{k: counters[k] for k in ("expert_pairs", "dropped_pairs", "pair_slabs")},
+            "pair_slabs_max": counters["pair_slabs"], "router_load": load,
             "score_sum": jnp.sum(scores)})
         return (self._residual(u, routed + shared),
                 (chosen.astype(jnp.int16), jax.lax.stop_gradient(picked)), stats)
@@ -262,7 +262,8 @@ class LatentMoELM:
             return h, None, {}
         n = stat["score_sum"].shape[0]
         return (h, tuple(x.reshape(n, b, t, self.top_k) for x in chosen),
-                {k: jnp.sum(v, axis=1) for k, v in stat.items()})  # over the blocks
+                {k: (jnp.max if k.endswith("_max") else jnp.sum)(v, axis=1)
+                 for k, v in stat.items()})  # over the blocks
 
     def trunk(self, p: dict, tokens: jax.Array, done: jax.Array):
         """`tokens, done [B, T]` -> (h_L `[1, B, T, D]` before the final
@@ -270,8 +271,8 @@ class LatentMoELM:
         expert layers' facts, every leaf with a leading layer axis:
         `routes`, `route_scores [layers, B, T, top_k]` (the experts every
         position chose and their unbiased scores), `router_load [layers,
-        E]`, `expert_pairs [layers, held]`, `dropped_pairs`, `score_sum
-        [layers]`: `counters` reduces them)."""
+        E]`, `expert_pairs [layers, held]`, `dropped_pairs`, `pair_slabs`,
+        `pair_slabs_max`, `score_sum [layers]`: `counters` reduces them)."""
         facts = []
         with jax.named_scope(scopes.LAYERS):
             h = p["embed"][tokens].astype(self.dtype)
@@ -307,7 +308,8 @@ class LatentMoELM:
         `merged`) facts over `tokens` positions."""
         pairs = facts["expert_pairs"].astype(F32)  # [layers, held]
         load = facts["router_load"].astype(F32)  # [layers, E]
-        layers = pairs.shape[0]
+        layers, b = facts["routes"].shape[:2]
+        calls = layers * (b // math.gcd(b, self.row_block))  # of `held_experts`
         over_mean = lambda x: jnp.max(
             jnp.max(x, -1) / jnp.maximum(jnp.mean(x, -1), 1e-9))
         return {
@@ -316,8 +318,16 @@ class LatentMoELM:
             "router_load_max_over_mean": over_mean(load),
             "experts_untouched": jnp.sum(pairs == 0).astype(F32),
             "dropped_pairs": jnp.sum(facts["dropped_pairs"]).astype(F32),
+            "pair_slabs_mean": jnp.sum(facts["pair_slabs"]) / calls,
+            "pair_slabs_max": jnp.max(facts["pair_slabs_max"]).astype(F32),
             "router_score_mean": jnp.sum(facts["score_sum"])
             / (layers * tokens * self.num_experts)}
+
+    def pair_slab_rows(self, b: int, t: int) -> int:
+        """Rows of a slab of the learner's sorted pairs (`expert_share.
+        slab_rows`) where a layer is applied to `[B, T]` a row block at a time."""
+        return expert_share.slab_rows(math.gcd(b, self.row_block) * t * self.top_k,
+                                      self.experts_held, self.num_experts)
 
     def token_stats(self, p: dict, h: jax.Array, actions: jax.Array) -> dict:
         """`HybridLM.token_stats` (float32 `logp` of the taken action,

@@ -220,7 +220,7 @@ class MoELM:
         with jax.named_scope(scope["experts"]):
             routed, counters = expert_share.held_experts(
                 x, chosen, weight, lp["expert_wgu"], lp["expert_wd"],
-                self.first_expert, self.dtype)
+                self.first_expert, self.num_experts, self.dtype)
         with jax.named_scope(scope["shared"]):
             gate, up = jnp.split(self._mm(x, lp["shared_wgu"]), 2, axis=-1)
             share = jax.nn.sigmoid(x @ lp["shared_gate"].astype(F32))
@@ -367,11 +367,19 @@ class MoELM:
                 jnp.max(pairs, -1) / jnp.maximum(jnp.mean(pairs, -1), 1e-9)),
             "experts_untouched": jnp.sum(pairs == 0).astype(F32),
             "dropped_pairs": jnp.sum(every("dropped_pairs")).astype(F32),
+            "pair_slabs_mean": jnp.mean(every("pair_slabs").astype(F32)),
+            "pair_slabs_max": jnp.max(every("pair_slabs")).astype(F32),
             "router_entropy": jnp.sum(every("router_entropy_sum")) / (layers * tokens),
             "shared_gate_mean": jnp.sum(every("shared_gate_sum")) / (layers * tokens),
             "beta_mean": sum(jnp.sum(s["beta_sum"]) for s in delta) / max(1, steps),
             "decay_min": jnp.min(jnp.stack([jnp.min(s["decay_min"]) for s in delta]))
             if delta else jnp.ones(())}
+
+    def pair_slab_rows(self, b: int, t: int) -> int:
+        """Rows of a slab of the learner's sorted pairs (`expert_share.
+        slab_rows`) where a layer is applied to `[B, T]` a row block at a time."""
+        return expert_share.slab_rows(math.gcd(b, self.row_block) * t * self.top_k,
+                                      self.experts_held, self.num_experts)
 
     # The heads on a block of positions -> float32 `logp` of the taken
     # action, `entropy`, `gate` (1: one pass, never left early), `value`:

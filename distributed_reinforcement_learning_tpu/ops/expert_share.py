@@ -14,12 +14,28 @@ it, in a deployment, through an exchange this file does not stand in
 for). `ops/moe.py` is the other expert layer of the repo: all experts
 here, a one-hot `[N, E, C]` dispatch with a fixed capacity that DROPS
 what overflows. This one is dropless with static shapes: the pairs are
-sorted by expert into a buffer of the worst case's size (`N x top_k`
-rows: every choice of every token held here), the held experts run as
-two grouped products over it (`jax.lax.ragged_dot`: rows past the last
-group are not computed, so the work follows the pairs that are really
-here, about `held / E` of the buffer), and the results are added back to
-their tokens. No array has an expert AND a capacity axis.
+sorted, held first and by expert, into a list of the worst case's length
+(`N x top_k`: every choice of every token held here), and the SORTED LIST
+is worked a slab at a time, as many slabs as hold a held pair
+(`ceil(held pairs / slab)`, a trip count read from the data: nothing is
+compiled again when it changes). A slab gathers its rows of `x`, runs the
+held experts as two grouped products (`jax.lax.ragged_dot`), weights the
+result and adds it to its tokens. A slab is the held pairs a uniform
+router would send here and a quarter more, `1.25 N x top_k x held / E`
+rounded up to 512 rows (`slab_rows`); where that is the whole list (a decode step; a layer that
+holds every expert) there is one slab and no loop. No array has an expert
+AND a capacity axis, and none has the list's length and a model width.
+
+Which work follows the pairs that are here. Until PR 42 the list was ONE
+buffer `[N x top_k, D]`: the grouped products skipped the rows past the
+last group, and every gather, mask, weighting and scatter-add, forward
+and backward, passed over all of it, sixteen times the held pairs at a
+sixteenth of the experts. Now only the sort and the counts (`[N x top_k]`
+and `[N x top_k, held]`, no model width) are as long as the list; the
+pairs' weights are gathered, and everything `D`, `F` or `2 F` wide is, a
+slab at a time. What a trip costs whatever its rows: in the backward,
+passes over arrays as large as the weights (their two transposes, their
+gradients' float32 sums).
 
 Router logits, softmax, top-k and the weights w are float32 (the product
 at `highest` precision: a choice between two experts is discontinuous,
@@ -28,6 +44,8 @@ grouped products take operands in `dtype` with float32 accumulation.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -82,32 +100,122 @@ def held_pairs(chosen: jax.Array, first_expert: int, held: int):
     return order, sizes, here
 
 
-def held_experts(x: jax.Array, chosen: jax.Array, weight: jax.Array,
-                 wgu: jax.Array, wd: jax.Array, first_expert: int,
-                 dtype=jnp.bfloat16):
-    """`x [N, D]`, `chosen, weight [N, top_k]` (`route`'s), `wgu [held,
-    D, 2 F]` (gate and up side by side), `wd [held, F, D]` -> (`out [N,
-    D]` float32: the held experts' weighted part of the layer's result;
-    counters). Dropless: the buffer has a row for every pair."""
-    n, top_k = chosen.shape
-    held = wgu.shape[0]
-    order, sizes, here = held_pairs(chosen, first_expert, held)
-    token = order // top_k
+def slab_rows(pairs: int, held: int, num_experts: int) -> int:
+    """Rows of one slab of the sorted pair list, from shapes alone: the
+    held pairs a uniform router would send here (`pairs x held /
+    num_experts`) and a quarter more, up to the next multiple of 512, and
+    never more than the list. The quarter: whatever its rows, a trip costs
+    the backward several passes over arrays as large as the weights (4.2 ms
+    a call at the sixth cell's shape, where 512 more rows cost 0.15; my
+    chip run, PR 42), and a slab AT the expectation would run a second one
+    for every other call."""
+    rows = -(-5 * pairs * held // (4 * num_experts))
+    return min(pairs, -(-rows // 512) * 512)
+
+
+def _slab(rows: jax.Array, weight: jax.Array, wgu: jax.Array, wd: jax.Array,
+          sizes: jax.Array, live_rows: jax.Array) -> jax.Array:
+    """The held experts on one slab of the sorted pairs: `rows [S, D]`
+    (the pairs' tokens, operands' dtype), `weight [S]`, `sizes [held]` (the
+    rows of each expert inside the slab, in order), `live_rows`: how many
+    of the slab's rows are held pairs -> `[S, D]` float32, each pair's
+    weighted expert output and 0 on the rest."""
     # A row past the last group belongs to no held expert, and the grouped
     # product neither reads nor WRITES it: on the chip it holds whatever
     # the buffer held (my chip run, PR 36: finite garbage; nothing says it
-    # is), forward and in the backward's products alike. So the buffer is
+    # is), forward and in the backward's products alike. So the slab is
     # masked where it is filled and where it is read: no such row reaches
     # the result, and no cotangent of one reaches `x`.
-    count = jnp.sum(sizes)
-    live = (jnp.arange(n * top_k) < count)[:, None]
-    rows = jnp.where(live, x.astype(dtype)[token], 0)  # the pair buffer [N * top_k, D]
+    live = (jnp.arange(rows.shape[0]) < live_rows)[:, None]
     gate, up = jnp.split(jax.lax.ragged_dot(
-        rows, wgu.astype(dtype), sizes, preferred_element_type=F32), 2, -1)
-    y = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(dtype),
-                           wd.astype(dtype), sizes, preferred_element_type=F32)
-    y = jnp.where(live, y, 0.0) * weight.reshape(-1)[order][:, None]
-    out = jnp.zeros((n, x.shape[-1]), F32).at[token].add(y)
-    counters = {"held_pairs": count, "expert_pairs": sizes,
-                "dropped_pairs": jnp.sum(here) - jnp.sum(live)}
+        jnp.where(live, rows, 0), wgu, sizes, preferred_element_type=F32), 2, -1)
+    y = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(rows.dtype), wd, sizes,
+                           preferred_element_type=F32)
+    return jnp.where(live, y, 0.0) * weight[:, None]
+
+
+def _trips(sizes, slab: int):
+    """The slabs that hold a held pair."""
+    return (jnp.sum(sizes) + slab - 1) // slab
+
+
+def _slab_of(i, slab: int, top_k: int, order, sizes):
+    """Slab `i` of the sorted list: the flat pair index and the token of
+    each of its rows, the overlap of every expert's `[begin, end)` with it,
+    its live rows."""
+    lo = i * slab
+    end = jnp.cumsum(sizes)
+    inside = jnp.clip(end, lo, lo + slab) - jnp.clip(end - sizes, lo, lo + slab)
+    pair = jax.lax.dynamic_slice(order, (lo,), (slab,))
+    return pair, pair // top_k, inside, end[-1] - lo
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _slabs(x, weight, wgu, wd, order, sizes, slab: int, top_k: int):
+    """`sum_i scatter(_slab(slab i))` over the slabs that hold a held pair:
+    `x [N, D]`, `wgu`, `wd` in the operands' dtype, `weight [N x top_k]`
+    by flat pair index, `order [trips_max x slab]` -> `[N, D]` float32."""
+    return _slabs_fwd(x, weight, wgu, wd, order, sizes, slab, top_k)[0]
+
+
+def _slabs_fwd(x, weight, wgu, wd, order, sizes, slab, top_k):
+    def trip(i, out):
+        pair, tok, inside, live_rows = _slab_of(i, slab, top_k, order, sizes)
+        return out.at[tok].add(_slab(x[tok], weight[pair], wgu, wd, inside, live_rows))
+
+    out = jax.lax.fori_loop(0, _trips(sizes, slab), trip, jnp.zeros(x.shape, F32))
+    return out, (x, weight, wgu, wd, order, sizes)
+
+
+def _slabs_bwd(slab, top_k, saved, g):
+    """The same loop backwards: a trip takes the slab's own VJP and adds
+    into float32 sums (reverse mode does not pass a loop whose trip count
+    is traced, and the caller rematerialises the layer anyway, so only the
+    inputs were kept)."""
+    x, weight, wgu, wd, order, sizes = saved
+
+    def trip(i, sums):
+        dx, dweight, dwgu, dwd = sums
+        pair, tok, inside, live_rows = _slab_of(i, slab, top_k, order, sizes)
+        _, back = jax.vjp(
+            lambda rows, w, wgu, wd: _slab(rows, w, wgu, wd, inside, live_rows),
+            x[tok], weight[pair], wgu, wd)
+        rows_bar, w_bar, wgu_bar, wd_bar = back(g[tok])
+        return (dx.at[tok].add(rows_bar.astype(F32)), dweight.at[pair].add(w_bar),
+                dwgu + wgu_bar.astype(F32), dwd + wd_bar.astype(F32))
+
+    zeros = lambda a: jnp.zeros(a.shape, F32)
+    dx, dweight, dwgu, dwd = jax.lax.fori_loop(
+        0, _trips(sizes, slab), trip, (zeros(x), zeros(weight), zeros(wgu), zeros(wd)))
+    return (dx.astype(x.dtype), dweight, dwgu.astype(wgu.dtype),
+            dwd.astype(wd.dtype), None, None)
+
+
+_slabs.defvjp(_slabs_fwd, _slabs_bwd)
+
+
+def held_experts(x: jax.Array, chosen: jax.Array, weight: jax.Array,
+                 wgu: jax.Array, wd: jax.Array, first_expert: int,
+                 num_experts: int, dtype=jnp.bfloat16):
+    """`x [N, D]`, `chosen, weight [N, top_k]` (`route`'s), `wgu [held,
+    D, 2 F]` (gate and up side by side), `wd [held, F, D]`, `num_experts`:
+    the router's width -> (`out [N, D]` float32: the held experts'
+    weighted part of the layer's result; counters). Dropless: every held
+    pair lies in exactly one slab, and every slab with one is run."""
+    n, top_k = chosen.shape
+    held = wgu.shape[0]
+    slab = slab_rows(n * top_k, held, num_experts)
+    order, sizes, here = held_pairs(chosen, first_expert, held)
+    count, trips = jnp.sum(sizes), _trips(sizes, slab)
+    x, wgu, wd = x.astype(dtype), wgu.astype(dtype), wd.astype(dtype)
+    weight = weight.reshape(-1)
+    if slab == n * top_k:  # the list is one slab: no loop, autodiff's own backward
+        token = order // top_k
+        out = jnp.zeros(x.shape, F32).at[token].add(
+            _slab(x[token], weight[order], wgu, wd, sizes, count))
+    else:  # the list's last slab is a whole one too: rows of pair 0, past `count`
+        out = _slabs(x, weight, wgu, wd, jnp.pad(order, (0, -order.size % slab)),
+                     sizes, slab, top_k)
+    counters = {"held_pairs": count, "expert_pairs": sizes, "pair_slabs": trips,
+                "dropped_pairs": jnp.sum(here) - jnp.minimum(count, trips * slab)}
     return out, counters

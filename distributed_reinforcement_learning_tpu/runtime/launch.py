@@ -593,7 +593,7 @@ def train_anakin_tokens(config_path: str, section: str, num_updates: int,
                for i in range(1, agent_cfg.total_ut_steps)]
         return (f"[anakin-tokens] step {step}: mean_return {mean_ret:.2f} "
                 f"({eps:.0f} episodes, loss {last['loss']:.2f}, exit cdf {cdf}, "
-                f"rho clipped {float(m['rho_clipped_share'][-1]):.3f})")
+                f"rho clipped {float(m['rho_clipped_share'][-1]):.3f}{_pair_slabs(m)})")
 
     returns = []
     profiler = ProfilerSession.from_env()  # DRL_PROFILE_DIR: one on_step a chunk
@@ -631,3 +631,13 @@ def _token_agent(agent_cfg):
         if isinstance(agent_cfg, config):
             return agent(agent_cfg)
     return None
+
+
+def _pair_slabs(m) -> str:
+    """The chunk's last update's slabs a call of the held experts (mean and
+    max over layers and row blocks), for the token loop's log line; nothing
+    for a family without an expert share."""
+    if "pair_slabs_mean" not in m:
+        return ""
+    return (f", pair slabs {float(m['pair_slabs_mean'][-1]):.3f} "
+            f"max {float(m['pair_slabs_max'][-1]):.0f}")

@@ -1,0 +1,314 @@
+"""`ops/expert_share.held_experts`: the held experts run over slabs of the
+sorted pair list, as many as hold a held pair. Its value and its four
+gradients are held against the whole-buffer computation it replaced
+(written out here), at every count the loop's edges make special; the
+loop is one compilation for all of them, is absent where the list is one
+slab, and leaves no pair-list-long wide array behind.
+"""
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_reinforcement_learning_tpu.ops import expert_share
+
+F32 = jnp.float32
+# 256 tokens x 4 choices of 16 experts, 2 held: 1,024 pairs, slabs of 512
+# (the expectation, 128, and a quarter more, rounded up).
+N, TOP_K, E, HELD, D, WIDTH = 256, 4, 16, 2, 32, 16
+SLAB = 512
+
+
+def whole_buffer(x, chosen, weight, wgu, wd, first_expert, dtype):
+    """The layer over the worst case's buffer, a row for every pair."""
+    n, top_k = chosen.shape
+    order, sizes, _ = expert_share.held_pairs(chosen, first_expert, wgu.shape[0])
+    token = order // top_k
+    live = (jnp.arange(n * top_k) < jnp.sum(sizes))[:, None]
+    rows = jnp.where(live, x.astype(dtype)[token], 0)
+    gate, up = jnp.split(jax.lax.ragged_dot(
+        rows, wgu.astype(dtype), sizes, preferred_element_type=F32), 2, -1)
+    y = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(dtype),
+                           wd.astype(dtype), sizes, preferred_element_type=F32)
+    y = jnp.where(live, y, 0.0) * weight.reshape(-1)[order][:, None]
+    return jnp.zeros((n, x.shape[-1]), F32).at[token].add(y)
+
+
+def layer(seed=0, n=N, top_k=TOP_K, experts=E, held=HELD, d=D, width=WIDTH):
+    r = np.random.RandomState(seed)
+    f = lambda *shape: jnp.asarray(r.normal(size=shape) * 0.3, F32)
+    return {"x": f(n, d) * 3.0, "router": f(d, experts),
+            "wgu": f(held, d, 2 * width), "wd": f(held, width, d)}
+
+
+def choices(count, n=N, top_k=TOP_K, experts=E, held=HELD, first=4, seed=0):
+    """`chosen [n, top_k]` with exactly `count` pairs on the held experts
+    `[first, first + held)`, spread over tokens and choices, the rest on
+    the absent ones."""
+    r = np.random.RandomState(seed)
+    absent = np.setdiff1d(np.arange(experts), np.arange(first, first + held))
+    flat = r.choice(absent, size=n * top_k)
+    where = r.choice(n * top_k, size=count, replace=False)
+    flat[where] = first + r.randint(held, size=count)
+    return jnp.asarray(flat.reshape(n, top_k), jnp.int32)
+
+
+def weights(lay, chosen):
+    """The pairs' weights as a differentiable function of the router."""
+    def of(router):
+        probs = jax.nn.softmax(lay["x"] @ router, axis=-1)
+        top = jnp.take_along_axis(probs, chosen, axis=-1)
+        return top / jnp.sum(top, -1, keepdims=True)
+    return of
+
+
+def losses(lay, chosen, dtype, first=4, experts=E):
+    """(the slab version's loss, the whole buffer's) of (x, router, wgu, wd)."""
+    def loss(fn, x, router, wgu, wd):
+        out = fn(x, chosen, weights({**lay, "x": x}, chosen)(router), wgu, wd)
+        return jnp.sum(out * jnp.cos(jnp.arange(out.size, dtype=F32).reshape(out.shape)))
+
+    slabs = lambda *a: expert_share.held_experts(*a, first, experts, dtype)[0]
+    whole = lambda *a: whole_buffer(*a, first, dtype)
+    return functools.partial(loss, slabs), functools.partial(loss, whole)
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+COUNTS = {"none": 0, "one": 1, "a_slab": SLAB, "a_slab_and_one": SLAB + 1,
+          "every_pair": N * TOP_K}
+
+
+@pytest.fixture(scope="module")
+def graded():
+    """One jitted value-and-gradients of each version, `chosen` an
+    argument: every count below goes through the same two programs."""
+    lay = layer()
+
+    def both(dtype):
+        def run(which, chosen, x, router, wgu, wd):
+            return jax.value_and_grad(
+                losses(lay, chosen, dtype)[which], argnums=(0, 1, 2, 3))(
+                    x, router, wgu, wd)
+        return jax.jit(functools.partial(run, 0)), jax.jit(functools.partial(run, 1))
+
+    return lay, {F32: both(F32), jnp.bfloat16: both(jnp.bfloat16)}
+
+
+@pytest.mark.parametrize("count", COUNTS.values(), ids=COUNTS.keys())
+def test_value_and_gradients_are_the_whole_buffers_in_float32(graded, count):
+    lay, fns = graded
+    slabs, whole = fns[F32]
+    args = (choices(count), lay["x"], lay["router"], lay["wgu"], lay["wd"])
+    with jax.default_matmul_precision("highest"):
+        got, want = slabs(*args), whole(*args)
+    assert abs(float(got[0]) - float(want[0])) <= 1e-6 * max(1.0, abs(float(want[0])))
+    for name, a, b in zip(("x", "router", "wgu", "wd"), got[1], want[1]):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        if count == 0:
+            assert not np.any(np.asarray(a)) and not np.any(np.asarray(b)), name
+        else:
+            assert rel(a, b) < 1e-6, (name, rel(a, b))
+
+
+@pytest.mark.parametrize("count", COUNTS.values(), ids=COUNTS.keys())
+def test_value_and_gradients_in_bfloat16_are_inside_the_cells_limits(graded, count):
+    """The cells hold a bfloat16 program to 2e-2 of its float32 reference
+    (`perfbench/families/moelm.py`, `GRAD_REL`'s order); the slab version
+    stays well inside that of the whole buffer in the SAME dtype: the
+    products are the same, the sums over slabs are float32."""
+    lay, fns = graded
+    slabs, whole = fns[jnp.bfloat16]
+    args = (choices(count, seed=1), lay["x"], lay["router"], lay["wgu"], lay["wd"])
+    got, want = slabs(*args), whole(*args)
+    assert abs(float(got[0]) - float(want[0])) <= 5e-3 * max(1.0, abs(float(want[0])))
+    for name, a, b in zip(("x", "router", "wgu", "wd"), got[1], want[1]):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        if count:
+            assert rel(a, b) < 5e-3, (name, rel(a, b))
+
+
+def test_every_count_is_one_compilation(graded):
+    lay, fns = graded
+    slabs, _ = fns[F32]
+    with jax.default_matmul_precision("highest"):  # as the value test's calls: one key
+        for count in (0, 1, 300, SLAB, SLAB + 1, 700, N * TOP_K):
+            slabs(choices(count, seed=2), lay["x"], lay["router"], lay["wgu"], lay["wd"])
+    assert slabs._cache_size() == 1
+
+
+@pytest.mark.parametrize("count", [0, 1, SLAB - 1, SLAB, SLAB + 1, 2 * SLAB])
+def test_pair_slabs_is_the_count_over_the_slab_rounded_up(count):
+    lay = layer()
+    chosen = choices(count, seed=3)
+    weight = weights(lay, chosen)(lay["router"])
+    _, counters = expert_share.held_experts(
+        lay["x"], chosen, weight, lay["wgu"], lay["wd"], 4, E, F32)
+    assert expert_share.slab_rows(N * TOP_K, HELD, E) == SLAB
+    assert int(counters["held_pairs"]) == count
+    assert int(counters["pair_slabs"]) == math.ceil(count / SLAB)
+    assert int(counters["dropped_pairs"]) == 0
+    assert int(jnp.sum(counters["expert_pairs"])) == count
+
+
+def test_every_pair_held_runs_every_slab_and_drops_none():
+    """1,024 x 4 of 8 experts, 1 held, and the router sends every choice
+    to it: 4,096 pairs in slabs of 1,024 (the expectation's 512 and a
+    quarter more, rounded up), 4 trips where the expectation is an eighth."""
+    lay = layer(4, n=1024, experts=8, held=1)
+    chosen = jnp.full((1024, TOP_K), 5, jnp.int32)
+    weight = jnp.full((1024, TOP_K), 0.25, F32)
+    with jax.default_matmul_precision("highest"):
+        out, counters = expert_share.held_experts(
+            lay["x"], chosen, weight, lay["wgu"], lay["wd"], 5, 8, F32)
+        want = whole_buffer(lay["x"], chosen, weight, lay["wgu"], lay["wd"], 5, F32)
+    assert expert_share.slab_rows(4096, 1, 8) == 1024
+    assert int(counters["pair_slabs"]) == 4 and int(counters["dropped_pairs"]) == 0
+    assert rel(out, want) < 1e-6
+
+
+@pytest.mark.parametrize("pairs,held,experts,want", [
+    (4096 * 8, 16, 256, 2560), (4096 * 10, 32, 512, 3584),  # the two cells' learners
+    (16 * 8, 16, 256, 128), (32 * 10, 32, 512, 320),  # their decode steps: the list
+    (1024, 2, 16, 512), (1000, 2, 16, 512), (4096, 8, 8, 4096), (600, 16, 16, 600),
+    (4096 * 8, 17, 256, 3072)])
+def test_slab_rows_is_the_expectation_and_a_quarter_rounded_up_and_capped(pairs, held, experts, want):
+    assert expert_share.slab_rows(pairs, held, experts) == want
+
+
+def test_a_list_that_is_no_multiple_of_the_slab_keeps_its_tail():
+    """250 x 4 = 1,000 pairs in slabs of 512: the second slab is padded,
+    and the pairs in rows 512..999 are all there."""
+    lay = layer(5, n=250)
+    chosen = choices(900, n=250, seed=5)
+    weight = weights(lay, chosen)(lay["router"])
+    with jax.default_matmul_precision("highest"):
+        out, counters = expert_share.held_experts(
+            lay["x"], chosen, weight, lay["wgu"], lay["wd"], 4, E, F32)
+        want = whole_buffer(lay["x"], chosen, weight, lay["wgu"], lay["wd"], 4, F32)
+    assert int(counters["pair_slabs"]) == 2 and int(counters["dropped_pairs"]) == 0
+    assert rel(out, want) < 1e-6
+
+
+@pytest.mark.parametrize("count", [1, SLAB + 1])
+def test_under_checkpoint_in_a_scan_as_the_models_call_it(count):
+    """A stack of 3 layers' weights scanned over, each layer a
+    `jax.checkpoint` mapped over 2 row blocks: gradients through the
+    loop's own backward equal the whole buffer's through autodiff."""
+    lay = layer(6)
+    r = np.random.RandomState(6)
+    stack = {k: jnp.stack([lay[k] * s for s in (1.0, 0.5, 0.25)])
+             for k in ("router", "wgu", "wd")}
+    xs = jnp.stack([lay["x"], jnp.asarray(r.normal(size=lay["x"].shape), F32)])
+    chosen = jnp.stack([choices(count, seed=6), choices(count, seed=7)])
+
+    def total(fn, xs, stack):
+        @jax.checkpoint
+        def block(x, chosen, lp):
+            weight = weights({"x": x}, chosen)(lp["router"])
+            return x + 0.1 * fn(x, chosen, weight, lp["wgu"], lp["wd"])
+
+        def one(h, lp):
+            return jax.lax.map(lambda a: block(*a, lp), (h, chosen)), None
+
+        h, _ = jax.lax.scan(one, xs, stack)
+        return jnp.sum(h * jnp.sin(jnp.arange(h.size, dtype=F32).reshape(h.shape)))
+
+    slabs = lambda *a: expert_share.held_experts(*a, 4, E, F32)[0]
+    whole = lambda *a: whole_buffer(*a, 4, F32)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.value_and_grad(functools.partial(total, slabs), (0, 1)))(xs, stack)
+        want = jax.jit(jax.value_and_grad(functools.partial(total, whole), (0, 1)))(xs, stack)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert rel(a, b) < 1e-6
+
+
+def test_rows_the_grouped_product_leaves_unwritten_reach_nothing(monkeypatch):
+    """On the chip a row past the last group holds whatever the buffer
+    held. With a grouped product that leaves NaN there, forward and in
+    both of its gradients, the loop's value and gradients stay finite and
+    equal: each slab masks where it fills and where it reads."""
+    clean = jax.lax.ragged_dot
+
+    def poison(out, sizes):
+        return jnp.where((jnp.arange(out.shape[0]) < jnp.sum(sizes))[:, None], out, jnp.nan)
+
+    @jax.custom_vjp
+    def dirty(lhs, rhs, sizes):
+        return poison(clean(lhs, rhs, sizes, preferred_element_type=F32), sizes)
+
+    def fwd(lhs, rhs, sizes):
+        return dirty(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+    def bwd(res, g):
+        lhs, rhs, sizes = res
+        d_lhs, d_rhs = jax.vjp(lambda a, b: clean(
+            a, b, sizes, preferred_element_type=F32), lhs, rhs)[1](g)
+        return poison(d_lhs, sizes), d_rhs, None
+
+    dirty.defvjp(fwd, bwd)
+    lay = layer(8)
+    chosen = choices(SLAB + 37, seed=8)
+    loss = losses(lay, chosen, F32)[0]
+    args = (lay["x"], lay["router"], lay["wgu"], lay["wd"])
+    want = jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(*args)
+    monkeypatch.setattr(jax.lax, "ragged_dot",
+                        lambda lhs, rhs, sizes, **_: dirty(lhs, rhs, sizes))
+    got = jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(*args)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert bool(jnp.all(jnp.isfinite(a))) and rel(a, b) < 1e-5
+
+
+def _primitives(jaxpr, found=None):
+    """Every (primitive name, result shapes) of a jaxpr and the jaxprs
+    inside it."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        found.append((eqn.primitive.name, [v.aval.shape for v in eqn.outvars
+                                           if hasattr(v.aval, "shape")]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, found)
+    return found
+
+
+def _graded_jaxpr(n, top_k, experts, held, d, width):
+    shapes = [jax.ShapeDtypeStruct(s, F32) for s in (
+        (n, d), (n, top_k), (held, d, 2 * width), (held, width, d))]
+    chosen = jax.ShapeDtypeStruct((n, top_k), jnp.int32)
+
+    def graded(x, weight, wgu, wd, chosen):
+        return jax.value_and_grad(lambda *a: jnp.sum(expert_share.held_experts(
+            a[0], chosen, *a[1:], 0, experts, jnp.bfloat16)[0] ** 2),
+            argnums=(0, 1, 2, 3))(x, weight, wgu, wd)
+
+    return jax.make_jaxpr(graded)(*shapes, chosen).jaxpr
+
+
+@pytest.mark.parametrize("n,top_k,experts,held", [(16, 8, 256, 16), (32, 10, 512, 32)])
+def test_a_decode_steps_list_is_one_slab_and_no_loop(n, top_k, experts, held):
+    names = {name for name, _ in _primitives(_graded_jaxpr(n, top_k, experts, held, 64, 32))}
+    assert "while" not in names and "ragged_dot_general" in names
+    assert not any("custom_vjp" in name for name in names)
+
+
+@pytest.mark.parametrize("n,top_k,experts,held", [(512, 8, 256, 16), (512, 10, 512, 32)])
+def test_at_a_learners_shape_nothing_wide_is_as_long_as_the_pair_list(
+        n, top_k, experts, held):
+    """Forward and backward: the `[P]` index vectors and the `[P, held]`
+    count stay, no array has `P` rows and `D`, `F` or `2 F` columns."""
+    d, width = 64, 48  # no width is `held`: the `[P, held]` count stays
+    pairs = n * top_k
+    assert expert_share.slab_rows(pairs, held, experts) == 512 < pairs
+    found = _primitives(_graded_jaxpr(n, top_k, experts, held, d, width))
+    assert any(name == "while" for name, _ in found)
+    wide = [(name, s) for name, shapes in found for s in shapes
+            if len(s) == 2 and s[0] >= pairs and s[1] in (d, width, 2 * width)]
+    assert not wide, wide
+    assert any(s == (pairs, held) for _, shapes in found for s in shapes)

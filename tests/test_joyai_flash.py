@@ -449,7 +449,7 @@ def test_the_shares_add_up_to_the_uncut_layer(shares):
         _, chosen, weight, _ = _route(layer)
         parts = [expert_share.held_experts(
             layer["x"][0], chosen, weight, layer["expert_wgu"][first:first + held],
-            layer["expert_wd"][first:first + held], first, jnp.float32)
+            layer["expert_wd"][first:first + held], first, 16, jnp.float32)
             for first in range(0, 16, held)]
         routed, shared, _ = ref.moe(layer["x"], layer, hp)
         assert _rel(sum(p[0] for p in parts), routed[0]) < 1e-5

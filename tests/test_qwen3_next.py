@@ -410,7 +410,7 @@ def _share(layer, first, held, top_k=3):
     _, chosen, weight = expert_share.route(x, layer["router"], top_k)
     return expert_share.held_experts(
         x, chosen, weight, layer["expert_wgu"][first:first + held],
-        layer["expert_wd"][first:first + held], first, jnp.float32)
+        layer["expert_wd"][first:first + held], first, 16, jnp.float32)
 
 
 @pytest.mark.parametrize("shares", [1, 2, 4, 16])
@@ -495,7 +495,8 @@ def test_rows_past_the_last_group_reach_neither_the_result_nor_a_gradient(monkey
     dirty.defvjp(fwd, bwd)
 
     def loss(x, weight, wgu, wd):
-        out, _ = expert_share.held_experts(x, chosen, weight, wgu, wd, 4, jnp.float32)
+        out, _ = expert_share.held_experts(x, chosen, weight, wgu, wd, 4, 16,
+                                             jnp.float32)
         return jnp.sum(out * jnp.cos(jnp.arange(out.size).reshape(out.shape)))
 
     args = (x, weight, layer["expert_wgu"][4:8], layer["expert_wd"][4:8])
@@ -527,7 +528,7 @@ def test_a_wrong_expert_share_is_seen(fault):
             chosen = chosen.at[:, 1].set(15)
         out, _ = expert_share.held_experts(
             x, chosen, weight, layer["expert_wgu"][4:8], layer["expert_wd"][4:8],
-            first, jnp.float32)
+            first, 16, jnp.float32)
     assert _rel(out, routed[0]) > 0.02
 
 

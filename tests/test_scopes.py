@@ -205,8 +205,8 @@ def test_hybrid_backward_stack_keeps_its_names(hybrid_chunk_names, name):
                for n in hybrid_chunk_names)
 
 
-@pytest.fixture(scope="module")
-def moe_chunk_names():
+def _moe_anakin(trajectory=16, row_block=2):
+    """The small Qwen3-Next loop: 4 rows of `trajectory` tokens."""
     import jax.numpy as jnp
 
     from distributed_reinforcement_learning_tpu.agents.moelm import (
@@ -220,8 +220,14 @@ def moe_chunk_names():
         linear_num_value_heads=4, linear_key_head_dim=8, linear_value_head_dim=8,
         num_experts=4, router_width=16, first_expert=4, num_experts_per_tok=3,
         moe_intermediate_size=16, shared_expert_intermediate_size=16,
-        trajectory=16, gdn_chunk=8, dtype=jnp.float32, head_block=16, row_block=2)
-    anakin = AnakinTokens(MoELMAgent(cfg), 4, TokenRecall(64, 16))
+        trajectory=trajectory, gdn_chunk=8, dtype=jnp.float32, head_block=16,
+        row_block=row_block)
+    return AnakinTokens(MoELMAgent(cfg), 4, TokenRecall(64, trajectory))
+
+
+@pytest.fixture(scope="module")
+def moe_chunk_names():
+    anakin = _moe_anakin()
     return _op_names(anakin.train_chunk, anakin.init(jax.random.PRNGKey(0)), 1)
 
 
@@ -240,8 +246,8 @@ def test_moe_backward_stack_keeps_its_names(moe_chunk_names, name):
                for n in moe_chunk_names)
 
 
-@pytest.fixture(scope="module")
-def mla_chunk_names():
+def _mla_anakin(trajectory=16, row_block=2):
+    """The small JoyAI-LLM-Flash loop: 4 rows of `trajectory` tokens."""
     import jax.numpy as jnp
 
     from distributed_reinforcement_learning_tpu.agents.mlalm import (
@@ -254,8 +260,13 @@ def mla_chunk_names():
         q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
         v_head_dim=8, intermediate_size=48, n_routed_experts=4, router_width=16,
         first_expert=4, num_experts_per_tok=3, moe_intermediate_size=16,
-        trajectory=16, dtype=jnp.float32, head_block=16, row_block=2)
-    anakin = AnakinTokens(MLALMAgent(cfg), 4, TokenRecall(64, 16))
+        trajectory=trajectory, dtype=jnp.float32, head_block=16, row_block=row_block)
+    return AnakinTokens(MLALMAgent(cfg), 4, TokenRecall(64, trajectory))
+
+
+@pytest.fixture(scope="module")
+def mla_chunk_names():
+    anakin = _mla_anakin()
     return _op_names(anakin.train_chunk, anakin.init(jax.random.PRNGKey(0)), 1)
 
 
@@ -396,3 +407,54 @@ def test_scope_and_span_names_are_spelled_in_one_place():
             if rel != os.path.join("observability", "scopes.py")
             and literal.search(text)]
     assert hits == []
+
+
+# -- the slab loop of the held experts (ISSUE 42) ------------------------------
+
+
+def _loops_over_slabs(jaxpr, above=""):
+    """[(the loop's own composed name stack, [that of every equation inside
+    it])] for every `while` of `jaxpr` whose body runs a grouped product
+    itself (not through a loop inside it): the slab loops."""
+    def inside(jaxpr, above, loops_too):
+        for eqn in jaxpr.eqns:
+            stack = f"{above}/{eqn.source_info.name_stack}"
+            yield eqn.primitive.name, stack
+            if loops_too or eqn.primitive.name not in ("while", "scan"):
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from inside(sub, stack, loops_too)
+
+    found = []
+    for eqn in jaxpr.eqns:
+        stack = f"{above}/{eqn.source_info.name_stack}"
+        subs = list(jax.core.jaxprs_in_params(eqn.params))
+        if eqn.primitive.name == "while" and any(
+                name == "ragged_dot_general"
+                for sub in subs for name, _ in inside(sub, stack, False)):
+            found.append((stack, [s for sub in subs for _, s in inside(sub, stack, True)]))
+        else:
+            for sub in subs:
+                found.extend(_loops_over_slabs(sub, stack))
+    return found
+
+
+@pytest.mark.parametrize("make,experts", [
+    (_moe_anakin, [scopes.MOE_EXPERTS]),
+    (_mla_anakin, [scopes.MOE_EXPERTS, scopes.MLA_MTP["experts"]])],
+    ids=["qwen3_next", "joyai_flash"])
+def test_every_op_of_the_slab_loop_is_named_for_the_experts(make, experts):
+    """4 rows x 64 tokens x 3 choices = 768 pairs in slabs of 512: the
+    learner loops. Every equation of the loop, forward and in the loop's own
+    backward (a custom VJP's, traced apart from the forward), carries the
+    experts' scope, which `moelm_experts_` / `mlalm_experts_` read."""
+    anakin = make(trajectory=64, row_block=4)
+    assert anakin.static_facts["pair_slab_rows"] == 512
+    state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+    loops = _loops_over_slabs(
+        jax.make_jaxpr(anakin.train_chunk, static_argnums=1)(state, 1).jaxpr)
+    for scope in experts:
+        own = [stacks for loop, stacks in loops if scope in loop]
+        assert any("transpose(jvp(" in loop for loop, _ in loops if scope in loop)
+        assert any("transpose(jvp(" not in loop for loop, _ in loops if scope in loop)
+        assert all(scope in s for stacks in own for s in stacks)
+    assert all(any(scope in loop for scope in experts) for loop, _ in loops)
