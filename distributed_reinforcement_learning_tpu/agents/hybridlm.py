@@ -12,13 +12,14 @@ per state-space layer, a key/value cache for the attention layer.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+from typing import ClassVar
 
 import jax
 import jax.numpy as jnp
 
 from distributed_reinforcement_learning_tpu.agents import common
-from distributed_reinforcement_learning_tpu.agents.looplm import LoopLMAgent
+from distributed_reinforcement_learning_tpu.agents.looplm import (
+    LoopLMAgent, TokenLMConfig, fixed)
 from distributed_reinforcement_learning_tpu.models import hybrid_lm
 
 F32 = jnp.float32
@@ -26,10 +27,9 @@ STATE_SAMPLE = 16384  # elements of the final recurrent state a chunk logs
 
 
 @dataclasses.dataclass(frozen=True)
-class HybridLMConfig:
+class HybridLMConfig(TokenLMConfig):
     """The model's published keys under their published names (section
-    `granite_hybrid` of `config.json`), IMPALA's loss and optimizer keys
-    under `ImpalaConfig`'s."""
+    `granite_hybrid` of `config.json`)."""
 
     vocab_size: int = 12_544
     hidden_size: int = 2048
@@ -47,33 +47,38 @@ class HybridLMConfig:
     attention_multiplier: float = 0.015625
     logits_scaling: float = 8.0
     rms_norm_eps: float = 1e-5
-    trajectory: int = 1024  # unroll == episode == cache length
-    recall_distance: int = 8  # envs/token_recall_jax.py
-    discount_factor: float = 0.99
-    baseline_loss_coef: float = 1.0
-    entropy_coef: float = 0.05
-    gradient_clip_norm: float = 40.0
-    reward_clipping: str = "abs_one"
-    start_learning_rate: float = 1e-5
-    end_learning_rate: float = 0.0
-    learning_frame: int = 1_000_000_000
-    dtype: Any = jnp.bfloat16  # matmul operands and the residual stream
-    init_std: float = 0.02
-    head_block: int = 1024  # positions whose `[*, V]` logits live at once (no section key)
-    row_block: int = 4  # rows a layer is applied to at a time (no section key)
-    attention_backend: str = "auto"
-    # One pass of the stack and no exit gate: what `LoopLMAgent` and the
-    # token loop read of a looped model, said for this one.
-    total_ut_steps: int = 1
-    exit_entropy_coef: float = 0.0
+    row_block: int = fixed(4)  # rows a layer is applied to at a time
 
-    @property
-    def num_actions(self) -> int:  # what `utils.config.check_config` reads
-        return self.vocab_size
+    MUST: ClassVar[tuple] = (
+        "vocab_size", "hidden_size", "layer_types", "num_hidden_layers",
+        "num_attention_heads", "num_key_value_heads", "shared_intermediate_size",
+        "mamba_n_heads", "mamba_d_head", "mamba_d_state", "mamba_d_conv",
+        "mamba_chunk_size", "embedding_multiplier", "residual_multiplier",
+        "attention_multiplier", "logits_scaling")
+    ONLY: ClassVar[dict] = {
+        "mamba_n_groups": 1, "num_local_experts": 0,
+        "position_embedding_type": "nope", "tie_word_embeddings": True,
+        "mamba_expand": 2, "mamba_conv_bias": True, "mamba_proj_bias": False,
+        "attention_bias": False}
+
+    @classmethod
+    def check_section(cls, d: dict) -> None:
+        check_layer_types(d, hybrid_lm.LAYER_KINDS)
+        if d["mamba_n_heads"] * d["mamba_d_head"] != 2 * d["hidden_size"]:
+            raise ValueError("mamba_n_heads x mamba_d_head is not twice hidden_size")
 
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
+
+
+def check_layer_types(d: dict, kinds) -> None:
+    """A section's `layer_types`: every one of `kinds` (an unknown layer
+    type raises in `layer_runs`), as many as `num_hidden_layers`."""
+    hybrid_lm.layer_runs(tuple(d["layer_types"]), kinds)
+    if len(d["layer_types"]) != d["num_hidden_layers"]:
+        raise ValueError(f"{len(d['layer_types'])} layer_types for "
+                         f"num_hidden_layers {d['num_hidden_layers']}")
 
 
 class HybridLMAgent(LoopLMAgent):

@@ -31,7 +31,7 @@ floats a position.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, NamedTuple
+from typing import Any, ClassVar, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -44,11 +44,63 @@ from distributed_reinforcement_learning_tpu.ops import vtrace
 F32 = jnp.float32
 
 
+def fixed(default):
+    """A field of a token family's config that no section of `config.json`
+    sets: `utils.config.load_config` leaves it at this default."""
+    return dataclasses.field(default=default, metadata={"section_key": False})
+
+
 @dataclasses.dataclass(frozen=True)
-class LoopLMConfig:
+class TokenLMConfig:
+    """What every token family's config says of the loop, the loss and the
+    optimizer they share (`LoopLMAgent`, `runtime/anakin_tokens.py`):
+    IMPALA's keys under `ImpalaConfig`'s names. A family's class adds its
+    model's published keys under their published names, and says beside
+    them what `utils.config.load_config` holds a section to:
+
+    - `MUST`: the published keys a section must carry, `KeyError` naming
+      the one it lacks (a width is never guessed);
+    - `ONLY`: what the family's config can say and this program does not
+      compute, as key -> the one value that is: `ValueError` naming the key;
+    - `check_section(d)`: the checks across keys, `ValueError`.
+    """
+
+    rms_norm_eps: float = 1e-6
+    trajectory: int = 1024  # unroll == episode == cache length
+    recall_distance: int = 8  # envs/token_recall_jax.py
+    discount_factor: float = 0.99
+    baseline_loss_coef: float = 1.0
+    entropy_coef: float = 0.05
+    gradient_clip_norm: float = 40.0
+    reward_clipping: str = "abs_one"
+    start_learning_rate: float = 1e-5
+    end_learning_rate: float = 0.0
+    learning_frame: int = 1_000_000_000
+    dtype: Any = jnp.bfloat16  # matmul operands, act-time state and activations
+    init_std: float = 0.02  # the section's `initializer_range`
+    head_block: int = fixed(1024)  # positions whose `[*, V]` logits live at once
+    attention_backend: str = fixed("auto")
+    # One pass of the stack and no exit gate: what `LoopLMAgent` and the
+    # token loop read of a looped model, said for every other.
+    total_ut_steps: int = fixed(1)
+    exit_entropy_coef: float = fixed(0.0)
+
+    MUST: ClassVar[tuple] = ()
+    ONLY: ClassVar[dict] = {}
+
+    @classmethod
+    def check_section(cls, d: dict) -> None:
+        pass
+
+    @property
+    def num_actions(self) -> int:  # what `utils.config.check_config` reads
+        return self.vocab_size
+
+
+@dataclasses.dataclass(frozen=True)
+class LoopLMConfig(TokenLMConfig):
     """The model's published keys under their published names (section
-    `ouro_looplm` of `config.json`), IMPALA's loss and optimizer keys
-    under `ImpalaConfig`'s."""
+    `ouro_looplm` of `config.json`)."""
 
     vocab_size: int = 49_152
     hidden_size: int = 2048
@@ -58,27 +110,13 @@ class LoopLMConfig:
     num_hidden_layers: int = 8
     total_ut_steps: int = 4  # R: passes of the whole stack
     early_exit_threshold: float = 1.0  # 1 = every position runs every pass
-    rms_norm_eps: float = 1e-6
     rope_theta: float = 1e6
-    trajectory: int = 128  # unroll == episode == cache length
-    recall_distance: int = 8  # envs/token_recall_jax.py
-    discount_factor: float = 0.99
-    baseline_loss_coef: float = 1.0
-    entropy_coef: float = 0.05
+    trajectory: int = 128
     exit_entropy_coef: float = 0.05  # beta
-    gradient_clip_norm: float = 40.0
-    reward_clipping: str = "abs_one"
-    start_learning_rate: float = 1e-5
-    end_learning_rate: float = 0.0
-    learning_frame: int = 1_000_000_000
-    dtype: Any = jnp.bfloat16  # matmul operands and activations
-    init_std: float = 0.02
-    head_block: int = 1024  # positions whose `[*, V]` logits live at once (no section key)
-    attention_backend: str = "auto"
 
-    @property
-    def num_actions(self) -> int:  # what `utils.config.check_config` reads
-        return self.vocab_size
+    MUST: ClassVar[tuple] = (
+        "vocab_size", "hidden_size", "num_attention_heads", "head_dim",
+        "intermediate_size", "num_hidden_layers", "total_ut_steps")
 
 
 class LoopLMBatch(NamedTuple):

@@ -31,14 +31,14 @@ file's own:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+from typing import ClassVar
 
 import jax
 import jax.numpy as jnp
 
 from distributed_reinforcement_learning_tpu.agents import common
 from distributed_reinforcement_learning_tpu.agents.looplm import (
-    LoopLMAgent, LoopLMBatch)
+    LoopLMAgent, LoopLMBatch, TokenLMConfig, fixed)
 from distributed_reinforcement_learning_tpu.models import latent_moe_lm
 from distributed_reinforcement_learning_tpu.observability import scopes
 
@@ -46,11 +46,10 @@ F32 = jnp.float32
 
 
 @dataclasses.dataclass(frozen=True)
-class MLALMConfig:
+class MLALMConfig(TokenLMConfig):
     """The model's published keys under their published names (section
-    `joyai_flash` of `config.json`), IMPALA's loss and optimizer keys
-    under `ImpalaConfig`'s. `n_routed_experts` is what this chip HOLDS of
-    a layer's `router_width` experts, from `first_expert` on."""
+    `joyai_flash` of `config.json`). `n_routed_experts` is what this chip
+    HOLDS of a layer's `router_width` experts, from `first_expert` on."""
 
     vocab_size: int = 16_160
     hidden_size: int = 2048
@@ -71,32 +70,32 @@ class MLALMConfig:
     moe_intermediate_size: int = 768
     n_shared_experts: int = 1
     routed_scaling_factor: float = 2.5
-    rms_norm_eps: float = 1e-6
     bias_update_speed: float = 1e-3  # gamma (no key of the source's config.json)
     mtp_loss_coef: float = 0.3  # lambda, on the SUMMED module loss (no key of the source's config.json)
-    trajectory: int = 2048  # unroll == episode == cache length
-    recall_distance: int = 8  # envs/token_recall_jax.py
-    discount_factor: float = 0.99
-    baseline_loss_coef: float = 1.0
-    entropy_coef: float = 0.05
-    gradient_clip_norm: float = 40.0
-    reward_clipping: str = "abs_one"
-    start_learning_rate: float = 1e-5
-    end_learning_rate: float = 0.0
-    learning_frame: int = 1_000_000_000
-    dtype: Any = jnp.bfloat16  # matmul operands, the cache and the residual stream
-    init_std: float = 0.02
-    head_block: int = 1024  # positions whose `[*, V]` logits live at once (no section key)
-    row_block: int = 2  # rows a layer is applied to at a time (no section key)
-    attention_backend: str = "auto"
-    # One pass of the stack and no exit gate: what `LoopLMAgent` and the
-    # token loop read of a looped model, said for this one.
-    total_ut_steps: int = 1
-    exit_entropy_coef: float = 0.0
+    trajectory: int = 2048
+    row_block: int = fixed(2)  # rows a layer is applied to at a time
 
-    @property
-    def num_actions(self) -> int:  # what `utils.config.check_config` reads
-        return self.vocab_size
+    MUST: ClassVar[tuple] = (
+        "vocab_size", "hidden_size", "num_hidden_layers", "first_k_dense_replace",
+        "num_attention_heads", "num_key_value_heads", "q_lora_rank",
+        "kv_lora_rank", "qk_head_dim", "qk_nope_head_dim", "qk_rope_head_dim",
+        "v_head_dim", "rope_theta", "intermediate_size", "n_routed_experts",
+        "router_width", "first_expert", "num_experts_per_tok",
+        "moe_intermediate_size", "n_shared_experts", "routed_scaling_factor")
+    ONLY: ClassVar[dict] = {
+        "scoring_func": "sigmoid", "topk_method": "noaux_tc", "n_group": 1,
+        "topk_group": 1, "moe_layer_freq": 1, "norm_topk_prob": True,
+        "rope_interleave": True, "rope_scaling": None, "attention_bias": False,
+        "tie_word_embeddings": False, "hidden_act": "silu",
+        "num_nextn_predict_layers": 1, "ep_size": 1}
+
+    @classmethod
+    def check_section(cls, d: dict) -> None:
+        if d["qk_head_dim"] != d["qk_nope_head_dim"] + d["qk_rope_head_dim"]:
+            raise ValueError("qk_head_dim is not qk_nope_head_dim + qk_rope_head_dim")
+        if d["num_key_value_heads"] != d["num_attention_heads"]:
+            raise ValueError("latent attention rebuilds a key and a value for "
+                             "every query head: num_key_value_heads differs")
 
     @property
     def layer_types(self) -> tuple:

@@ -14,13 +14,15 @@ every decode step chose.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+from typing import ClassVar
 
 import jax
 import jax.numpy as jnp
 
 from distributed_reinforcement_learning_tpu.agents import common
-from distributed_reinforcement_learning_tpu.agents.looplm import LoopLMAgent
+from distributed_reinforcement_learning_tpu.agents.hybridlm import check_layer_types
+from distributed_reinforcement_learning_tpu.agents.looplm import (
+    LoopLMAgent, TokenLMConfig, fixed)
 from distributed_reinforcement_learning_tpu.models import moe_lm
 
 F32 = jnp.float32
@@ -28,11 +30,10 @@ STATE_SAMPLE = 16384  # elements of the final recurrent state a chunk logs
 
 
 @dataclasses.dataclass(frozen=True)
-class MoELMConfig:
+class MoELMConfig(TokenLMConfig):
     """The model's published keys under their published names (section
-    `qwen3_next` of `config.json`), IMPALA's loss and optimizer keys
-    under `ImpalaConfig`'s. `num_experts` is what this chip HOLDS of a
-    layer's `router_width` experts, from `first_expert` on."""
+    `qwen3_next` of `config.json`). `num_experts` is what this chip HOLDS
+    of a layer's `router_width` experts, from `first_expert` on."""
 
     vocab_size: int = 18_992
     hidden_size: int = 2048
@@ -53,31 +54,25 @@ class MoELMConfig:
     num_experts_per_tok: int = 10
     moe_intermediate_size: int = 512
     shared_expert_intermediate_size: int = 512
-    rms_norm_eps: float = 1e-6
-    trajectory: int = 1024  # unroll == episode == cache length
-    recall_distance: int = 8  # envs/token_recall_jax.py
-    discount_factor: float = 0.99
-    baseline_loss_coef: float = 1.0
-    entropy_coef: float = 0.05
-    gradient_clip_norm: float = 40.0
-    reward_clipping: str = "abs_one"
-    start_learning_rate: float = 1e-5
-    end_learning_rate: float = 0.0
-    learning_frame: int = 1_000_000_000
-    dtype: Any = jnp.bfloat16  # matmul operands and the residual stream
-    init_std: float = 0.02
-    head_block: int = 1024  # positions whose `[*, V]` logits live at once (no section key)
-    row_block: int = 4  # rows a layer is applied to at a time (no section key)
-    gdn_chunk: int = 64  # steps of the delta rule a chunk (no section key)
-    attention_backend: str = "auto"
-    # One pass of the stack and no exit gate: what `LoopLMAgent` and the
-    # token loop read of a looped model, said for this one.
-    total_ut_steps: int = 1
-    exit_entropy_coef: float = 0.0
+    row_block: int = fixed(4)  # rows a layer is applied to at a time
+    gdn_chunk: int = fixed(64)  # steps of the delta rule a chunk
 
-    @property
-    def num_actions(self) -> int:  # what `utils.config.check_config` reads
-        return self.vocab_size
+    MUST: ClassVar[tuple] = (
+        "vocab_size", "hidden_size", "layer_types", "num_hidden_layers",
+        "num_attention_heads", "num_key_value_heads", "head_dim",
+        "partial_rotary_factor", "rope_theta", "linear_num_key_heads",
+        "linear_num_value_heads", "linear_key_head_dim", "linear_value_head_dim",
+        "linear_conv_kernel_dim", "num_experts", "router_width", "first_expert",
+        "num_experts_per_tok", "moe_intermediate_size",
+        "shared_expert_intermediate_size")
+    ONLY: ClassVar[dict] = {
+        "decoder_sparse_step": 1, "mlp_only_layers": [], "norm_topk_prob": True,
+        "tie_word_embeddings": False, "use_sliding_window": False,
+        "rope_scaling": None, "attention_bias": False, "hidden_act": "silu"}
+
+    @classmethod
+    def check_section(cls, d: dict) -> None:
+        check_layer_types(d, moe_lm.LAYER_KINDS)
 
 
 class MoELMAgent(LoopLMAgent):

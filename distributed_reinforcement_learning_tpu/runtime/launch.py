@@ -566,14 +566,12 @@ def train_anakin_tokens(config_path: str, section: str, num_updates: int,
     updates on the same `_run_chunk` as the other fused loops."""
     open_devices("anakin-tokens")
     agent_cfg, rt = load_config(config_path, section)
-    # One token-level actor-critic, two models: a looped decoder (`looplm`)
-    # or a hybrid state-space / attention stack (`hybridlm`).
+    # One token-level actor-critic, as many models as
+    # `agents/token_families.TOKEN_FAMILIES` has rows.
     from distributed_reinforcement_learning_tpu.envs.registry import make_jittable_env
     from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import AnakinTokens
 
     agent = _token_agent(agent_cfg)  # at the end of this file; refuses the rest
-    if agent is None:
-        raise ValueError("anakin-tokens mode runs the looplm, hybridlm, moelm and mlalm families")
     env = make_jittable_env(
         rt.envs[0], vocab=agent_cfg.vocab_size,
         episode_len=agent_cfg.trajectory, distance=agent_cfg.recall_distance)
@@ -614,23 +612,16 @@ def train_anakin_tokens(config_path: str, section: str, num_updates: int,
 
 
 def _token_agent(agent_cfg):
-    """The token-level agent of a `looplm`, `hybridlm`, `moelm` or `mlalm`
-    section's configuration, None for any other family's."""
-    from distributed_reinforcement_learning_tpu.agents.hybridlm import (
-        HybridLMAgent, HybridLMConfig)
-    from distributed_reinforcement_learning_tpu.agents.looplm import (
-        LoopLMAgent, LoopLMConfig)
-    from distributed_reinforcement_learning_tpu.agents.mlalm import (
-        MLALMAgent, MLALMConfig)
-    from distributed_reinforcement_learning_tpu.agents.moelm import (
-        MoELMAgent, MoELMConfig)
+    """The token-level agent of a configuration of one of the token
+    families (`agents/token_families.py`); any other is refused."""
+    from distributed_reinforcement_learning_tpu.agents.token_families import (
+        TOKEN_FAMILIES)
 
-    for config, agent in ((LoopLMConfig, LoopLMAgent),
-                          (HybridLMConfig, HybridLMAgent),
-                          (MoELMConfig, MoELMAgent), (MLALMConfig, MLALMAgent)):
+    for config, agent in TOKEN_FAMILIES.values():
         if isinstance(agent_cfg, config):
             return agent(agent_cfg)
-    return None
+    raise ValueError("anakin-tokens mode runs the "
+                     f"{', '.join(TOKEN_FAMILIES)} families")
 
 
 def _pair_slabs(m) -> str:

@@ -98,6 +98,31 @@ def _compute_dtype(d: dict[str, Any], default: str):
     return jnp.dtype(name).type
 
 
+def _token_config(cls, d: dict[str, Any]):
+    """A token family's section -> its config, by the class's own
+    declaration (`agents/looplm.TokenLMConfig`): the model's keys are the
+    source's own, so a `MUST` key the section lacks is an error and not a
+    default (a width is never guessed), what the family's config can say
+    and this program does not compute is refused by name (`ONLY`,
+    `check_section`), and every other field is the section's value or the
+    dataclass's default. The three renames are here and nowhere else."""
+    for key in cls.MUST:
+        if key not in d:
+            raise KeyError(key)
+    for key, only in cls.ONLY.items():
+        if d.get(key, only) != only:
+            raise ValueError(f"{key} {d[key]!r}: only {only!r} is computed")
+    cls.check_section(d)
+    values = {"dtype": _compute_dtype(d, "bfloat16")}
+    for f in dataclasses.fields(cls):
+        key = "initializer_range" if f.name == "init_std" else f.name
+        if key in d and f.name != "dtype" and f.metadata.get("section_key", True):
+            values[f.name] = tuple(d[key]) if isinstance(d[key], list) else d[key]
+    if "learning_frame" in values:
+        values["learning_frame"] = int(values["learning_frame"])
+    return cls(**values)
+
+
 def load_config(path: str | Path, section: str):
     """Load one config section -> (agent_config, runtime_config).
 
@@ -225,204 +250,13 @@ def load_config(path: str | Path, section: str):
             remat=d.get("remat", False),
             dtype=_compute_dtype(d, "float32"),
         )
-    elif algorithm == "looplm":
-        from distributed_reinforcement_learning_tpu.agents.looplm import LoopLMConfig
-
-        # The model's keys are the source's own (`config.json` of the
-        # published model); a key the section lacks is an error, not a
-        # default: a width is never guessed.
-        agent_cfg = LoopLMConfig(
-            vocab_size=d["vocab_size"],
-            hidden_size=d["hidden_size"],
-            num_attention_heads=d["num_attention_heads"],
-            head_dim=d["head_dim"],
-            intermediate_size=d["intermediate_size"],
-            num_hidden_layers=d["num_hidden_layers"],
-            total_ut_steps=d["total_ut_steps"],
-            early_exit_threshold=d.get("early_exit_threshold", 1.0),
-            rms_norm_eps=d.get("rms_norm_eps", 1e-6),
-            rope_theta=d.get("rope_theta", 1e6),
-            trajectory=d.get("trajectory", 128),
-            recall_distance=d.get("recall_distance", 8),
-            discount_factor=d.get("discount_factor", 0.99),
-            baseline_loss_coef=d.get("baseline_loss_coef", 1.0),
-            entropy_coef=d.get("entropy_coef", 0.05),
-            exit_entropy_coef=d.get("exit_entropy_coef", 0.05),
-            gradient_clip_norm=d.get("gradient_clip_norm", 40.0),
-            reward_clipping=d.get("reward_clipping", "abs_one"),
-            start_learning_rate=d.get("start_learning_rate", 1e-5),
-            end_learning_rate=d.get("end_learning_rate", 0.0),
-            learning_frame=int(d.get("learning_frame", 1e9)),
-            dtype=_compute_dtype(d, "bfloat16"),
-            init_std=d.get("initializer_range", 0.02),
-        )
-    elif algorithm == "hybridlm":
-        from distributed_reinforcement_learning_tpu.agents.hybridlm import (
-            HybridLMConfig)
-        from distributed_reinforcement_learning_tpu.models.hybrid_lm import (
-            layer_runs)
-
-        # As `looplm`: the source's own keys, none of them guessed. What
-        # the family's config can say and this program does not compute
-        # is refused by name.
-        layer_types = tuple(d["layer_types"])
-        layer_runs(layer_types)  # an unknown layer type raises here
-        if len(layer_types) != d["num_hidden_layers"]:
-            raise ValueError(f"{len(layer_types)} layer_types for "
-                             f"num_hidden_layers {d['num_hidden_layers']}")
-        for key, only in (("mamba_n_groups", 1), ("num_local_experts", 0),
-                          ("position_embedding_type", "nope"),
-                          ("tie_word_embeddings", True), ("mamba_expand", 2),
-                          ("mamba_conv_bias", True), ("mamba_proj_bias", False),
-                          ("attention_bias", False)):
-            if d.get(key, only) != only:
-                raise ValueError(f"{key} {d[key]!r}: only {only!r} is computed")
-        if d["mamba_n_heads"] * d["mamba_d_head"] != 2 * d["hidden_size"]:
-            raise ValueError("mamba_n_heads x mamba_d_head is not twice hidden_size")
-        agent_cfg = HybridLMConfig(
-            vocab_size=d["vocab_size"],
-            hidden_size=d["hidden_size"],
-            layer_types=layer_types,
-            num_attention_heads=d["num_attention_heads"],
-            num_key_value_heads=d["num_key_value_heads"],
-            shared_intermediate_size=d["shared_intermediate_size"],
-            mamba_n_heads=d["mamba_n_heads"],
-            mamba_d_head=d["mamba_d_head"],
-            mamba_d_state=d["mamba_d_state"],
-            mamba_d_conv=d["mamba_d_conv"],
-            mamba_chunk_size=d["mamba_chunk_size"],
-            embedding_multiplier=d["embedding_multiplier"],
-            residual_multiplier=d["residual_multiplier"],
-            attention_multiplier=d["attention_multiplier"],
-            logits_scaling=d["logits_scaling"],
-            rms_norm_eps=d.get("rms_norm_eps", 1e-5),
-            trajectory=d.get("trajectory", 1024),
-            recall_distance=d.get("recall_distance", 8),
-            discount_factor=d.get("discount_factor", 0.99),
-            baseline_loss_coef=d.get("baseline_loss_coef", 1.0),
-            entropy_coef=d.get("entropy_coef", 0.05),
-            gradient_clip_norm=d.get("gradient_clip_norm", 40.0),
-            reward_clipping=d.get("reward_clipping", "abs_one"),
-            start_learning_rate=d.get("start_learning_rate", 1e-5),
-            end_learning_rate=d.get("end_learning_rate", 0.0),
-            learning_frame=int(d.get("learning_frame", 1e9)),
-            dtype=_compute_dtype(d, "bfloat16"),
-            init_std=d.get("initializer_range", 0.02),
-        )
-    elif algorithm == "moelm":
-        from distributed_reinforcement_learning_tpu.agents.moelm import MoELMConfig
-        from distributed_reinforcement_learning_tpu.models.hybrid_lm import (
-            layer_runs)
-        from distributed_reinforcement_learning_tpu.models.moe_lm import (
-            LAYER_KINDS)
-
-        # As `hybridlm`: the source's own keys, none of them guessed, and
-        # what the family's config can say and this program does not
-        # compute refused by name. `num_experts` is the chip's share of a
-        # layer's `router_width` experts, from `first_expert` on.
-        layer_types = tuple(d["layer_types"])
-        layer_runs(layer_types, LAYER_KINDS)  # an unknown layer type raises here
-        if len(layer_types) != d["num_hidden_layers"]:
-            raise ValueError(f"{len(layer_types)} layer_types for "
-                             f"num_hidden_layers {d['num_hidden_layers']}")
-        for key, only in (("decoder_sparse_step", 1), ("mlp_only_layers", []),
-                          ("norm_topk_prob", True), ("tie_word_embeddings", False),
-                          ("use_sliding_window", False), ("rope_scaling", None),
-                          ("attention_bias", False), ("hidden_act", "silu")):
-            if d.get(key, only) != only:
-                raise ValueError(f"{key} {d[key]!r}: only {only!r} is computed")
-        agent_cfg = MoELMConfig(
-            vocab_size=d["vocab_size"],
-            hidden_size=d["hidden_size"],
-            layer_types=layer_types,
-            num_attention_heads=d["num_attention_heads"],
-            num_key_value_heads=d["num_key_value_heads"],
-            head_dim=d["head_dim"],
-            partial_rotary_factor=d["partial_rotary_factor"],
-            rope_theta=d["rope_theta"],
-            linear_num_key_heads=d["linear_num_key_heads"],
-            linear_num_value_heads=d["linear_num_value_heads"],
-            linear_key_head_dim=d["linear_key_head_dim"],
-            linear_value_head_dim=d["linear_value_head_dim"],
-            linear_conv_kernel_dim=d["linear_conv_kernel_dim"],
-            num_experts=d["num_experts"],
-            router_width=d["router_width"],
-            first_expert=d["first_expert"],
-            num_experts_per_tok=d["num_experts_per_tok"],
-            moe_intermediate_size=d["moe_intermediate_size"],
-            shared_expert_intermediate_size=d["shared_expert_intermediate_size"],
-            rms_norm_eps=d.get("rms_norm_eps", 1e-6),
-            trajectory=d.get("trajectory", 1024),
-            recall_distance=d.get("recall_distance", 8),
-            discount_factor=d.get("discount_factor", 0.99),
-            baseline_loss_coef=d.get("baseline_loss_coef", 1.0),
-            entropy_coef=d.get("entropy_coef", 0.05),
-            gradient_clip_norm=d.get("gradient_clip_norm", 40.0),
-            reward_clipping=d.get("reward_clipping", "abs_one"),
-            start_learning_rate=d.get("start_learning_rate", 1e-5),
-            end_learning_rate=d.get("end_learning_rate", 0.0),
-            learning_frame=int(d.get("learning_frame", 1e9)),
-            dtype=_compute_dtype(d, "bfloat16"),
-            init_std=d.get("initializer_range", 0.02),
-        )
-    elif algorithm == "mlalm":
-        from distributed_reinforcement_learning_tpu.agents.mlalm import MLALMConfig
-
-        # As `moelm`: the source's own keys, none of them guessed, and what
-        # the family's config can say and this program does not compute
-        # refused by name. `n_routed_experts` is the chip's share of a
-        # layer's `router_width` experts, from `first_expert` on.
-        for key, only in (("scoring_func", "sigmoid"), ("topk_method", "noaux_tc"),
-                          ("n_group", 1), ("topk_group", 1), ("moe_layer_freq", 1),
-                          ("norm_topk_prob", True), ("rope_interleave", True),
-                          ("rope_scaling", None), ("attention_bias", False),
-                          ("tie_word_embeddings", False), ("hidden_act", "silu"),
-                          ("num_nextn_predict_layers", 1), ("ep_size", 1)):
-            if d.get(key, only) != only:
-                raise ValueError(f"{key} {d[key]!r}: only {only!r} is computed")
-        if d["qk_head_dim"] != d["qk_nope_head_dim"] + d["qk_rope_head_dim"]:
-            raise ValueError("qk_head_dim is not qk_nope_head_dim + qk_rope_head_dim")
-        if d["num_key_value_heads"] != d["num_attention_heads"]:
-            raise ValueError("latent attention rebuilds a key and a value for "
-                             "every query head: num_key_value_heads differs")
-        agent_cfg = MLALMConfig(
-            vocab_size=d["vocab_size"],
-            hidden_size=d["hidden_size"],
-            num_hidden_layers=d["num_hidden_layers"],
-            first_k_dense_replace=d["first_k_dense_replace"],
-            num_attention_heads=d["num_attention_heads"],
-            q_lora_rank=d["q_lora_rank"],
-            kv_lora_rank=d["kv_lora_rank"],
-            qk_nope_head_dim=d["qk_nope_head_dim"],
-            qk_rope_head_dim=d["qk_rope_head_dim"],
-            v_head_dim=d["v_head_dim"],
-            rope_theta=d["rope_theta"],
-            intermediate_size=d["intermediate_size"],
-            n_routed_experts=d["n_routed_experts"],
-            router_width=d["router_width"],
-            first_expert=d["first_expert"],
-            num_experts_per_tok=d["num_experts_per_tok"],
-            moe_intermediate_size=d["moe_intermediate_size"],
-            n_shared_experts=d["n_shared_experts"],
-            routed_scaling_factor=d["routed_scaling_factor"],
-            rms_norm_eps=d.get("rms_norm_eps", 1e-6),
-            bias_update_speed=d.get("bias_update_speed", 1e-3),
-            mtp_loss_coef=d.get("mtp_loss_coef", 0.3),
-            trajectory=d.get("trajectory", 2048),
-            recall_distance=d.get("recall_distance", 8),
-            discount_factor=d.get("discount_factor", 0.99),
-            baseline_loss_coef=d.get("baseline_loss_coef", 1.0),
-            entropy_coef=d.get("entropy_coef", 0.05),
-            gradient_clip_norm=d.get("gradient_clip_norm", 40.0),
-            reward_clipping=d.get("reward_clipping", "abs_one"),
-            start_learning_rate=d.get("start_learning_rate", 1e-5),
-            end_learning_rate=d.get("end_learning_rate", 0.0),
-            learning_frame=int(d.get("learning_frame", 1e9)),
-            dtype=_compute_dtype(d, "bfloat16"),
-            init_std=d.get("initializer_range", 0.02),
-        )
     else:
-        raise ValueError(f"unknown algorithm {algorithm!r}")
+        from distributed_reinforcement_learning_tpu.agents.token_families import (
+            TOKEN_FAMILIES)
+
+        if algorithm not in TOKEN_FAMILIES:
+            raise ValueError(f"unknown algorithm {algorithm!r}")
+        agent_cfg = _token_config(TOKEN_FAMILIES[algorithm][0], d)
 
     check_config(rt, agent_cfg.num_actions)
     return agent_cfg, rt

@@ -160,3 +160,138 @@ class TestValidationParity:
                            available_action=(2,))
         with pytest.raises(ValueError, match="available_action"):
             check_config(rt, num_actions=2)
+
+
+# -- a token family is declared once (ISSUE 45) --------------------------------
+
+import dataclasses  # noqa: E402
+
+from distributed_reinforcement_learning_tpu.agents.token_families import (  # noqa: E402
+    TOKEN_FAMILIES)
+
+TOKEN_SECTIONS = {"looplm": "ouro_looplm", "hybridlm": "granite_hybrid",
+                  "moelm": "qwen3_next", "mlalm": "joyai_flash"}
+RENAMED = {"init_std": "initializer_range"}  # field -> the section's key
+
+
+def _section(family, **changes):
+    with open("config.json") as f:
+        section = json.load(f)[TOKEN_SECTIONS[family]]
+    return {**section, **changes}
+
+
+def _load(tmp_path, section):
+    return load_config(_write(tmp_path, "s", section), "s")[0]
+
+
+def _fields(cls, section_key: bool):
+    return [f for f in dataclasses.fields(cls)
+            if f.metadata.get("section_key", True) is section_key]
+
+
+def _cases(per_family):
+    return [pytest.param(family, item, id=f"{family}-{item}")
+            for family, (cls, _) in TOKEN_FAMILIES.items()
+            for item in per_family(cls)]
+
+
+def test_the_table_names_the_committed_sections():
+    assert list(TOKEN_FAMILIES) == list(TOKEN_SECTIONS)
+    for family, section in TOKEN_SECTIONS.items():
+        cfg, rt = load_config("config.json", section)
+        assert type(cfg) is TOKEN_FAMILIES[family][0] and rt.algorithm == family
+
+
+@pytest.mark.parametrize("family", list(TOKEN_FAMILIES))
+def test_a_committed_section_is_its_config_built_by_keyword(family):
+    """Every field a section may set is the section's value (three
+    renames: `initializer_range`, `dtype` by name, `learning_frame` an
+    int); every other field is the dataclass's default."""
+    import jax.numpy as jnp
+
+    cls = TOKEN_FAMILIES[family][0]
+    section = _section(family)
+    values = {}
+    for f in _fields(cls, True):
+        key = RENAMED.get(f.name, f.name)
+        if key in section:
+            value = section[key]
+            values[f.name] = tuple(value) if isinstance(value, list) else value
+    values["dtype"] = jnp.dtype(section["dtype"]).type
+    values["learning_frame"] = int(section["learning_frame"])
+    cfg, _ = load_config("config.json", TOKEN_SECTIONS[family])
+    assert cfg == cls(**values) and hash(cfg) == hash(cls(**values))
+    assert set(cls.MUST) <= set(section)
+
+
+@pytest.mark.parametrize("family", list(TOKEN_FAMILIES))
+def test_a_section_of_only_its_must_keys_gives_the_defaults(family, tmp_path):
+    cls = TOKEN_FAMILIES[family][0]
+    full = _section(family)
+    section = {k: full[k] for k in (*cls.MUST, "algorithm", "env", "available_action")}
+    cfg = _load(tmp_path, section)
+    for f in dataclasses.fields(cls):
+        if f.name not in cls.MUST:
+            assert getattr(cfg, f.name) == f.default, f.name
+
+
+@pytest.mark.parametrize("family,key", _cases(lambda cls: cls.MUST))
+def test_a_missing_must_key_is_a_key_error_naming_it(family, key, tmp_path):
+    section = _section(family)
+    del section[key]
+    with pytest.raises(KeyError, match=key):
+        _load(tmp_path, section)
+
+
+@pytest.mark.parametrize("family,key", _cases(lambda cls: cls.ONLY))
+def test_a_refused_value_is_a_value_error_naming_its_key(family, key, tmp_path):
+    with pytest.raises(ValueError, match=key):
+        _load(tmp_path, _section(family, **{key: "something else"}))
+
+
+@pytest.mark.parametrize("family,key", _cases(
+    lambda cls: [f.name for f in _fields(cls, False)]))
+def test_a_field_that_is_no_section_key_does_not_become_one(family, key, tmp_path):
+    """`head_block`, `row_block`, `gdn_chunk`, `attention_backend` (and the
+    one-pass stubs of the three families that do not loop): a section
+    that carries the name is read as if it did not."""
+    cls = TOKEN_FAMILIES[family][0]
+    default = {f.name: f.default for f in _fields(cls, False)}[key]
+    cfg = _load(tmp_path, _section(family, **{key: "not read"}))
+    assert getattr(cfg, key) == default
+
+
+def test_the_shared_fields_are_written_once():
+    """What the four families share is `TokenLMConfig`'s; a family's class
+    redefines of it only a default of its own."""
+    from distributed_reinforcement_learning_tpu.agents.looplm import TokenLMConfig
+
+    shared = {f.name: f.default for f in dataclasses.fields(TokenLMConfig)}
+    own = {"looplm": {"trajectory", "total_ut_steps", "exit_entropy_coef"},
+           "hybridlm": {"rms_norm_eps"}, "moelm": set(), "mlalm": {"trajectory"}}
+    for family, (cls, agent) in TOKEN_FAMILIES.items():
+        assert issubclass(cls, TokenLMConfig)
+        redefined = {name for name in shared if name in cls.__dict__.get(
+            "__annotations__", {})}
+        assert redefined == own[family], family
+        assert agent.__init__.__annotations__["cfg"] == cls.__name__
+
+
+@pytest.mark.parametrize("path", [
+    "distributed_reinforcement_learning_tpu/utils/config.py",
+    "distributed_reinforcement_learning_tpu/runtime/launch.py",
+    "train_ximpala.py"])
+def test_the_family_names_are_spelled_in_one_place(path):
+    """No quoted `looplm` / `hybridlm` / `moelm` / `mlalm` in the
+    configuration reader or the launchers: they read
+    `agents/token_families.TOKEN_FAMILIES` (after `tests/test_scopes.py`'s
+    test of the scope names)."""
+    import re
+
+    with open(path) as f:
+        text = f.read()
+    quoted = re.compile("[\"'](%s)[\"']" % "|".join(TOKEN_FAMILIES))
+    assert quoted.findall(text) == []
+    if path.endswith("config.py"):
+        assert not re.search(r"^\s*(from|import) \S*\.models\b", text, re.M)
+        assert text.count("TOKEN_FAMILIES[") == 1

@@ -7,15 +7,24 @@ sequence parallelism, MoE, pipelining, remat all apply).
 
     python train_ximpala.py --section ximpala --updates 300
 
-`--mode anakin` is the fused on-device loop of the `looplm`, `hybridlm`,
-`moelm` and `mlalm` sections (runtime/anakin_tokens.py): a language model as the
-policy of a token-level IMPALA, generation by decode through its
-act-time state and the learn step in one compiled chunk.
+`--mode anakin` is the fused on-device loop of the token families
+(runtime/anakin_tokens.py): a language model as the policy of a
+token-level IMPALA, generation by decode through its act-time state and
+the learn step in one compiled chunk. The families are the rows of
+`agents/token_families.TOKEN_FAMILIES`; a section names one as its
+`algorithm`, and another family's section is refused with that list.
 
     python train_ximpala.py --mode anakin --section ouro_looplm --updates 8
     python train_ximpala.py --mode anakin --section granite_hybrid --updates 2 --anakin_chunk 1
     python train_ximpala.py --mode anakin --section qwen3_next --updates 2 --anakin_chunk 1
     python train_ximpala.py --mode anakin --section joyai_flash --updates 2 --anakin_chunk 1
+
+Adding a token family: its model file (`models/`), its agent file with
+its config class (`agents/`, a subclass of `agents/looplm.TokenLMConfig`
+that says which published keys a section must carry and what is refused),
+ONE row of `agents/token_families.TOKEN_FAMILIES`, and a section of
+`config.json`. What the benchmark needs of a new cell is in
+`perfbench/README.md`.
 """
 
 from __future__ import annotations
