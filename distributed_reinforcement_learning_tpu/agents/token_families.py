@@ -8,6 +8,8 @@ is its model file, its agent file with its config class
 section loads no language model.
 """
 
+from distributed_reinforcement_learning_tpu.agents.convlm import (
+    ConvLMAgent, ConvLMConfig)
 from distributed_reinforcement_learning_tpu.agents.hybridlm import (
     HybridLMAgent, HybridLMConfig)
 from distributed_reinforcement_learning_tpu.agents.looplm import (
@@ -22,4 +24,5 @@ TOKEN_FAMILIES = {
     "hybridlm": (HybridLMConfig, HybridLMAgent),
     "moelm": (MoELMConfig, MoELMAgent),
     "mlalm": (MLALMConfig, MLALMAgent),
+    "convlm": (ConvLMConfig, ConvLMAgent),
 }

@@ -356,26 +356,22 @@ class LatentMoELM:
         value = z @ p["w_value"].astype(F32) + p["b_value"]
         return logits, jnp.ones_like(value), value
 
+    @property
+    def bias_holders(self) -> tuple:
+        """The key path of every dict of the parameters that holds a
+        selection bias, in the order of `router_load`'s rows: the trunk's
+        expert runs, then the prediction module's layer
+        (`expert_share.rebias`)."""
+        return (*((f"run{i}",) for i, (kind, _) in enumerate(self.runs)
+                  if kind == "moe"), ("mtp", "layer"))
+
     def rebias(self, before: dict, after: dict, load: jax.Array, gamma: float):
-        """`after` (the parameters an optimizer step made of `before`)
-        with every router's selection bias set to `before`'s moved by
-        `gamma sign(mean_j(n_j) - n_i)`, `load [expert layers + 1, E]` the
-        tokens that chose each expert in the step's forward (the trunk's
-        layers in order, then the prediction module's): whatever the
-        optimizer did to the bias is dropped."""
-        load = load.astype(F32)
-        move = gamma * jnp.sign(jnp.mean(load, -1, keepdims=True) - load)
-        p, new = before["params"], dict(after["params"])
-        at = 0
-        for i, (kind, n) in enumerate(self.runs):
-            if kind == "moe":
-                new[f"run{i}"] = {**new[f"run{i}"], "router_bias":
-                                  p[f"run{i}"]["router_bias"] + move[at:at + n]}
-                at += n
-        layer = {**new["mtp"]["layer"], "router_bias":
-                 p["mtp"]["layer"]["router_bias"] + move[at:at + 1]}
-        new["mtp"] = {**new["mtp"], "layer": layer}
-        return {"params": new}
+        """`after` with every router's selection bias set to `before`'s
+        moved by `gamma sign(mean_j(n_j) - n_i)` (`expert_share.rebias`),
+        `load [expert layers + 1, E]`: the trunk's layers in order, then
+        the prediction module's."""
+        return {"params": expert_share.rebias(
+            before["params"], after["params"], load, gamma, self.bias_holders)}
 
     # -- acting as decode --------------------------------------------------
     def init_state(self, num_rows: int, length: int) -> LatentState:

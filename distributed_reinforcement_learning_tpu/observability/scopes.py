@@ -114,6 +114,16 @@ MLA_MTP = {"project": f"{MTP}/mla/project", "attend": f"{MTP}/mla/attend",
            "route": f"{MTP}/moe/route", "experts": f"{MTP}/moe/experts",
            "shared": f"{MTP}/moe/shared"}
 
+# The gated-short-convolution sparse-expert model in the same loop
+# (models/conv_moe_lm.py, ops/expert_share.py); layers, the attention
+# mixer, cache, the dense layer, the expert scopes (no shared expert),
+# heads, V-trace and optimizer under the names above. No bump of
+# CACHE_TAG, for the reason given there.
+ACT_CONV = "collect/act/conv"  # the in-projection's split, both gates, the window shift, the taps
+CONV = "learn/loss/layers/conv"  # the whole convolution mixer: W_in, both gates, the taps, W_out
+CONV_ACT = {"dense": ACT_LAYERS, "route": ACT_MOE_ROUTE, "experts": ACT_MOE_EXPERTS}
+CONV_LEARN = {"dense": DENSE, "route": MOE_ROUTE, "experts": MOE_EXPERTS}
+
 IMPALA_CHUNK_SCOPES = (COLLECT, ACT, ENV, RENDER, RECORD,
                        LEARN, LOSS, VTRACE, OPTIMIZER)
 REPLAY_CHUNK_SCOPES = (COLLECT, REPLAY, LEARN)
@@ -134,6 +144,10 @@ MLA_CHUNK_SCOPES = (ENV, ACT, ACT_LAYERS, ACT_MLA_PROJECT, ACT_MLA_ATTEND,
                     LAYERS, MLA_PROJECT, MLA_ATTEND, DENSE, MOE_ROUTE,
                     MOE_EXPERTS, MOE_SHARED, MTP, *MLA_MTP.values(), HEADS,
                     LOSS_VTRACE, OPTIMIZER)
+
+CONV_CHUNK_SCOPES = (ENV, ACT, ACT_LAYERS, ACT_CONV, ACT_MOE_ROUTE,
+                      ACT_MOE_EXPERTS, ACT_CACHE, ACT_HEAD, LAYERS, CONV, ATTENTION,
+                      DENSE, MOE_ROUTE, MOE_EXPERTS, HEADS, LOSS_VTRACE, OPTIMIZER)
 
 # -- host spans of the fused loops (runtime/launch.py) ---------------------
 STEP_READ = "anakin/step_read"  # int(state.train.step) at the loop head

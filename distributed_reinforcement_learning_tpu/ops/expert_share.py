@@ -46,6 +46,7 @@ grouped products take operands in `dtype` with float32 accumulation.
 from __future__ import annotations
 
 import functools
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -55,7 +56,7 @@ F32 = jnp.float32
 
 def route(x: jax.Array, w_router: jax.Array, top_k: int,
           scoring: str = "softmax", select_bias: jax.Array | None = None,
-          scale: float = 1.0):
+          scale: float = 1.0, weight_eps: float = 1e-20):
     """`x [N, D]`, `w_router [D, E]` -> (`probs [N, E]`, `chosen [N,
     top_k]` int32 expert ids in order of decreasing probability, `weight
     [N, top_k]` renormalised over the chosen), float32.
@@ -64,9 +65,10 @@ def route(x: jax.Array, w_router: jax.Array, top_k: int,
     arXiv:2412.19437 section 2.1.2): `probs` are the sigmoid scores of
     ALL experts, the set is CHOSEN by score + `select_bias [E]` (a bias
     no gradient reaches), the weights are the UNBIASED scores of the
-    chosen, renormalised and times `scale`; a fourth result, `load [E]`
+    chosen, renormalised (over their sum + `weight_eps`, the source
+    family's own constant) and times `scale`; a fourth result, `load [E]`
     int32, counts the tokens that chose each expert (what moves the bias
-    after the step)."""
+    after the step: `rebias`)."""
     logits = jnp.dot(x.astype(F32), w_router.astype(F32),
                      precision=jax.lax.Precision.HIGHEST)
     if scoring == "softmax":
@@ -82,7 +84,33 @@ def route(x: jax.Array, w_router: jax.Array, top_k: int,
     load = jnp.sum(chosen[..., None] == jnp.arange(scores.shape[-1]),
                    axis=(0, 1), dtype=jnp.int32)
     return (scores, chosen.astype(jnp.int32),
-            scale * top / (jnp.sum(top, -1, keepdims=True) + 1e-20), load)
+            scale * top / (jnp.sum(top, -1, keepdims=True) + weight_eps), load)
+
+
+def rebias(before: dict, after: dict, load: jax.Array, gamma: float,
+           holders) -> dict:
+    """`after` (the parameters an optimizer step made of `before`; both
+    the tree under `"params"`) with every router's selection bias set to
+    `before`'s moved by `gamma sign(mean_j(n_j) - n_i)` (arXiv:2412.19437
+    section 2.1.2), `load [rows, E]` the tokens that chose each expert in
+    the step's forward: whatever the optimizer did to the bias is
+    dropped. `holders`: the key path of every dict that holds a
+    `router_bias [n, E]`, in the order of `load`'s rows (a stacked run of
+    n layers takes n of them)."""
+    load = load.astype(F32)
+    move = gamma * jnp.sign(jnp.mean(load, -1, keepdims=True) - load)
+
+    def replaced(old: dict, new: dict, path: tuple, rows: jax.Array) -> dict:
+        if not path:
+            return {**new, "router_bias": old["router_bias"] + rows}
+        return {**new, path[0]: replaced(old[path[0]], new[path[0]], path[1:], rows)}
+
+    new, at = after, 0
+    for path in holders:
+        n = functools.reduce(operator.getitem, path, before)["router_bias"].shape[0]
+        new = replaced(before, new, tuple(path), move[at:at + n])
+        at += n
+    return new
 
 
 def held_pairs(chosen: jax.Array, first_expert: int, held: int):

@@ -178,7 +178,10 @@ def test_every_pair_held_runs_every_slab_and_drops_none():
     (4096 * 8, 16, 256, 2560), (4096 * 10, 32, 512, 3584),  # the two cells' learners
     (16 * 8, 16, 256, 128), (32 * 10, 32, 512, 320),  # their decode steps: the list
     (1024, 2, 16, 512), (1000, 2, 16, 512), (4096, 8, 8, 4096), (600, 16, 16, 600),
-    (4096 * 8, 17, 256, 3072)])
+    (4096 * 8, 17, 256, 3072),
+    # a QUARTER of the experts (16 of 64, 4 a token: `lfm2_moe`): a learner's
+    # row block of 4 x 1,024 tokens, a decode step at 64 rows, one row
+    (4096 * 4, 16, 64, 5120), (64 * 4, 16, 64, 256), (1024 * 4, 16, 64, 1536)])
 def test_slab_rows_is_the_expectation_and_a_quarter_rounded_up_and_capped(pairs, held, experts, want):
     assert expert_share.slab_rows(pairs, held, experts) == want
 
@@ -291,14 +294,16 @@ def _graded_jaxpr(n, top_k, experts, held, d, width):
     return jax.make_jaxpr(graded)(*shapes, chosen).jaxpr
 
 
-@pytest.mark.parametrize("n,top_k,experts,held", [(16, 8, 256, 16), (32, 10, 512, 32)])
+@pytest.mark.parametrize("n,top_k,experts,held", [(16, 8, 256, 16), (32, 10, 512, 32),
+                                                  (64, 4, 64, 16)])
 def test_a_decode_steps_list_is_one_slab_and_no_loop(n, top_k, experts, held):
     names = {name for name, _ in _primitives(_graded_jaxpr(n, top_k, experts, held, 64, 32))}
     assert "while" not in names and "ragged_dot_general" in names
     assert not any("custom_vjp" in name for name in names)
 
 
-@pytest.mark.parametrize("n,top_k,experts,held", [(512, 8, 256, 16), (512, 10, 512, 32)])
+@pytest.mark.parametrize("n,top_k,experts,held", [(512, 8, 256, 16), (512, 10, 512, 32),
+                                                  (256, 4, 64, 16)])
 def test_at_a_learners_shape_nothing_wide_is_as_long_as_the_pair_list(
         n, top_k, experts, held):
     """Forward and backward: the `[P]` index vectors and the `[P, held]`
@@ -312,3 +317,50 @@ def test_at_a_learners_shape_nothing_wide_is_as_long_as_the_pair_list(
             if len(s) == 2 and s[0] >= pairs and s[1] in (d, width, 2 * width)]
     assert not wide, wide
     assert any(s == (pairs, held) for _, shapes in found for s in shapes)
+
+
+# -- a QUARTER of the experts and no shared expert (ISSUE 46) --------------------
+
+
+@pytest.mark.parametrize("count", [0, 1, 255, 256, 640, 1024])
+def test_pair_slabs_at_a_quarter_share(count):
+    """256 tokens x 4 choices of 16 experts, 4 held from expert 4 on: the
+    expectation is 256 pairs, the slab 320 rounded up to 512, so a uniform
+    router's call is ONE slab where a sixteenth's list of the same length
+    would be one too, and a call with every pair here is two."""
+    lay = layer(11, held=4)
+    assert expert_share.slab_rows(N * TOP_K, 4, E) == SLAB
+    chosen = choices(count, held=4, seed=11)
+    weight = weights(lay, chosen)(lay["router"])
+    with jax.default_matmul_precision("highest"):
+        out, counters = expert_share.held_experts(
+            lay["x"], chosen, weight, lay["wgu"], lay["wd"], 4, E, F32)
+        want = whole_buffer(lay["x"], chosen, weight, lay["wgu"], lay["wd"], 4, F32)
+    assert int(counters["pair_slabs"]) == math.ceil(count / SLAB)
+    assert int(counters["held_pairs"]) == count == int(jnp.sum(counters["expert_pairs"]))
+    assert int(counters["dropped_pairs"]) == 0
+    assert rel(out, want) < 1e-6
+
+
+def test_a_layer_without_a_shared_expert_is_the_sum_of_its_shares():
+    """Four shares of four experts: with a sigmoid router over all 16 and
+    nothing computed alike on every chip, the shares' parts add up to the
+    layer over all 16 held at once, and a token none of whose choices is
+    held here gets exactly zero from this share."""
+    lay = layer(12, held=E)
+    bias = 0.05 * jnp.asarray(np.random.RandomState(12).normal(size=E), F32)
+    with jax.default_matmul_precision("highest"):
+        _, chosen, weight, load = expert_share.route(
+            lay["x"], lay["router"], TOP_K, "sigmoid", bias, 1.0, 1e-6)
+        whole, counters = expert_share.held_experts(
+            lay["x"], chosen, weight, lay["wgu"], lay["wd"], 0, E, F32)
+        parts = [expert_share.held_experts(
+            lay["x"], chosen, weight, lay["wgu"][first:first + 4],
+            lay["wd"][first:first + 4], first, E, F32) for first in (0, 4, 8, 12)]
+    assert rel(sum(p[0] for p in parts), whole) < 1e-6
+    assert sum(int(p[1]["held_pairs"]) for p in parts) == N * TOP_K == int(jnp.sum(load))
+    assert int(counters["pair_slabs"]) == 1  # every expert held: the list is one slab
+    first_share = np.asarray(parts[0][0])
+    nobody_here = ~np.any(np.asarray(chosen) < 4, axis=-1)
+    assert nobody_here.any() and not np.any(first_share[nobody_here])
+    assert np.all(np.any(first_share[~nobody_here] != 0, axis=-1))

@@ -36,9 +36,11 @@ from test_anakin import anakin_cfg
 from test_anakin_r2d2 import make as make_r2d2
 from test_granite_hybrid import CFG as HYBRID_CFG
 from test_joyai_flash import CFG as MLA_CFG
+from test_lfm2_moe import CFG as CONV_CFG
 from test_ouro_looplm import CFG as LOOP_CFG
 from test_qwen3_next import CFG as MOE_CFG
 
+from distributed_reinforcement_learning_tpu.agents.convlm import ConvLMAgent
 from distributed_reinforcement_learning_tpu.agents.hybridlm import HybridLMAgent
 from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent
 from distributed_reinforcement_learning_tpu.agents.looplm import LoopLMAgent
@@ -112,6 +114,8 @@ LOOPS = {
     "hybridlm": (_tokens(HybridLMAgent, HYBRID_CFG), 1, (776, 39, 39), 1611),
     "moelm": (_tokens(MoELMAgent, MOE_CFG), 1, (983, 43, 43), 2305),
     "mlalm": (_tokens(MLALMAgent, MLA_CFG), 1, (1041, 46, 46), 2335),
+    # read at PR 46's own tree: the family is new there
+    "convlm": (_tokens(ConvLMAgent, CONV_CFG), 1, (765, 38, 38), 1908),
 }
 
 
@@ -173,6 +177,11 @@ _SMALL = {
         intermediate_size=48, n_routed_experts=4, router_width=16,
         first_expert=4, num_experts_per_tok=3, moe_intermediate_size=16,
         vocab_size=96, available_action=[96], trajectory=32),
+    "lfm2_moe": dict(
+        hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
+        intermediate_size=48, num_experts=4, router_width=16, first_expert=4,
+        num_experts_per_tok=3, moe_intermediate_size=16, vocab_size=96,
+        available_action=[96], trajectory=32),
 }
 
 

@@ -295,6 +295,82 @@ def test_the_prediction_modules_layer_is_not_named_for_the_stack(mla_chunk_names
     assert not any(scopes.ACT in n and scopes.MTP in n for n in mla_chunk_names)
 
 
+def _conv_anakin(trajectory=16, row_block=2):
+    """The small LFM2 loop: 4 rows of `trajectory` tokens."""
+    import jax.numpy as jnp
+
+    from distributed_reinforcement_learning_tpu.agents.convlm import (
+        ConvLMAgent, ConvLMConfig)
+    from distributed_reinforcement_learning_tpu.envs.token_recall_jax import TokenRecall
+    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import AnakinTokens
+
+    cfg = ConvLMConfig(
+        vocab_size=64, hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
+        rope_theta=1e4, intermediate_size=48, num_experts=4, router_width=16,
+        first_expert=4, num_experts_per_tok=3, moe_intermediate_size=16,
+        trajectory=trajectory, dtype=jnp.float32, head_block=16, row_block=row_block)
+    return AnakinTokens(ConvLMAgent(cfg), 4, TokenRecall(64, trajectory))
+
+
+@pytest.fixture(scope="module")
+def conv_chunk_names():
+    anakin = _conv_anakin()
+    return _op_names(anakin.train_chunk, anakin.init(jax.random.PRNGKey(0)), 1)
+
+
+@pytest.mark.parametrize("name", scopes.CONV_CHUNK_SCOPES)
+def test_conv_chunk_carries_scope(conv_chunk_names, name):
+    """The names `perfbench/layer_metrics/convlm_*` read (ISSUE 46)."""
+    assert any(name in n for n in conv_chunk_names), name
+
+
+@pytest.mark.parametrize("name", [scopes.LAYERS, scopes.CONV, scopes.ATTENTION,
+                                  scopes.DENSE, scopes.MOE_ROUTE, scopes.MOE_EXPERTS])
+def test_conv_backward_stack_keeps_its_names(conv_chunk_names, name):
+    """The rematerialised blocks are entered again under the transpose."""
+    assert any(f"transpose(jvp({scopes.LOSS}))" in n and name in n
+               for n in conv_chunk_names)
+
+
+def _equations_under(jaxpr, scope, above="", found=None):
+    """The primitive of every equation whose composed name stack holds
+    `scope`, through every jaxpr inside."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        stack = f"{above}/{eqn.source_info.name_stack}"
+        subs = list(jax.core.jaxprs_in_params(eqn.params))
+        if scope in stack and not subs:
+            found.append(eqn.primitive.name)
+        for sub in subs:
+            _equations_under(sub, scope, stack, found)
+    return found
+
+
+def test_every_equation_of_the_convolution_mixer_is_under_its_name():
+    """In the learner the whole mixer is `learn/loss/layers/conv`: the
+    in-projection and the out-projection (two products a convolution
+    layer's block), the split, both gates, the pads and the three taps.
+    At act time `collect/act/conv` holds what is the convolution's alone:
+    the split, both gates, the window's shift (a concatenate and a slice)
+    and the taps' product; the two projections stay with the decode step's
+    other products under `collect/act/layers`. No shared expert's scope is
+    anywhere in the chunk."""
+    anakin = _conv_anakin()
+    state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+    jaxpr = jax.make_jaxpr(anakin.train_chunk, static_argnums=1)(state, 1).jaxpr
+    learner = _equations_under(jaxpr, scopes.CONV)
+    assert learner.count("dot_general") >= 2 * 2  # W_in, W_out in two convolution runs
+    for primitive in ("split", "mul", "pad", "select_n"):
+        assert primitive in learner, primitive
+    act = _equations_under(jaxpr, scopes.ACT_CONV)
+    for primitive in ("split", "mul", "concatenate", "slice", "dot_general"):
+        assert primitive in act, primitive
+    # the taps are the one product under the name: 4 convolution layers x 2 decode bodies
+    assert act.count("dot_general") == 4 * len(anakin.decode_spans)
+    assert not _equations_under(jaxpr, scopes.MOE_SHARED)
+    assert not _equations_under(jaxpr, "collect/act/gdn")
+
+
 def test_r2d2_backward_recurrence_keeps_the_unroll_name(r2d2_chunk_names):
     """The inner scope is entered again inside the transposed outer one:
     `transpose(jvp(learn/loss))/.../learn/loss/unroll/...`."""
@@ -440,8 +516,9 @@ def _loops_over_slabs(jaxpr, above=""):
 
 @pytest.mark.parametrize("make,experts", [
     (_moe_anakin, [scopes.MOE_EXPERTS]),
-    (_mla_anakin, [scopes.MOE_EXPERTS, scopes.MLA_MTP["experts"]])],
-    ids=["qwen3_next", "joyai_flash"])
+    (_mla_anakin, [scopes.MOE_EXPERTS, scopes.MLA_MTP["experts"]]),
+    (_conv_anakin, [scopes.MOE_EXPERTS])],
+    ids=["qwen3_next", "joyai_flash", "lfm2_moe"])
 def test_every_op_of_the_slab_loop_is_named_for_the_experts(make, experts):
     """4 rows x 64 tokens x 3 choices = 768 pairs in slabs of 512: the
     learner loops. Every equation of the loop, forward and in the loop's own

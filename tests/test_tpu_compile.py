@@ -5,7 +5,7 @@ described, not opened (`/opt/skills/guides/on-chip-measurement`, section
 2). That catches what interpret mode cannot — a slice not aligned to the
 tiling, a kernel over its VMEM budget, a program Mosaic refuses — at the
 shapes the main path really runs (`config.json` sections `impala`,
-`apex`, `r2d2_pixel`, `r2d2_atari`, `ouro_looplm`, `granite_hybrid`, `qwen3_next`, `joyai_flash`; the Anakin chunk
+`apex`, `r2d2_pixel`, `r2d2_atari`, `ouro_looplm`, `granite_hybrid`, `qwen3_next`, `joyai_flash`, `lfm2_moe`; the Anakin chunk
 `chip_smoke.py` drives), and
 costs no chip time. It also shows what the compiler DID with a program:
 which layout copies and which collectives it put in (the fused IMPALA
@@ -555,6 +555,66 @@ def test_joyai_flash_chunk_fits_and_holds_its_fourteen_kernels(chip,
     assert not re.findall(r"bf16\[16,\d+,32,(192|128)\]", text)
     # no array with the router's width AND a capacity beside the tokens
     assert not re.findall(r"\[4096,256,\d+\]|\[32768,256,\d+\]", text)
+
+
+@pytest.mark.slow  # 80-100 s, in the file that ends tier-1's run: before a chip call
+def test_lfm2_moe_chunk_fits_and_holds_its_six_kernels(chip, kernels_as_on_chip):
+    """The fused token chunk at the `lfm2_moe` section's sizes (64 envs x
+    1,024 tokens; a dense layer and one period of LFM2-24B-A2B's order at
+    2048 wide: four double-gated short convolutions and one grouped-query
+    attention, a 64-way router over 16 held experts, no shared expert;
+    chunk of 1): it compiles for a described v5e and the donated state
+    (788.1 M parameters + their second moments, 8 B each) is aliased
+    whole. Arguments + scratch by `memory_analysis` read 18.98 GB at a row
+    block of 4 (18.47 at 2: the temporaries are the optimizer's whole
+    trees, 15.4 B a parameter as in `joyai_flash`, not the row block's),
+    which reads about 1.2 times the chip's own peak (PERF.md section 4):
+    held here under 19.2 GB, the chip's own under 95 % of its
+    `bytes_limit` by the benchmark's run. Six Mosaic kernels in the
+    LOWERED chunk, the configuration file's count: flash attention in the
+    ONE run of layers that holds attention (forward, rematerialised
+    forward, dq, dkv) and V-trace's two views. The act-time state is four
+    windows of two columns in the compute dtype and one cache of the
+    key/value heads: no cache of the 32 query heads."""
+    import json
+
+    from distributed_reinforcement_learning_tpu.agents.convlm import ConvLMAgent
+    from distributed_reinforcement_learning_tpu.envs.registry import (
+        make_jittable_env)
+    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import (
+        AnakinTokens)
+
+    cfg, rt = load_config(CONFIG, "lfm2_moe")
+    env = make_jittable_env(rt.envs[0], vocab=cfg.vocab_size,
+                            episode_len=cfg.trajectory,
+                            distance=cfg.recall_distance)
+    anakin = AnakinTokens(ConvLMAgent(cfg), rt.num_actors * rt.envs_per_actor, env)
+    state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+    lowered = anakin.train_chunk.lower(_on(chip, state), 1)
+    with open(os.path.join(os.path.dirname(CONFIG), "perfbench", "configs",
+                           "lfm2_moe.json")) as f:
+        named = json.load(f)["kernels"]["tpu_custom_call"]
+    assert len(re.findall("tpu_custom_call", lowered.as_text())) == named == 6
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    params = 788_054_145
+    assert mem.alias_size_in_bytes == mem.argument_size_in_bytes > 8 * params
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert held < 19.2e9, held
+    facts = anakin.static_facts
+    assert facts["layer_order"] == ("conv+dense", "full_attention+moe",
+                                    "conv+moe", "conv+moe", "conv+moe")
+    assert facts["conv_state_bytes"] == 4 * 64 * 2 * 2048 * 2
+    assert facts["kv_cache_bytes"] == 2 * 64 * 1024 * 8 * 64 * 2
+    assert (facts["experts_held"], facts["router_width"]) == (16, 64)
+    assert facts["act_weight_bytes"] == 1_577_058_304
+    assert facts["decode_spans"] == tuple(range(128, 1025, 128))
+    text = compiled.as_text()
+    assert re.findall(r"bf16\[64,2,2048\]", text)  # the windows, in the compute dtype
+    assert not re.findall(r"bf16\[64,\d{3,4},32,64\]", text)  # no cache of the query heads
+    # no array with the router's width AND a capacity beside the tokens
+    assert not re.findall(r"\[4096,64,\d+\]|\[65536,64,\d+\]", text)
 
 
 def test_breakout_step_keeps_no_raster_and_one_luma(chip):
