@@ -227,7 +227,9 @@ class MoELM:
             shared = share[..., None] * self._mm(jax.nn.silu(gate) * up,
                                                  lp["shared_wd"])
         stats = jax.lax.stop_gradient({
-            **counters, "shared_gate_sum": jnp.sum(share),
+            **{k: counters[k] for k in ("held_pairs", "expert_pairs", "pair_slabs",
+                                        "dropped_pairs")},
+            "shared_gate_sum": jnp.sum(share),
             "router_entropy_sum": -jnp.sum(jnp.where(
                 probs > 0, probs * jnp.log(jnp.where(probs > 0, probs, 1.0)), 0.0))})
         picked = jax.lax.stop_gradient(jnp.take_along_axis(probs, chosen, axis=-1))

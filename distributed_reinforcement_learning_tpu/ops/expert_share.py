@@ -13,18 +13,37 @@ What the absent experts would have added is left out (their chips add
 it, in a deployment, through an exchange this file does not stand in
 for). `ops/moe.py` is the other expert layer of the repo: all experts
 here, a one-hot `[N, E, C]` dispatch with a fixed capacity that DROPS
-what overflows. This one is dropless with static shapes: the pairs are
-sorted, held first and by expert, into a list of the worst case's length
-(`N x top_k`: every choice of every token held here), and the SORTED LIST
-is worked a slab at a time, as many slabs as hold a held pair
-(`ceil(held pairs / slab)`, a trip count read from the data: nothing is
-compiled again when it changes). A slab gathers its rows of `x`, runs the
-held experts as two grouped products (`jax.lax.ragged_dot`), weights the
-result and adds it to its tokens. A slab is the held pairs a uniform
-router would send here and a quarter more, `1.25 N x top_k x held / E`
-rounded up to 512 rows (`slab_rows`); where that is the whole list (a decode step; a layer that
-holds every expert) there is one slab and no loop. No array has an expert
-AND a capacity axis, and none has the list's length and a model width.
+what overflows. This one is dropless with static shapes, and takes one of
+two forms of the same sum, chosen when it is traced from the shapes alone
+(`slab_rows`, `one_slab_form`; `call_form` says which, and `runtime/
+launch.py` prints it once at start-up):
+
+SORTED. The pairs are sorted, held first and by expert, into a list of the
+worst case's length (`N x top_k`: every choice of every token held here),
+and the SORTED LIST is worked a slab at a time, as many slabs as hold a
+held pair (`ceil(held pairs / slab)`, a trip count read from the data:
+nothing is compiled again when it changes). A slab gathers its rows of
+`x`, runs the held experts as two grouped products (`jax.lax.ragged_dot`),
+weights the result and adds it to its tokens. A slab is the held pairs a
+uniform router would send here and a quarter more, `1.25 N x top_k x held
+/ E` rounded up to 512 rows (`slab_rows`); where that is the whole list (a
+decode step; a layer that holds every expert) there is one slab and no
+loop. No array has an expert AND a capacity axis, and none has the list's
+length and a model width. Every learner runs this form (4,096 rows a call
+and more), and the decode steps that send a held expert under one pair a
+call (`qwen3_next`: 32 rows x 10 of 512, `joyai_flash`: 16 x 8 of 256),
+where the grouped product reads the touched experts' weights alone.
+
+DENSE (PR 47). Where the list is one slab, the router sends every held
+expert more than one pair a call and the rows are few (`lfm2_moe`'s decode
+step: 64 rows x 4 of 64, 16 held, four pairs an expert), every held
+expert's weights are read whatever the routing, and the sort, the gather,
+the scatter-add and the grouped products buy nothing: the held experts run
+as ONE batched product over all rows, `[held, N, 2 F]` then `[held, N,
+D]`, the weights contracted as they are stored, and each row's results
+are weighted by what the router gave the expert, 0 for one it did not
+choose. The same sum in another order of float32 additions, 429 us a layer
+a step where the sorted form takes 623 (`one_slab_form` has the table).
 
 Which work follows the pairs that are here. Until PR 42 the list was ONE
 buffer `[N x top_k, D]`: the grouped products skipped the rows past the
@@ -35,12 +54,12 @@ and `[N x top_k, held]`, no model width) are as long as the list; the
 pairs' weights are gathered, and everything `D`, `F` or `2 F` wide is, a
 slab at a time. What a trip costs whatever its rows: in the backward,
 passes over arrays as large as the weights (their two transposes, their
-gradients' float32 sums).
+gradients' float32 sums). (The sorted form's.)
 
 Router logits, softmax, top-k and the weights w are float32 (the product
 at `highest` precision: a choice between two experts is discontinuous,
 and it is made from float32 logits as the configuration states); the
-grouped products take operands in `dtype` with float32 accumulation.
+products of both forms take operands in `dtype` with float32 accumulation.
 """
 
 from __future__ import annotations
@@ -115,17 +134,13 @@ def rebias(before: dict, after: dict, load: jax.Array, gamma: float,
 
 def held_pairs(chosen: jax.Array, first_expert: int, held: int):
     """The (token, choice) pairs whose expert lies in `[first_expert,
-    first_expert + held)`, sorted by expert -> (`order [N * top_k]`: the
-    flat pair index at every row of the buffer, held pairs first, by
-    expert; `sizes [held]` int32 pairs an expert; `here [N, top_k]`
-    bool)."""
+    first_expert + held)` -> (`key [N * top_k]` int32: every pair's expert
+    among the held, `held` for an absent one; `here [N, top_k]` bool).
+    Both forms of `held_experts` take their pairs from here and nowhere
+    else."""
     local = chosen - first_expert
     here = (local >= 0) & (local < held)
-    key = jnp.where(here, local, held).reshape(-1)  # `held`: an absent expert
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
-                    dtype=jnp.int32)
-    return order, sizes, here
+    return jnp.where(here, local, held).reshape(-1), here
 
 
 def slab_rows(pairs: int, held: int, num_experts: int) -> int:
@@ -160,6 +175,76 @@ def _slab(rows: jax.Array, weight: jax.Array, wgu: jax.Array, wd: jax.Array,
     y = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(rows.dtype), wd, sizes,
                            preferred_element_type=F32)
     return jnp.where(live, y, 0.0) * weight[:, None]
+
+
+def one_slab_form(n: int, top_k: int, num_experts: int) -> str:
+    """`"dense"` or `"sorted"`: the form of a call of `held_experts` on `n`
+    rows whose pair list is one slab, from shapes alone. Dense where (i) a
+    uniform router sends every held expert MORE than one pair a call (`n x
+    top_k > num_experts`), so every held expert's weights are read in
+    either form, and (ii) `n <= 256`, where `n x held` rows of product
+    still take less than the weights' read (a bfloat16 weight gives `n`
+    operations a byte, the chip's ridge is about 240). The readings, us a
+    layer a step in a scan of 64 decode steps of 4 layers, bfloat16, D
+    2,048, sorted | dense (my chip run, PR 47; `scripts/expert_share_bench.
+    py --rows`; 302 MB of weights take 369 us at HBM's peak, 151 MB 184):
+
+        64 rows, top 4 of 64, 16 held, F 1,536 (`lfm2_moe`'s decode)  623 | 429
+        the same at 16 / 32 / 128 rows          334 | 437, 470 | 439, 1,016 | 470
+        the same at F 768, 32 / 64 / 128 rows   263 | 217, 358 | 225, 556 | 241
+        32 rows, top 10 of 512, 32 held, F 512 (`qwen3_next`'s)       234 | 294
+        16 rows, top 8 of 256, 16 held, F 768 (`joyai_flash`'s)       156 | 214
+        the same at 32 / 64 rows                          249 | 217, 492 | 225
+        all 16 of 16 held, F 1,536, 128 / 256 / 384 / 512 rows
+                              1,016 | 470, 1,171 | 482, 1,271 | 745, 1,400 | 958
+
+    Under one pair an expert the sorted form wins (it reads the touched
+    experts alone: 39-46 % of them at the two other cells' decode steps),
+    over one the dense form does; AT one the two shapes measured disagree
+    (16 rows of 64: sorted by 24 %; 32 rows of 256: dense by 13 %) and the
+    rule says sorted. The dense product is bound by the weights' read up
+    to 256 rows (482 us) and by the matrix unit past it (745 at 384); it
+    still beat the sorted form there with every pair held, which no caller
+    does, so the bound is the ridge and not the last win."""
+    return "dense" if n * top_k > num_experts and n <= 256 else "sorted"
+
+
+def _form(n: int, top_k: int, held: int, num_experts: int) -> tuple[str, int]:
+    """(`"dense"`, `"sorted"` (one slab) or `"slabs"`; the rows of a slab) of
+    a call of `held_experts` on `n` rows."""
+    slab = slab_rows(n * top_k, held, num_experts)
+    if slab < n * top_k:
+        return "slabs", slab
+    return one_slab_form(n, top_k, num_experts), slab
+
+
+def call_form(n: int, top_k: int, held: int, num_experts: int) -> str:
+    """The form and the shape of a call of `held_experts` on `n` rows, as a
+    start-up line says it (`runtime/launch.py`): static, as compiled."""
+    form, slab = _form(n, top_k, held, num_experts)
+    return {"dense": f"dense, {n} rows x {held} held",
+            "sorted": f"sorted, one slab of {slab} pairs",
+            "slabs": f"sorted, {n * top_k} pairs in slabs of {slab}"}[form]
+
+
+def _dense(x: jax.Array, key: jax.Array, weight: jax.Array, wgu: jax.Array,
+           wd: jax.Array) -> jax.Array:
+    """Every held expert on every row, one batched product over the
+    experts, a row's results weighted by what the router gave each (0: an
+    expert the row did not choose): `x [N, D]`, `key, weight [N x top_k]`
+    (`held_pairs`' experts, the router's weights) -> `[N, D]` float32, the
+    sorted form's sum in another order of float32 additions."""
+    held, pairs = wgu.shape[0], (x.shape[0], -1)
+    w = jnp.sum(jnp.where(
+        key.reshape(pairs)[None] == jnp.arange(held)[:, None, None],
+        weight.reshape(pairs)[None], 0.0), axis=-1)[..., None]  # [held, N, 1]
+    gate, up = jnp.split(jnp.einsum(
+        "end,edf->enf", jnp.broadcast_to(x, (held, *x.shape)), wgu,
+        preferred_element_type=F32), 2, -1)
+    y = jnp.einsum("enf,efd->end", (jax.nn.silu(gate) * up).astype(x.dtype), wd,
+                   preferred_element_type=F32)
+    # 0 x a non-finite result of an expert the row did not choose would be NaN
+    return jnp.sum(jnp.where(w != 0, y, 0.0) * w, axis=0)
 
 
 def _trips(sizes, slab: int):
@@ -229,15 +314,22 @@ def held_experts(x: jax.Array, chosen: jax.Array, weight: jax.Array,
     D, 2 F]` (gate and up side by side), `wd [held, F, D]`, `num_experts`:
     the router's width -> (`out [N, D]` float32: the held experts'
     weighted part of the layer's result; counters). Dropless: every held
-    pair lies in exactly one slab, and every slab with one is run."""
+    pair lies in exactly one slab, and every slab with one is run; the
+    dense form (`one_slab_form`) runs every held expert on every row."""
     n, top_k = chosen.shape
     held = wgu.shape[0]
-    slab = slab_rows(n * top_k, held, num_experts)
-    order, sizes, here = held_pairs(chosen, first_expert, held)
+    form, slab = _form(n, top_k, held, num_experts)
+    key, here = held_pairs(chosen, first_expert, held)
+    if form != "dense":  # held pairs first, by expert: the flat pair index a row
+        order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                    dtype=jnp.int32)  # pairs an expert
     count, trips = jnp.sum(sizes), _trips(sizes, slab)
     x, wgu, wd = x.astype(dtype), wgu.astype(dtype), wd.astype(dtype)
     weight = weight.reshape(-1)
-    if slab == n * top_k:  # the list is one slab: no loop, autodiff's own backward
+    if form == "dense":  # every held expert is read anyway: no sort, no grouped product
+        out = _dense(x, key, weight, wgu, wd)
+    elif form == "sorted":  # the list is one slab: no loop, autodiff's own backward
         token = order // top_k
         out = jnp.zeros(x.shape, F32).at[token].add(
             _slab(x[token], weight[order], wgu, wd, sizes, count))
@@ -245,5 +337,6 @@ def held_experts(x: jax.Array, chosen: jax.Array, weight: jax.Array,
         out = _slabs(x, weight, wgu, wd, jnp.pad(order, (0, -order.size % slab)),
                      sizes, slab, top_k)
     counters = {"held_pairs": count, "expert_pairs": sizes, "pair_slabs": trips,
-                "dropped_pairs": jnp.sum(here) - jnp.minimum(count, trips * slab)}
+                "dropped_pairs": jnp.sum(here) - jnp.minimum(count, trips * slab),
+                "dense_rows": jnp.int32(n * held if form == "dense" else 0)}
     return out, counters

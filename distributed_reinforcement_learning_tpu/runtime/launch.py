@@ -11,6 +11,7 @@ runtime/transport.
 
 from __future__ import annotations
 
+import math
 import time
 from typing import Any
 
@@ -577,7 +578,8 @@ def train_anakin_tokens(config_path: str, section: str, num_updates: int,
         episode_len=agent_cfg.trajectory, distance=agent_cfg.recall_distance)
     anakin = AnakinTokens(agent,
                           num_envs or rt.num_actors * rt.envs_per_actor, env)
-    print(f"[anakin-tokens] {anakin.static_facts}")  # static, as compiled
+    # static, as compiled
+    print(f"[anakin-tokens] {anakin.static_facts}{_expert_calls(anakin)}")
     state = anakin.init(jax.random.PRNGKey(seed))
     ckpt, train = _restore_train(checkpoint_dir, state.train)
     state = state._replace(train=train)
@@ -622,6 +624,23 @@ def _token_agent(agent_cfg):
             return agent(agent_cfg)
     raise ValueError("anakin-tokens mode runs the "
                      f"{', '.join(TOKEN_FAMILIES)} families")
+
+
+def _expert_calls(anakin) -> str:
+    """The form and shape of the held experts' call on a decode step's rows
+    and on a row block of the learner's `[B, T]` (`ops/expert_share.
+    call_form`: chosen from the shapes when the chunk is traced), for the
+    token loop's start-up line; nothing for a family without an expert
+    share."""
+    model = anakin.agent.model
+    if not hasattr(model, "experts_held"):
+        return ""
+    from distributed_reinforcement_learning_tpu.ops.expert_share import call_form
+
+    act, learn = (call_form(n, model.top_k, model.experts_held, model.num_experts)
+                  for n in (anakin.num_envs, math.gcd(anakin.num_envs, model.row_block)
+                            * anakin.agent.cfg.trajectory))
+    return f", held experts at act time: {act}; at learn time: {learn}"
 
 
 def _pair_slabs(m) -> str:

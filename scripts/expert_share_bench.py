@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Time the held experts' layer alone at a learner's shape.
+"""Time the held experts' layer alone, at a learner's shape or a decode step's.
 
 Forward, and forward + backward (all four gradients: x, the pairs' weights,
 wgu, wd), of `ops/expert_share.held_experts` on `--tokens` rows with
@@ -7,12 +7,26 @@ exactly `share x tokens x top_k` pairs on the held experts, for every
 `--share` of the pair list (default 1/32, 1/16, 1/8, 1/2 and all of it):
 the median of `--iters` timed calls after a warm one, one JSON line a point
 on stdout. `--cell` names the shape: `joyai` (4,096 x 8 of 256, 16 held, D
-2,048, F 768) or `qwen3` (4,096 x 10 of 512, 32 held, D 2,048, F 512).
+2,048, F 768), `qwen3` (4,096 x 10 of 512, 32 held, D 2,048, F 512) or
+`lfm2` (4,096 x 4 of 64, 16 held, D 2,048, F 1,536).
 `--slab` times other slab sizes than the rule's (`slab_rows`), each
 compiled in turn, which is how the rule was chosen (PERF.md section 6, PR
 42):
 
     python scripts/expert_share_bench.py --cell joyai --slab 1024 2048 4096
+
+`--rows` is the decode mode, forward only: a decode step's expert layers
+(`--layers` of them, each with weights of its own, so that every call
+reads its weights from HBM as a step does) on `--rows` rows a call, routed
+by a uniform router over all the experts, `--steps` steps in one compiled
+scan; a line gives the microseconds a layer a step of BOTH forms of the
+one-slab path side by side (`sorted_us`, `dense_us`: each forced in turn
+through `one_slab_form`, which says the rule's own choice under `form`),
+which is how the rule's constants were fixed (PERF.md section 6, PR 47).
+`--width` and `--router` replace the cell's F and its router's width:
+
+    python scripts/expert_share_bench.py --cell lfm2 --rows 16 32 64 128
+    python scripts/expert_share_bench.py --cell lfm2 --rows 64 --width 768
 
 A time is the chip's only there: on the CPU pass a tiny `--tokens`, and the
 line says `"platform": "cpu"`. No cell of the benchmark runs this. It runs
@@ -34,6 +48,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CELLS = {  # top_k, router width, held, D, F
     "joyai": (8, 256, 16, 2048, 768),
     "qwen3": (10, 512, 32, 2048, 512),
+    "lfm2": (4, 64, 16, 2048, 1536),
 }
 
 
@@ -95,6 +110,73 @@ def measure(cell: str, tokens: int, share: float, slab: int | None, iters: int,
     }
 
 
+def measure_decode(cell: str, rows: int, width: int | None, router: int | None,
+                   layers: int, steps: int, iters: int, dtype: str) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_reinforcement_learning_tpu.ops import expert_share
+
+    top_k, experts, held, d, f = CELLS[cell]
+    width, experts = width or f, router or experts
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    # every row's top_k distinct experts of a uniform router, a set a step a layer
+    chosen = jnp.argsort(jax.random.uniform(
+        keys[0], (steps, layers, rows, experts)))[..., :top_k].astype(jnp.int32)
+    weight = jnp.full((rows, top_k), 1.0 / top_k, jnp.float32)
+    x = jax.random.normal(keys[1], (rows, d), jnp.float32).astype(dtype)
+    # a tuple of each layer's own arrays, as the decode bodies hold them: a
+    # slice of one stacked array would be a weight-sized copy a step
+    made = lambda key, *shape: tuple(
+        (0.02 * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
+        for k in jax.random.split(key, layers))
+    wgu, wd = made(keys[2], held, d, 2 * width), made(keys[3], held, width, d)
+
+    def us_a_layer(form):
+        if form is not None:
+            expert_share.one_slab_form = lambda *_: form
+
+        def run(x, wgu, wd, chosen):  # a function of its own a form: traced anew
+            def step(h, chosen):
+                for i in range(layers):
+                    out, counters = expert_share.held_experts(
+                        h, chosen[i], weight, wgu[i], wd[i], 0, experts,
+                        jnp.dtype(dtype))
+                    h = (h.astype(jnp.float32) + out).astype(h.dtype)
+                return h * 0.5, (counters["held_pairs"], counters.get("dense_rows", 0))
+            return jax.lax.scan(step, x, chosen)
+
+        fn = jax.jit(run)
+        _, (pairs, dense_rows) = jax.block_until_ready(fn(x, wgu, wd, chosen))
+        if (form == "dense") != bool(dense_rows[0]):
+            raise SystemExit(f"asked for the {form} form, dense_rows {dense_rows[0]}")
+        times = []
+        for _ in range(iters):
+            start = time.perf_counter()
+            jax.block_until_ready(fn(x, wgu, wd, chosen))
+            times.append(time.perf_counter() - start)
+        return statistics.median(times) * 1e6 / (steps * layers), float(pairs.mean())
+
+    rule = getattr(expert_share, "one_slab_form", None)  # None: a checkout before it
+    device = jax.devices()[0]
+    line = {"cell": cell, "rows": rows, "top_k": top_k, "router": experts,
+            "held": held, "d": d, "width": width, "layers": layers, "steps": steps,
+            "dtype": jnp.dtype(dtype).name, "iters": iters,
+            "weight_bytes_a_layer": held * 3 * d * width * jnp.dtype(dtype).itemsize,
+            "form": rule(rows, top_k, experts) if rule else "sorted",
+            "one_slab": expert_share.slab_rows(rows * top_k, held, experts) == rows * top_k}
+    try:
+        if rule is None or not line["one_slab"]:  # a list in slabs has one form
+            line["sorted_us"], line["held_pairs_a_call"] = us_a_layer(None)
+        else:
+            line["sorted_us"], line["held_pairs_a_call"] = us_a_layer("sorted")
+            line["dense_us"], _ = us_a_layer("dense")
+    finally:
+        if rule is not None:
+            expert_share.one_slab_form = rule
+    return {**line, "platform": device.platform, "device_kind": device.device_kind}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--cell", nargs="*", choices=sorted(CELLS), default=sorted(CELLS))
@@ -105,11 +187,23 @@ def main(argv=None) -> int:
                     help="held pairs over the pair list")
     ap.add_argument("--slab", type=int, nargs="*", default=[],
                     help="slab sizes to time in place of the rule's")
+    ap.add_argument("--rows", type=int, nargs="*", default=[],
+                    help="decode mode: rows a call, forward only, both forms")
+    ap.add_argument("--width", type=int, help="decode mode: F in place of the cell's")
+    ap.add_argument("--router", type=int,
+                    help="decode mode: the router's width in place of the cell's")
+    ap.add_argument("--layers", type=int, default=4,
+                    help="decode mode: expert layers a step, each its own weights")
+    ap.add_argument("--steps", type=int, default=64, help="decode mode: steps a call")
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--iters", type=int, default=10)
     args = ap.parse_args(argv)
     for cell in args.cell:
-        for slab in args.slab or [None]:
+        for rows in args.rows:
+            print(json.dumps(measure_decode(
+                cell, rows, args.width, args.router, args.layers, args.steps,
+                args.iters, args.dtype)), flush=True)
+        for slab in [] if args.rows else args.slab or [None]:
             for share in args.share:
                 print(json.dumps(measure(cell, args.tokens, share, slab, args.iters,
                                          args.dtype)), flush=True)

@@ -26,7 +26,10 @@ SLAB = 512
 def whole_buffer(x, chosen, weight, wgu, wd, first_expert, dtype):
     """The layer over the worst case's buffer, a row for every pair."""
     n, top_k = chosen.shape
-    order, sizes, _ = expert_share.held_pairs(chosen, first_expert, wgu.shape[0])
+    held = wgu.shape[0]
+    key, _ = expert_share.held_pairs(chosen, first_expert, held)
+    order = jnp.argsort(key, stable=True)  # held pairs first, by expert
+    sizes = jnp.sum(key[:, None] == jnp.arange(held), axis=0, dtype=jnp.int32)
     token = order // top_k
     live = (jnp.arange(n * top_k) < jnp.sum(sizes))[:, None]
     rows = jnp.where(live, x.astype(dtype)[token], 0)
@@ -294,12 +297,42 @@ def _graded_jaxpr(n, top_k, experts, held, d, width):
     return jax.make_jaxpr(graded)(*shapes, chosen).jaxpr
 
 
-@pytest.mark.parametrize("n,top_k,experts,held", [(16, 8, 256, 16), (32, 10, 512, 32),
-                                                  (64, 4, 64, 16)])
+@pytest.mark.parametrize("n,top_k,experts,held", [(16, 8, 256, 16), (32, 10, 512, 32)])
 def test_a_decode_steps_list_is_one_slab_and_no_loop(n, top_k, experts, held):
     names = {name for name, _ in _primitives(_graded_jaxpr(n, top_k, experts, held, 64, 32))}
     assert "while" not in names and "ragged_dot_general" in names
     assert not any("custom_vjp" in name for name in names)
+
+
+# The rule's table (ISSUE 47): the callers of `held_experts` in the three
+# expert cells, (rows, top_k, router width, held) -> the form of the call.
+RULE = {"lfm2_decode": ((64, 4, 64, 16), "dense", "dense, 64 rows x 16 held"),
+        "qwen3_decode": ((32, 10, 512, 32), "sorted", "sorted, one slab of 320 pairs"),
+        "joyai_decode": ((16, 8, 256, 16), "sorted", "sorted, one slab of 128 pairs"),
+        "lfm2_learner": ((4096, 4, 64, 16), "slabs", "sorted, 16384 pairs in slabs of 5120"),
+        "qwen3_learner": ((4096, 10, 512, 32), "slabs", "sorted, 40960 pairs in slabs of 3584"),
+        "joyai_learner": ((4096, 8, 256, 16), "slabs", "sorted, 32768 pairs in slabs of 2560")}
+
+
+@pytest.mark.parametrize("shape,form,said", RULE.values(), ids=RULE.keys())
+def test_the_form_follows_the_shapes_as_the_rules_table_says(shape, form, said):
+    """Forward and backward of a call at each caller's rows: the dense form
+    has no grouped product, no sort and no scatter-add (and no loop); the
+    sorted one-slab form all three and no loop; a learner's call the loop."""
+    n, top_k, experts, held = shape
+    assert expert_share.call_form(n, top_k, held, experts) == said
+    one_slab = expert_share.slab_rows(n * top_k, held, experts) == n * top_k
+    assert one_slab == (form != "slabs")
+    if one_slab:
+        assert expert_share.one_slab_form(n, top_k, experts) == form
+    names = {name for name, _ in _primitives(_graded_jaxpr(n, top_k, experts, held, 64, 32))}
+    sorted_forms = {"ragged_dot_general", "sort", "scatter-add"}
+    if form == "dense":
+        assert not names & (sorted_forms | {"while"}), names
+        assert "dot_general" in names
+    else:
+        assert sorted_forms <= names
+        assert ("while" in names) == (form == "slabs")
 
 
 @pytest.mark.parametrize("n,top_k,experts,held", [(512, 8, 256, 16), (512, 10, 512, 32),
@@ -364,3 +397,130 @@ def test_a_layer_without_a_shared_expert_is_the_sum_of_its_shares():
     nobody_here = ~np.any(np.asarray(chosen) < 4, axis=-1)
     assert nobody_here.any() and not np.any(first_share[nobody_here])
     assert np.all(np.any(first_share[~nobody_here] != 0, axis=-1))
+
+
+# -- the one-slab path's dense form (ISSUE 47) -----------------------------------
+# 32 rows x 4 choices of 16 experts, 4 held from expert 4 on: 128 pairs, one
+# slab, and 8 pairs an expert from a uniform router: the rule takes the dense
+# form, and `sorted_form` is the same call with the rule answering "sorted".
+DN, DE, DHELD, DFIRST = 32, 16, 4, 4
+
+
+def by_experts(x, chosen, weight, wgu, wd, first_expert):
+    """The layer as its equation reads, float32: a loop over the held
+    experts, each on every row, weighted where the row chose it."""
+    out = jnp.zeros(x.shape, F32)
+    for e in range(wgu.shape[0]):
+        gate, up = jnp.split(x @ wgu[e], 2, -1)
+        w = jnp.sum(jnp.where(chosen == first_expert + e, weight, 0.0), -1)
+        out = out + w[:, None] * ((jax.nn.silu(gate) * up) @ wd[e])
+    return out
+
+
+def sorted_form(monkeypatch, fn, *args):
+    with monkeypatch.context() as m:
+        m.setattr(expert_share, "one_slab_form", lambda *_: "sorted")
+        return fn(*args)
+
+
+def dense_case(seed, count=None):
+    lay = layer(seed, n=DN, experts=DE, held=DHELD)
+    if count is None:  # the router's own choice
+        chosen = jax.lax.top_k(lay["x"] @ lay["router"], TOP_K)[1].astype(jnp.int32)
+    else:
+        chosen = choices(count, n=DN, experts=DE, held=DHELD, first=DFIRST, seed=seed)
+    return lay, chosen
+
+
+def dense_graded(lay, chosen, dtype):
+    def loss(fn, x, router, wgu, wd):
+        out = fn(x, chosen, weights({**lay, "x": x}, chosen)(router), wgu, wd)
+        return jnp.sum(out * jnp.cos(jnp.arange(out.size, dtype=F32).reshape(out.shape)))
+
+    program = lambda *a: expert_share.held_experts(*a, DFIRST, DE, dtype)[0]
+    equation = lambda *a: by_experts(*a, DFIRST)
+    args = (lay["x"], lay["router"], lay["wgu"], lay["wd"])
+    graded = lambda fn: jax.value_and_grad(functools.partial(loss, fn),
+                                           argnums=(0, 1, 2, 3))(*args)
+    return graded, program, equation
+
+
+def test_the_rule_takes_the_dense_form_at_the_dense_tests_shape():
+    assert expert_share.slab_rows(DN * TOP_K, DHELD, DE) == DN * TOP_K
+    assert expert_share.one_slab_form(DN, TOP_K, DE) == "dense"
+    assert expert_share.one_slab_form(4, TOP_K, DE) == "sorted"  # ONE pair an expert
+    assert expert_share.one_slab_form(5, TOP_K, DE) == "dense"
+    assert expert_share.one_slab_form(256, TOP_K, DE) == "dense"  # the row bound
+    assert expert_share.one_slab_form(257, TOP_K, DE) == "sorted"
+
+
+@pytest.mark.parametrize("count", [None, 0, 1, DN * TOP_K], ids=[
+    "routed", "none", "one", "every_pair"])
+def test_the_dense_form_is_the_sorted_form_and_the_equation_in_float32(monkeypatch, count):
+    """Value and all four gradients (x, the router through the pairs'
+    weights, wgu, wd)."""
+    lay, chosen = dense_case(20, count)
+    graded, program, equation = dense_graded(lay, chosen, F32)
+    with jax.default_matmul_precision("highest"):
+        dense = graded(program)
+        by_sort = sorted_form(monkeypatch, graded, program)
+        want = graded(equation)
+    for got in (dense, by_sort):
+        assert abs(float(got[0]) - float(want[0])) <= 1e-5 * max(1.0, abs(float(want[0])))
+        for name, a, b in zip(("x", "router", "wgu", "wd"), got[1], want[1]):
+            assert bool(jnp.all(jnp.isfinite(a))), name
+            if count == 0:
+                assert not np.any(np.asarray(a)), name
+            else:
+                assert rel(a, b) < 1e-5, (name, rel(a, b))
+
+
+@pytest.mark.parametrize("count", [None, 1, DN * TOP_K], ids=["routed", "one", "every_pair"])
+def test_the_dense_form_in_bfloat16_is_inside_the_cells_limits(monkeypatch, count):
+    """Against the sorted form in the SAME dtype (the same products, other
+    float32 sums) and against the float32 equation under 2e-2, the order
+    of the cells' limit on a bfloat16 program."""
+    lay, chosen = dense_case(21, count)
+    graded, program, equation = dense_graded(lay, chosen, jnp.bfloat16)
+    dense = graded(program)
+    by_sort = sorted_form(monkeypatch, graded, program)
+    with jax.default_matmul_precision("highest"):
+        want = graded(equation)
+    assert abs(float(dense[0]) - float(by_sort[0])) <= 5e-3 * max(1.0, abs(float(by_sort[0])))
+    for name, a, b, c in zip(("x", "router", "wgu", "wd"), dense[1], by_sort[1], want[1]):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert rel(a, b) < 5e-3, (name, rel(a, b))
+        assert rel(a, c) < 2e-2, (name, rel(a, c))
+
+
+def test_in_the_dense_form_a_token_that_chose_no_held_expert_gets_exactly_zero():
+    """And an expert that no row chose may hold anything: its results,
+    infinite here, reach no row."""
+    lay, _ = dense_case(22)
+    chosen = choices(40, n=DN, experts=DE, held=DHELD, first=DFIRST, seed=22)
+    chosen = jnp.where(chosen == DFIRST, 0, chosen)  # nobody chose the first held
+    weight = weights(lay, chosen)(lay["router"])
+    out, counters = expert_share.held_experts(
+        lay["x"], chosen, weight, lay["wgu"].at[0].set(jnp.inf), lay["wd"],
+        DFIRST, DE, F32)
+    out, chosen = np.asarray(out), np.asarray(chosen)
+    nobody_here = ~np.any((chosen >= DFIRST) & (chosen < DFIRST + DHELD), axis=-1)
+    assert nobody_here.any() and not np.any(out[nobody_here])
+    assert np.all(np.isfinite(out))
+    assert np.all(np.any(out[~nobody_here] != 0, axis=-1))
+    assert int(counters["dense_rows"]) == DN * DHELD and int(counters["expert_pairs"][0]) == 0
+
+
+@pytest.mark.parametrize("count", [None, 0, 1, 77, DN * TOP_K])
+def test_the_counters_are_the_same_in_both_forms(monkeypatch, count):
+    lay, chosen = dense_case(23, count)
+    weight = weights(lay, chosen)(lay["router"])
+    call = lambda: expert_share.held_experts(
+        lay["x"], chosen, weight, lay["wgu"], lay["wd"], DFIRST, DE, F32)[1]
+    dense, by_sort = call(), sorted_form(monkeypatch, call)
+    for key in ("held_pairs", "expert_pairs", "dropped_pairs", "pair_slabs"):
+        assert np.array_equal(np.asarray(dense[key]), np.asarray(by_sort[key])), key
+    assert int(dense["dropped_pairs"]) == 0
+    if count is not None:
+        assert int(dense["held_pairs"]) == count
+    assert int(dense["dense_rows"]) == DN * DHELD and int(by_sort["dense_rows"]) == 0

@@ -220,3 +220,7 @@ def test_the_launchers_loop_adds_nothing_after_its_first_chunk(
     assert at_entry[1] == at_entry[2] == at_return
     assert watched[0]._cache_size() == 1
     assert lines.count("[anakin-tokens] step ") == 3
+    # the start-up line names the form of the held experts' calls, once
+    assert lines.count("held experts at act time: sorted, one slab of") == (
+        "router_width" in small)
+    assert lines.count("; at learn time: dense, ") == ("router_width" in small)

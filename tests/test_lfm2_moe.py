@@ -588,6 +588,46 @@ def test_decode_across_an_episode_boundary_is_the_learners_forward(agent, params
     assert _rel(learner, want["logits"][0]) < 2e-4
 
 
+def test_decode_in_the_dense_form_is_the_learners_forward_in_the_sorted(params):
+    """The two forms of the held experts meet inside one model as they do
+    in the cell: 8 rows a decode step (24 pairs over a router of 16: the
+    dense form), a row block of 8 x T = 256 positions in the learner (768
+    pairs in sorted slabs of 512), the same parameters, the same tokens."""
+    from distributed_reinforcement_learning_tpu.ops import expert_share
+
+    wide = ConvLMAgent(dataclasses.replace(CFG, row_block=8))
+    assert expert_share.call_form(8, 3, 4, 16) == "dense, 8 rows x 4 held"
+    assert expert_share.call_form(8 * T, 3, 4, 16) == "sorted, 768 pairs in slabs of 512"
+    first, second = seeded_batch(3, False), seeded_batch(4, False)
+    tokens = np.concatenate([first["tokens"], second["tokens"]], axis=0)  # [8, T]
+    done = np.concatenate([first["done"], second["done"]], axis=0)
+    model = wide.model
+    hs, facts = model.apply(params, jnp.asarray(tokens), jnp.asarray(done),
+                            method=model.trunk)
+    learner = model.apply(params, hs, method=model.logits)[0][0]
+    acted, state = _decode_all(wide, params, tokens)
+    assert _rel(acted, learner) < 2e-4
+    assert np.array_equal(np.moveaxis(np.asarray(state.routes), 2, 0),
+                          np.asarray(facts["routes"]))
+    assert int(jnp.sum(facts["dropped_pairs"])) == 0
+
+
+def test_at_the_cells_sizes_acting_is_dense_and_learning_sorted():
+    """The `lfm2_moe` section as the cell runs it (64 envs x 1,024 steps,
+    a row block of 4): what the launcher's start-up line says of the held
+    experts' calls."""
+    import types
+
+    from distributed_reinforcement_learning_tpu.runtime import launch
+
+    cfg, rt = load_config("config.json", "lfm2_moe")
+    anakin = types.SimpleNamespace(agent=ConvLMAgent(cfg),
+                                   num_envs=rt.num_actors * rt.envs_per_actor)
+    assert launch._expert_calls(anakin) == (
+        ", held experts at act time: dense, 64 rows x 16 held; "
+        "at learn time: sorted, 16384 pairs in slabs of 5120")
+
+
 def test_the_state_is_two_columns_a_convolution_layer_and_one_cache(agent, params,
                                                                     whole_episode):
     nb, _, state, _ = whole_episode

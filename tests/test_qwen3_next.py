@@ -532,11 +532,16 @@ def test_a_wrong_expert_share_is_seen(fault):
     assert _rel(out, routed[0]) > 0.02
 
 
-def test_no_array_of_the_learn_step_has_an_expert_and_a_capacity_axis(agent, params):
+def test_no_array_of_the_learn_step_has_an_expert_and_a_capacity_axis(
+        agent, params, monkeypatch):
     """`ops/moe.py` dispatches through `[tokens, experts, capacity]`
     one-hot arrays; this layer's lowered learn step has no array with
     the tokens of a row block AND the router's width beside a third axis
-    (at the timed sizes that one would be 4,096 x 512 x 100 and more)."""
+    (at the timed sizes that one would be 4,096 x 512 x 100 and more).
+    In the SORTED form, which every cell's learner takes: this section's
+    row block is 64 tokens, few enough for the dense form, whose `[held,
+    tokens, F]` has F = 16 = the router's width here."""
+    monkeypatch.setattr(expert_share, "one_slab_form", lambda *_: "sorted")
     nb = seeded_batch(0)
     batch = LoopLMBatch(**{k: jnp.asarray(v) for k, v in nb.items()})
     text = jax.jit(jax.grad(agent._loss, has_aux=True)).lower(params, batch).as_text()
