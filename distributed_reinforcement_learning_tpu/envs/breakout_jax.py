@@ -34,7 +34,7 @@ The observation pipeline runs on-device and matches
 consecutive post-frameskip raw frames -> luma -> INTER_AREA resize to
 110x84 (the separable overlap weights of `atari.area_resize`, folded to
 an 84x210 matrix by pre-cropping the row weights) -> [84, 84] uint8 ->
-4-frame newest-last stack. The resize is two small matmuls per frame —
+4-frame newest-last observation. The resize is two small matmuls per frame —
 MXU work, which is the point of doing it on-device.
 
 No picture is state, and `step` makes no RGB picture at all. A pixel
@@ -97,9 +97,11 @@ _ROW_POINTS = np.asarray(sim.ROW_POINTS, np.float32)
 class BreakoutState(NamedTuple):
     """Batched game + observation-pipeline state (`[N, ...]` leaves).
 
-    The observation stack is the only picture here. The last raw frame
-    is not: it always shows `_classes` of this state's `_DRAWN` fields
-    (true after `reset`, kept by every `step`), so nothing has to store it.
+    The frame history is the only picture here, one 32-bit word a pixel
+    (`pixel_jax`: byte 0 the oldest frame, byte 3 the newest); `step`
+    returns the observation unpacked and the state does not hold it. The
+    last raw frame is no state either: it always shows `_classes` of this
+    state's `_DRAWN` fields (true after `reset`, kept by every `step`).
     """
 
     bricks: jax.Array      # [N, 6, 18] bool
@@ -111,7 +113,7 @@ class BreakoutState(NamedTuple):
     ball_y: jax.Array      # [N] f32
     vx: jax.Array          # [N] f32
     vy: jax.Array          # [N] f32
-    stack: jax.Array       # [N, 84, 84, 4] u8 — current observation
+    history: jax.Array     # [N, 84, 84] u32 — last four frames, a byte each
     returns: jax.Array     # [N] f32 raw (unclipped) episode return
 
 
@@ -313,8 +315,8 @@ def reset(rng: jax.Array, num_envs: int) -> tuple[BreakoutState, jax.Array]:
     del rng
     f = _reset_fields(num_envs)
     raw = _render_batch(f)
-    state = BreakoutState(stack=pixel_jax.reset_stack(raw), **f)
-    return state, state.stack
+    state = BreakoutState(history=pixel_jax.reset_history(raw), **f)
+    return state, pixel_jax.observe(state.history)
 
 
 @functools.partial(jax.jit, static_argnames=("frameskip", "max_frames",
@@ -340,8 +342,10 @@ def step(
     no RGB frame is made) -> resize. The max's previous frame shows the
     `_DRAWN` fields `state` came in with, because that is what the last
     step drew (the invariant on `BreakoutState`); a game-over slot takes
-    its fresh fields for both frames and zeros for the older stack slots,
-    which is the reset observation.
+    its fresh fields for both frames. Then `pixel_jax.push` shifts the
+    history's words down a byte and ors the frame in on top (a game-over
+    slot keeps the frame alone: zeros in the older bytes, which is the
+    reset observation), and `pixel_jax.observe` unpacks the words once.
     """
     n = state.lives.shape[0]
     lives_before = state.lives
@@ -390,12 +394,11 @@ def step(
 
     with jax.named_scope(scopes.RENDER):
         frame = jax.vmap(pixel_jax.resize)(_luma_batch(fields, entered))
-        older = jnp.where(game_over[:, None, None, None], jnp.uint8(0),
-                          state.stack[..., 1:])
-        stack = jnp.concatenate([older, frame[..., None]], axis=-1)
+        history = pixel_jax.push(state.history, frame, game_over)
+        obs = pixel_jax.observe(history)
 
-    new_state = BreakoutState(stack=stack, **fields)
-    return new_state, stack, reward, done, episode_return
+    new_state = BreakoutState(history=history, **fields)
+    return new_state, obs, reward, done, episode_return
 
 
 def completed_episode_mask(done: jax.Array, new_state: BreakoutState) -> jax.Array:

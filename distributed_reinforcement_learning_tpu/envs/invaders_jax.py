@@ -79,7 +79,7 @@ class InvadersState(NamedTuple):
     lives: jax.Array        # [N] i32
     frames: jax.Array       # [N] i32
     prev_raw: jax.Array     # [N, 210, 160, 3] u8
-    stack: jax.Array        # [N, 84, 84, 4] u8
+    history: jax.Array      # [N, 84, 84] u32 — last four frames, a byte each
     returns: jax.Array      # [N] f32 raw episode return
 
 
@@ -337,8 +337,9 @@ def reset(rng: jax.Array, num_envs: int) -> tuple[InvadersState, jax.Array]:
     del rng
     f = _reset_fields(num_envs)
     raw = _render_state(f)
-    state = InvadersState(prev_raw=raw, stack=pixel_jax.reset_stack(raw), **f)
-    return state, state.stack
+    state = InvadersState(
+        prev_raw=raw, history=pixel_jax.reset_history(raw), **f)
+    return state, pixel_jax.observe(state.history)
 
 
 @functools.partial(jax.jit, static_argnames=("frameskip", "max_frames",
@@ -389,7 +390,7 @@ def step(
         returns=state.returns + reward)
     with jax.named_scope(scopes.RENDER):
         raw = _render_state(fields)
-        stack = pixel_jax.observe(raw, state.prev_raw, state.stack)
+        frame = pixel_jax.frame_of(raw, state.prev_raw)
 
     episode_return = jnp.where(game_over, fields["returns"], 0.0)
     lost_life = lives < lives_before
@@ -403,12 +404,14 @@ def step(
     fresh = _reset_fields(n)
     with jax.named_scope(scopes.RENDER):
         raw0 = _render_state(fresh)
-        stack0 = pixel_jax.reset_stack(raw0)
+        history = pixel_jax.push(state.history, frame, game_over,
+                                 pixel_jax.reset_history(raw0))
+        obs = pixel_jax.observe(history)
     pick = pixel_jax.make_pick(game_over)
     new_fields = {k: pick(fresh[k], fields[k]) for k in fresh}
     new_state = InvadersState(
-        prev_raw=pick(raw0, raw), stack=pick(stack0, stack), **new_fields)
-    return new_state, new_state.stack, reward, done, episode_return
+        prev_raw=pick(raw0, raw), history=history, **new_fields)
+    return new_state, obs, reward, done, episode_return
 
 
 def completed_episode_mask(done: jax.Array, new_state: InvadersState) -> jax.Array:

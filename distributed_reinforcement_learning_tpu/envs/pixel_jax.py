@@ -3,10 +3,23 @@
 One implementation of the `envs.atari.AtariPreprocessor` stages —
 2-frame max over consecutive post-frameskip raw frames, luma, INTER_AREA
 resize as two matmuls (the separable overlap weights of
-`atari.area_resize`, rows pre-cropped), `[84, 84]` uint8, 4-frame
-newest-last stacking — used by both `breakout_jax` and `pong_jax` so the
-subtle parts (crop window, stack shift, reset-stack semantics,
-auto-reset merge) cannot diverge between games.
+`atari.area_resize`, rows pre-cropped), `[84, 84]` uint8, a 4-frame
+newest-last observation — used by `breakout_jax`, `pong_jax` and
+`invaders_jax` so the subtle parts (crop window, history push,
+reset-history semantics, auto-reset merge) cannot diverge between games.
+
+The four-frame history is STATE as one 32-bit word a pixel,
+`u32[N, 84, 84]`: byte `k` of a word is the frame at stack index `k`
+(byte 0 the oldest, byte 3 the newest: the order
+`lax.bitcast_convert_type(u32 -> u8[..., 4])` gives). `push` shifts the
+words down a byte and ors the new frame in on top, so an old frame is
+never copied, selected or concatenated; a reset slot's word is the reset
+frame alone, i.e. zeros in the three older bytes (the host pipeline
+clears its buffer on reset). `observe` unpacks the words to the
+`u8[N, 84, 84, 4]` observation once a step, pinned batch-minor: the
+layout the policy's first convolution and the rollout's stacked write
+read on the chip. Without the pin a scan's carry falls to row-major there
+and two transposing copies of the words appear.
 
 `preprocess` is `resize(luma(rgb))`. Pong and Invaders hand it their RGB
 frames. Breakout's `step` makes no RGB frame: it selects the luma plane
@@ -20,6 +33,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from distributed_reinforcement_learning_tpu.envs.atari import _area_weights
 
@@ -35,6 +49,9 @@ _LUMA = np.array([0.299, 0.587, 0.114], np.float32)
 # `_WH_CROP` is zero, so what a frame shows there reaches no observation.
 _READ = np.flatnonzero(_WH_CROP.any(axis=0))
 CROP_ROWS = (int(_READ[0]), int(_READ[-1]) + 1)  # (34, 195)
+
+# The observation's layout on the chip, N minor-most (`{0,3,2,1}` in HLO).
+_BATCH_MINOR = Layout(major_to_minor=(1, 2, 3, 0))
 
 
 def luma(rgb: jax.Array) -> jax.Array:
@@ -53,20 +70,44 @@ def preprocess(rgb: jax.Array) -> jax.Array:
     return resize(luma(rgb))
 
 
-def observe(raw: jax.Array, prev_raw: jax.Array, stack: jax.Array) -> jax.Array:
-    """Next observation stack: 2-frame max with the previous adapter-step
-    raw frame, preprocess, shift the newest-last 4-stack."""
-    maxed = jnp.maximum(raw, prev_raw)
-    frame = jax.vmap(preprocess)(maxed)
-    return jnp.concatenate([stack[..., 1:], frame[..., None]], axis=-1)
+def frame_of(raw: jax.Array, prev_raw: jax.Array) -> jax.Array:
+    """`[N, 210, 160, 3]` u8 x2 -> `[N, 84, 84]` u8: 2-frame max with the
+    previous adapter-step raw frame, preprocess."""
+    return jax.vmap(preprocess)(jnp.maximum(raw, prev_raw))
 
 
-def reset_stack(raw0: jax.Array) -> jax.Array:
-    """Observation stack right after a reset: zeros with the reset frame
-    in the newest slot (the host pipeline clears its buffer on reset)."""
-    frame0 = jax.vmap(preprocess)(raw0)
-    stack = jnp.zeros(frame0.shape[:1] + (84, 84, 4), jnp.uint8)
-    return stack.at[..., -1].set(frame0)
+def _newest(frame: jax.Array) -> jax.Array:
+    """`[N, 84, 84]` u8 -> the words holding it in the newest byte alone."""
+    return frame.astype(jnp.uint32) << 24
+
+
+def reset_history(raw0: jax.Array) -> jax.Array:
+    """History right after a reset: zeros with the reset frame in the
+    newest byte (the host pipeline clears its buffer on reset)."""
+    return _newest(jax.vmap(preprocess)(raw0))
+
+
+def push(history: jax.Array, frame: jax.Array, reset: jax.Array,
+         history0: jax.Array | None = None) -> jax.Array:
+    """Next history `u32[N, 84, 84]`: drop the oldest byte, `frame` on
+    top; `reset` `[N]` slots hold `history0`, the reset history (`frame`
+    alone where the game renders one frame for both)."""
+    new = _newest(frame)
+    shifted = (history >> 8) | new
+    return jnp.where(reset[:, None, None],
+                     new if history0 is None else history0, shifted)
+
+
+# Jitted so that an eager call (`reset`) cannot hand the pin on: a jit's
+# result has the default layout. An observation that left `reset` pinned
+# made the fused chunk compile again at its second call, whose `obs` is the
+# first call's result.
+@jax.jit
+def observe(history: jax.Array) -> jax.Array:
+    """`u32[N, 84, 84]` -> the observation `u8[N, 84, 84, 4]`, newest last."""
+    shifts = jnp.arange(0, 32, 8, dtype=jnp.uint32)
+    obs = ((history[..., None] >> shifts) & 0xFF).astype(jnp.uint8)
+    return with_layout_constraint(obs, _BATCH_MINOR)
 
 
 def make_pick(game_over: jax.Array):

@@ -70,7 +70,7 @@ class PongState(NamedTuple):
     vx: jax.Array            # [N] f32
     vy: jax.Array            # [N] f32
     prev_raw: jax.Array      # [N, 210, 160, 3] u8
-    stack: jax.Array         # [N, 84, 84, 4] u8
+    history: jax.Array       # [N, 84, 84] u32 — last four frames, a byte each
     returns: jax.Array       # [N] f32 signed episode return
 
 
@@ -253,8 +253,8 @@ def reset(rng: jax.Array, num_envs: int) -> tuple[PongState, jax.Array]:
     f = _reset_fields(num_envs)
     raw = jax.vmap(_render)(
         f["player_y"], f["enemy_y"], f["ball_dead"], f["ball_x"], f["ball_y"])
-    state = PongState(prev_raw=raw, stack=pixel_jax.reset_stack(raw), **f)
-    return state, state.stack
+    state = PongState(prev_raw=raw, history=pixel_jax.reset_history(raw), **f)
+    return state, pixel_jax.observe(state.history)
 
 
 @functools.partial(jax.jit, static_argnames=("frameskip", "max_frames"))
@@ -294,7 +294,7 @@ def step(
 
     with jax.named_scope(scopes.RENDER):
         raw = jax.vmap(_render)(player_y, enemy_y, ball_dead, ball_x, ball_y)
-        stack = pixel_jax.observe(raw, state.prev_raw, state.stack)
+        frame = pixel_jax.frame_of(raw, state.prev_raw)
 
     returns = state.returns + reward
     episode_return = jnp.where(game_over, returns, 0.0)
@@ -304,7 +304,9 @@ def step(
         raw0 = jax.vmap(_render)(
             fresh["player_y"], fresh["enemy_y"], fresh["ball_dead"],
             fresh["ball_x"], fresh["ball_y"])
-        stack0 = pixel_jax.reset_stack(raw0)
+        history = pixel_jax.push(state.history, frame, game_over,
+                                 pixel_jax.reset_history(raw0))
+        obs = pixel_jax.observe(history)
 
     pick = pixel_jax.make_pick(game_over)
     new_state = PongState(
@@ -322,10 +324,10 @@ def step(
         vx=pick(fresh["vx"], vx),
         vy=pick(fresh["vy"], vy),
         prev_raw=pick(raw0, raw),
-        stack=pick(stack0, stack),
+        history=history,
         returns=pick(fresh["returns"], returns),
     )
-    return new_state, new_state.stack, reward, game_over, episode_return
+    return new_state, obs, reward, game_over, episode_return
 
 
 def completed_episode_mask(done: jax.Array, new_state: PongState) -> jax.Array:

@@ -45,7 +45,7 @@ def tagged(fn):
 COLLECT = "collect"  # the scan over env steps: loop control + stacked outputs
 ACT = "collect/act"  # obs prep, torso, LSTM, sampling (`agent._act`)
 ENV = "collect/env"  # env dynamics (`env.step` less rendering)
-RENDER = "collect/env/render"  # raw screen, 2-frame max, luma, resize, stack
+RENDER = "collect/env/render"  # raw screen, 2-frame max, luma, resize, history
 RECORD = "collect/record"  # the per-step record + carry of the rollout
 # [T, B, ...] rollout -> [B, T, ...] batch: `AnakinImpala` under a mesh
 # only. The one-chip chunk learns time-major (PR 29): no op has this name.

@@ -15,7 +15,8 @@ import numpy as np
 import pytest
 
 from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent, ImpalaConfig
-from distributed_reinforcement_learning_tpu.envs import breakout_jax, breakout_sim, pixel_jax
+from distributed_reinforcement_learning_tpu.envs import (
+    breakout_jax, breakout_sim, invaders_jax, pixel_jax, pong_jax)
 from distributed_reinforcement_learning_tpu.envs.atari import AtariPreprocessor, preprocess_frame
 from distributed_reinforcement_learning_tpu.envs.breakout_sim import BreakoutSimRaw
 from distributed_reinforcement_learning_tpu.runtime.anakin import AnakinImpala
@@ -274,8 +275,19 @@ class TestAnakinBreakout:
 # -- parity with the formulation that carried the RGB raster ----------------
 
 
+def _plain_push(stack, frame, over, frame0):
+    """The byte form of the history, kept plain: `u8[N, 84, 84, 4]`,
+    newest last, shifted by a concat; a game-over slot holds zeros with
+    the reset frame newest. What `pixel_jax.push` + `observe` stand for."""
+    shifted = jnp.concatenate([stack[..., 1:], frame[..., None]], axis=-1)
+    fresh = jnp.zeros_like(stack).at[..., -1].set(frame0)
+    return jnp.where(over[:, None, None, None], fresh, shifted)
+
+
 class _RasterState(NamedTuple):
-    """PR 24's state: the game, and the last RGB frame beside it."""
+    """PR 24's state: the game, and the last RGB frame beside it. The
+    twin's `game.history` is the PLAIN history: the `u8[N, 84, 84, 4]`
+    stack `_plain_push` shifts, not `step`'s words."""
 
     game: breakout_jax.BreakoutState
     prev_raw: jax.Array  # [N, 210, 160, 3] u8
@@ -283,7 +295,11 @@ class _RasterState(NamedTuple):
 
 def _raster_reset(n):
     state, obs = breakout_jax.reset(jax.random.PRNGKey(0), n)
-    return _RasterState(state, breakout_jax._render_batch(state._asdict())), obs
+    raw = breakout_jax._render_batch(state._asdict())
+    frame0 = jax.vmap(pixel_jax.preprocess)(raw)
+    stack = _plain_push(jnp.zeros((n, 84, 84, 4), jnp.uint8), frame0,
+                        jnp.ones(n, bool), frame0)
+    return _RasterState(state._replace(history=stack), raw), stack
 
 
 @functools.partial(jax.jit, static_argnames=("max_frames",))
@@ -309,7 +325,7 @@ def _raster_step(rs, actions, rng, max_frames):
     live = dict(zip(names, live))
 
     raw = breakout_jax._render_batch(live)
-    stack = pixel_jax.observe(raw, prev_raw, state.stack)
+    frame = pixel_jax.frame_of(raw, prev_raw)
 
     live["returns"] = state.returns + reward
     episode_return = jnp.where(game_over, live["returns"], 0.0)
@@ -319,22 +335,25 @@ def _raster_step(rs, actions, rng, max_frames):
 
     fresh = breakout_jax._reset_fields(n)
     raw0 = breakout_jax._render_batch(fresh)
-    stack0 = pixel_jax.reset_stack(raw0)
+    stack = _plain_push(state.history, frame, game_over,
+                        jax.vmap(pixel_jax.preprocess)(raw0))
     pick = pixel_jax.make_pick(game_over)
     new_state = breakout_jax.BreakoutState(
-        stack=pick(stack0, stack), **{k: pick(fresh[k], live[k]) for k in live})
-    return (_RasterState(new_state, pick(raw0, raw)), new_state.stack, reward,
+        history=stack, **{k: pick(fresh[k], live[k]) for k in live})
+    return (_RasterState(new_state, pick(raw0, raw)), stack, reward,
             done, episode_return)
 
 
 def _assert_same_step(out, out_r, t):
     """`breakout_jax.step`'s results against `_raster_step`'s, bit for bit:
-    obs, reward, done, episode return and every leaf of the state."""
+    obs, reward, done, episode return and every leaf of the state (the
+    history's words unpacked, against the twin's bytes)."""
     (state, *rest), (rs, *rest_r) = out, out_r
     for name, got, want in zip(("obs", "reward", "done", "episode_return"),
                                rest, rest_r):
         np.testing.assert_array_equal(
             np.asarray(got), np.asarray(want), err_msg=f"{name}, step {t}")
+    state = state._replace(history=pixel_jax.observe(state.history))
     for name in state._fields:
         np.testing.assert_array_equal(
             np.asarray(getattr(state, name)), np.asarray(getattr(rs.game, name)),
@@ -403,6 +422,75 @@ class TestNoRasterInState:
         assert "prev_raw" not in state._fields
         for name, leaf in state._asdict().items():
             assert leaf.size * leaf.dtype.itemsize <= obs.size * obs.dtype.itemsize, name
+
+
+# -- the history as 32-bit words (PR 48) --------------------------------------
+
+_GAMES = {"breakout": breakout_jax, "invaders": invaders_jax, "pong": pong_jax}
+
+
+@functools.lru_cache(maxsize=None)
+def _word_rollout(game):
+    """Ten steps of `game` at 2 envs with the frame cap at 24: env 1 enters
+    with 12 frames played, so it is over at step 2 and env 0 at step 5.
+    -> (reset obs, [(obs, history words, game-over mask) a step])."""
+    env = _GAMES[game]
+    state, obs0 = env.reset(jax.random.PRNGKey(0), 2)
+    state = state._replace(frames=jnp.asarray([0, 12], jnp.int32))
+    rng, steps = np.random.default_rng(5), []
+    for t in range(10):
+        before = np.asarray(state.frames)
+        actions = jnp.asarray(rng.integers(0, env.NUM_ACTIONS, size=2))
+        state, obs, *_ = env.step(state, actions, jax.random.PRNGKey(t),
+                                  max_frames=24)
+        over = np.asarray(state.frames) < before  # the auto-reset zeroes them
+        steps.append((np.asarray(obs), np.asarray(state.history), over))
+    return np.asarray(obs0), steps
+
+
+@pytest.mark.parametrize("case", ["after_reset", "mid_run_reset", "byte_order"])
+@pytest.mark.parametrize("game", sorted(_GAMES))
+def test_history_words_match_the_plain_stack(game, case):
+    """`pixel_jax.push` + `observe`, alone and as the three games' `step`
+    uses them, against `_plain_push` on the same frames: the first four
+    steps after a reset fill the stack from the newest slot down; a slot
+    whose game ends mid-run shows the reset frame over three planes of
+    zeros while the other slot shifts on; byte `k` of a word is the frame
+    at stack index `k` (`lax.bitcast_convert_type`'s order)."""
+    obs0, steps = _word_rollout(game)
+    frame0 = obs0[..., 3]
+    assert frame0.any() and not obs0[..., :3].any()
+    plain = jnp.asarray(obs0)
+    words = words0 = pixel_jax.push(jnp.zeros((2, 84, 84), jnp.uint32),
+                                    jnp.asarray(frame0), jnp.ones(2, bool))
+    np.testing.assert_array_equal(np.asarray(pixel_jax.observe(words)), obs0)
+    overs = np.stack([over for _, _, over in steps])
+    assert overs[2].tolist() == [False, True] and overs[5].tolist() == [True, False]
+    window = {"after_reset": range(0, 4), "mid_run_reset": range(2, 10),
+              "byte_order": range(0, 10)}[case]
+    for t, (obs, history, over) in enumerate(steps):
+        frame = jnp.asarray(obs[..., 3])  # the frame `step` made
+        plain = _plain_push(plain, frame, jnp.asarray(over), jnp.asarray(frame0))
+        words = pixel_jax.push(words, frame, jnp.asarray(over),
+                               None if game == "breakout" else words0)
+        if t not in window:
+            continue
+        if case == "byte_order":
+            np.testing.assert_array_equal(
+                np.asarray(jax.lax.bitcast_convert_type(jnp.asarray(history),
+                                                        jnp.uint8)), obs)
+            np.testing.assert_array_equal(history >> 24, obs[..., 3])
+            np.testing.assert_array_equal(history & 0xFF, obs[..., 0])
+            continue
+        np.testing.assert_array_equal(obs, np.asarray(plain), err_msg=f"step {t}")
+        np.testing.assert_array_equal(np.asarray(words), history, err_msg=f"step {t}")
+        np.testing.assert_array_equal(np.asarray(pixel_jax.observe(words)), obs)
+        if case == "after_reset":  # env 0: t + 1 frames since its reset
+            filled = obs[0].reshape(-1, 4).any(axis=0).tolist()
+            assert filled == [k >= 2 - t for k in range(4)], (t, filled)
+        for e in np.flatnonzero(over):
+            assert not obs[e, ..., :3].any()
+            np.testing.assert_array_equal(obs[e, ..., 3], frame0[e])
 
 
 # -- the luma plane `step` selects from tables (PR 35) ------------------------
@@ -524,7 +612,9 @@ class TestDrawnCases:
         state = state._replace(
             bricks=bricks, ball_dead=state.ball_dead & ~live, ball_x=x, ball_y=y,
             vy=vy, lives=jnp.where(i == 4, 1, jnp.where(i == 5, 3, state.lives)))
-        return state, _RasterState(state, breakout_jax._render_batch(state._asdict()))
+        stack = _raster_reset(n)[0].game.history  # the edits drew no frame yet
+        return state, _RasterState(state._replace(history=stack),
+                                   breakout_jax._render_batch(state._asdict()))
 
     @staticmethod
     def _drawn(state):

@@ -46,6 +46,7 @@ from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent
 from distributed_reinforcement_learning_tpu.agents.looplm import LoopLMAgent
 from distributed_reinforcement_learning_tpu.agents.mlalm import MLALMAgent
 from distributed_reinforcement_learning_tpu.agents.moelm import MoELMAgent
+from distributed_reinforcement_learning_tpu.envs import breakout_jax
 from distributed_reinforcement_learning_tpu.envs.token_recall_jax import TokenRecall
 from distributed_reinforcement_learning_tpu.runtime import anakin_tokens, launch
 from distributed_reinforcement_learning_tpu.runtime.anakin import AnakinImpala
@@ -102,6 +103,14 @@ def _impala():
     return AnakinImpala(ImpalaAgent(anakin_cfg()), num_envs=N)
 
 
+def _impala_breakout():
+    """The pixel cells' env: `reset` runs eagerly inside `init` and hands
+    the first chunk its `obs`, the chunk hands the second its own."""
+    cfg = anakin_cfg(obs_shape=(84, 84, 4), num_actions=4, trajectory=5,
+                     lstm_size=16)
+    return AnakinImpala(ImpalaAgent(cfg), num_envs=2, env=breakout_jax)
+
+
 # (build the loop, chunk updates, upper limits read in a fresh process at
 # the parent, c585750: `init`'s trace / lower / compile events and the trace
 # events of the chunk's one lowering, which nest: one event a traced
@@ -109,6 +118,9 @@ def _impala():
 # the last.
 LOOPS = {
     "impala": (_impala, 2, (415, 77, 77), 882),
+    # read at PR 48's own tree: with the observation's layout pinned where
+    # an eager `reset` could hand it on, "second" compiled the chunk again
+    "impala_breakout": (_impala_breakout, 1, (515, 130, 130), 1967),
     "r2d2": (make_r2d2, 2, (904, 99, 99), 987),
     "looplm": (_tokens(LoopLMAgent, LOOP_CFG), 1, (549, 26, 26), 809),
     "hybridlm": (_tokens(HybridLMAgent, HYBRID_CFG), 1, (776, 39, 39), 1611),

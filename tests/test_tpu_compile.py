@@ -617,6 +617,19 @@ def test_lfm2_moe_chunk_fits_and_holds_its_six_kernels(chip, kernels_as_on_chip)
     assert not re.findall(r"\[4096,64,\d+\]|\[65536,64,\d+\]", text)
 
 
+def _breakout_step_text(chip, n: int) -> str:
+    """`breakout_jax.step` alone at `n` envs, compiled for the described chip."""
+    from distributed_reinforcement_learning_tpu.envs import breakout_jax
+
+    state = jax.eval_shape(
+        lambda: breakout_jax.reset(jax.random.PRNGKey(0), n)[0])
+    return breakout_jax.step.lower(
+        _on(chip, state),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=chip),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=chip),
+    ).compile().as_text()
+
+
 def test_breakout_step_keeps_no_raster_and_one_luma(chip):
     """`breakout_jax.step` at 64 envs makes no RGB raster: `u8[N,210,160,3]`
     is nowhere in the compiled step, fusions included; no class mask
@@ -626,17 +639,11 @@ def test_breakout_step_keeps_no_raster_and_one_luma(chip):
     (a constant folded by the compiler would hold the host's arithmetic,
     not the chip's). The step before PR 35 shows four `pred[N,210,160]`
     buffers, the raster inside a fusion and a `[N,210,160]` reduction."""
-    from distributed_reinforcement_learning_tpu.envs import breakout_jax, pixel_jax
+    from distributed_reinforcement_learning_tpu.envs import pixel_jax
 
     n = 64
     lo, hi = pixel_jax.CROP_ROWS
-    state = jax.eval_shape(
-        lambda: breakout_jax.reset(jax.random.PRNGKey(0), n)[0])
-    text = breakout_jax.step.lower(
-        _on(chip, state),
-        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=chip),
-        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=chip),
-    ).compile().as_text()
+    text = _breakout_step_text(chip, n)
     buffers = text[text.index("\nENTRY"):]  # fused computations come before
     assert f"u8[{n},210,160,3]" not in text
     assert not re.findall(rf"= \(?pred\[{n},(?:210|{hi - lo}),160\]", buffers)
@@ -731,6 +738,69 @@ def test_breakout_chunk_frames_stay_bytes_until_conv0(breakout_chunk):
                and "torso/conv_general_dilated" in line]
     assert any("/jvp(learn/loss)/" in r for r in readers), readers  # conv0 forward
     assert any("/transpose(jvp(learn/loss))/" in r for r in readers), readers  # dL/dk
+
+
+def _history_ops(text: str, n: int) -> list[tuple[str, str, str]]:
+    """(result, opcode, scope path) of every op, in the computation of
+    `text` whose fusions are named `collect/env/render` (the collect
+    scan's body; the entry of a lone `step`), whose result is an array of
+    `n` x 84 x 84 x {1, 3, 4} `u8` or `n` x 84 x 84 `u32` elements: a
+    frame, the old stack's three planes, a stack, the history's words."""
+    import math
+
+    from distributed_reinforcement_learning_tpu.observability import scopes
+
+    bodies = [c for c in re.split(r"\n(?=(?:ENTRY )?%\S+ \([^\n]*\{\n)", text)
+              if not c.startswith("%fused_computation")
+              and re.search(rf" fusion\(.*{scopes.RENDER}/", c)]
+    if len(bodies) > 1:  # the entry holds what was hoisted out of the scan
+        bodies = [c for c in bodies if f"body={c.split(' ', 1)[0]}," in text]
+    assert len(bodies) == 1, len(bodies)
+    sizes = {("u8", n * 84 * 84 * k) for k in (1, 3, 4)} | {("u32", n * 84 * 84)}
+    hits = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%\S+ = ((\w+)\[([\d,]+)\]\S*) ([\w-]+)\((.*)$",
+                         bodies[0], re.M):
+        result, dtype, dims, opcode, rest = m.groups()
+        if opcode in ("parameter", "get-tuple-element", "bitcast"):
+            continue  # no bytes move
+        if (dtype, math.prod(int(d) for d in dims.split(","))) in sizes:
+            name = re.search(r'op_name="([^"]*)"', rest)
+            hits.append((result, opcode, name[1] if name else ""))
+    return hits
+
+
+@pytest.mark.parametrize("program, n", [("chunk", 256), ("step", 2048)])
+def test_breakout_history_is_two_fusions_a_step(request, chip, program, n):
+    """The four-frame history is `u32[N,84,84]` words (PR 48). In the fused
+    chunk's collect body at 256 envs and in `breakout_jax.step` alone at
+    2,048, what touches a frame-, stack- or words-sized array is TWO
+    fusions under `collect/env/render`: one writes the words batch-minor
+    (the resize's last product, the shift, the or and the game-over select:
+    the frame is never an array of its own) and one unpacks them to
+    `u8[N,84,84,4]` batch-minor with the four bytes of a pixel in one word,
+    the layout conv0 and the scan's stacked write read. Beside them at most
+    one async copy of the words between memory spaces. The byte stack
+    before showed six a step: the frame's layout `copy`, a select of zeros
+    (`broadcast_select_fusion`), the concat (`pad_add_fusion`), and, in
+    the chunk, two nameless copies of the whole stack, which `step`
+    returned twice. Without `pixel_jax.observe`'s layout pin the chunk
+    holds two transposing copies of the words."""
+    from distributed_reinforcement_learning_tpu.observability import scopes
+
+    if program == "chunk":
+        _, text, _ = request.getfixturevalue("breakout_chunk")
+    else:
+        text = _breakout_step_text(chip, n)
+    ops = _history_ops(text, n)
+    fusions = [(result, path) for result, opcode, path in ops if opcode == "fusion"]
+    assert len(fusions) == 2, ops
+    (words, words_path), (obs, obs_path) = sorted(fusions)  # "u32" < "u8"
+    assert words.startswith(f"u32[{n},84,84]{{0,2,1:"), words
+    assert obs.startswith(f"u8[{n},84,84,4]{{0,3,2,1:T(4,128)(4,1)"), obs
+    assert f"/{scopes.RENDER}/" in words_path and f"/{scopes.RENDER}/" in obs_path
+    others = [(result, opcode) for result, opcode, _ in ops if opcode != "fusion"]
+    assert all(opcode in ("copy-start", "copy-done") and result.startswith("u32[")
+               for result, opcode in others) and len(others) <= 1, others
 
 
 def test_mesh_breakout_chunk_moves_no_frames_between_chips(
