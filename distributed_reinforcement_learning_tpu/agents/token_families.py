@@ -18,6 +18,8 @@ from distributed_reinforcement_learning_tpu.agents.mlalm import (
     MLALMAgent, MLALMConfig)
 from distributed_reinforcement_learning_tpu.agents.moelm import (
     MoELMAgent, MoELMConfig)
+from distributed_reinforcement_learning_tpu.agents.swalm import (
+    SwaLMAgent, SwaLMConfig)
 
 TOKEN_FAMILIES = {
     "looplm": (LoopLMConfig, LoopLMAgent),
@@ -25,4 +27,5 @@ TOKEN_FAMILIES = {
     "moelm": (MoELMConfig, MoELMAgent),
     "mlalm": (MLALMConfig, MLALMAgent),
     "convlm": (ConvLMConfig, ConvLMAgent),
+    "swalm": (SwaLMConfig, SwaLMAgent),
 }

@@ -124,6 +124,15 @@ CONV = "learn/loss/layers/conv"  # the whole convolution mixer: W_in, both gates
 CONV_ACT = {"dense": ACT_LAYERS, "route": ACT_MOE_ROUTE, "experts": ACT_MOE_EXPERTS}
 CONV_LEARN = {"dense": DENSE, "route": MOE_ROUTE, "experts": MOE_EXPERTS}
 
+# The sliding-window / global-attention sparse-expert model in the same
+# loop (models/window_moe_lm.py); layers, the global layer's cache, the
+# expert scopes (the route ahead of attention; no shared expert), heads,
+# V-trace and optimizer under the names above. No bump of CACHE_TAG, for
+# the reason given there.
+ACT_RING = "collect/act/ring"  # a window layer's ring: the write at t mod W, scores and weighted sum on min(span, W) slots (the global layer's on its prefix: ACT_CACHE)
+GLOBAL_ATTENTION = "learn/loss/layers/global_attention"  # the NoPE layer: q, k, v, the full causal core
+WINDOW_ATTENTION = "learn/loss/layers/window_attention"  # a window layer: q, k, v, rotary, the windowed core
+
 IMPALA_CHUNK_SCOPES = (COLLECT, ACT, ENV, RENDER, RECORD,
                        LEARN, LOSS, VTRACE, OPTIMIZER)
 REPLAY_CHUNK_SCOPES = (COLLECT, REPLAY, LEARN)
@@ -148,6 +157,11 @@ MLA_CHUNK_SCOPES = (ENV, ACT, ACT_LAYERS, ACT_MLA_PROJECT, ACT_MLA_ATTEND,
 CONV_CHUNK_SCOPES = (ENV, ACT, ACT_LAYERS, ACT_CONV, ACT_MOE_ROUTE,
                       ACT_MOE_EXPERTS, ACT_CACHE, ACT_HEAD, LAYERS, CONV, ATTENTION,
                       DENSE, MOE_ROUTE, MOE_EXPERTS, HEADS, LOSS_VTRACE, OPTIMIZER)
+
+SWA_CHUNK_SCOPES = (ENV, ACT, ACT_LAYERS, ACT_RING, ACT_CACHE, ACT_MOE_ROUTE,
+                    ACT_MOE_EXPERTS, ACT_HEAD, LAYERS, GLOBAL_ATTENTION,
+                    WINDOW_ATTENTION, MOE_ROUTE, MOE_EXPERTS, HEADS, LOSS_VTRACE,
+                    OPTIMIZER)
 
 # -- host spans of the fused loops (runtime/launch.py) ---------------------
 STEP_READ = "anakin/step_read"  # int(state.train.step) at the loop head

@@ -30,8 +30,10 @@ def _causal_mask(q_pos: jax.Array, k_pos: jax.Array) -> jax.Array:
     return q_pos[:, None] >= k_pos[None, :]
 
 
-def _combined_mask(causal, q_pos, k_pos, q_seg, k_seg):
+def _combined_mask(causal, q_pos, k_pos, q_seg, k_seg, window=None):
     """[B|1, 1, Tq, Tk] bool mask, or None when nothing constrains.
+    `window` (a static int): a query sees the keys at most `window - 1`
+    positions behind it, itself included (`q_pos - k_pos < window`).
 
     Segment ids (per batch row, e.g. episode indices from cumsum(done))
     confine attention within an episode: RL sequences cross episode
@@ -41,6 +43,9 @@ def _combined_mask(causal, q_pos, k_pos, q_seg, k_seg):
     mask = None
     if causal:
         mask = _causal_mask(q_pos, k_pos)[None, None]
+    if window is not None:
+        near = (q_pos[:, None] - k_pos[None, :] < window)[None, None]
+        mask = near if mask is None else (mask & near)
     if q_seg is not None:
         seg = (q_seg[:, None, :, None] == k_seg[:, None, None, :])
         mask = seg if mask is None else (mask & seg)
@@ -57,16 +62,17 @@ def dense_attention(
     kv_offset: int | jax.Array = 0,
     q_seg: jax.Array | None = None,
     k_seg: jax.Array | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Plain softmax(QKᵀ/√d)V — the golden reference the blockwise and
     ring paths are tested against, and the fast path for short sequences
     where one fused XLA softmax beats any blocking. `v` may have a
-    width of its own."""
+    width of its own. `window`: a sliding window (`_combined_mask`)."""
     dim = q.shape[-1]
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (dim**-0.5)
     q_pos = q_offset + jnp.arange(q.shape[1])
     k_pos = kv_offset + jnp.arange(k.shape[1])
-    mask = _combined_mask(causal, q_pos, k_pos, q_seg, k_seg)
+    mask = _combined_mask(causal, q_pos, k_pos, q_seg, k_seg, window)
     if mask is not None:
         logits = jnp.where(mask, logits, _MASK_VALUE)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
@@ -103,6 +109,7 @@ def attention_block_step(
     k_pos: jax.Array,
     q_seg: jax.Array | None = None,
     k_seg: jax.Array | None = None,
+    window: int | None = None,
 ):
     """Fold one KV block into the accumulator (flash-attention recurrence).
 
@@ -116,7 +123,7 @@ def attention_block_step(
     m, l, o = acc
     dim = q.shape[-1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k_block).astype(jnp.float32) * (dim**-0.5)
-    mask = _combined_mask(causal, q_pos, k_pos, q_seg, k_seg)
+    mask = _combined_mask(causal, q_pos, k_pos, q_seg, k_seg, window)
     if mask is not None:
         s = jnp.where(mask, s, _MASK_VALUE)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1))
@@ -146,6 +153,7 @@ def causal_attention(
     q_seg: jax.Array | None = None,
     k_seg: jax.Array | None = None,
     backend: str = "auto",
+    window: int | None = None,
 ) -> jax.Array:
     """Causal (optionally segment-masked) MHA with backend dispatch.
 
@@ -157,6 +165,9 @@ def causal_attention(
     or the blockwise online-softmax path for long ones. All paths share the same
     numerics contract (validated against dense in tests). `v` may have
     a width of its own (`[B, T, H, Dv]`, the output's), on every path.
+    `window` (a static int, None: full causal): key j is visible to query
+    t iff `t - j < window` as well; the kernels skip and do not fetch the
+    blocks that lie wholly outside it.
     """
     from distributed_reinforcement_learning_tpu.ops.pallas import resolve_backend
     from distributed_reinforcement_learning_tpu.ops.pallas.attention import flash_blocks
@@ -177,14 +188,15 @@ def causal_attention(
         seg_flat = lambda s: jnp.repeat(s, h, axis=0)
         out = flash_attention_bhtd(
             flat(q), flat(k), flat(v), seg_flat(qs), seg_flat(ks),
-            interpret=(resolved == "pallas_interpret"),
+            interpret=(resolved == "pallas_interpret"), window=window,
         )
         return out.reshape(b, h, t, v.shape[-1]).transpose(0, 2, 1, 3)
     if t <= 1024:
-        return dense_attention(q, k, v, causal=True, q_seg=q_seg, k_seg=k_seg)
+        return dense_attention(q, k, v, causal=True, q_seg=q_seg, k_seg=k_seg,
+                               window=window)
     return blockwise_attention(
         q, k, v, causal=True, block_size=512,
-        segment_ids=q_seg, kv_segment_ids=k_seg,
+        segment_ids=q_seg, kv_segment_ids=k_seg, window=window,
     )
 
 
@@ -197,6 +209,7 @@ def blockwise_attention(
     block_size: int = 512,
     segment_ids: jax.Array | None = None,
     kv_segment_ids: jax.Array | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Single-device attention computed block-by-block over keys.
 
@@ -227,7 +240,7 @@ def blockwise_attention(
         return (
             attention_block_step(
                 acc, q, k_blk, v_blk, causal=causal, q_pos=q_pos, k_pos=k_pos,
-                q_seg=segment_ids, k_seg=seg_blk,
+                q_seg=segment_ids, k_seg=seg_blk, window=window,
             ),
             None,
         )

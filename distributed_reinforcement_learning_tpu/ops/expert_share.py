@@ -7,7 +7,7 @@ expert) pairs routed to them:
     p = softmax(x W_r) over all E;   I = the top_k largest;   w_i = p_i / sum_{j in I} p_j
     (or, `scoring="sigmoid"`: s = sigmoid(x W_r), I = the top_k of s + b, w_i = c s_i / sum_{j in I} s_j)
     out(x) = sum_{i in I, first <= i < first + held} w_i E_i(x),
-    E(x) = W_d (silu(W_g x) * W_u x)
+    E(x) = W_d (silu(W_g x) * W_u x)      (`activation="relu"`: relu(W_g x) * W_u x, ReGLU)
 
 What the absent experts would have added is left out (their chips add
 it, in a deployment, through an exchange this file does not stand in
@@ -31,8 +31,10 @@ decode step; a layer that holds every expert) there is one slab and no
 loop. No array has an expert AND a capacity axis, and none has the list's
 length and a model width. Every learner runs this form (4,096 rows a call
 and more), and the decode steps that send a held expert under one pair a
-call (`qwen3_next`: 32 rows x 10 of 512, `joyai_flash`: 16 x 8 of 256),
-where the grouped product reads the touched experts' weights alone.
+call (`qwen3_next`: 32 rows x 10 of 512, `joyai_flash`: 16 x 8 of 256,
+`smallthinker_moe`: 8 x 6 of 64, 0.75 pair an expert and 16 held of width
+768), where the grouped product reads the touched experts' weights alone
+(about 8.7 of 16 a layer a step there).
 
 DENSE (PR 47). Where the list is one slab, the router sends every held
 expert more than one pair a call and the rows are few (`lfm2_moe`'s decode
@@ -156,13 +158,25 @@ def slab_rows(pairs: int, held: int, num_experts: int) -> int:
     return min(pairs, -(-rows // 512) * 512)
 
 
+def _gated(activation: str, gate: jax.Array, counted: jax.Array):
+    """The expert's gate under its activation (`"silu"`: SwiGLU;
+    `"relu"`: ReGLU) and, for `"relu"`, how many of the gate values of the
+    pairs `counted` (a mask that broadcasts against `gate`) it zeroes
+    (None for `"silu"`, which zeroes none: no op is added to its callers)."""
+    if activation == "silu":
+        return jax.nn.silu(gate), None
+    if activation != "relu":
+        raise ValueError(f"unknown activation {activation!r}: silu or relu")
+    return jax.nn.relu(gate), jnp.sum(counted & (gate <= 0), dtype=jnp.int32)
+
+
 def _slab(rows: jax.Array, weight: jax.Array, wgu: jax.Array, wd: jax.Array,
-          sizes: jax.Array, live_rows: jax.Array) -> jax.Array:
+          sizes: jax.Array, live_rows: jax.Array, activation: str = "silu"):
     """The held experts on one slab of the sorted pairs: `rows [S, D]`
     (the pairs' tokens, operands' dtype), `weight [S]`, `sizes [held]` (the
     rows of each expert inside the slab, in order), `live_rows`: how many
-    of the slab's rows are held pairs -> `[S, D]` float32, each pair's
-    weighted expert output and 0 on the rest."""
+    of the slab's rows are held pairs -> (`[S, D]` float32, each pair's
+    weighted expert output and 0 on the rest; `_gated`'s count)."""
     # A row past the last group belongs to no held expert, and the grouped
     # product neither reads nor WRITES it: on the chip it holds whatever
     # the buffer held (my chip run, PR 36: finite garbage; nothing says it
@@ -172,9 +186,10 @@ def _slab(rows: jax.Array, weight: jax.Array, wgu: jax.Array, wd: jax.Array,
     live = (jnp.arange(rows.shape[0]) < live_rows)[:, None]
     gate, up = jnp.split(jax.lax.ragged_dot(
         jnp.where(live, rows, 0), wgu, sizes, preferred_element_type=F32), 2, -1)
-    y = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(rows.dtype), wd, sizes,
+    gate, zeroed = _gated(activation, gate, live)
+    y = jax.lax.ragged_dot((gate * up).astype(rows.dtype), wd, sizes,
                            preferred_element_type=F32)
-    return jnp.where(live, y, 0.0) * weight[:, None]
+    return jnp.where(live, y, 0.0) * weight[:, None], zeroed
 
 
 def one_slab_form(n: int, top_k: int, num_experts: int) -> str:
@@ -195,6 +210,9 @@ def one_slab_form(n: int, top_k: int, num_experts: int) -> str:
         32 rows, top 10 of 512, 32 held, F 512 (`qwen3_next`'s)       234 | 294
         16 rows, top 8 of 256, 16 held, F 768 (`joyai_flash`'s)       156 | 214
         the same at 32 / 64 rows                          249 | 217, 492 | 225
+        8 rows, top 6 of 64, 16 held, F 768 (`smallthinker_moe`'s): 48 pairs
+          for 64 experts, UNDER one pair an expert: sorted by the rule (not
+          timed alone; the cell's traced run has the layer's act-time share)
         all 16 of 16 held, F 1,536, 128 / 256 / 384 / 512 rows
                               1,016 | 470, 1,171 | 482, 1,271 | 745, 1,400 | 958
 
@@ -228,12 +246,13 @@ def call_form(n: int, top_k: int, held: int, num_experts: int) -> str:
 
 
 def _dense(x: jax.Array, key: jax.Array, weight: jax.Array, wgu: jax.Array,
-           wd: jax.Array) -> jax.Array:
+           wd: jax.Array, activation: str = "silu"):
     """Every held expert on every row, one batched product over the
     experts, a row's results weighted by what the router gave each (0: an
     expert the row did not choose): `x [N, D]`, `key, weight [N x top_k]`
-    (`held_pairs`' experts, the router's weights) -> `[N, D]` float32, the
-    sorted form's sum in another order of float32 additions."""
+    (`held_pairs`' experts, the router's weights) -> (`[N, D]` float32, the
+    sorted form's sum in another order of float32 additions; `_gated`'s
+    count over the (expert, row) the router paired)."""
     held, pairs = wgu.shape[0], (x.shape[0], -1)
     w = jnp.sum(jnp.where(
         key.reshape(pairs)[None] == jnp.arange(held)[:, None, None],
@@ -241,10 +260,11 @@ def _dense(x: jax.Array, key: jax.Array, weight: jax.Array, wgu: jax.Array,
     gate, up = jnp.split(jnp.einsum(
         "end,edf->enf", jnp.broadcast_to(x, (held, *x.shape)), wgu,
         preferred_element_type=F32), 2, -1)
-    y = jnp.einsum("enf,efd->end", (jax.nn.silu(gate) * up).astype(x.dtype), wd,
+    gate, zeroed = _gated(activation, gate, w != 0)
+    y = jnp.einsum("enf,efd->end", (gate * up).astype(x.dtype), wd,
                    preferred_element_type=F32)
     # 0 x a non-finite result of an expert the row did not choose would be NaN
-    return jnp.sum(jnp.where(w != 0, y, 0.0) * w, axis=0)
+    return jnp.sum(jnp.where(w != 0, y, 0.0) * w, axis=0), zeroed
 
 
 def _trips(sizes, slab: int):
@@ -263,35 +283,43 @@ def _slab_of(i, slab: int, top_k: int, order, sizes):
     return pair, pair // top_k, inside, end[-1] - lo
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _slabs(x, weight, wgu, wd, order, sizes, slab: int, top_k: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _slabs(x, weight, wgu, wd, order, sizes, slab: int, top_k: int,
+           activation: str = "silu"):
     """`sum_i scatter(_slab(slab i))` over the slabs that hold a held pair:
     `x [N, D]`, `wgu`, `wd` in the operands' dtype, `weight [N x top_k]`
-    by flat pair index, `order [trips_max x slab]` -> `[N, D]` float32."""
-    return _slabs_fwd(x, weight, wgu, wd, order, sizes, slab, top_k)[0]
+    by flat pair index, `order [trips_max x slab]` -> (`[N, D]` float32,
+    the slabs' `_gated` counts summed: None for `"silu"`)."""
+    return _slabs_fwd(x, weight, wgu, wd, order, sizes, slab, top_k, activation)[0]
 
 
-def _slabs_fwd(x, weight, wgu, wd, order, sizes, slab, top_k):
-    def trip(i, out):
+def _slabs_fwd(x, weight, wgu, wd, order, sizes, slab, top_k, activation):
+    def trip(i, sums):
+        out, zeroed = sums
         pair, tok, inside, live_rows = _slab_of(i, slab, top_k, order, sizes)
-        return out.at[tok].add(_slab(x[tok], weight[pair], wgu, wd, inside, live_rows))
+        y, z = _slab(x[tok], weight[pair], wgu, wd, inside, live_rows, activation)
+        return out.at[tok].add(y), None if z is None else zeroed + z
 
-    out = jax.lax.fori_loop(0, _trips(sizes, slab), trip, jnp.zeros(x.shape, F32))
-    return out, (x, weight, wgu, wd, order, sizes)
+    sums = jax.lax.fori_loop(
+        0, _trips(sizes, slab), trip,
+        (jnp.zeros(x.shape, F32), None if activation == "silu" else jnp.int32(0)))
+    return sums, (x, weight, wgu, wd, order, sizes)
 
 
-def _slabs_bwd(slab, top_k, saved, g):
+def _slabs_bwd(slab, top_k, activation, saved, g):
     """The same loop backwards: a trip takes the slab's own VJP and adds
     into float32 sums (reverse mode does not pass a loop whose trip count
     is traced, and the caller rematerialises the layer anyway, so only the
     inputs were kept)."""
     x, weight, wgu, wd, order, sizes = saved
+    g = g[0]  # the count is an integer: no cotangent
 
     def trip(i, sums):
         dx, dweight, dwgu, dwd = sums
         pair, tok, inside, live_rows = _slab_of(i, slab, top_k, order, sizes)
         _, back = jax.vjp(
-            lambda rows, w, wgu, wd: _slab(rows, w, wgu, wd, inside, live_rows),
+            lambda rows, w, wgu, wd: _slab(rows, w, wgu, wd, inside, live_rows,
+                                           activation)[0],
             x[tok], weight[pair], wgu, wd)
         rows_bar, w_bar, wgu_bar, wd_bar = back(g[tok])
         return (dx.at[tok].add(rows_bar.astype(F32)), dweight.at[pair].add(w_bar),
@@ -309,13 +337,16 @@ _slabs.defvjp(_slabs_fwd, _slabs_bwd)
 
 def held_experts(x: jax.Array, chosen: jax.Array, weight: jax.Array,
                  wgu: jax.Array, wd: jax.Array, first_expert: int,
-                 num_experts: int, dtype=jnp.bfloat16):
+                 num_experts: int, dtype=jnp.bfloat16, activation: str = "silu"):
     """`x [N, D]`, `chosen, weight [N, top_k]` (`route`'s), `wgu [held,
     D, 2 F]` (gate and up side by side), `wd [held, F, D]`, `num_experts`:
     the router's width -> (`out [N, D]` float32: the held experts'
     weighted part of the layer's result; counters). Dropless: every held
     pair lies in exactly one slab, and every slab with one is run; the
-    dense form (`one_slab_form`) runs every held expert on every row."""
+    dense form (`one_slab_form`) runs every held expert on every row.
+    `activation`: the gate's, `"silu"` or `"relu"` (ReGLU, `relu(W_g x) *
+    W_u x`), which adds the counter `gate_zeroed`: the held pairs' gate
+    values (F a pair) that ReLU zeroed."""
     n, top_k = chosen.shape
     held = wgu.shape[0]
     form, slab = _form(n, top_k, held, num_experts)
@@ -328,15 +359,18 @@ def held_experts(x: jax.Array, chosen: jax.Array, weight: jax.Array,
     x, wgu, wd = x.astype(dtype), wgu.astype(dtype), wd.astype(dtype)
     weight = weight.reshape(-1)
     if form == "dense":  # every held expert is read anyway: no sort, no grouped product
-        out = _dense(x, key, weight, wgu, wd)
+        out, zeroed = _dense(x, key, weight, wgu, wd, activation)
     elif form == "sorted":  # the list is one slab: no loop, autodiff's own backward
-        token = order // top_k
-        out = jnp.zeros(x.shape, F32).at[token].add(
-            _slab(x[token], weight[order], wgu, wd, sizes, count))
+        token, out = order // top_k, jnp.zeros(x.shape, F32)
+        y, zeroed = _slab(x[token], weight[order], wgu, wd, sizes, count, activation)
+        out = out.at[token].add(y)
     else:  # the list's last slab is a whole one too: rows of pair 0, past `count`
-        out = _slabs(x, weight, wgu, wd, jnp.pad(order, (0, -order.size % slab)),
-                     sizes, slab, top_k)
+        out, zeroed = _slabs(x, weight, wgu, wd,
+                             jnp.pad(order, (0, -order.size % slab)), sizes, slab,
+                             top_k, activation)
     counters = {"held_pairs": count, "expert_pairs": sizes, "pair_slabs": trips,
                 "dropped_pairs": jnp.sum(here) - jnp.minimum(count, trips * slab),
                 "dense_rows": jnp.int32(n * held if form == "dense" else 0)}
+    if zeroed is not None:
+        counters["gate_zeroed"] = jax.lax.stop_gradient(zeroed)
     return out, counters

@@ -71,17 +71,47 @@ def _pos(start, rows, cols, axis):
     return start + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), axis)
 
 
-def _block_mask(iq_start, jk_start, bq, bkv, qs, ks_row):
-    """[bq, bkv] causal & same-segment mask.
+def _block_mask(iq_start, jk_start, bq, bkv, qs, ks_row, window=None):
+    """[bq, bkv] causal & same-segment mask, inside `window` if there is one
+    (`q_pos - k_pos < window`).
 
     qs: [bq, 1] query segment ids; ks_row: [1, bkv] key segment ids.
     """
-    causal = _pos(iq_start, bq, bkv, 0) >= _pos(jk_start, bq, bkv, 1)
+    q_pos, k_pos = _pos(iq_start, bq, bkv, 0), _pos(jk_start, bq, bkv, 1)
+    causal = q_pos >= k_pos
+    if window is not None:
+        causal &= q_pos - k_pos < window
     return causal & (qs == ks_row)
 
 
+def _kv_walk(iq, bq: int, bkv: int, window):
+    """(first, last) kv block that q block `iq` sees: `last` holds its
+    last row's own position; `first` (0 without a window) the oldest key
+    its FIRST row's window reaches. None: no lower bound."""
+    last = ((iq + 1) * bq - 1) // bkv
+    if window is None:
+        return None, last
+    return jnp.maximum(iq * bq - (window - 1), 0) // bkv, last
+
+
+def _in_walk(j, first, last):
+    """Whether block `j` lies in a walk `first..last` (None: unbounded)."""
+    inside = j <= last if last is not None else True
+    return inside if first is None else inside & (j >= first)
+
+
+def _q_walk(jk, bq: int, bkv: int, n_q: int, window):
+    """(first, last) q block that sees kv block `jk`: the first is the one
+    that holds its first key's position (causal), the last (None without
+    a window) the one whose first row still reaches its last key."""
+    first = (jk * bkv) // bq
+    if window is None:
+        return first, None
+    return first, jnp.minimum(((jk + 1) * bkv - 1 + window - 1) // bq, n_q - 1)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr):
+                m_scr, l_scr, acc_scr, *, window=None):
     """Grid (BH, nq, nkv): kv is a GRID axis (one k/v block VMEM-resident
     at a time — a full [T, D] K/V residency caps T at ~8k), with the
     online-softmax state in scratch across the inner kv walk; o/lse
@@ -98,9 +128,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, o_ref, lse_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    last = ((iq + 1) * bq - 1) // bkv  # last kv block this q block attends
+    first, last = _kv_walk(iq, bq, bkv, window)  # the kv blocks this q block attends
 
-    @pl.when(jk <= last)
+    @pl.when(_in_walk(jk, first, last))
     def _():
         q = q_ref[0]
         qs = qs_ref[0]
@@ -110,7 +140,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, o_ref, lse_ref,
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        msk = _block_mask(iq * bq, jk * bkv, bq, bkv, qs, ks_row)
+        msk = _block_mask(iq * bq, jk * bkv, bq, bkv, qs, ks_row, window)
         s = jnp.where(msk, s, _NEG)
         m = m_scr[:]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -130,7 +160,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, o_ref, lse_ref,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_scr):
+               dq_ref, dq_scr, *, window=None):
     """Grid (BH, nq, nkv), kv walked by the grid; dq accumulates in
     scratch and flushes on the last step (same shape as _fwd_kernel)."""
     iq = pl.program_id(1)
@@ -143,9 +173,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    last = ((iq + 1) * bq - 1) // bkv
+    first, last = _kv_walk(iq, bq, bkv, window)
 
-    @pl.when(jk <= last)
+    @pl.when(_in_walk(jk, first, last))
     def _():
         q = q_ref[0]
         qs = qs_ref[0]
@@ -158,7 +188,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        msk = _block_mask(iq * bq, jk * bkv, bq, bkv, qs, ks_row)
+        msk = _block_mask(iq * bq, jk * bkv, bq, bkv, qs, ks_row, window)
         p = jnp.where(msk, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(
             do, v_blk, (((1,), (1,)), ((), ())),
@@ -174,7 +204,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, block_q: int):
+                dk_ref, dv_ref, dk_scr, dv_scr, *, block_q: int, window=None):
     """Grid (BH, nk, nq): the q axis is a GRID dimension, not an
     in-kernel loop, so only one q/do block is VMEM-resident at a time
     (a full [T, D] q + do residency overflowed scoped VMEM at T=8192).
@@ -191,8 +221,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    # Causal: q blocks strictly before this kv block are fully masked.
-    @pl.when(iq * block_q + block_q > jk * bkv)
+    # Causal: q blocks strictly before this kv block are fully masked; so
+    # are those whose first row's window ends before it.
+    seen = iq * block_q + block_q > jk * bkv
+    if window is not None:
+        seen &= iq <= _q_walk(jk, block_q, bkv, n_q, window)[1]
+
+    @pl.when(seen)
     def _():
         k_blk = k_ref[0]
         v_blk = v_ref[0]
@@ -205,7 +240,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q_i, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        msk = _block_mask(iq * block_q, jk * bkv, block_q, bkv, qs_i, ks_row)
+        msk = _block_mask(iq * block_q, jk * bkv, block_q, bkv, qs_i, ks_row, window)
         p = jnp.where(msk, jnp.exp(s - lse_i), 0.0)
         dv_scr[:] += jax.lax.dot_general(
             p.astype(do_i.dtype), do_i, (((0,), (0,)), ((), ())),
@@ -224,7 +259,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _qkv_specs(d: int, bq: int, bkv: int, dv: int):
+def _qkv_specs(d: int, bq: int, bkv: int, dv: int, window=None):
     """3-D-grid (b, i_q, j_kv) block specs: q-indexed, kv-indexed rows
     at q/k's width `d`, then the output (and its cotangent) and the
     value rows at the value's width `dv`.
@@ -233,11 +268,14 @@ def _qkv_specs(d: int, bq: int, bkv: int, dv: int):
     current q block: past it the index map repeats the same block, which
     Pallas recognizes as a revisit and does not re-DMA — the ~half of
     the rectangular grid that is fully future-masked (compute skipped by
-    pl.when in the kernels) costs no HBM traffic either.
+    pl.when in the kernels) costs no HBM traffic either. Under a `window`
+    it is clamped from below too, to the first block the window reaches:
+    the steps before it fetch the block the first computed step needs.
     """
 
     def jcap(i, j):
-        return jnp.minimum(j, ((i + 1) * bq - 1) // bkv)
+        first, last = _kv_walk(i, bq, bkv, window)
+        return jnp.minimum(j, last) if first is None else jnp.clip(j, first, last)
 
     q3 = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM)
     qrow3 = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM)
@@ -251,12 +289,12 @@ def _qkv_specs(d: int, bq: int, bkv: int, dv: int):
     return q3, qrow3, kv3, krow3, o3, v3
 
 
-def _fwd_call(q, k, v, qs, ks, bq, bkv, interpret):
+def _fwd_call(q, k, v, qs, ks, bq, bkv, interpret, window=None):
     bh, t, d = q.shape
     dv = v.shape[2]
-    q3, qrow3, kv3, krow3, o3, v3 = _qkv_specs(d, bq, bkv, dv)
+    q3, qrow3, kv3, krow3, o3, v3 = _qkv_specs(d, bq, bkv, dv, window)
     return pl.pallas_call(
-        _fwd_kernel,
+        functools.partial(_fwd_kernel, window=window),
         grid=(bh, t // bq, t // bkv),
         in_specs=[q3, kv3, v3, qrow3, krow3],
         out_specs=[o3, qrow3],
@@ -273,12 +311,12 @@ def _fwd_call(q, k, v, qs, ks, bq, bkv, interpret):
     )(q, k, v, qs, ks)
 
 
-def _bwd_call(q, k, v, qs, ks, do, lse, delta, bq, bkv, interpret):
+def _bwd_call(q, k, v, qs, ks, do, lse, delta, bq, bkv, interpret, window=None):
     bh, t, d = q.shape
     dv = v.shape[2]
-    q3, qrow3, kv3, krow3, o3, v3 = _qkv_specs(d, bq, bkv, dv)
+    q3, qrow3, kv3, krow3, o3, v3 = _qkv_specs(d, bq, bkv, dv, window)
     dq = pl.pallas_call(
-        _dq_kernel,
+        functools.partial(_dq_kernel, window=window),
         grid=(bh, t // bq, t // bkv),
         in_specs=[q3, kv3, v3, qrow3, krow3, o3, qrow3, qrow3],
         out_specs=[q3],
@@ -289,9 +327,11 @@ def _bwd_call(q, k, v, qs, ks, do, lse, delta, bq, bkv, interpret):
     # 3-D grid: kv blocks indexed by j (middle), q/do blocks by the
     # innermost iq axis; dk/dv blocks revisit across iq. The q index is
     # clamped to the first causally-contributing block for this kv block
-    # (skipped early steps revisit it — no re-DMA, compute pl.when'd off).
+    # (skipped early steps revisit it — no re-DMA, compute pl.when'd off),
+    # and under a window to the last q block that still reaches it.
     def icap(j, i):
-        return jnp.maximum(i, (j * bkv) // bq)
+        first, last = _q_walk(j, bq, bkv, t // bq, window)
+        return jnp.maximum(i, first) if last is None else jnp.clip(i, first, last)
 
     kv3 = pl.BlockSpec((1, bkv, d), lambda b, j, i: (b, j, 0), memory_space=pltpu.VMEM)
     v3 = pl.BlockSpec((1, bkv, dv), lambda b, j, i: (b, j, 0), memory_space=pltpu.VMEM)
@@ -303,7 +343,7 @@ def _bwd_call(q, k, v, qs, ks, do, lse, delta, bq, bkv, interpret):
     qrow3 = pl.BlockSpec(
         (1, bq, 1), lambda b, j, i: (b, icap(j, i), 0), memory_space=pltpu.VMEM)
     dk, dv_ = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=bq),
+        functools.partial(_dkv_kernel, block_q=bq, window=window),
         grid=(bh, t // bkv, t // bq),
         in_specs=[q3, kv3, v3, qrow3, krow3, o3, qrow3, qrow3],
         out_specs=[kv3, v3],
@@ -321,18 +361,18 @@ def _bwd_call(q, k, v, qs, ks, do, lse, delta, bq, bkv, interpret):
 
 
 @functools.cache
-def _make_flash(bq: int, bkv: int, interpret: bool):
+def _make_flash(bq: int, bkv: int, interpret: bool, window: int | None = None):
     # Every kernel is independent along the batch*heads dim: under a
     # mesh each device runs it on its own rows (batch_partitioned reads
     # the context mesh at TRACE time, hence inside these functions).
     def fwd_call(q, k, v, qs, ks):
         return batch_partitioned(
-            lambda *a: tuple(_fwd_call(*a, bq, bkv, interpret)),
+            lambda *a: tuple(_fwd_call(*a, bq, bkv, interpret, window)),
             (0,) * 5, (0, 0))(q, k, v, qs, ks)
 
     def bwd_call(q, k, v, qs, ks, do, lse, delta):
         return batch_partitioned(
-            lambda *a: _bwd_call(*a, bq, bkv, interpret),
+            lambda *a: _bwd_call(*a, bq, bkv, interpret, window),
             (0,) * 8, (0, 0, 0))(q, k, v, qs, ks, do, lse, delta)
 
     @jax.custom_vjp
@@ -364,6 +404,7 @@ def flash_attention_bhtd(
     block_q: int | None = None,
     block_kv: int | None = None,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jax.Array:
     """Causal flash attention on `[BH, T, D]` with `[BH, T]` segment ids.
 
@@ -372,14 +413,20 @@ def flash_attention_bhtd(
     dq/dkv kernels. `v` may have a width of its own (`[BH, T, Dv]`: the
     output's); the scores are scaled by q/k's `D ** -0.5`. Every product
     takes its operands in the dtype they arrive in and accumulates in
-    float32; the softmax is float32 throughout.
+    float32; the softmax is float32 throughout. `window` (a static int;
+    None: full causal): key j is visible to query t iff `t - j < window`
+    too; kv blocks wholly outside a q block's window are neither computed
+    nor fetched, and a row whose window holds no same-episode key gives
+    zeros.
     """
     bh, t, d = q.shape
     if block_q is None or block_kv is None:
         block_q, block_kv = flash_blocks(t, d, v.shape[2], q.dtype.itemsize)
     if not block_q or t % block_q or t % block_kv:
         raise ValueError(f"T={t} not divisible by blocks ({block_q}, {block_kv})")
-    f = _make_flash(block_q, block_kv, interpret)
+    if window is not None and window < 1:
+        raise ValueError(f"window {window}: a query sees itself at least")
+    f = _make_flash(block_q, block_kv, interpret, window)
     return f(q, k, v,
              q_seg.astype(jnp.int32).reshape(bh, t, 1),
              k_seg.astype(jnp.int32).reshape(bh, 1, t))

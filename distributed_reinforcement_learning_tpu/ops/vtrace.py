@@ -91,7 +91,7 @@ def from_importance_weights(
     from distributed_reinforcement_learning_tpu.ops.pallas import resolve_backend
 
     resolved = resolve_backend(backend)
-    if resolved != "reference":
+    if resolved != "reference" and _kernel_fits(*log_rhos.shape):
         from distributed_reinforcement_learning_tpu.ops.pallas.vtrace import vtrace_pallas
 
         # The whole V-trace target is stop-gradded (the reference's
@@ -227,3 +227,16 @@ def entropy_loss(policy_probs: jax.Array) -> jax.Array:
     """
     plogp = jnp.where(policy_probs > 0, policy_probs * jnp.log(jnp.where(policy_probs > 0, policy_probs, 1.0)), 0.0)
     return jnp.sum(plogp)
+
+
+def _kernel_fits(T: int, B: int) -> bool:
+    """Whether the Pallas kernel's working set at `[T, B]` fits the 16 MiB
+    of scoped VMEM: it unrolls T and holds about nine `[T, block]` float32
+    arrays (four inputs, two outputs, `next_values`, `deltas`, `cs`), a
+    column padded to the 128 lanes of a tile and a block of at most 256
+    columns (the compiler's own count at [8190, 8]: 35.78 MB). Where it
+    does not (an episode of thousands of steps), `from_importance_weights`
+    takes its scan. Defined BELOW the call sites: a line shifted above
+    them changes the kernel's serialized body (its call stack's lines) and
+    with it every compile-cache key of the IMPALA cells."""
+    return 9 * T * min(max(B, 128), 256) * 4 <= 16 * 2**20

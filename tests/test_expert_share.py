@@ -311,7 +311,11 @@ RULE = {"lfm2_decode": ((64, 4, 64, 16), "dense", "dense, 64 rows x 16 held"),
         "joyai_decode": ((16, 8, 256, 16), "sorted", "sorted, one slab of 128 pairs"),
         "lfm2_learner": ((4096, 4, 64, 16), "slabs", "sorted, 16384 pairs in slabs of 5120"),
         "qwen3_learner": ((4096, 10, 512, 32), "slabs", "sorted, 40960 pairs in slabs of 3584"),
-        "joyai_learner": ((4096, 8, 256, 16), "slabs", "sorted, 32768 pairs in slabs of 2560")}
+        "joyai_learner": ((4096, 8, 256, 16), "slabs", "sorted, 32768 pairs in slabs of 2560"),
+        # ISSUE 49: 48 pairs for 64 experts, under one pair an expert: sorted
+        "smallthinker_decode": ((8, 6, 64, 16), "sorted", "sorted, one slab of 48 pairs"),
+        "smallthinker_learner": ((8192, 6, 64, 16), "slabs",
+                                 "sorted, 49152 pairs in slabs of 15360")}
 
 
 @pytest.mark.parametrize("shape,form,said", RULE.values(), ids=RULE.keys())
@@ -524,3 +528,89 @@ def test_the_counters_are_the_same_in_both_forms(monkeypatch, count):
     if count is not None:
         assert int(dense["held_pairs"]) == count
     assert int(dense["dense_rows"]) == DN * DHELD and int(by_sort["dense_rows"]) == 0
+
+
+# -- the gate's activation (ISSUE 49): ReGLU beside SwiGLU --------------------------
+# The three forms at one shape each: the dense and the sorted one-slab form at
+# the dense tests' shape (the rule's and the rule answered "sorted"), the loop
+# over slabs at the module's (1,024 pairs in slabs of 512).
+
+def relu_by_experts(x, chosen, weight, wgu, wd, first_expert):
+    """`by_experts` with ReGLU, and the gate values that ReLU zeroed of the
+    pairs routed to the held experts."""
+    out, zeroed = jnp.zeros(x.shape, F32), 0
+    for e in range(wgu.shape[0]):
+        gate, up = jnp.split(x @ wgu[e], 2, -1)
+        pairs = jnp.sum(chosen == first_expert + e, -1)  # a router's sets: 0 or 1
+        w = jnp.sum(jnp.where(chosen == first_expert + e, weight, 0.0), -1)
+        out = out + w[:, None] * ((jax.nn.relu(gate) * up) @ wd[e])
+        zeroed = zeroed + jnp.sum(pairs[:, None] * (gate <= 0))
+    return out, zeroed
+
+
+def relu_case(form, monkeypatch):
+    """(layer, chosen, first, experts, the program's call in `form`)."""
+    if form == "slabs":
+        lay, first, experts = layer(30), 4, E
+        chosen = choices(SLAB + 77, seed=30)
+    else:
+        (lay, chosen), first, experts = dense_case(30), DFIRST, DE  # the router's sets
+    if form == "sorted":
+        monkeypatch.setattr(expert_share, "one_slab_form", lambda *_: "sorted")
+    n, top_k = chosen.shape
+    said = {"dense": "dense", "sorted": "one slab", "slabs": "in slabs of"}[form]
+    assert said in expert_share.call_form(n, top_k, lay["wgu"].shape[0], experts)
+    return lay, chosen, first, experts
+
+
+@pytest.mark.parametrize("form", ["dense", "sorted", "slabs"])
+def test_relu_experts_are_the_equation_in_every_form(monkeypatch, form):
+    """Value, all four gradients and the count of zeroed gate values of
+    `held_experts(activation="relu")` against a loop over the held experts
+    with `relu`, float32."""
+    lay, chosen, first, experts = relu_case(form, monkeypatch)
+
+    def loss(fn, x, router, wgu, wd):
+        out, aux = fn(x, chosen, weights({**lay, "x": x}, chosen)(router), wgu, wd)
+        return jnp.sum(out * jnp.cos(
+            jnp.arange(out.size, dtype=F32).reshape(out.shape))), aux
+
+    def program(*a):
+        out, counters = expert_share.held_experts(*a, first, experts, F32, "relu")
+        return out, counters["gate_zeroed"]
+
+    args = (lay["x"], lay["router"], lay["wgu"], lay["wd"])
+    graded = lambda fn: jax.value_and_grad(
+        functools.partial(loss, fn), argnums=(0, 1, 2, 3), has_aux=True)(*args)
+    with jax.default_matmul_precision("highest"):
+        (got, zeroed), grads = graded(program)
+        (want, by_hand), want_grads = graded(
+            lambda *a: relu_by_experts(*a, first))
+    assert abs(float(got) - float(want)) <= 1e-5 * max(1.0, abs(float(want)))
+    assert int(zeroed) == int(by_hand) > 0
+    for name, a, b in zip(("x", "router", "wgu", "wd"), grads, want_grads):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert rel(a, b) < 1e-5, (name, rel(a, b))
+
+
+@pytest.mark.parametrize("form", ["dense", "sorted", "slabs"])
+def test_silu_is_the_default_and_is_what_it_was(monkeypatch, form):
+    """`activation="silu"` and no argument are the same program: the same
+    jaxpr, no `gate_zeroed` counter, no op beside what was there; and ReGLU
+    is another result."""
+    lay, chosen, first, experts = relu_case(form, monkeypatch)
+    w = weights(lay, chosen)(lay["router"])
+    call = lambda *activation: expert_share.held_experts(
+        lay["x"], chosen, w, lay["wgu"], lay["wd"], first, experts, jnp.bfloat16,
+        *activation)
+    (default, counters), (named, _), (relu, relu_counters) = \
+        call(), call("silu"), call("relu")
+    np.testing.assert_array_equal(default, named)
+    assert "gate_zeroed" not in counters and "gate_zeroed" in relu_counters
+    assert set(relu_counters) - set(counters) == {"gate_zeroed"}
+    assert float(jnp.max(jnp.abs(relu - default))) > 1e-3
+    text = lambda *activation: str(jax.make_jaxpr(lambda: call(*activation)[0])())
+    assert text() == text("silu") and "logistic" in text()
+    assert "logistic" not in text("relu")
+    with pytest.raises(ValueError, match="unknown activation"):
+        call("gelu")

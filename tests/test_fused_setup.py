@@ -39,6 +39,7 @@ from test_joyai_flash import CFG as MLA_CFG
 from test_lfm2_moe import CFG as CONV_CFG
 from test_ouro_looplm import CFG as LOOP_CFG
 from test_qwen3_next import CFG as MOE_CFG
+from test_smallthinker_moe import CFG as SWA_CFG
 
 from distributed_reinforcement_learning_tpu.agents.convlm import ConvLMAgent
 from distributed_reinforcement_learning_tpu.agents.hybridlm import HybridLMAgent
@@ -46,6 +47,7 @@ from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent
 from distributed_reinforcement_learning_tpu.agents.looplm import LoopLMAgent
 from distributed_reinforcement_learning_tpu.agents.mlalm import MLALMAgent
 from distributed_reinforcement_learning_tpu.agents.moelm import MoELMAgent
+from distributed_reinforcement_learning_tpu.agents.swalm import SwaLMAgent
 from distributed_reinforcement_learning_tpu.envs import breakout_jax
 from distributed_reinforcement_learning_tpu.envs.token_recall_jax import TokenRecall
 from distributed_reinforcement_learning_tpu.runtime import anakin_tokens, launch
@@ -128,6 +130,8 @@ LOOPS = {
     "mlalm": (_tokens(MLALMAgent, MLA_CFG), 1, (1041, 46, 46), 2335),
     # read at PR 46's own tree: the family is new there
     "convlm": (_tokens(ConvLMAgent, CONV_CFG), 1, (765, 38, 38), 1908),
+    # read at PR 49's own tree: the family is new there
+    "swalm": (_tokens(SwaLMAgent, SWA_CFG), 1, (585, 30, 30), 1742),
 }
 
 
@@ -194,6 +198,11 @@ _SMALL = {
         intermediate_size=48, num_experts=4, router_width=16, first_expert=4,
         num_experts_per_tok=3, moe_intermediate_size=16, vocab_size=96,
         available_action=[96], trajectory=32),
+    "smallthinker_moe": dict(
+        hidden_size=32, num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        sliding_window_size=8, moe_num_primary_experts=4, router_width=16,
+        first_expert=4, moe_num_active_primary_experts=3, moe_ffn_hidden_size=16,
+        vocab_size=96, available_action=[96], trajectory=32),
 }
 
 

@@ -239,3 +239,120 @@ class TestKernelsUnderAMesh:
         for a, b in zip(g1, g0):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
         assert g1[0].sharding.spec == P("data")
+
+
+# -- a static sliding window in the flash kernels (ISSUE 49) ----------------------
+
+def _window_case(t=64, b=2, h=2, d=16, seed=0):
+    """q, k, v `[B, T, H, D]` and episode ids with ends inside blocks."""
+    r = np.random.RandomState(seed)
+    q, k, v = (jnp.asarray(r.normal(size=(b, t, h, d)), jnp.float32) for _ in range(3))
+    done = np.zeros((b, t), bool)
+    done[0, 21], done[1, 40] = True, True
+    seg = jnp.asarray(np.cumsum(done, axis=1) - done, jnp.int32)
+    return q, k, v, seg
+
+
+def _flash_bthd(q, k, v, seg, bq, bkv, window):
+    from distributed_reinforcement_learning_tpu.ops.pallas.attention import (
+        flash_attention_bhtd)
+
+    b, t, h, _ = q.shape
+    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, x.shape[-1])
+    out = flash_attention_bhtd(flat(q), flat(k), flat(v), jnp.repeat(seg, h, 0),
+                               jnp.repeat(seg, h, 0), block_q=bq, block_kv=bkv,
+                               interpret=True, window=window)
+    return out.reshape(b, h, t, v.shape[-1]).transpose(0, 2, 1, 3)
+
+
+# T 64. 16: a block's side, so a q block's window ends ON a kv block's edge for
+# its last row and cuts the block before it for every other; 24 and 33 cut
+# inside; 1: itself alone; 32: two blocks; 64 and 100: the whole row and more.
+@pytest.mark.parametrize("blocks", [(16, 16), (8, 32), (32, 16)])
+@pytest.mark.parametrize("window", [1, 7, 16, 24, 32, 33, 64, 100])
+def test_windowed_flash_kernels_match_dense(window, blocks):
+    """Interpret mode: the forward and all three gradients against
+    `dense_attention(window=W)`, with an episode end inside a block."""
+    from distributed_reinforcement_learning_tpu.ops.attention import dense_attention
+
+    q, k, v, seg = _window_case()
+    weigh = lambda out: jnp.sum(jnp.sin(out))
+    want = jax.value_and_grad(lambda *a: weigh(dense_attention(
+        *a, q_seg=seg, k_seg=seg, window=window)), (0, 1, 2))(q, k, v)
+    got = jax.value_and_grad(lambda *a: weigh(_flash_bthd(
+        *a, seg, *blocks, window)), (0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [None, 64, 1000])
+def test_without_a_window_the_kernels_are_what_they_were(window):
+    """`window=None` is the call without the argument, bit for bit, values
+    and gradients (the lowered text of the five token chunks is held to the
+    parent's by `scripts/chunk_text_digest.py`); a window that reaches the
+    whole row lets the same pairs through."""
+    from distributed_reinforcement_learning_tpu.ops.pallas.attention import (
+        flash_attention_bhtd)
+
+    q, k, v, seg = _window_case()
+    b, t, h, d = q.shape
+    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+    ids = jnp.repeat(seg, h, 0)
+
+    def run(**kw):
+        return jax.value_and_grad(lambda *a: jnp.sum(jnp.sin(flash_attention_bhtd(
+            *a, ids, ids, block_q=16, block_kv=16, interpret=True, **kw))),
+            (0, 1, 2))(flat(q), flat(k), flat(v))
+
+    for a, b in zip(jax.tree.leaves(run()), jax.tree.leaves(run(window=window))):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("bq,bkv", [(16, 16), (8, 32), (32, 16), (512, 512)])
+@pytest.mark.parametrize("window", [1, 7, 16, 24, 33, 4096])
+def test_the_walks_are_the_blocks_the_mask_lets_through(bq, bkv, window):
+    """`_kv_walk` / `_q_walk` against the mask itself: the kv blocks a q
+    block computes are exactly those with a visible pair (so a block wholly
+    outside the window is neither computed nor, the index maps being
+    clamped to the same bounds, fetched), and the same for the q blocks of a
+    kv block."""
+    from distributed_reinforcement_learning_tpu.ops.pallas import attention as fa
+
+    t = 16 * max(bq, bkv)
+    pos = np.arange(t)
+    seen = (pos[:, None] >= pos[None, :]) & (pos[:, None] - pos[None, :] < window)
+    by_block = seen.reshape(t // bq, bq, t // bkv, bkv).any(axis=(1, 3))
+    for iq in range(t // bq):
+        first, last = fa._kv_walk(jnp.int32(iq), bq, bkv, window)
+        assert list(np.flatnonzero(by_block[iq])) == list(range(int(first), int(last) + 1))
+    for jk in range(t // bkv):
+        first, last = fa._q_walk(jnp.int32(jk), bq, bkv, t // bq, window)
+        assert list(np.flatnonzero(by_block[:, jk])) == list(range(int(first), int(last) + 1))
+    assert fa._kv_walk(3, bq, bkv, None)[0] is None
+    assert fa._q_walk(3, bq, bkv, t // bq, None)[1] is None
+    # at the cell's shape a window layer computes 108 of a full layer's 136 blocks
+    if (bq, bkv, window) == (512, 512, 4096):
+        assert by_block.sum() == 108 and np.tril(np.ones((16, 16))).sum() == 136
+
+
+@pytest.mark.parametrize("window", [5, 16, 40])
+def test_every_path_of_causal_attention_takes_the_window(window):
+    """The dense path, the blockwise path and the kernels (interpret mode)
+    behind `causal_attention`'s one argument; a window under 1 is refused."""
+    from distributed_reinforcement_learning_tpu.ops.attention import (
+        blockwise_attention, causal_attention, dense_attention)
+
+    q, k, v, seg = _window_case(t=128, d=8)
+    want = dense_attention(q, k, v, q_seg=seg, k_seg=seg, window=window)
+    full = dense_attention(q, k, v, q_seg=seg, k_seg=seg)
+    assert float(jnp.max(jnp.abs(want - full))) > 1e-2
+    for backend in ("reference", "pallas_interpret"):
+        got = causal_attention(q, k, v, q_seg=seg, k_seg=seg, backend=backend,
+                               window=window)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+    got = blockwise_attention(q, k, v, block_size=32, segment_ids=seg,
+                              kv_segment_ids=seg, window=window)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    with pytest.raises(ValueError, match="sees itself"):
+        causal_attention(q, k, v, q_seg=seg, k_seg=seg, backend="pallas_interpret",
+                         window=0)

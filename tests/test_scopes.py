@@ -371,6 +371,72 @@ def test_every_equation_of_the_convolution_mixer_is_under_its_name():
     assert not _equations_under(jaxpr, "collect/act/gdn")
 
 
+def _swa_anakin(trajectory=16, row_block=2):
+    """The small SmallThinker loop: 4 rows of `trajectory` tokens, a window
+    of 8."""
+    import jax.numpy as jnp
+
+    from distributed_reinforcement_learning_tpu.agents.swalm import (
+        SwaLMAgent, SwaLMConfig)
+    from distributed_reinforcement_learning_tpu.envs.token_recall_jax import TokenRecall
+    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import AnakinTokens
+
+    cfg = SwaLMConfig(
+        vocab_size=64, hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=8, rope_theta=1e4, sliding_window_size=8, moe_num_primary_experts=4,
+        router_width=16, first_expert=4, moe_num_active_primary_experts=3,
+        moe_ffn_hidden_size=16, trajectory=trajectory, dtype=jnp.float32,
+        head_block=16, row_block=row_block)
+    return AnakinTokens(SwaLMAgent(cfg), 4, TokenRecall(64, trajectory))
+
+
+@pytest.fixture(scope="module")
+def swa_chunk_names():
+    anakin = _swa_anakin()
+    return _op_names(anakin.train_chunk, anakin.init(jax.random.PRNGKey(0)), 1)
+
+
+@pytest.mark.parametrize("name", scopes.SWA_CHUNK_SCOPES)
+def test_swa_chunk_carries_scope(swa_chunk_names, name):
+    """The names `perfbench/layer_metrics/swalm_*` read (ISSUE 49)."""
+    assert any(name in n for n in swa_chunk_names), name
+
+
+@pytest.mark.parametrize("name", [scopes.LAYERS, scopes.GLOBAL_ATTENTION,
+                                  scopes.WINDOW_ATTENTION, scopes.MOE_ROUTE,
+                                  scopes.MOE_EXPERTS])
+def test_swa_backward_stack_keeps_its_names(swa_chunk_names, name):
+    """The rematerialised blocks are entered again under the transpose."""
+    assert any(f"transpose(jvp({scopes.LOSS}))" in n and name in n
+               for n in swa_chunk_names)
+
+
+def test_the_two_kinds_of_layer_and_of_cache_have_names_of_their_own():
+    """The learner's window layers are `learn/loss/layers/window_attention`
+    and its global layer `.../global_attention` (q, k, v, rotary where
+    there is one, the core, W_o), never one inside the other, and the
+    router's product, ahead of attention, is under neither. At act time a
+    ring's write and read are `collect/act/ring` (three window layers a
+    decode body) and the full cache's `collect/act/cache` (one)."""
+    anakin = _swa_anakin()
+    state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+    jaxpr = jax.make_jaxpr(anakin.train_chunk, static_argnums=1)(state, 1).jaxpr
+    for own in (scopes.WINDOW_ATTENTION, scopes.GLOBAL_ATTENTION):
+        assert _equations_under(jaxpr, own).count("dot_general") >= 5  # q, kv, s, pv, o
+    assert scopes.WINDOW_ATTENTION not in scopes.GLOBAL_ATTENTION
+    assert scopes.GLOBAL_ATTENTION not in scopes.WINDOW_ATTENTION
+    route = _equations_under(jaxpr, scopes.MOE_ROUTE)
+    assert "dot_general" in route and "top_k" in route
+    bodies = len(anakin.decode_spans)
+    ring = _equations_under(jaxpr, scopes.ACT_RING)
+    cache = _equations_under(jaxpr, scopes.ACT_CACHE)
+    assert ring.count("dynamic_update_slice") == 2 * 3 * bodies  # k and v, three rings
+    assert cache.count("dynamic_update_slice") == 2 * 1 * bodies
+    assert "rem" in ring and "rem" not in cache  # t mod W is the ring's alone
+    assert not _equations_under(jaxpr, scopes.MOE_SHARED)
+    assert not _equations_under(jaxpr, scopes.ACT_CONV)
+
+
 def test_r2d2_backward_recurrence_keeps_the_unroll_name(r2d2_chunk_names):
     """The inner scope is entered again inside the transposed outer one:
     `transpose(jvp(learn/loss))/.../learn/loss/unroll/...`."""
@@ -517,8 +583,9 @@ def _loops_over_slabs(jaxpr, above=""):
 @pytest.mark.parametrize("make,experts", [
     (_moe_anakin, [scopes.MOE_EXPERTS]),
     (_mla_anakin, [scopes.MOE_EXPERTS, scopes.MLA_MTP["experts"]]),
-    (_conv_anakin, [scopes.MOE_EXPERTS])],
-    ids=["qwen3_next", "joyai_flash", "lfm2_moe"])
+    (_conv_anakin, [scopes.MOE_EXPERTS]),
+    (_swa_anakin, [scopes.MOE_EXPERTS])],
+    ids=["qwen3_next", "joyai_flash", "lfm2_moe", "smallthinker_moe"])
 def test_every_op_of_the_slab_loop_is_named_for_the_experts(make, experts):
     """4 rows x 64 tokens x 3 choices = 768 pairs in slabs of 512: the
     learner loops. Every equation of the loop, forward and in the loop's own

@@ -5,7 +5,7 @@ described, not opened (`/opt/skills/guides/on-chip-measurement`, section
 2). That catches what interpret mode cannot — a slice not aligned to the
 tiling, a kernel over its VMEM budget, a program Mosaic refuses — at the
 shapes the main path really runs (`config.json` sections `impala`,
-`apex`, `r2d2_pixel`, `r2d2_atari`, `ouro_looplm`, `granite_hybrid`, `qwen3_next`, `joyai_flash`, `lfm2_moe`; the Anakin chunk
+`apex`, `r2d2_pixel`, `r2d2_atari`, `ouro_looplm`, `granite_hybrid`, `qwen3_next`, `joyai_flash`, `lfm2_moe`, `smallthinker_moe`; the Anakin chunk
 `chip_smoke.py` drives), and
 costs no chip time. It also shows what the compiler DID with a program:
 which layout copies and which collectives it put in (the fused IMPALA
@@ -615,6 +615,73 @@ def test_lfm2_moe_chunk_fits_and_holds_its_six_kernels(chip, kernels_as_on_chip)
     assert not re.findall(r"bf16\[64,\d{3,4},32,64\]", text)  # no cache of the query heads
     # no array with the router's width AND a capacity beside the tokens
     assert not re.findall(r"\[4096,64,\d+\]|\[65536,64,\d+\]", text)
+
+
+@pytest.mark.slow  # minutes, in the file that ends tier-1's run: before a chip call
+def test_smallthinker_moe_chunk_fits_and_holds_its_eight_kernels(chip, kernels_as_on_chip):
+    """The fused token chunk at the `smallthinker_moe` section's sizes (8
+    envs x 8,192 tokens; one period of SmallThinker-21BA3B's order at 2560
+    wide: one global NoPE attention layer and three sliding-window rotary
+    layers of 4,096, a 64-way router over 16 held ReGLU experts in every
+    layer, an untied head; chunk of 1): it compiles for a described v5e and
+    the donated state (656.5 M parameters + their second moments, 8 B
+    each) is aliased whole. Eight Mosaic kernels in the LOWERED chunk, the
+    configuration file's count: flash attention in the TWO runs of layers
+    (the global run and the window run, each forward, rematerialised
+    forward, dq, dkv); V-trace's two views of 8,190 steps take the scan
+    (`ops/vtrace._kernel_fits`: the kernel unrolls T and asked for 35.8 MB
+    of 16 MB of scoped VMEM). The act-time state is one
+    full cache and three rings of 4,096 positions of the key/value heads:
+    no window layer holds 8,192 positions, none the 28 query heads."""
+    import json
+
+    from distributed_reinforcement_learning_tpu.agents.swalm import SwaLMAgent
+    from distributed_reinforcement_learning_tpu.envs.registry import (
+        make_jittable_env)
+    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import (
+        AnakinTokens)
+
+    cfg, rt = load_config(CONFIG, "smallthinker_moe")
+    env = make_jittable_env(rt.envs[0], vocab=cfg.vocab_size,
+                            episode_len=cfg.trajectory,
+                            distance=cfg.recall_distance)
+    anakin = AnakinTokens(SwaLMAgent(cfg), rt.num_actors * rt.envs_per_actor, env)
+    state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+    lowered = anakin.train_chunk.lower(_on(chip, state), 1)
+    with open(os.path.join(os.path.dirname(CONFIG), "perfbench", "configs",
+                           "smallthinker_moe.json")) as f:
+        named = json.load(f)["kernels"]["tpu_custom_call"]
+    assert len(re.findall("tpu_custom_call", lowered.as_text())) == named == 8
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    params = 656_532_481
+    assert mem.alias_size_in_bytes == mem.argument_size_in_bytes > 8 * params
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(f"smallthinker_moe chunk: {held / 1e9:.2f} GB held, arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.2f}, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.2f}")
+    assert held < 19.2e9, held
+    facts = anakin.static_facts
+    assert facts["layer_order"] == ("global", "window", "window", "window")
+    assert facts["kv_cache_bytes"] == 2 * 8 * 8192 * 4 * 128 * 2 == 134_217_728
+    assert facts["ring_bytes"] == 3 * 2 * 8 * 4096 * 4 * 128 * 2 == 3 * 67_108_864
+    assert (facts["experts_held"], facts["router_width"]) == (16, 64)
+    assert facts["decode_spans"] == tuple(range(1024, 8193, 1024))
+    text = compiled.as_text()
+    # the benchmark's reader of the kernels' share of their roofline tells the
+    # two kinds of layer apart by the Mosaic calls' op names
+    with open(os.path.join(os.path.dirname(CONFIG), "perfbench", "layer_metrics",
+                           "swa_flash_roofline.json")) as f:
+        pattern = re.compile(json.load(f)["source_detail"]["pattern"])
+    calls = [pattern.search(name) for name in re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]+)"', text)
+        if "pallas_call" in name]  # the compiler's own grouped products beside them
+    assert sorted(m.group(1) for m in calls) == 4 * ["global"] + 4 * ["window"]
+    assert re.findall(r"bf16\[8,4096,4,128\]", text)  # the rings, in the compute dtype
+    assert not re.findall(r"bf16\[8,\d{4},28,128\]", text)  # no cache of the query heads
+    # no array with the router's width AND a capacity beside the tokens
+    assert not re.findall(r"\[8192,64,\d+\]|\[65536,64,\d+\]", text)
 
 
 def _breakout_step_text(chip, n: int) -> str:
