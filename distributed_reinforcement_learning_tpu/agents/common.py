@@ -273,12 +273,13 @@ class SequenceReplayLearnMixin:
     """td_error/loss/learn shared by the sequence-replay agents.
 
     Host class provides `_sequence_td(params, target_params, batch,
-    unroll_scope=None)` -> (target_value, sav) — optionally with a third
-    scalar model aux loss (e.g. the MoE router's load-balancing term),
-    added to the TD loss as-is — and `self.tx`. `unroll_scope` is the
-    profile name for a sequential recurrence inside the forward, given
-    by the learn step only (a family without one ignores it). Loss =
-    IS-weighted mean over time of squared TD (`agent/r2d2.py:88-89`).
+    unroll_scope=None, online_q=None)` -> (target_value, sav) — optionally
+    with a third scalar model aux loss (e.g. the MoE router's
+    load-balancing term), added to the TD loss as-is — and `self.tx`.
+    `unroll_scope` is the profile name for a sequential recurrence inside
+    the forward, given by the learn step only (a family without one
+    ignores it); `online_q` is `_td_error`'s. Loss = IS-weighted mean
+    over time of squared TD (`agent/r2d2.py:88-89`).
 
     Priority: the reference's quirk |mean_t TD| (`agent/r2d2.py:151-153`
     — signed TDs cancel across the sequence, so a high-error sequence
@@ -297,8 +298,13 @@ class SequenceReplayLearnMixin:
         ad = jnp.abs(delta)
         return eta * jnp.max(ad, axis=1) + (1.0 - eta) * jnp.mean(ad, axis=1)
 
-    def _td_error(self, state, batch):
-        tv, sav = self._sequence_td(state.params, state.target_params, batch)[:2]
+    def _td_error(self, state, batch, online_q=None):
+        """Priority of each sequence of `batch`. `online_q` `[B, T, A]`:
+        the online net's Q-values over the batch where the caller holds
+        them (a fused loop whose actors ran `state.params` on these very
+        inputs); the forward then runs the target net alone."""
+        tv, sav = self._sequence_td(state.params, state.target_params, batch,
+                                    online_q=online_q)[:2]
         return self._seq_priority(tv, sav)
 
     @jax.named_scope(scopes.LOSS)

@@ -125,7 +125,9 @@ class R2D2Agent(common.SequenceReplayLearnMixin):
     # supplies the model forward. Burn-in, double-Q, and rescaling live
     # in `common.sequence_double_q_td` (`agent/r2d2.py:64-87`).
     def _sequence_td(self, params, target_params, batch: R2D2Batch,
-                     unroll_scope: str | None = None):
+                     unroll_scope: str | None = None, online_q=None):
+        """`online_q`: `unroll(params)` where the caller already holds it
+        (`runtime/anakin_r2d2.py`: acting's Q-values of the collect scan)."""
         cfg = self.cfg
         obs = self._prep_obs(batch.state)
         unroll = lambda p: self.model.apply(
@@ -133,6 +135,7 @@ class R2D2Agent(common.SequenceReplayLearnMixin):
             unroll_scope, method=self.model.unroll)
         discounts = (~batch.done).astype(jnp.float32) * cfg.discount_factor
         return common.sequence_double_q_td(
-            unroll(params), unroll(target_params), batch.action, batch.reward,
+            unroll(params) if online_q is None else online_q,
+            unroll(target_params), batch.action, batch.reward,
             discounts, burn_in=cfg.burn_in, rescale_eps=cfg.rescale_eps,
             n_step=cfg.n_step)

@@ -124,7 +124,10 @@ class R2D2Net(nn.Module):
 
         `scope`: a `jax.named_scope` around the recurrence alone; the
         learn step names it (observability/scopes.py UNROLL), the
-        scoring of new sequences does not.
+        scoring of new sequences does not. The fused loop scores with
+        the TARGET net's unroll only: the online net's values over a new
+        sequence are `step`'s, one env step at a time, under the same
+        parameters, start state and resets (`runtime/anakin_r2d2.py`).
         """
         B, T = obs_seq.shape[:2]
         x = self._torso(obs_seq.reshape((B * T,) + obs_seq.shape[2:]))

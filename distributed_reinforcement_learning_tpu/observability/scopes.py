@@ -51,7 +51,9 @@ RECORD = "collect/record"  # the per-step record + carry of the rollout
 # only. The one-chip chunk learns time-major (PR 29): no op has this name.
 TO_BATCH_MAJOR = "to_batch_major"
 REPLAY = "replay"  # device ring: ingest, sample, priority write-back
-REPLAY_SCORE = "replay/score"  # TD error of the new sequences, both nets
+# TD error of the new sequences: the target net's unroll alone. The online
+# net's Q-values are `collect/act`'s own, stacked by the collect scan.
+REPLAY_SCORE = "replay/score"
 REPLAY_WRITE = "replay/write"  # the ring write at `ptr`
 REPLAY_SAMPLE = "replay/sample"  # cumsum, stratified search, batch gather
 REPLAY_PRIORITIES = "replay/priorities"  # write-back of sampled priorities

@@ -15,8 +15,11 @@ Replay semantics mirror `data/replay.py` (itself the re-design of
 - priority `(|err| + 0.001) ** 0.6`, stratified sampling over `total/n`
   segments, IS weights `(N * p) ** -beta` batch-max-normalized, beta
   annealed 0.4 -> 1.0 by 0.001 per sample;
-- new sequences scored with `agent.td_error` under the current params
-  (what the host learner does at ingest, `runtime/r2d2_runner.py:274`);
+- new sequences scored with `agent._td_error` under the current params
+  (what the host learner does at ingest, `runtime/r2d2_runner.py:274`).
+  The online net's Q-values are the ones acting computed in the collect
+  scan (same params, inputs, start state and resets, and no optimizer
+  step between), so the scoring pass unrolls the target net alone;
 - every sampled index's priority updated after the step (the
   `update_batch` fix of `train_r2d2.py:159`).
 
@@ -180,7 +183,7 @@ class AnakinR2D2(DataMeshReplayMixin):
         env, obs, prev_action, h, c, episodes, rng = carry
         rng, k_act, k_env = jax.random.split(rng, 3)
         with jax.named_scope(scopes.ACT):
-            action, _q, new_h, new_c = self.agent._act(
+            action, q, new_h, new_c = self.agent._act(
                 params, obs, h, c, prev_action, self._epsilon(episodes), k_act)
         with jax.named_scope(scopes.ENV):
             env_action = (action % self.env.NUM_ACTIONS
@@ -193,7 +196,7 @@ class AnakinR2D2(DataMeshReplayMixin):
                               lambda done, _state: done)
             record = dict(
                 state=obs, previous_action=prev_action, action=action,
-                reward=reward, done=done, episode_return=ep_ret,
+                reward=reward, done=done, online_q=q, episode_return=ep_ret,
                 episode_completed=mask_fn(done, env),
             )
             keep = (~done).astype(new_h.dtype)[:, None]
@@ -205,7 +208,11 @@ class AnakinR2D2(DataMeshReplayMixin):
 
     def _collect(self, state: AnakinR2D2State):
         """One seq_len unroll from all envs -> (state', R2D2Batch [B, T],
-        episode stats)."""
+        acting's Q-values f32[B, T, A], episode stats).
+
+        The Q-values are what `R2D2Net.unroll` gives over the batch under
+        `state.train.params`: they score the sequences and are not stored.
+        """
         cfg = self.agent.cfg
         h0, c0 = state.h, state.c  # sequence-start stored state
         carry = (state.env, state.obs, state.prev_action, state.h, state.c,
@@ -221,6 +228,7 @@ class AnakinR2D2(DataMeshReplayMixin):
                 action=bt("action"), reward=bt("reward"), done=bt("done"),
                 initial_h=h0, initial_c=c0,
             )
+            online_q = bt("online_q")
         stats = {
             "episode_return_sum": rec["episode_return"].sum(),
             "episodes_done": rec["episode_completed"].sum().astype(jnp.float32),
@@ -228,13 +236,13 @@ class AnakinR2D2(DataMeshReplayMixin):
         }
         new_state = state._replace(env=env, obs=obs, prev_action=prev_action,
                                    h=h, c=c, episodes=episodes, rng=rng)
-        return new_state, batch, stats
+        return new_state, batch, online_q, stats
 
-    def _ingest(self, train, replay: DeviceReplay, batch: R2D2Batch
-                ) -> DeviceReplay:
+    def _ingest(self, train, replay: DeviceReplay, batch: R2D2Batch,
+                online_q: jax.Array) -> DeviceReplay:
         """Score + write B new sequences into the ring at `ptr`."""
         with jax.named_scope(scopes.REPLAY_SCORE):
-            errs = self.agent._td_error(train, batch)  # [B]
+            errs = self.agent._td_error(train, batch, online_q)  # [B]
         with jax.named_scope(scopes.REPLAY_WRITE):
             return device_replay.ingest(replay, batch, errs)
 
@@ -245,8 +253,8 @@ class AnakinR2D2(DataMeshReplayMixin):
 
     # -- one update: collect, ingest, K prioritized steps ----------------
     def _update(self, state: AnakinR2D2State, _):
-        state, seqs, stats = self._collect(state)
-        replay = self._ingest(state.train, state.replay, seqs)
+        state, seqs, online_q, stats = self._collect(state)
+        replay = self._ingest(state.train, state.replay, seqs, online_q)
         train = state.train
 
         def one_learn(carry, _):
@@ -292,8 +300,8 @@ class AnakinR2D2(DataMeshReplayMixin):
         return jax.lax.scan(self._update, state, None, length=num_updates)
 
     def _collect_only(self, state: AnakinR2D2State, _):
-        state, seqs, stats = self._collect(state)
-        replay = self._ingest(state.train, state.replay, seqs)
+        state, seqs, online_q, stats = self._collect(state)
+        replay = self._ingest(state.train, state.replay, seqs, online_q)
         return state._replace(replay=replay), self._psum(stats)
 
     def _collect_chunk(self, state: AnakinR2D2State, num_collects: int):

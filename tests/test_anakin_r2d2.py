@@ -8,6 +8,7 @@ per-episode epsilon decay — expressed as a device-resident ring.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from distributed_reinforcement_learning_tpu.agents.r2d2 import R2D2Agent, R2D2Config
 from distributed_reinforcement_learning_tpu.envs.cartpole import pomdp_project
@@ -164,3 +165,74 @@ class TestPixelR2D2:
         assert np.isfinite(np.asarray(m["loss"])).all()
         ev = an.greedy_eval(st.train.params, 2, 8, jax.random.PRNGKey(1))
         assert "mean_return" in ev
+
+
+def _pixel_anakin(priority_eta):
+    """The pixel cell's shape of agent (Nature torso, dueling streams,
+    5-step targets) at 4 envs; env 0's ball is put on its way out of the
+    field, so a life is lost, and `done` set, inside the next sequence."""
+    from distributed_reinforcement_learning_tpu.envs import breakout_jax
+
+    cfg = R2D2Config(obs_shape=(84, 84, 4), num_actions=4, seq_len=12,
+                     burn_in=4, n_step=5, lstm_size=16, torso="nature",
+                     dueling_hidden=32, priority_eta=priority_eta)
+    an = AnakinR2D2(R2D2Agent(cfg), num_envs=4, capacity=8, batch_size=2,
+                    env=breakout_jax)
+
+    def lose_a_ball(env):
+        first = lambda leaf, v: leaf.at[0].set(v)
+        return env._replace(
+            ball_dead=first(env.ball_dead, False),
+            paddle_x=first(env.paddle_x, 136.0),
+            ball_x=first(env.ball_x, 12.0), vx=first(env.vx, 0.0),
+            ball_y=first(env.ball_y, 150.0), vy=first(env.vy, 3.0))
+
+    return an, lose_a_ball
+
+
+def _mlp_anakin(priority_eta):
+    """The reference's CartPole net; a random policy (epsilon 1 before
+    the first episode ends) drops the pole inside 24 steps."""
+    cfg = R2D2Config(obs_shape=(2,), num_actions=2, seq_len=12, burn_in=4,
+                     n_step=5, lstm_size=16, priority_eta=priority_eta)
+    an = AnakinR2D2(R2D2Agent(cfg), num_envs=4, capacity=8, batch_size=2,
+                    obs_transform=pomdp_project)
+    return an, lambda env: env
+
+
+@pytest.mark.parametrize("priority_eta", [None, 0.9], ids=["ref", "eta0.9"])
+@pytest.mark.parametrize("build", [_pixel_anakin, _mlp_anakin],
+                         ids=["nature", "mlp"])
+def test_acting_q_scores_the_new_sequences(build, priority_eta):
+    """The collect scan's Q-values ARE the online net's unroll over the
+    batch it records (same params, inputs, stored start state and
+    resets), so `_ingest` may hand them to `_td_error` and unroll the
+    target net alone (ISSUE 50)."""
+    an, before_second = build(priority_eta)
+    agent = an.agent
+    st = an.init(jax.random.PRNGKey(3))
+    # A target net of its own: equal nets would hide a mixed-up argument.
+    other = agent.init_state(jax.random.PRNGKey(4)).params
+    st = st._replace(train=st.train.replace(target_params=other))
+    collect = jax.jit(an._collect)
+    st = collect(st)[0]
+    st = st._replace(env=before_second(st.env))
+    st, batch, online_q, _ = collect(st)
+    assert float(jnp.abs(batch.initial_h).max()) > 0  # stored state, not zeros
+    done = np.asarray(batch.done)
+    assert done[:, :-1].any(), "no reset inside a sequence"
+
+    unrolled = agent.model.apply(
+        st.train.params, agent._prep_obs(batch.state), batch.previous_action,
+        batch.done, batch.initial_h, batch.initial_c,
+        method=agent.model.unroll)
+    assert online_q.shape == unrolled.shape == (4, 12, agent.cfg.num_actions)
+    scale = float(jnp.abs(unrolled).max())
+    np.testing.assert_allclose(online_q, unrolled, rtol=0, atol=1e-5 * scale)
+
+    both = np.asarray(agent.td_error(st.train, batch))
+    np.testing.assert_allclose(agent.td_error(st.train, batch, online_q),
+                               both, rtol=1e-5, atol=1e-5 * both.max())
+    # And the online values are read: another net's give another score.
+    assert not np.allclose(agent.td_error(st.train, batch, online_q[::-1]),
+                           both, rtol=1e-3)

@@ -332,17 +332,18 @@ def test_conv_backward_stack_keeps_its_names(conv_chunk_names, name):
                for n in conv_chunk_names)
 
 
-def _equations_under(jaxpr, scope, above="", found=None):
+def _equations_under(jaxpr, scope, above="", found=None, loops=False):
     """The primitive of every equation whose composed name stack holds
-    `scope`, through every jaxpr inside."""
+    `scope`, through every jaxpr inside; with `loops`, the equations that
+    hold a jaxpr themselves (`scan`, `pjit`, ...) as well."""
     found = [] if found is None else found
     for eqn in jaxpr.eqns:
         stack = f"{above}/{eqn.source_info.name_stack}"
         subs = list(jax.core.jaxprs_in_params(eqn.params))
-        if scope in stack and not subs:
+        if scope in stack and (loops or not subs):
             found.append(eqn.primitive.name)
         for sub in subs:
-            _equations_under(sub, scope, stack, found)
+            _equations_under(sub, scope, stack, found, loops)
     return found
 
 
@@ -449,6 +450,70 @@ def test_r2d2_scoring_unroll_is_not_named_for_the_learn_step(r2d2_chunk_names):
     learn step's recurrence is `learn/loss/unroll`."""
     scoring = [n for n in r2d2_chunk_names if scopes.REPLAY_SCORE in n]
     assert scoring and not any(scopes.LEARN in n for n in scoring)
+
+
+def _pixel_r2d2():
+    """The pixel cell's shape of agent at 2 envs: Nature torso (three
+    convolutions), dueling streams, 5-step targets."""
+    from distributed_reinforcement_learning_tpu.agents.r2d2 import (
+        R2D2Agent, R2D2Config)
+    from distributed_reinforcement_learning_tpu.envs import breakout_jax
+    from distributed_reinforcement_learning_tpu.runtime.anakin_r2d2 import AnakinR2D2
+
+    cfg = R2D2Config(obs_shape=(84, 84, 4), num_actions=4, seq_len=6, burn_in=2,
+                     n_step=5, lstm_size=16, torso="nature", dueling_hidden=32)
+    return AnakinR2D2(R2D2Agent(cfg), num_envs=2, capacity=4, batch_size=2,
+                      env=breakout_jax)
+
+
+@pytest.mark.parametrize("chunk", ["train_chunk", "collect_chunk"])
+def test_r2d2_scoring_pass_unrolls_one_net(chunk):
+    """Under `replay/score` runs the target net alone: one torso and one
+    recurrence. The online net's Q-values come out of the collect scan
+    (ISSUE 50); with both nets there the counts are 6 and 2. Static, so
+    every update of the training chunk and of the warm-up takes the path."""
+    anakin = _pixel_r2d2()
+    state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+    jaxpr = jax.make_jaxpr(getattr(anakin, chunk), static_argnums=1)(state, 1).jaxpr
+    scoring = _equations_under(jaxpr, scopes.REPLAY_SCORE, loops=True)
+    assert scoring.count("conv_general_dilated") == 3
+    assert scoring.count("scan") == 1
+    acting = _equations_under(jaxpr, scopes.ACT)
+    assert acting.count("conv_general_dilated") == 3
+
+
+def test_r2d2_learn_step_still_unrolls_both_nets():
+    """`_sequence_td` without `online_q` is the program it was: `_loss`
+    lowers to the text of the forward written out here with both unrolls."""
+    import jax.numpy as jnp
+
+    from distributed_reinforcement_learning_tpu.agents import common
+
+    anakin = _pixel_r2d2()
+    agent, cfg = anakin.agent, anakin.agent.cfg
+    train = jax.eval_shape(agent.init_state, jax.random.PRNGKey(0))
+    entries = anakin._sequence_entries()
+    batch = jax.tree.map(
+        lambda e: jax.ShapeDtypeStruct((2, *e.shape), e.dtype), entries)
+    weight = jax.ShapeDtypeStruct((2,), jnp.float32)
+
+    def _loss(params, target_params, batch, is_weight):
+        unroll = lambda p: agent.model.apply(
+            p, agent._prep_obs(batch.state), batch.previous_action, batch.done,
+            batch.initial_h, batch.initial_c, scopes.UNROLL,
+            method=agent.model.unroll)
+        discounts = (~batch.done).astype(jnp.float32) * cfg.discount_factor
+        tv, sav = common.sequence_double_q_td(
+            unroll(params), unroll(target_params), batch.action, batch.reward,
+            discounts, burn_in=cfg.burn_in, rescale_eps=cfg.rescale_eps,
+            n_step=cfg.n_step)
+        per_seq = jnp.mean(jnp.square(tv - sav), axis=1)
+        return jnp.mean(per_seq * is_weight) + 0.0, agent._seq_priority(tv, sav)
+
+    args = (train.params, train.target_params, batch, weight)
+    ours = jax.jit(agent._loss).lower(*args).as_text()
+    assert ours.count("stablehlo.convolution") == 6
+    assert ours == jax.jit(_loss).lower(*args).as_text()
 
 
 def _host_events(profile_dir: str) -> list[str]:
