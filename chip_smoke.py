@@ -32,8 +32,9 @@ topology whose learner builds that mesh by itself.
 A chip belongs to one process at a time, so this parent never imports
 JAX: every phase is a child process, one after the other, and the
 device in the last line is what the children reported. Each phase
-prints one JSON line (seconds, set-up = trace+lower+compile seconds
-apart from run seconds, loss, kernels in the compiled learn step); the
+prints one JSON line (seconds, set-up = the wall seconds of JAX's trace,
+lower and compile events, as the program's own record has them, apart
+from run seconds, loss, kernels in the compiled learn step); the
 full output of each phase goes to `chiprun_out/chip_smoke/<phase>.log`.
 The LAST line of stdout is
 
@@ -60,7 +61,6 @@ import signal
 import subprocess
 import sys
 import tempfile
-import threading
 import time
 
 REPO = os.path.dirname(os.path.abspath(__file__))
@@ -98,38 +98,6 @@ def _check(ok: bool, what: str) -> None:
 
 
 # ---------------------------------------------------------------- children
-
-
-class _CompileClock:
-    """Seconds JAX spent tracing, lowering and compiling (or fetching
-    from the persistent cache) in this process, from JAX's own
-    monitoring events — the set-up share of a phase."""
-
-    _DURATIONS = ("/jax/core/compile/jaxpr_trace_duration",
-                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                  "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        import jax
-
-        self._lock = threading.Lock()  # compiles happen on worker threads too
-        self.seconds = 0.0
-        self.cache_hits = 0
-        self.cache_misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event: str, seconds: float, **_) -> None:
-        if event in self._DURATIONS:
-            with self._lock:
-                self.seconds += seconds
-
-    def _on_event(self, event: str, **_) -> None:
-        with self._lock:
-            if event == "/jax/compilation_cache/cache_hits":
-                self.cache_hits += 1
-            elif event == "/jax/compilation_cache/cache_misses":
-                self.cache_misses += 1
 
 
 def _compiled_text(jitted, *args, static: tuple = ()) -> str:
@@ -345,17 +313,18 @@ _CHILD_PHASES = {
 
 
 def _child_main(args) -> int:
+    from distributed_reinforcement_learning_tpu.observability.trace import (
+        HOST_RECORD)
     from distributed_reinforcement_learning_tpu.utils.device import (
         enable_compile_cache, open_devices)
 
     cache_dir = enable_compile_cache()
-    device = open_devices("chip_smoke")
+    device = open_devices("chip_smoke")  # the program's record listens from here
     if device["platform"] != args.expect_platform:
         print(f"[chip_smoke] JAX found {device}, not a "
               f"{args.expect_platform} device: nothing is run",
               file=sys.stderr)
         return EXIT_WRONG_PLATFORM
-    clock = _CompileClock()
     t0 = time.perf_counter()
     try:
         out = _CHILD_PHASES[args.phase](args)
@@ -363,11 +332,15 @@ def _child_main(args) -> int:
         print(f"[chip_smoke] {args.phase} FAILED: {e}", file=sys.stderr)
         return 1
     seconds = time.perf_counter() - t0
+    # Wall seconds JAX spent tracing, lowering and compiling (or reading
+    # the persistent cache) in this process: the union of its events, from
+    # the program's own record (observability/trace.py).
+    setup = HOST_RECORD.seconds()["any"]
     print(json.dumps({
         "phase": args.phase, "ok": True, **out, "device": device,
-        "setup_s": round(clock.seconds, 2),
-        "run_s": round(seconds - clock.seconds, 2),
-        "cache_hits": clock.cache_hits, "cache_misses": clock.cache_misses,
+        "setup_s": round(setup, 2), "run_s": round(seconds - setup, 2),
+        "cache_hits": HOST_RECORD.cache["hits"],
+        "cache_misses": HOST_RECORD.cache["misses"],
         "cache_dir": cache_dir}), flush=True)
     return 0
 

@@ -39,7 +39,10 @@ import threading
 import time
 from typing import Any, Callable
 
-from distributed_reinforcement_learning_tpu.observability.trace import TraceEmitter
+from distributed_reinforcement_learning_tpu.observability.trace import (
+    HOST_RECORD,
+    TraceEmitter,
+)
 from distributed_reinforcement_learning_tpu.utils.environ import env_flag, env_float
 
 # Weight-staleness histogram edges — the single source of truth for the
@@ -325,5 +328,12 @@ def maybe_configure(role: str, rank: int = 0, run_dir: str | None = None) -> boo
     out = telemetry_dir(run_dir)
     if out is None:
         return False
+    fresh = not TELEMETRY.enabled
     TELEMETRY.configure(out, role, rank)
+    if fresh and TELEMETRY.trace is not None:
+        # The spans this process made before it knew its role (its import,
+        # a backend opened earlier): the Chrome trace begins where the
+        # process did.
+        for name, _parent, wall, duration in list(HOST_RECORD.spans):
+            TELEMETRY.trace.emit(name, wall, duration)
     return True

@@ -7,6 +7,9 @@ back per op. Host spans go through `observability.trace.chip_span`
 around the fused loops' chunks. Nothing else in the program spells
 these strings; a reader of a profile (docs/performance.md
 "Observability", the benchmark's per-layer metrics) matches on them.
+The same call feeds `observability.trace.HOST_RECORD`, the process's own
+record of its host spans on the wall clock, which the fused launchers
+print in their log: the start's spans at the end of this file are its.
 
 A nested scope is written out in full (`collect/env/render`, not
 `render`): a `lax.scan` or a `jax.jit` in between puts `while/body` or
@@ -171,3 +174,14 @@ DISPATCH = "anakin/dispatch"  # the train_chunk call
 WAIT = "anakin/wait"  # first blocking read of the chunk's metrics
 REPORT = "anakin/report"  # host sums, gauges, the log line
 CHECKPOINT = "anakin/checkpoint"
+
+# -- host spans of the start (utils/device.py, runtime/launch.py) ----------
+# Each opened where the work happens, on the wall clock, into
+# `observability.trace.HOST_RECORD`; the chunk spans above follow them.
+START_IMPORT = "start/import"  # the kernel's start of the process -> launch.py imported
+START_BACKEND = "start/backend"  # `open_devices`: the first call opens the backend
+START_BUILD = "start/build"  # config, agent, env, the `Anakin*` constructor
+START_INIT = "start/init"  # `anakin.init`: parameters and the eager `reset`
+START_RESTORE = "start/restore"  # `_restore_train`
+START_WARM_COLLECT = "start/warm_collect"  # the replay loops' `collect_chunk(state, warm)`
+CHUNK_SPANS = (STEP_READ, DISPATCH, WAIT, REPORT, CHECKPOINT)

@@ -33,6 +33,9 @@ from distributed_reinforcement_learning_tpu.observability import (
     maybe_configure,
     scopes,
 )
+from distributed_reinforcement_learning_tpu.observability.trace import (
+    HOST_RECORD as _HOST,
+)
 from distributed_reinforcement_learning_tpu.runtime import (
     apex_runner,
     impala_runner,
@@ -300,13 +303,17 @@ def _read_step(state) -> int:
         return int(state.train.step)
 
 
-def _run_chunk(anakin, state, u: int, steps_per_update: int, log, ckpt):
+def _run_chunk(anakin, state, u: int, steps_per_update: int, log, ckpt,
+               label: str):
     """One chunk of a fused loop's host side under its `chip_span`s
     (observability/scopes.py): dispatch `u` chunk updates of
     `steps_per_update` optimizer steps each, wait, report
     (`log(step, mean_return, episodes, metrics)` is the chunk's line),
-    checkpoint. Per chunk and not the whole loop, so that no frame keeps
-    an earlier state's device buffers alive. -> (state, mean return)."""
+    checkpoint; then the line of the chunk's spans with their wall start
+    (`[<label>] chunk <n>: ...`) and, after the first chunk during which
+    nothing compiled, the start's line (`observability.trace.HostRecord`).
+    Per chunk and not the whole loop, so that no frame keeps an earlier
+    state's device buffers alive. -> (state, mean return)."""
     import numpy as np
 
     trace = _OBS.trace
@@ -329,7 +336,16 @@ def _run_chunk(anakin, state, u: int, steps_per_update: int, log, ckpt):
     if ckpt is not None:
         with chip_span(scopes.CHECKPOINT, trace):
             ckpt.save(step, state.train, {})
+    print("\n".join(_HOST.end_chunk(label)))
     return state, mean_ret
+
+
+def _print_unclosed_start(label: str) -> None:
+    """A loop that ends before a chunk closed its start (a short run, a
+    failure in the first chunk) still prints where the start went."""
+    line = _HOST.close_start(label)
+    if line is not None:
+        print(line)
 
 
 def train_anakin(config_path: str, section: str, num_updates: int,
@@ -343,22 +359,25 @@ def train_anakin(config_path: str, section: str, num_updates: int,
     saves/restores the TrainState per chunk (env/LSTM state is
     ephemeral: a resume starts fresh episodes, same as every
     actor restart in the distributed topology)."""
-    open_devices("anakin")
-    agent_cfg, rt = load_config(config_path, section)
-    if _algo_of(agent_cfg) != "impala":
-        raise ValueError("anakin mode currently runs the IMPALA family")
-    from distributed_reinforcement_learning_tpu.runtime.anakin import AnakinImpala
-
-    env_mod, _ = _jittable_env_for(agent_cfg, rt)
-    agent = ImpalaAgent(agent_cfg)
-    anakin = AnakinImpala(agent, num_envs or rt.num_actors * rt.envs_per_actor,
-                          env=env_mod)
-    print(f"[anakin] learn handoff: {anakin.handoff}")  # static, as compiled
-    state = anakin.init(jax.random.PRNGKey(seed))
-    ckpt, train = _restore_train(checkpoint_dir, state.train)
-    state = state._replace(train=train)
-    chunk = max(1, min(chunk, num_updates))
     maybe_configure("anakin", 0, run_dir)  # env-gated run-wide telemetry
+    open_devices("anakin")
+    with chip_span(scopes.START_BUILD, _OBS.trace):
+        agent_cfg, rt = load_config(config_path, section)
+        if _algo_of(agent_cfg) != "impala":
+            raise ValueError("anakin mode currently runs the IMPALA family")
+        from distributed_reinforcement_learning_tpu.runtime.anakin import AnakinImpala
+
+        env_mod, _ = _jittable_env_for(agent_cfg, rt)
+        agent = ImpalaAgent(agent_cfg)
+        anakin = AnakinImpala(
+            agent, num_envs or rt.num_actors * rt.envs_per_actor, env=env_mod)
+    print(f"[anakin] learn handoff: {anakin.handoff}")  # static, as compiled
+    with chip_span(scopes.START_INIT, _OBS.trace):
+        state = anakin.init(jax.random.PRNGKey(seed))
+    with chip_span(scopes.START_RESTORE, _OBS.trace):
+        ckpt, train = _restore_train(checkpoint_dir, state.train)
+        state = state._replace(train=train)
+    chunk = max(1, min(chunk, num_updates))
     last = {"loss": None}
 
     def log(step: int, mean_ret: float, eps: float, m) -> str:
@@ -372,10 +391,12 @@ def train_anakin(config_path: str, section: str, num_updates: int,
         while (step := _read_step(state)) < num_updates:
             profiler.on_step(step)
             state, mean_ret = _run_chunk(
-                anakin, state, min(chunk, num_updates - step), 1, log, ckpt)
+                anakin, state, min(chunk, num_updates - step), 1, log, ckpt,
+                "anakin")
             returns.append(mean_ret)
     finally:
         profiler.close()
+        _print_unclosed_start("anakin")
     return {
         "frames": int(state.train.step) * anakin.num_envs * agent_cfg.trajectory,
         "last_loss": last["loss"],
@@ -385,8 +406,7 @@ def train_anakin(config_path: str, section: str, num_updates: int,
 
 
 def _replay_chunk_loop(anakin, state, num_updates: int, chunk: int, ckpt,
-                       label: str, frames_per_collect: int, warm: int,
-                       run_dir: str | None = None) -> dict:
+                       label: str, frames_per_collect: int, warm: int) -> dict:
     """Shared warm-up + chunked train loop for the on-device replay
     families (AnakinR2D2 / AnakinApex — same train_chunk/metrics
     contract). `num_updates` counts OPTIMIZER steps; each chunk update is
@@ -394,9 +414,9 @@ def _replay_chunk_loop(anakin, state, num_updates: int, chunk: int, ckpt,
     and the frame count are in collect-updates and the final chunk may
     overshoot by up to K-1 optimizer steps."""
     print(f"[{label}] {device_replay.describe_storage(state.replay)}")
-    state, _ = anakin.collect_chunk(state, warm)
+    with chip_span(scopes.START_WARM_COLLECT, _OBS.trace):
+        state, _ = anakin.collect_chunk(state, warm)
     K = anakin.updates_per_collect
-    maybe_configure(label, 0, run_dir)  # env-gated run-wide telemetry
 
     def log(step: int, mean_ret: float, eps: float, m) -> str:
         return (f"[{label}] step {step}: mean_return {mean_ret:.1f} "
@@ -410,11 +430,12 @@ def _replay_chunk_loop(anakin, state, num_updates: int, chunk: int, ckpt,
         while (step := _read_step(state)) < num_updates:
             profiler.on_step(step)
             u = max(1, min(chunk, -(-(num_updates - step) // K)))
-            state, mean_ret = _run_chunk(anakin, state, u, K, log, ckpt)
+            state, mean_ret = _run_chunk(anakin, state, u, K, log, ckpt, label)
             collects += u
             returns.append(mean_ret)
     finally:
         profiler.close()
+        _print_unclosed_start(label)
     return {
         "frames": collects * frames_per_collect,
         "chunk_mean_returns": [round(r, 2) for r in returns],
@@ -437,32 +458,36 @@ def train_anakin_apex(config_path: str, section: str, num_updates: int,
     each pixel transition stores TWO 84x84x4 uint8 stacks (s and s',
     ~56 KB), so the default ring costs ~1.8 GB of device memory; the
     host topology's 100k default would triple that."""
+    maybe_configure("anakin-apex", 0, run_dir)  # env-gated run-wide telemetry
     open_devices("anakin-apex")
-    agent_cfg, rt = load_config(config_path, section)
-    if _algo_of(agent_cfg) != "apex":
-        raise ValueError("anakin-apex mode runs the Ape-X family")
-    from distributed_reinforcement_learning_tpu.runtime.anakin_apex import AnakinApex
+    with chip_span(scopes.START_BUILD, _OBS.trace):
+        agent_cfg, rt = load_config(config_path, section)
+        if _algo_of(agent_cfg) != "apex":
+            raise ValueError("anakin-apex mode runs the Ape-X family")
+        from distributed_reinforcement_learning_tpu.runtime.anakin_apex import AnakinApex
 
-    env_mod, obs_transform = _jittable_env_for(agent_cfg, rt)
-    agent = ApexAgent(agent_cfg)
-    n = num_envs or rt.num_actors * rt.envs_per_actor
-    steps = 16
-    width = n * steps
-    cap = capacity or min(rt.replay_capacity, 32768)
-    cap = max(width, cap - cap % width)  # ring writes stay width-aligned
-    anakin = AnakinApex(
-        agent, num_envs=n, batch_size=rt.batch_size, capacity=cap,
-        steps_per_collect=steps,
-        target_sync_interval=rt.target_sync_interval,
-        updates_per_collect=rt.updates_per_call,
-        epsilon_floor=rt.epsilon_floor or 0.0,
-        env=env_mod, obs_transform=obs_transform)
-    state = anakin.init(jax.random.PRNGKey(seed))
-    ckpt, train = _restore_train(checkpoint_dir, state.train)
-    state = state._replace(train=train)
+        env_mod, obs_transform = _jittable_env_for(agent_cfg, rt)
+        agent = ApexAgent(agent_cfg)
+        n = num_envs or rt.num_actors * rt.envs_per_actor
+        steps = 16
+        width = n * steps
+        cap = capacity or min(rt.replay_capacity, 32768)
+        cap = max(width, cap - cap % width)  # ring writes stay width-aligned
+        anakin = AnakinApex(
+            agent, num_envs=n, batch_size=rt.batch_size, capacity=cap,
+            steps_per_collect=steps,
+            target_sync_interval=rt.target_sync_interval,
+            updates_per_collect=rt.updates_per_call,
+            epsilon_floor=rt.epsilon_floor or 0.0,
+            env=env_mod, obs_transform=obs_transform)
+    with chip_span(scopes.START_INIT, _OBS.trace):
+        state = anakin.init(jax.random.PRNGKey(seed))
+    with chip_span(scopes.START_RESTORE, _OBS.trace):
+        ckpt, train = _restore_train(checkpoint_dir, state.train)
+        state = state._replace(train=train)
     warm = -(-rt.train_start_factor * rt.batch_size // width)
     return _replay_chunk_loop(anakin, state, num_updates, chunk, ckpt,
-                              "anakin-apex", width, warm, run_dir=run_dir)
+                              "anakin-apex", width, warm)
 
 
 def train_anakin_r2d2(config_path: str, section: str, num_updates: int,
@@ -478,32 +503,35 @@ def train_anakin_r2d2(config_path: str, section: str, num_updates: int,
     defaults to min(replay_capacity, 4096) sequences — the ring lives in
     device memory, so the host topology's 100k default would swamp HBM
     for pixel observations."""
+    maybe_configure("anakin-r2d2", 0, run_dir)  # env-gated run-wide telemetry
     open_devices("anakin-r2d2")
-    agent_cfg, rt = load_config(config_path, section)
-    if _algo_of(agent_cfg) != "r2d2":
-        raise ValueError("anakin-r2d2 mode runs the R2D2 family")
-    from distributed_reinforcement_learning_tpu.runtime.anakin_r2d2 import AnakinR2D2
+    with chip_span(scopes.START_BUILD, _OBS.trace):
+        agent_cfg, rt = load_config(config_path, section)
+        if _algo_of(agent_cfg) != "r2d2":
+            raise ValueError("anakin-r2d2 mode runs the R2D2 family")
+        from distributed_reinforcement_learning_tpu.runtime.anakin_r2d2 import AnakinR2D2
 
-    env_mod, obs_transform = _jittable_env_for(agent_cfg, rt)
-    agent = R2D2Agent(agent_cfg)
-    n = num_envs or rt.num_actors * rt.envs_per_actor
-    cap = capacity or min(rt.replay_capacity, 4096)
-    cap = max(n, cap - cap % n)  # ring writes stay n-aligned
-    anakin = AnakinR2D2(
-        agent, num_envs=n, batch_size=rt.batch_size, capacity=cap,
-        target_sync_interval=rt.target_sync_interval,
-        updates_per_collect=rt.updates_per_call,
-        epsilon_floor=rt.epsilon_floor or 0.0,
-        env=env_mod, obs_transform=obs_transform)
-    state = anakin.init(jax.random.PRNGKey(seed))
-    ckpt, train = _restore_train(checkpoint_dir, state.train)
-    state = state._replace(train=train)
+        env_mod, obs_transform = _jittable_env_for(agent_cfg, rt)
+        agent = R2D2Agent(agent_cfg)
+        n = num_envs or rt.num_actors * rt.envs_per_actor
+        cap = capacity or min(rt.replay_capacity, 4096)
+        cap = max(n, cap - cap % n)  # ring writes stay n-aligned
+        anakin = AnakinR2D2(
+            agent, num_envs=n, batch_size=rt.batch_size, capacity=cap,
+            target_sync_interval=rt.target_sync_interval,
+            updates_per_collect=rt.updates_per_call,
+            epsilon_floor=rt.epsilon_floor or 0.0,
+            env=env_mod, obs_transform=obs_transform)
+    with chip_span(scopes.START_INIT, _OBS.trace):
+        state = anakin.init(jax.random.PRNGKey(seed))
+    with chip_span(scopes.START_RESTORE, _OBS.trace):
+        ckpt, train = _restore_train(checkpoint_dir, state.train)
+        state = state._replace(train=train)
     # Warm-up: the host learner's train-start gate (queue > factor*batch
     # sequences) expressed as explicit collect-only chunks.
     warm = -(-rt.train_start_factor * rt.batch_size // n)
     return _replay_chunk_loop(anakin, state, num_updates, chunk, ckpt,
-                              "anakin-r2d2", n * agent_cfg.seq_len, warm,
-                              run_dir=run_dir)
+                              "anakin-r2d2", n * agent_cfg.seq_len, warm)
 
 
 def train_local(config_path: str, section: str, num_updates: int,
@@ -565,26 +593,29 @@ def train_anakin_tokens(config_path: str, section: str, num_updates: int,
     envs each play one episode by decode through a per-pass key/value
     cache, then one V-trace learn step, in compiled chunks of `chunk`
     updates on the same `_run_chunk` as the other fused loops."""
+    maybe_configure("anakin-tokens", 0, run_dir)  # env-gated run-wide telemetry
     open_devices("anakin-tokens")
-    agent_cfg, rt = load_config(config_path, section)
-    # One token-level actor-critic, as many models as
-    # `agents/token_families.TOKEN_FAMILIES` has rows.
-    from distributed_reinforcement_learning_tpu.envs.registry import make_jittable_env
-    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import AnakinTokens
+    with chip_span(scopes.START_BUILD, _OBS.trace):
+        agent_cfg, rt = load_config(config_path, section)
+        # One token-level actor-critic, as many models as
+        # `agents/token_families.TOKEN_FAMILIES` has rows.
+        from distributed_reinforcement_learning_tpu.envs.registry import make_jittable_env
+        from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import AnakinTokens
 
-    agent = _token_agent(agent_cfg)  # at the end of this file; refuses the rest
-    env = make_jittable_env(
-        rt.envs[0], vocab=agent_cfg.vocab_size,
-        episode_len=agent_cfg.trajectory, distance=agent_cfg.recall_distance)
-    anakin = AnakinTokens(agent,
-                          num_envs or rt.num_actors * rt.envs_per_actor, env)
+        agent = _token_agent(agent_cfg)  # at the end of this file; refuses the rest
+        env = make_jittable_env(
+            rt.envs[0], vocab=agent_cfg.vocab_size,
+            episode_len=agent_cfg.trajectory, distance=agent_cfg.recall_distance)
+        anakin = AnakinTokens(
+            agent, num_envs or rt.num_actors * rt.envs_per_actor, env)
     # static, as compiled
     print(f"[anakin-tokens] {anakin.static_facts}{_expert_calls(anakin)}")
-    state = anakin.init(jax.random.PRNGKey(seed))
-    ckpt, train = _restore_train(checkpoint_dir, state.train)
-    state = state._replace(train=train)
+    with chip_span(scopes.START_INIT, _OBS.trace):
+        state = anakin.init(jax.random.PRNGKey(seed))
+    with chip_span(scopes.START_RESTORE, _OBS.trace):
+        ckpt, train = _restore_train(checkpoint_dir, state.train)
+        state = state._replace(train=train)
     chunk = max(1, min(chunk, num_updates))
-    maybe_configure("anakin-tokens", 0, run_dir)  # env-gated run-wide telemetry
     last = {"loss": None}
 
     def log(step: int, mean_ret: float, eps: float, m) -> str:
@@ -601,10 +632,12 @@ def train_anakin_tokens(config_path: str, section: str, num_updates: int,
         while (step := _read_step(state)) < num_updates:
             profiler.on_step(step)
             state, mean_ret = _run_chunk(
-                anakin, state, min(chunk, num_updates - step), 1, log, ckpt)
+                anakin, state, min(chunk, num_updates - step), 1, log, ckpt,
+                "anakin-tokens")
             returns.append(mean_ret)
     finally:
         profiler.close()
+        _print_unclosed_start("anakin-tokens")
     return {
         "frames": int(state.train.step) * anakin.num_envs * agent_cfg.trajectory,
         "last_loss": last["loss"],
@@ -651,3 +684,10 @@ def _pair_slabs(m) -> str:
         return ""
     return (f", pair slabs {float(m['pair_slabs_mean'][-1]):.3f} "
             f"max {float(m['pair_slabs_max'][-1]):.0f}")
+
+
+# The record's first span, `start/import`: from the kernel's start of this
+# process to here, the last line of this module's import (the interpreter,
+# JAX, flax, the package).
+_HOST.add(scopes.START_IMPORT, None, _HOST.process_start,
+          time.time() - _HOST.process_start)

@@ -16,7 +16,9 @@ scripts):
 - `open_devices(role)` as the process's FIRST touch of the backend: it
   opens the devices under a deadline and prints one line saying where
   this process runs, so every log names its platform and a quiet
-  fall-back to another backend cannot pass for a chip run.
+  fall-back to another backend cannot pass for a chip run. It is also
+  where the process's record of its start (`observability.trace
+  .HOST_RECORD`) starts listening, and the span `start/backend`.
 """
 
 from __future__ import annotations
@@ -27,6 +29,13 @@ import sys
 from pathlib import Path
 
 import jax
+
+from distributed_reinforcement_learning_tpu.observability import (
+    TELEMETRY,
+    chip_span,
+    scopes,
+)
+from distributed_reinforcement_learning_tpu.observability.trace import HOST_RECORD
 
 # <repo>/.jax_cache — in .gitignore and .chiprunignore.
 _DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
@@ -67,10 +76,12 @@ def open_devices(role: str) -> dict:
     and exits 1 instead of hanging its launcher forever."""
     # sys.__stderr__: the watchdog writes from C to a file descriptor,
     # which a replaced sys.stderr (a test's capture, a logger) lacks.
+    HOST_RECORD.begin()  # before anything can compile
     faulthandler.dump_traceback_later(_OPEN_DEADLINE_S, exit=True,
                                       file=sys.__stderr__)
     try:
-        devices = jax.devices()
+        with chip_span(scopes.START_BACKEND, TELEMETRY.trace):
+            devices = jax.devices()
     finally:
         faulthandler.cancel_dump_traceback_later()
     info = {"platform": devices[0].platform, "kind": devices[0].device_kind,
