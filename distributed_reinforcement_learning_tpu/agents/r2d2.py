@@ -79,6 +79,35 @@ class R2D2Batch(NamedTuple):
     initial_c: jax.Array  # [B, H]
 
 
+def _bt(x: jax.Array) -> jax.Array:
+    """`[T, B, ...]` <-> `[B, T, ...]`."""
+    return jnp.swapaxes(x, 0, 1)
+
+
+class R2D2Rollout(NamedTuple):
+    """New sequences time-major, `[T, B, ...]`: a rollout as `lax.scan`
+    stacks it, with the Q-values its actors computed. What
+    `_td_error_time_major` takes; `batch()` is the ring's entry."""
+
+    state: jax.Array  # [T, B, *obs]
+    previous_action: jax.Array  # [T, B] i32
+    action: jax.Array  # [T, B] i32
+    reward: jax.Array  # [T, B] f32
+    done: jax.Array  # [T, B] bool
+    initial_h: jax.Array  # [B, H] sequence-start stored h
+    initial_c: jax.Array  # [B, H]
+    online_q: jax.Array  # [T, B, A] f32, the online net's, from acting
+
+    def batch(self) -> R2D2Batch:
+        """The sequences as `[B, T, ...]` entries: what the ring stores
+        (acting's Q-values score them and are not stored)."""
+        return R2D2Batch(
+            state=_bt(self.state), previous_action=_bt(self.previous_action),
+            action=_bt(self.action), reward=_bt(self.reward),
+            done=_bt(self.done), initial_h=self.initial_h,
+            initial_c=self.initial_c)
+
+
 class R2D2Agent(common.SequenceReplayLearnMixin):
     def __init__(self, cfg: R2D2Config):
         self.cfg = cfg
@@ -139,3 +168,26 @@ class R2D2Agent(common.SequenceReplayLearnMixin):
             unroll(target_params), batch.action, batch.reward,
             discounts, burn_in=cfg.burn_in, rescale_eps=cfg.rescale_eps,
             n_step=cfg.n_step)
+
+    def _td_error_time_major(self, state, rollout: R2D2Rollout):
+        """`_td_error(state, rollout.batch(), online_q [B, T, A])`, `[B]`,
+        with the frames left in the order the scan wrote them: the target
+        net unrolls time-major (`R2D2Net.unroll_time_major`), and only
+        what the TD arithmetic reads (two `[T, B, A]` sets of Q-values and
+        the per-step scalars) is swapped to the `[B, T]` order that
+        `common.sequence_double_q_td` and `_seq_priority` take.
+
+        Called by the fused loop (`runtime/anakin_r2d2.py`), which holds a
+        rollout; whoever holds an `R2D2Batch` calls `_td_error`.
+        """
+        cfg = self.cfg
+        target_q = self.model.apply(
+            state.target_params, self._prep_obs(rollout.state),
+            rollout.previous_action, rollout.done, rollout.initial_h,
+            rollout.initial_c, method=self.model.unroll_time_major)
+        discounts = (~rollout.done).astype(jnp.float32) * cfg.discount_factor
+        tv, sav = common.sequence_double_q_td(
+            _bt(rollout.online_q), _bt(target_q), _bt(rollout.action),
+            _bt(rollout.reward), _bt(discounts), burn_in=cfg.burn_in,
+            rescale_eps=cfg.rescale_eps, n_step=cfg.n_step)
+        return self._seq_priority(tv, sav)

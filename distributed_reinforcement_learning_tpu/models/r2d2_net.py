@@ -110,6 +110,11 @@ class R2D2Net(nn.Module):
                scope: str | None = None):
         """Q-values over a `[B, T, ...]` sequence from stored start state.
 
+        Batch-major: what everyone who holds an `R2D2Batch` calls (the
+        learn step's `_loss`, `_td_error`, the host learner
+        `runtime/r2d2_runner.py`, `scripts/`, the benchmark's reference
+        check). `unroll_time_major` is the same net over `[T, B, ...]`.
+
         done-masked like `model/r2d2_lstm.py:78-80`: (h, c) are zeroed
         *after* the step at which done[t] is True. Returns `[B, T, A]`.
 
@@ -129,11 +134,38 @@ class R2D2Net(nn.Module):
         sequence are `step`'s, one env step at a time, under the same
         parameters, start state and resets (`runtime/anakin_r2d2.py`).
         """
-        B, T = obs_seq.shape[:2]
-        x = self._torso(obs_seq.reshape((B * T,) + obs_seq.shape[2:]))
-        x = x.reshape((B, T, -1))
-        a = self.action_embed(prev_action_seq)
-        z = jnp.concatenate([x, a], axis=-1)
-        with jax.named_scope(scope) if scope else contextlib.nullcontext():
-            h_all, _ = self.cell.unroll(z, done_seq, h0, c0)
-        return self._head(h_all)
+        return _unroll(self, obs_seq, prev_action_seq, done_seq, h0, c0,
+                       scope, time_major=False)
+
+    def unroll_time_major(self, obs_seq, prev_action_seq, done_seq, h0, c0,
+                          scope: str | None = None):
+        """`unroll` over a `[T, B, ...]` sequence, as a `lax.scan` stacks
+        a rollout: returns `[T, B, A]`. Same parameters, same sub-modules.
+
+        What the fused loop's scoring pass calls
+        (`R2D2Agent._td_error_time_major`, `runtime/anakin_r2d2.py`):
+        the frames are flattened to `T*B` rows as they lie (the torso does
+        not care about the order of its rows) and the recurrence, which
+        runs over time, is entered with time outermost, so the frame batch
+        is never transposed. The caller chooses the entry: the order of
+        two leading axes cannot be read from their sizes.
+        """
+        return _unroll(self, obs_seq, prev_action_seq, done_seq, h0, c0,
+                       scope, time_major=True)
+
+
+def _unroll(net: R2D2Net, obs_seq, prev_action_seq, done_seq, h0, c0,
+            scope: str | None, time_major: bool):
+    """The body of both unrolls. Only the recurrence reads which of the
+    two leading axes is time; everything around it runs row by row.
+    A plain function: a method of the module would put a scope of its
+    own into every op's name, and the profile's readers know the ops by
+    `R2D2Net.unroll/...`."""
+    lead = obs_seq.shape[:2]
+    x = net._torso(obs_seq.reshape((lead[0] * lead[1],) + obs_seq.shape[2:]))
+    x = x.reshape(lead + (-1,))
+    a = net.action_embed(prev_action_seq)
+    z = jnp.concatenate([x, a], axis=-1)
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        h_all, _ = net.cell.unroll(z, done_seq, h0, c0, time_major=time_major)
+    return net._head(h_all)

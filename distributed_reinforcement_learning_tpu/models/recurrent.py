@@ -8,7 +8,8 @@ timestep. Here:
 - `LSTMCell` holds one fused `[x; h] @ W + b` gate projection (TF-style
   forget bias 1.0) and exposes `unroll` over a whole `[B, T]` sequence:
   the time-parallel input projection runs as one big MXU matmul, and the
-  sequential recursion goes through `ops.lstm.lstm_scan` — a `lax.scan`
+  sequential recursion goes through `ops.lstm.lstm_scan` (or, for a
+  caller that holds `[T, B]`, `lstm_scan_time_major`) — a `lax.scan`
   by default; the fused Pallas VMEM kernel (`ops/pallas/lstm.py`) is
   opt-in via DRL_LSTM_PALLAS=1 (its measured margin over the scan is
   not yet stable across artifacts — see ops/lstm.py).
@@ -25,7 +26,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from distributed_reinforcement_learning_tpu.ops.lstm import lstm_scan
+from distributed_reinforcement_learning_tpu.ops.lstm import (
+    lstm_scan, lstm_scan_time_major)
 
 
 class LSTMCell(nn.Module):
@@ -50,11 +52,18 @@ class LSTMCell(nn.Module):
         h: jax.Array,  # [B, hidden]
         c: jax.Array,
         backend: str | None = None,
+        time_major: bool = False,
     ):
         """-> (h_all [B, T, hidden] pre-mask outputs, (hT, cT) masked carry).
 
         (h, c) are zeroed AFTER any step where done is set
         (`model/r2d2_lstm.py:78-80` semantics).
+
+        `time_major`: `z_seq` `[T, B, F]`, `done_seq` `[T, B]`, `h_all`
+        `[T, B, hidden]`, the recursion's own order with nothing swapped
+        (`ops.lstm.lstm_scan_time_major`). The caller says which order it
+        holds (shapes cannot: T and B may coincide): `R2D2Net.unroll_time_major`
+        is the one that holds `[T, B]`.
         """
         feat = z_seq.shape[-1]
         hid = self.hidden_size
@@ -64,7 +73,8 @@ class LSTMCell(nn.Module):
         bias = self.param("gates_bias", nn.initializers.zeros_init(), (4 * hid,))
         xg = jnp.dot(z_seq.astype(self.dtype), kernel[:feat]) + bias
         keep = 1.0 - done_seq.astype(xg.dtype)
-        return lstm_scan(
+        scan = lstm_scan_time_major if time_major else lstm_scan
+        return scan(
             xg, kernel[feat:], keep, h, c, backend=backend or self.backend
         )
 

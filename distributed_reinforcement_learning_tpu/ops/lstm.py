@@ -35,12 +35,25 @@ Gate math (TF1 `LSTMCell` parity, forget bias 1.0):
 Done-masking: the carried (h, c) are zeroed AFTER the step at which
 done[t] is set (`model/r2d2_lstm.py:78-80`); the emitted h_t is pre-mask.
 
-Shapes (batch-major public API, matching the models):
-    xg   [B, T, 4H]   input projection + bias
+Two entries, one recursion (the axis order is not observable from the
+shapes, so WHO CALLS chooses):
+
+`lstm_scan_time_major`, `[T, B, ...]`, is the recursion itself: a
+`lax.scan` runs over the leading axis, so time-major is the order it
+wants. Called by `LSTMCell.unroll(time_major=True)`, which the fused
+R2D2 loop's scoring pass reaches through `R2D2Net.unroll_time_major`
+(`runtime/anakin_r2d2.py`: the collect scan wrote the rollout that way).
+    xg   [T, B, 4H]   input projection + bias
     wh   [H, 4H]      recurrent weights
-    keep [B, T]       1.0 - done
+    keep [T, B]       1.0 - done
     h0/c0 [B, H]      sequence-start stored state (`agent/r2d2.py:110-111`)
-Returns (h_all [B, T, H], (hT [B, H], cT [B, H])).
+Returns (h_all [T, B, H], (hT [B, H], cT [B, H])).
+
+`lstm_scan`, `[B, T, ...]`, is the batch-major adapter around it (two
+`swapaxes` in, one out), matching the models: everyone who holds a
+`[B, T]` batch calls it (`LSTMCell.unroll` / `__call__`: the learn steps,
+the act steps, IMPALA, the host learners).
+    xg [B, T, 4H], keep [B, T] -> h_all [B, T, H], same carries.
 """
 
 from __future__ import annotations
@@ -74,6 +87,28 @@ def _scan_reference(xg_tm, wh, keep_tm, h0, c0):
     return h_all, (hT, cT)
 
 
+def lstm_scan_time_major(
+    xg_tm: jax.Array,
+    wh: jax.Array,
+    keep_tm: jax.Array,
+    h0: jax.Array,
+    c0: jax.Array,
+    backend: str = "auto",
+):
+    """Run the recursion over `[T, B, ...]`; see module docstring."""
+    backend = resolve_backend(backend, opt_in_env="DRL_LSTM_PALLAS")
+    keep_tm = keep_tm.astype(xg_tm.dtype)
+    if backend == "reference":
+        return _scan_reference(xg_tm, wh, keep_tm, h0, c0)
+    from distributed_reinforcement_learning_tpu.ops.pallas.lstm import lstm_pallas
+
+    h_all_tm, hT, cT = lstm_pallas(
+        xg_tm, wh, keep_tm[..., None], h0, c0,
+        interpret=(backend == "pallas_interpret"),
+    )
+    return h_all_tm, (hT, cT)
+
+
 def lstm_scan(
     xg: jax.Array,
     wh: jax.Array,
@@ -82,17 +117,8 @@ def lstm_scan(
     c0: jax.Array,
     backend: str = "auto",
 ):
-    """Run the recursion; see module docstring for shapes/semantics."""
-    backend = resolve_backend(backend, opt_in_env="DRL_LSTM_PALLAS")
-    xg_tm = jnp.swapaxes(xg, 0, 1)  # [T, B, 4H]
-    keep_tm = jnp.swapaxes(keep, 0, 1).astype(xg.dtype)  # [T, B]
-    if backend == "reference":
-        h_all_tm, (hT, cT) = _scan_reference(xg_tm, wh, keep_tm, h0, c0)
-    else:
-        from distributed_reinforcement_learning_tpu.ops.pallas.lstm import lstm_pallas
-
-        h_all_tm, hT, cT = lstm_pallas(
-            xg_tm, wh, keep_tm[..., None], h0, c0,
-            interpret=(backend == "pallas_interpret"),
-        )
-    return jnp.swapaxes(h_all_tm, 0, 1), (hT, cT)
+    """The batch-major adapter: `[B, T, ...]` in and out around
+    `lstm_scan_time_major`; see module docstring."""
+    h_all_tm, carry = lstm_scan_time_major(
+        jnp.swapaxes(xg, 0, 1), wh, jnp.swapaxes(keep, 0, 1), h0, c0, backend)
+    return jnp.swapaxes(h_all_tm, 0, 1), carry

@@ -15,8 +15,8 @@ Replay semantics mirror `data/replay.py` (itself the re-design of
 - priority `(|err| + 0.001) ** 0.6`, stratified sampling over `total/n`
   segments, IS weights `(N * p) ** -beta` batch-max-normalized, beta
   annealed 0.4 -> 1.0 by 0.001 per sample;
-- new sequences scored with `agent._td_error` under the current params
-  (what the host learner does at ingest, `runtime/r2d2_runner.py:274`).
+- new sequences scored under the current params (what the host learner
+  does at ingest with `agent._td_error`, `runtime/r2d2_runner.py:274`).
   The online net's Q-values are the ones acting computed in the collect
   scan (same params, inputs, start state and resets, and no optimizer
   step between), so the scoring pass unrolls the target net alone;
@@ -26,6 +26,16 @@ Replay semantics mirror `data/replay.py` (itself the re-design of
 Actor semantics mirror `R2D2Actor`: per-episode epsilon decay
 `1/(0.1*episodes+1)` with an optional floor, stored sequence-start LSTM
 state, done-masked carries, prev-action reset.
+
+Axis orders. The collect scan stacks its record time-major: `_collect`
+gives an `R2D2Rollout`, `[T, B, ...]`, and the score takes it in that
+order (`agent._td_error_time_major` -> `R2D2Net.unroll_time_major`), so
+the new frames are never transposed on their way to conv0. `[B, T, ...]`
+(`R2D2Batch`) is the order of the ring, whose entries are whole
+sequences: `_ingest` makes it for the write alone, and everything that
+reads the ring (`_sample`, the learn step's `agent._learn`) keeps it.
+One chip or a mesh alike (a shard scores its `[T, B/n]`); `score_order`
+says so at start-up.
 
 Differences from the host stack, by construction:
 - the ring overwrites oldest entries FIFO (the SumTree does too);
@@ -43,7 +53,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from distributed_reinforcement_learning_tpu.agents.r2d2 import R2D2Agent, R2D2Batch
+from distributed_reinforcement_learning_tpu.agents.r2d2 import (
+    R2D2Agent, R2D2Batch, R2D2Rollout)
 from distributed_reinforcement_learning_tpu.data import device_replay
 from distributed_reinforcement_learning_tpu.data.device_replay import (
     BETA0,
@@ -207,14 +218,15 @@ class AnakinR2D2(DataMeshReplayMixin):
         return carry, record
 
     def _collect(self, state: AnakinR2D2State):
-        """One seq_len unroll from all envs -> (state', R2D2Batch [B, T],
-        acting's Q-values f32[B, T, A], episode stats).
+        """One seq_len unroll from all envs -> (state', R2D2Rollout
+        `[T, B, ...]`, episode stats): the scan's own stacked record,
+        nothing swapped.
 
-        The Q-values are what `R2D2Net.unroll` gives over the batch under
-        `state.train.params`: they score the sequences and are not stored.
+        `rollout.online_q` is what `R2D2Net.unroll` gives over the
+        sequences under `state.train.params`: it scores them and is not
+        stored. Called by `_update` and `_collect_only`.
         """
         cfg = self.agent.cfg
-        h0, c0 = state.h, state.c  # sequence-start stored state
         carry = (state.env, state.obs, state.prev_action, state.h, state.c,
                  state.episodes, state.rng)
         with jax.named_scope(scopes.COLLECT):
@@ -222,13 +234,11 @@ class AnakinR2D2(DataMeshReplayMixin):
                 functools.partial(self._env_step, state.train.params), carry,
                 None, length=cfg.seq_len)
             env, obs, prev_action, h, c, episodes, rng = carry
-            bt = lambda name: jnp.swapaxes(rec[name], 0, 1)
-            batch = R2D2Batch(
-                state=bt("state"), previous_action=bt("previous_action"),
-                action=bt("action"), reward=bt("reward"), done=bt("done"),
-                initial_h=h0, initial_c=c0,
-            )
-            online_q = bt("online_q")
+            rollout = R2D2Rollout(
+                state=rec["state"], previous_action=rec["previous_action"],
+                action=rec["action"], reward=rec["reward"], done=rec["done"],
+                initial_h=state.h, initial_c=state.c,  # sequence-start state
+                online_q=rec["online_q"])
         stats = {
             "episode_return_sum": rec["episode_return"].sum(),
             "episodes_done": rec["episode_completed"].sum().astype(jnp.float32),
@@ -236,15 +246,22 @@ class AnakinR2D2(DataMeshReplayMixin):
         }
         new_state = state._replace(env=env, obs=obs, prev_action=prev_action,
                                    h=h, c=c, episodes=episodes, rng=rng)
-        return new_state, batch, online_q, stats
+        return new_state, rollout, stats
 
-    def _ingest(self, train, replay: DeviceReplay, batch: R2D2Batch,
-                online_q: jax.Array) -> DeviceReplay:
-        """Score + write B new sequences into the ring at `ptr`."""
+    score_order = "time_major"  # static: `_ingest` is the one scoring path
+
+    def _ingest(self, train, replay: DeviceReplay,
+                rollout: R2D2Rollout) -> DeviceReplay:
+        """Score + write B new sequences into the ring at `ptr`.
+
+        The score reads the rollout as the scan wrote it, `[T, B, ...]`;
+        the `[B, T, ...]` batch is made for the ring's write alone, which
+        packs the frames to words on its own way from the same record.
+        """
         with jax.named_scope(scopes.REPLAY_SCORE):
-            errs = self.agent._td_error(train, batch, online_q)  # [B]
+            errs = self.agent._td_error_time_major(train, rollout)  # [B]
         with jax.named_scope(scopes.REPLAY_WRITE):
-            return device_replay.ingest(replay, batch, errs)
+            return device_replay.ingest(replay, rollout.batch(), errs)
 
     @jax.named_scope(scopes.REPLAY_SAMPLE)
     def _sample(self, replay: DeviceReplay, rng: jax.Array):
@@ -253,8 +270,8 @@ class AnakinR2D2(DataMeshReplayMixin):
 
     # -- one update: collect, ingest, K prioritized steps ----------------
     def _update(self, state: AnakinR2D2State, _):
-        state, seqs, online_q, stats = self._collect(state)
-        replay = self._ingest(state.train, state.replay, seqs, online_q)
+        state, rollout, stats = self._collect(state)
+        replay = self._ingest(state.train, state.replay, rollout)
         train = state.train
 
         def one_learn(carry, _):
@@ -300,8 +317,8 @@ class AnakinR2D2(DataMeshReplayMixin):
         return jax.lax.scan(self._update, state, None, length=num_updates)
 
     def _collect_only(self, state: AnakinR2D2State, _):
-        state, seqs, online_q, stats = self._collect(state)
-        replay = self._ingest(state.train, state.replay, seqs, online_q)
+        state, rollout, stats = self._collect(state)
+        replay = self._ingest(state.train, state.replay, rollout)
         return state._replace(replay=replay), self._psum(stats)
 
     def _collect_chunk(self, state: AnakinR2D2State, num_collects: int):

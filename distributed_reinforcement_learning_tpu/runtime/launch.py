@@ -522,13 +522,13 @@ def train_anakin_r2d2(config_path: str, section: str, num_updates: int,
             updates_per_collect=rt.updates_per_call,
             epsilon_floor=rt.epsilon_floor or 0.0,
             env=env_mod, obs_transform=obs_transform)
+    print(f"[anakin-r2d2] score order: {anakin.score_order}")  # static, as compiled
     with chip_span(scopes.START_INIT, _OBS.trace):
         state = anakin.init(jax.random.PRNGKey(seed))
     with chip_span(scopes.START_RESTORE, _OBS.trace):
         ckpt, train = _restore_train(checkpoint_dir, state.train)
         state = state._replace(train=train)
-    # Warm-up: the host learner's train-start gate (queue > factor*batch
-    # sequences) expressed as explicit collect-only chunks.
+    # Warm-up: the host learner's train-start gate, as collect-only chunks.
     warm = -(-rt.train_start_factor * rt.batch_size // n)
     return _replay_chunk_loop(anakin, state, num_updates, chunk, ckpt,
                               "anakin-r2d2", n * agent_cfg.seq_len, warm)

@@ -167,14 +167,14 @@ class TestPixelR2D2:
         assert "mean_return" in ev
 
 
-def _pixel_anakin(priority_eta):
+def _pixel_anakin(priority_eta, n_step=5):
     """The pixel cell's shape of agent (Nature torso, dueling streams,
     5-step targets) at 4 envs; env 0's ball is put on its way out of the
     field, so a life is lost, and `done` set, inside the next sequence."""
     from distributed_reinforcement_learning_tpu.envs import breakout_jax
 
     cfg = R2D2Config(obs_shape=(84, 84, 4), num_actions=4, seq_len=12,
-                     burn_in=4, n_step=5, lstm_size=16, torso="nature",
+                     burn_in=4, n_step=n_step, lstm_size=16, torso="nature",
                      dueling_hidden=32, priority_eta=priority_eta)
     an = AnakinR2D2(R2D2Agent(cfg), num_envs=4, capacity=8, batch_size=2,
                     env=breakout_jax)
@@ -190,14 +190,30 @@ def _pixel_anakin(priority_eta):
     return an, lose_a_ball
 
 
-def _mlp_anakin(priority_eta):
+def _mlp_anakin(priority_eta, n_step=5):
     """The reference's CartPole net; a random policy (epsilon 1 before
     the first episode ends) drops the pole inside 24 steps."""
     cfg = R2D2Config(obs_shape=(2,), num_actions=2, seq_len=12, burn_in=4,
-                     n_step=5, lstm_size=16, priority_eta=priority_eta)
+                     n_step=n_step, lstm_size=16, priority_eta=priority_eta)
     an = AnakinR2D2(R2D2Agent(cfg), num_envs=4, capacity=8, batch_size=2,
                     obs_transform=pomdp_project)
     return an, lambda env: env
+
+
+def _second_rollout(an, before_second):
+    """-> (state, rollout) of the SECOND collect from seed 3, with a
+    target net of its own (equal nets would hide a mixed-up argument), a
+    stored start state that is not zeros and a `done` inside a sequence."""
+    st = an.init(jax.random.PRNGKey(3))
+    other = an.agent.init_state(jax.random.PRNGKey(4)).params
+    st = st._replace(train=st.train.replace(target_params=other))
+    collect = jax.jit(an._collect)
+    st = collect(st)[0]
+    st = st._replace(env=before_second(st.env))
+    st, rollout, _ = collect(st)
+    assert float(jnp.abs(rollout.initial_h).max()) > 0
+    assert np.asarray(rollout.done)[:-1].any(), "no reset inside a sequence"
+    return st, rollout
 
 
 @pytest.mark.parametrize("priority_eta", [None, 0.9], ids=["ref", "eta0.9"])
@@ -206,21 +222,12 @@ def _mlp_anakin(priority_eta):
 def test_acting_q_scores_the_new_sequences(build, priority_eta):
     """The collect scan's Q-values ARE the online net's unroll over the
     batch it records (same params, inputs, stored start state and
-    resets), so `_ingest` may hand them to `_td_error` and unroll the
-    target net alone (ISSUE 50)."""
+    resets), so `_ingest` may score with them and unroll the target net
+    alone (ISSUE 50)."""
     an, before_second = build(priority_eta)
     agent = an.agent
-    st = an.init(jax.random.PRNGKey(3))
-    # A target net of its own: equal nets would hide a mixed-up argument.
-    other = agent.init_state(jax.random.PRNGKey(4)).params
-    st = st._replace(train=st.train.replace(target_params=other))
-    collect = jax.jit(an._collect)
-    st = collect(st)[0]
-    st = st._replace(env=before_second(st.env))
-    st, batch, online_q, _ = collect(st)
-    assert float(jnp.abs(batch.initial_h).max()) > 0  # stored state, not zeros
-    done = np.asarray(batch.done)
-    assert done[:, :-1].any(), "no reset inside a sequence"
+    st, rollout = _second_rollout(an, before_second)
+    batch, online_q = rollout.batch(), jnp.swapaxes(rollout.online_q, 0, 1)
 
     unrolled = agent.model.apply(
         st.train.params, agent._prep_obs(batch.state), batch.previous_action,
@@ -236,3 +243,74 @@ def test_acting_q_scores_the_new_sequences(build, priority_eta):
     # And the online values are read: another net's give another score.
     assert not np.allclose(agent.td_error(st.train, batch, online_q[::-1]),
                            both, rtol=1e-3)
+
+
+@pytest.mark.parametrize("priority_eta", [None, 0.9], ids=["ref", "eta0.9"])
+@pytest.mark.parametrize("n_step", [1, 5])
+@pytest.mark.parametrize("build", [_pixel_anakin, _mlp_anakin],
+                         ids=["nature", "mlp"])
+def test_the_score_takes_the_rollout_as_the_scan_wrote_it(build, n_step,
+                                                          priority_eta):
+    """One net, two axis orders: `unroll_time_major` over `[T, B, ...]`
+    is `unroll` over the swapped `[B, T, ...]`, and the fused loop's score
+    of a rollout is `_td_error` of its `[B, T]` batch (ISSUE 52)."""
+    an, before_second = build(priority_eta, n_step)
+    agent = an.agent
+    st, rollout = _second_rollout(an, before_second)
+    batch, online_q = rollout.batch(), jnp.swapaxes(rollout.online_q, 0, 1)
+    assert rollout.state.shape[:2] == (12, 4) and batch.state.shape[:2] == (4, 12)
+
+    def q(method, seq):
+        return agent.model.apply(
+            st.train.target_params, agent._prep_obs(seq.state),
+            seq.previous_action, seq.done, seq.initial_h, seq.initial_c,
+            method=method)
+
+    batch_major = q(agent.model.unroll, batch)
+    time_major = q(agent.model.unroll_time_major, rollout)
+    assert time_major.shape == (12, 4, agent.cfg.num_actions)
+    scale = float(jnp.abs(batch_major).max())
+    np.testing.assert_allclose(jnp.swapaxes(time_major, 0, 1), batch_major,
+                               rtol=0, atol=1e-5 * scale)
+
+    want = np.asarray(agent.td_error(st.train, batch, online_q))
+    got = jax.jit(agent._td_error_time_major)(st.train, rollout)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5 * want.max())
+    # The order is read: the same rollout through the other entry is not it.
+    square = jax.tree.map(lambda x: x[:4], rollout)  # T = B = 4: shapes agree
+    assert not np.allclose(
+        q(agent.model.unroll_time_major, square),
+        q(agent.model.unroll, square), atol=1e-3 * scale)
+
+
+@pytest.mark.parametrize("build", [_pixel_anakin, _mlp_anakin],
+                         ids=["nature", "mlp"])
+def test_the_ring_holds_the_same_bytes_whichever_order_scored_them(build):
+    """After one `_collect_only` the ring is what the batch-major path
+    writes from the same seed (collect, swap every field to `[B, T]`,
+    `_td_error`, `device_replay.ingest`): the stored leaves to the bit,
+    the priorities to float32 rounding."""
+    from distributed_reinforcement_learning_tpu.data import device_replay
+
+    an, _ = build(0.9)
+    agent = an.agent
+    st = an.init(jax.random.PRNGKey(5))
+    other = agent.init_state(jax.random.PRNGKey(4)).params
+    st = st._replace(train=st.train.replace(target_params=other))
+
+    def batch_major(state):
+        state, rollout, _ = an._collect(state)
+        batch = rollout.batch()
+        errs = agent._td_error(state.train, batch,
+                               jnp.swapaxes(rollout.online_q, 0, 1))
+        return device_replay.ingest(state.replay, batch, errs)
+
+    want = jax.jit(batch_major)(st)
+    got = jax.jit(an._collect_only)(st, None)[0].replay
+    assert int(got.ptr) == int(want.ptr) == 4 and int(got.size) == 4
+    for ours, theirs in zip(jax.tree.leaves(got.storage),
+                            jax.tree.leaves(want.storage)):
+        np.testing.assert_array_equal(ours, theirs)
+    assert np.asarray(jax.tree.leaves(got.storage)[0]).any()
+    np.testing.assert_allclose(got.priorities, want.priorities, rtol=1e-5)
+    assert (np.asarray(got.priorities)[:4] > 0).all()

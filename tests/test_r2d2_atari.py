@@ -377,7 +377,7 @@ def test_chunk_counters_ride_in_the_metrics():
     assert ((w > 0) & (w <= 1)).all()
 
 
-def test_launcher_loop_survives_three_donated_chunks(tmp_path):
+def test_launcher_loop_survives_three_donated_chunks(tmp_path, capsys):
     from distributed_reinforcement_learning_tpu.runtime.launch import (
         train_anakin_r2d2)
 
@@ -390,6 +390,9 @@ def test_launcher_loop_survives_three_donated_chunks(tmp_path):
     out = train_anakin_r2d2(str(path), "r2d2_small", num_updates=12, chunk=2,
                             num_envs=4, capacity=16)
     assert len(out["chunk_mean_returns"]) == 3  # 3 chunks x 2 updates x K=2
+    # the static fact of the compiled chunks, once at start-up (ISSUE 52)
+    assert capsys.readouterr().out.count(
+        "[anakin-r2d2] score order: time_major\n") == 1
 
 
 # -- the configuration by name ----------------------------------------------------

@@ -332,19 +332,23 @@ def test_conv_backward_stack_keeps_its_names(conv_chunk_names, name):
                for n in conv_chunk_names)
 
 
-def _equations_under(jaxpr, scope, above="", found=None, loops=False):
-    """The primitive of every equation whose composed name stack holds
-    `scope`, through every jaxpr inside; with `loops`, the equations that
-    hold a jaxpr themselves (`scan`, `pjit`, ...) as well."""
-    found = [] if found is None else found
+def _walk_under(jaxpr, scope, above=""):
+    """(equation, whether it holds a jaxpr itself) for every equation whose
+    composed name stack holds `scope`, through every jaxpr inside."""
     for eqn in jaxpr.eqns:
         stack = f"{above}/{eqn.source_info.name_stack}"
         subs = list(jax.core.jaxprs_in_params(eqn.params))
-        if scope in stack and (loops or not subs):
-            found.append(eqn.primitive.name)
+        if scope in stack:
+            yield eqn, bool(subs)
         for sub in subs:
-            _equations_under(sub, scope, stack, found, loops)
-    return found
+            yield from _walk_under(sub, scope, stack)
+
+
+def _equations_under(jaxpr, scope, loops=False):
+    """The primitive of every equation under `scope`; with `loops`, the
+    equations that hold a jaxpr themselves (`scan`, `pjit`, ...) as well."""
+    return [eqn.primitive.name for eqn, holds in _walk_under(jaxpr, scope)
+            if loops or not holds]
 
 
 def test_every_equation_of_the_convolution_mixer_is_under_its_name():
@@ -446,8 +450,9 @@ def test_r2d2_backward_recurrence_keeps_the_unroll_name(r2d2_chunk_names):
 
 
 def test_r2d2_scoring_unroll_is_not_named_for_the_learn_step(r2d2_chunk_names):
-    """The same `R2D2Net.unroll` scores the new sequences; only the
-    learn step's recurrence is `learn/loss/unroll`."""
+    """The same net scores the new sequences (time-major,
+    `R2D2Net.unroll_time_major`); only the learn step's recurrence is
+    `learn/loss/unroll`."""
     scoring = [n for n in r2d2_chunk_names if scopes.REPLAY_SCORE in n]
     assert scoring and not any(scopes.LEARN in n for n in scoring)
 
@@ -480,6 +485,32 @@ def test_r2d2_scoring_pass_unrolls_one_net(chunk):
     assert scoring.count("scan") == 1
     acting = _equations_under(jaxpr, scopes.ACT)
     assert acting.count("conv_general_dilated") == 3
+
+
+@pytest.mark.parametrize("chunk", ["train_chunk", "collect_chunk"])
+def test_r2d2_scoring_pass_transposes_no_frames(chunk):
+    """The score takes the rollout as the collect scan stacked it
+    (ISSUE 52): under `replay/score` no `transpose` has an operand of the
+    frame batch's rank and size (`[T, B, 84, 84, 4]`; the Q-values and the
+    per-step scalars are swapped, and the recurrence's own order needs
+    none), and it is still one net: three convolutions, one scan. The
+    ring's `[B, T]` batch is made under `replay/write`."""
+    anakin = _pixel_r2d2()
+    state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+    jaxpr = jax.make_jaxpr(getattr(anakin, chunk), static_argnums=1)(state, 1).jaxpr
+    frames = 6 * 2 * 84 * 84 * 4
+    names = _equations_under(jaxpr, scopes.REPLAY_SCORE, loops=True)
+    assert names.count("conv_general_dilated") == 3 and names.count("scan") == 1
+    swapped_under = lambda scope: [
+        eqn.invars[0].aval for eqn, _ in _walk_under(jaxpr, scope)
+        if eqn.primitive.name == "transpose"]
+    swapped = swapped_under(scopes.REPLAY_SCORE)
+    assert swapped  # the small fields, on their way to the TD arithmetic
+    assert not [a for a in swapped if a.ndim >= 5 or a.size >= frames], swapped
+    swaps_frames = lambda scope: any(
+        a.shape == (6, 2, 84, 84, 4) for a in swapped_under(scope))
+    assert swaps_frames(scopes.REPLAY_WRITE) and not swaps_frames(scopes.COLLECT)
+    assert anakin.score_order == "time_major"
 
 
 def test_r2d2_learn_step_still_unrolls_both_nets():
