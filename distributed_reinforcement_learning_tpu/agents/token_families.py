@@ -18,6 +18,8 @@ from distributed_reinforcement_learning_tpu.agents.mlalm import (
     MLALMAgent, MLALMConfig)
 from distributed_reinforcement_learning_tpu.agents.moelm import (
     MoELMAgent, MoELMConfig)
+from distributed_reinforcement_learning_tpu.agents.ssmoelm import (
+    SSMoELMAgent, SSMoELMConfig)
 from distributed_reinforcement_learning_tpu.agents.swalm import (
     SwaLMAgent, SwaLMConfig)
 
@@ -28,4 +30,5 @@ TOKEN_FAMILIES = {
     "mlalm": (MLALMConfig, MLALMAgent),
     "convlm": (ConvLMConfig, ConvLMAgent),
     "swalm": (SwaLMConfig, SwaLMAgent),
+    "ssmoelm": (SSMoELMConfig, SSMoELMAgent),
 }

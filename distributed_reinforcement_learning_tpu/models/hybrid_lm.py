@@ -14,6 +14,11 @@ head. D wide, tokens x_1..x_T:
                 [x, B, C] = xBC;  dt = softplus(dt + dt_bias);  A = -exp(A_log)
                 S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t;  y_t = S_t C_t + D x_t
                 out = W_out RMSNorm(y * silu(z); g_n)
+    `mamba_groups` G (granite: 1; `models/ssm_moe_lm.py`, which borrows
+    this mixer, 8): B and C are `[G, N]`, head h reads those of group
+    h // (H / G), and the gated norm's mean square is over each group's
+    d_inner / G channels (the gate BEFORE the norm, as here); at G = 1
+    every trace is what it was without it.
 
 Parameters are one `[n, ...]`-stacked dict PER RUN of equal layers
 (`run0`: 5 state-space layers, `run1`: the attention layer, `run2`: 4
@@ -101,21 +106,6 @@ def layer_runs(layer_types, kinds=LAYER_KINDS) -> tuple:
     return tuple((k, n) for k, n in runs)
 
 
-def causal_conv(xbc: jax.Array, w: jax.Array, bias: jax.Array,
-                pos: jax.Array) -> jax.Array:
-    """Depthwise causal convolution over time, `xbc [B, T, C]`, `w [C, K]`:
-    out_t = bias + sum_j w[:, j] xbc_{t-(K-1)+j}; a tap that lies before
-    the episode's first step (`pos [B, T]`, the step inside its episode)
-    reads zero."""
-    width, t = w.shape[1], xbc.shape[1]
-    padded = jnp.pad(xbc, ((0, 0), (width - 1, 0), (0, 0)))
-    out = jnp.broadcast_to(bias, xbc.shape)
-    for j in range(width):
-        tap = jnp.where((pos >= width - 1 - j)[..., None], padded[:, j:j + t], 0.0)
-        out = out + w[:, j] * tap
-    return out
-
-
 @dataclasses.dataclass(frozen=True)
 class HybridLM:
     vocab: int
@@ -130,6 +120,7 @@ class HybridLM:
     mamba_state: int
     mamba_conv: int = 4
     mamba_chunk: int = 256
+    mamba_groups: int = 1  # G: head h reads B and C of group h // (heads / G)
     rms_eps: float = 1e-5
     embedding_multiplier: float = 12.0
     residual_multiplier: float = 0.22
@@ -151,7 +142,7 @@ class HybridLM:
 
     @property
     def conv_channels(self) -> int:
-        return self.d_inner + 2 * self.mamba_state  # x, B, C (one group)
+        return self.d_inner + 2 * self.mamba_groups * self.mamba_state  # x, B, C
 
     # -- parameters ---------------------------------------------------------
     def init(self, rng: jax.Array, *_) -> dict:
@@ -213,9 +204,13 @@ class HybridLM:
                                   self.d_inner + self.conv_channels], axis=-1)
 
     def _split_conv(self, xbc: jax.Array):
-        x, b, c = jnp.split(xbc, [self.d_inner,
-                                  self.d_inner + self.mamba_state], axis=-1)
-        return x.reshape(*x.shape[:-1], self.mamba_heads, self.mamba_head_dim), b, c
+        """-> x `[..., H, P]`, B, C `[..., N]` (one group) or `[..., G, N]`."""
+        x, b, c = jnp.split(xbc, [self.d_inner, (
+            self.d_inner + self.conv_channels) // 2], axis=-1)
+        by_group = lambda m: m if self.mamba_groups == 1 else m.reshape(
+            *m.shape[:-1], self.mamba_groups, self.mamba_state)
+        return (x.reshape(*x.shape[:-1], self.mamba_heads, self.mamba_head_dim),
+                by_group(b), by_group(c))
 
     def _step_size(self, dt: jax.Array, lp: dict) -> jax.Array:
         return jax.nn.softplus(dt + lp["dt_bias"])
@@ -225,9 +220,14 @@ class HybridLM:
         return -jnp.exp(lp["A_log"].astype(F32))
 
     def _gated_out(self, y: jax.Array, x: jax.Array, z: jax.Array, lp: dict):
-        """The skip, the gate and THEN one norm over the whole inner
-        width, and the output projection."""
+        """The skip, the gate and THEN the norm, its mean square over each
+        GROUP's `d_inner / G` channels (one group: the whole inner width),
+        and the output projection."""
         g = (y + lp["D"][:, None] * x).reshape(*z.shape) * jax.nn.silu(z)
+        if self.mamba_groups > 1:
+            groups = lambda v: v.reshape(*v.shape[:-1], self.mamba_groups, -1)
+            return self._mm(rms_norm(groups(g), groups(lp["gate_norm"]),
+                                     self.rms_eps).reshape(g.shape), lp["out_proj"])
         return self._mm(rms_norm(g, lp["gate_norm"], self.rms_eps),
                         lp["out_proj"])
 
@@ -349,10 +349,18 @@ class HybridLM:
             v.append(cache)
         return HybridState(tuple(ssm), tuple(conv), tuple(k), tuple(v))
 
-    def _decode_mamba(self, h, lp, state, window):
-        """One state-space layer of a decode step: the window shifted by
-        one, the state updated and read out -> (h', state, window)."""
-        y = rms_norm(h, lp["norms"][0], self.rms_eps)
+    def _per_head(self, m: jax.Array) -> jax.Array:
+        """A decode step's B or C, `[N, S]` or `[N, G, S]`, as every head
+        reads it: `[N, 1 or H, 1, S]`."""
+        if self.mamba_groups == 1:
+            return m[:, None, None, :]
+        return jnp.repeat(m, self.mamba_heads // self.mamba_groups, 1)[:, :, None]
+
+    def _decode_ssm(self, y, lp, state, window):
+        """The state-space mixer of a decode step on the normed rows `y`:
+        the window shifted by one, the state updated and read out, the
+        gated norm and the output projection -> (mix, state, the K taps
+        `[N, K, C]`: the last K - 1 are the next step's window)."""
         z, xbc, dt = self._split_in(self._mm(y, lp["in_proj"]))
         with jax.named_scope(scopes.ACT_SSM):
             taps = jnp.concatenate([window, xbc[:, None]], axis=1)  # [N, K, C]
@@ -361,10 +369,15 @@ class HybridLM:
             dt = self._step_size(dt, lp)
             decay = jnp.exp(dt * self._rate(lp))  # [N, H]
             state = (decay[..., None, None] * state.astype(F32)
-                     + (dt[..., None] * x)[..., None] * bmat[:, None, None, :]
+                     + (dt[..., None] * x)[..., None] * self._per_head(bmat)
                      ).astype(self.state_dtype)
-            read = jnp.sum(state.astype(F32) * cmat[:, None, None, :], axis=-1)
-        mix = self._gated_out(read, x, z, lp)
+            read = jnp.sum(state.astype(F32) * self._per_head(cmat), axis=-1)
+        return self._gated_out(read, x, z, lp), state, taps
+
+    def _decode_mamba(self, h, lp, state, window):
+        """One state-space layer of a decode step -> (h', state, window)."""
+        mix, state, taps = self._decode_ssm(
+            rms_norm(h, lp["norms"][0], self.rms_eps), lp, state, window)
         return self._mlp(self._residual(h, mix), lp), state, taps[:, 1:]
 
     def _decode_attention(self, h, lp, keys, values, t, span):
@@ -414,6 +427,21 @@ class HybridLM:
                     h, keys[i], values[i] = self._decode_attention(
                         h, lp, keys[i], values[i], t, span)
         return h, HybridState(tuple(ssm), tuple(conv), tuple(keys), tuple(values))
+
+
+def causal_conv(xbc: jax.Array, w: jax.Array, bias: jax.Array,
+                pos: jax.Array) -> jax.Array:
+    """Depthwise causal convolution over time, `xbc [B, T, C]`, `w [C, K]`:
+    out_t = bias + sum_j w[:, j] xbc_{t-(K-1)+j}; a tap that lies before
+    the episode's first step (`pos [B, T]`, the step inside its episode)
+    reads zero."""
+    width, t = w.shape[1], xbc.shape[1]
+    padded = jnp.pad(xbc, ((0, 0), (width - 1, 0), (0, 0)))
+    out = jnp.broadcast_to(bias, xbc.shape)
+    for j in range(width):
+        tap = jnp.where((pos >= width - 1 - j)[..., None], padded[:, j:j + t], 0.0)
+        out = out + w[:, j] * tap
+    return out
 
 
 def per_layer(p: dict, dtype=None, run_matrices=RUN_MATRICES) -> list:

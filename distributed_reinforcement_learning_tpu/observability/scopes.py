@@ -138,6 +138,14 @@ ACT_RING = "collect/act/ring"  # a window layer's ring: the write at t mod W, sc
 GLOBAL_ATTENTION = "learn/loss/layers/global_attention"  # the NoPE layer: q, k, v, the full causal core
 WINDOW_ATTENTION = "learn/loss/layers/window_attention"  # a window layer: q, k, v, rotary, the windowed core
 
+# The state-space / sparse-expert / attention model whose layers are one
+# sublayer each, in the same loop (models/ssm_moe_lm.py); the state-space
+# mixer's act-time scope and chunked scan, the convolution, the NoPE
+# attention layer and its cache, the expert scopes (route, held experts,
+# the UNGATED shared expert), heads, V-trace and optimizer under the names
+# above. No bump of CACHE_TAG, for the reason given there.
+ACT_ATTEND = "collect/act/attend"  # the attention layer of a decode step: q, k, v, W_o around its cache's scope
+
 IMPALA_CHUNK_SCOPES = (COLLECT, ACT, ENV, RENDER, RECORD,
                        LEARN, LOSS, VTRACE, OPTIMIZER)
 REPLAY_CHUNK_SCOPES = (COLLECT, REPLAY, LEARN)
@@ -167,6 +175,11 @@ SWA_CHUNK_SCOPES = (ENV, ACT, ACT_LAYERS, ACT_RING, ACT_CACHE, ACT_MOE_ROUTE,
                     ACT_MOE_EXPERTS, ACT_HEAD, LAYERS, GLOBAL_ATTENTION,
                     WINDOW_ATTENTION, MOE_ROUTE, MOE_EXPERTS, HEADS, LOSS_VTRACE,
                     OPTIMIZER)
+
+SSMOE_CHUNK_SCOPES = (ENV, ACT, ACT_LAYERS, ACT_SSM, ACT_ATTEND, ACT_CACHE,
+                      ACT_MOE, ACT_MOE_ROUTE, ACT_MOE_EXPERTS, ACT_HEAD, LAYERS,
+                      SSD, CONV, GLOBAL_ATTENTION, MOE_ROUTE, MOE_EXPERTS,
+                      MOE_SHARED, HEADS, LOSS_VTRACE, OPTIMIZER)
 
 # -- host spans of the fused loops (runtime/launch.py) ---------------------
 STEP_READ = "anakin/step_read"  # int(state.train.step) at the loop head

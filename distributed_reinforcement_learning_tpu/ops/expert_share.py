@@ -8,6 +8,7 @@ expert) pairs routed to them:
     (or, `scoring="sigmoid"`: s = sigmoid(x W_r), I = the top_k of s + b, w_i = c s_i / sum_{j in I} s_j)
     out(x) = sum_{i in I, first <= i < first + held} w_i E_i(x),
     E(x) = W_d (silu(W_g x) * W_u x)      (`activation="relu"`: relu(W_g x) * W_u x, ReGLU)
+    or, UNGATED (`activation="relu2"`):   E(x) = W_d relu(W_u x)^2, one up matrix `[E, D, F]`
 
 What the absent experts would have added is left out (their chips add
 it, in a deployment, through an exchange this file does not stand in
@@ -46,6 +47,9 @@ D]`, the weights contracted as they are stored, and each row's results
 are weighted by what the router gave the expert, 0 for one it did not
 choose. The same sum in another order of float32 additions, 429 us a layer
 a step where the sorted form takes 623 (`one_slab_form` has the table).
+Since PR 53 also where a width of the products is no whole number of the
+grouped product's tiles (`nemotron_h_moe`'s decode step: D 2,688, F 1,856;
+219 us where the sorted form takes 738 and follows the routing).
 
 Which work follows the pairs that are here. Until PR 42 the list was ONE
 buffer `[N x top_k, D]`: the grouped products skipped the rows past the
@@ -166,8 +170,22 @@ def _gated(activation: str, gate: jax.Array, counted: jax.Array):
     if activation == "silu":
         return jax.nn.silu(gate), None
     if activation != "relu":
-        raise ValueError(f"unknown activation {activation!r}: silu or relu")
+        raise ValueError(f"unknown activation {activation!r}: silu, relu or relu2")
     return jax.nn.relu(gate), jnp.sum(counted & (gate <= 0), dtype=jnp.int32)
+
+
+def _inner(activation: str, up: jax.Array, counted: jax.Array):
+    """What the down-projection reads, from the up-projection's result: a
+    GATED expert's (`"silu"`, `"relu"`: `up` is gate and up side by side,
+    `[..., 2 F]`) `act(gate) * up`, the UNGATED `"relu2"`'s (`[..., F]`)
+    `relu(up)^2`; and `_gated`'s count (for `"relu2"`: of the
+    up-projections that ReLU zeroed)."""
+    if activation == "relu2":
+        return (jnp.square(jax.nn.relu(up)),
+                jnp.sum(counted & (up <= 0), dtype=jnp.int32))
+    gate, up = jnp.split(up, 2, -1)
+    gate, zeroed = _gated(activation, gate, counted)
+    return gate * up, zeroed
 
 
 def _slab(rows: jax.Array, weight: jax.Array, wgu: jax.Array, wd: jax.Array,
@@ -176,7 +194,7 @@ def _slab(rows: jax.Array, weight: jax.Array, wgu: jax.Array, wd: jax.Array,
     (the pairs' tokens, operands' dtype), `weight [S]`, `sizes [held]` (the
     rows of each expert inside the slab, in order), `live_rows`: how many
     of the slab's rows are held pairs -> (`[S, D]` float32, each pair's
-    weighted expert output and 0 on the rest; `_gated`'s count)."""
+    weighted expert output and 0 on the rest; `_inner`'s count)."""
     # A row past the last group belongs to no held expert, and the grouped
     # product neither reads nor WRITES it: on the chip it holds whatever
     # the buffer held (my chip run, PR 36: finite garbage; nothing says it
@@ -184,22 +202,27 @@ def _slab(rows: jax.Array, weight: jax.Array, wgu: jax.Array, wd: jax.Array,
     # masked where it is filled and where it is read: no such row reaches
     # the result, and no cotangent of one reaches `x`.
     live = (jnp.arange(rows.shape[0]) < live_rows)[:, None]
-    gate, up = jnp.split(jax.lax.ragged_dot(
-        jnp.where(live, rows, 0), wgu, sizes, preferred_element_type=F32), 2, -1)
-    gate, zeroed = _gated(activation, gate, live)
-    y = jax.lax.ragged_dot((gate * up).astype(rows.dtype), wd, sizes,
+    inner, zeroed = _inner(activation, jax.lax.ragged_dot(
+        jnp.where(live, rows, 0), wgu, sizes, preferred_element_type=F32), live)
+    y = jax.lax.ragged_dot(inner.astype(rows.dtype), wd, sizes,
                            preferred_element_type=F32)
     return jnp.where(live, y, 0.0) * weight[:, None], zeroed
 
 
-def one_slab_form(n: int, top_k: int, num_experts: int) -> str:
+TILE = 256  # lanes of the grouped product's tile, by the readings of PR 53 below
+
+
+def one_slab_form(n: int, top_k: int, num_experts: int, widths=()) -> str:
     """`"dense"` or `"sorted"`: the form of a call of `held_experts` on `n`
     rows whose pair list is one slab, from shapes alone. Dense where (i) a
     uniform router sends every held expert MORE than one pair a call (`n x
     top_k > num_experts`), so every held expert's weights are read in
-    either form, and (ii) `n <= 256`, where `n x held` rows of product
-    still take less than the weights' read (a bfloat16 weight gives `n`
-    operations a byte, the chip's ridge is about 240). The readings, us a
+    either form, OR (PR 53) one of the products' `widths` (D, the
+    up-projection's, F) is over a tile and no whole number of tiles of
+    `TILE`, where the compiler's grouped product falls to a quarter of the
+    batched product's rate, and (ii) `n <= 256`, where `n x held` rows of
+    product still take less than the weights' read (a bfloat16 weight gives
+    `n` operations a byte, the chip's ridge is about 240). The readings, us a
     layer a step in a scan of 64 decode steps of 4 layers, bfloat16, D
     2,048, sorted | dense (my chip run, PR 47; `scripts/expert_share_bench.
     py --rows`; 302 MB of weights take 369 us at HBM's peak, 151 MB 184):
@@ -211,8 +234,16 @@ def one_slab_form(n: int, top_k: int, num_experts: int) -> str:
         16 rows, top 8 of 256, 16 held, F 768 (`joyai_flash`'s)       156 | 214
         the same at 32 / 64 rows                          249 | 217, 492 | 225
         8 rows, top 6 of 64, 16 held, F 768 (`smallthinker_moe`'s): 48 pairs
-          for 64 experts, UNDER one pair an expert: sorted by the rule (not
-          timed alone; the cell's traced run has the layer's act-time share)
+          for 64 experts, UNDER one pair an expert: sorted by the rule
+                                                          190 | 259 (PR 53)
+        UNGATED (`relu2`), 16 rows, top 6 of 128, 8 held (my chip run, PR 53;
+          the batched product streams its weights at 89 % of HBM's peak
+          whatever the widths and the routing, the grouped one does not):
+          D 2,688, F 1,856 (`nemotron_h_moe`'s: 10.5 and 7.25 tiles)  738 | 219
+            the same under a skewed router (5.3 held pairs for 6.1)  188 | 219
+            the same at 32 rows 1,132 | 220; with 16 held 1,540 | 430
+          D 2,688, F 1,792   370 | 210        D 2,688, F 2,048   289 | 241
+          D 2,560, F 1,856   275 | 208        D 2,048, F 1,536   136 | 146
         all 16 of 16 held, F 1,536, 128 / 256 / 384 / 512 rows
                               1,016 | 470, 1,171 | 482, 1,271 | 745, 1,400 | 958
 
@@ -223,23 +254,30 @@ def one_slab_form(n: int, top_k: int, num_experts: int) -> str:
     rule says sorted. The dense product is bound by the weights' read up
     to 256 rows (482 us) and by the matrix unit past it (745 at 384); it
     still beat the sorted form there with every pair held, which no caller
-    does, so the bound is the ridge and not the last win."""
-    return "dense" if n * top_k > num_experts and n <= 256 else "sorted"
+    does, so the bound is the ridge and not the last win. With widths that
+    are whole tiles the sorted form is at best level with the dense one at
+    these rows (136 | 146) unless the experts are narrow (F 768: 190 |
+    259); with a width that is not, it is 1.2 to 3.4 times slower AND its
+    time follows the routing (738 | 188 us by the router's skew alone: an
+    update's time then swings with the seed), so such shapes go dense."""
+    ragged = any(w > TILE and w % TILE for w in widths)
+    return "dense" if (n * top_k > num_experts or ragged) and n <= 256 else "sorted"
 
 
-def _form(n: int, top_k: int, held: int, num_experts: int) -> tuple[str, int]:
+def _form(n: int, top_k: int, held: int, num_experts: int,
+          widths=()) -> tuple[str, int]:
     """(`"dense"`, `"sorted"` (one slab) or `"slabs"`; the rows of a slab) of
-    a call of `held_experts` on `n` rows."""
+    a call of `held_experts` on `n` rows with products `widths` wide."""
     slab = slab_rows(n * top_k, held, num_experts)
     if slab < n * top_k:
         return "slabs", slab
-    return one_slab_form(n, top_k, num_experts), slab
+    return one_slab_form(n, top_k, num_experts, widths), slab
 
 
-def call_form(n: int, top_k: int, held: int, num_experts: int) -> str:
+def call_form(n: int, top_k: int, held: int, num_experts: int, widths=()) -> str:
     """The form and the shape of a call of `held_experts` on `n` rows, as a
     start-up line says it (`runtime/launch.py`): static, as compiled."""
-    form, slab = _form(n, top_k, held, num_experts)
+    form, slab = _form(n, top_k, held, num_experts, widths)
     return {"dense": f"dense, {n} rows x {held} held",
             "sorted": f"sorted, one slab of {slab} pairs",
             "slabs": f"sorted, {n * top_k} pairs in slabs of {slab}"}[form]
@@ -251,17 +289,16 @@ def _dense(x: jax.Array, key: jax.Array, weight: jax.Array, wgu: jax.Array,
     experts, a row's results weighted by what the router gave each (0: an
     expert the row did not choose): `x [N, D]`, `key, weight [N x top_k]`
     (`held_pairs`' experts, the router's weights) -> (`[N, D]` float32, the
-    sorted form's sum in another order of float32 additions; `_gated`'s
+    sorted form's sum in another order of float32 additions; `_inner`'s
     count over the (expert, row) the router paired)."""
     held, pairs = wgu.shape[0], (x.shape[0], -1)
     w = jnp.sum(jnp.where(
         key.reshape(pairs)[None] == jnp.arange(held)[:, None, None],
         weight.reshape(pairs)[None], 0.0), axis=-1)[..., None]  # [held, N, 1]
-    gate, up = jnp.split(jnp.einsum(
+    inner, zeroed = _inner(activation, jnp.einsum(
         "end,edf->enf", jnp.broadcast_to(x, (held, *x.shape)), wgu,
-        preferred_element_type=F32), 2, -1)
-    gate, zeroed = _gated(activation, gate, w != 0)
-    y = jnp.einsum("enf,efd->end", (gate * up).astype(x.dtype), wd,
+        preferred_element_type=F32), w != 0)
+    y = jnp.einsum("enf,efd->end", inner.astype(x.dtype), wd,
                    preferred_element_type=F32)
     # 0 x a non-finite result of an expert the row did not choose would be NaN
     return jnp.sum(jnp.where(w != 0, y, 0.0) * w, axis=0), zeroed
@@ -289,7 +326,7 @@ def _slabs(x, weight, wgu, wd, order, sizes, slab: int, top_k: int,
     """`sum_i scatter(_slab(slab i))` over the slabs that hold a held pair:
     `x [N, D]`, `wgu`, `wd` in the operands' dtype, `weight [N x top_k]`
     by flat pair index, `order [trips_max x slab]` -> (`[N, D]` float32,
-    the slabs' `_gated` counts summed: None for `"silu"`)."""
+    the slabs' `_inner` counts summed: None for `"silu"`)."""
     return _slabs_fwd(x, weight, wgu, wd, order, sizes, slab, top_k, activation)[0]
 
 
@@ -339,17 +376,20 @@ def held_experts(x: jax.Array, chosen: jax.Array, weight: jax.Array,
                  wgu: jax.Array, wd: jax.Array, first_expert: int,
                  num_experts: int, dtype=jnp.bfloat16, activation: str = "silu"):
     """`x [N, D]`, `chosen, weight [N, top_k]` (`route`'s), `wgu [held,
-    D, 2 F]` (gate and up side by side), `wd [held, F, D]`, `num_experts`:
+    D, 2 F]` (gate and up side by side; `[held, D, F]`, the one up matrix,
+    for the ungated `"relu2"`), `wd [held, F, D]`, `num_experts`:
     the router's width -> (`out [N, D]` float32: the held experts'
     weighted part of the layer's result; counters). Dropless: every held
     pair lies in exactly one slab, and every slab with one is run; the
     dense form (`one_slab_form`) runs every held expert on every row.
     `activation`: the gate's, `"silu"` or `"relu"` (ReGLU, `relu(W_g x) *
-    W_u x`), which adds the counter `gate_zeroed`: the held pairs' gate
-    values (F a pair) that ReLU zeroed."""
+    W_u x`), or `"relu2"` (ungated, `relu(W_u x)^2`); the two ReLU forms
+    add the counter `gate_zeroed`: the held pairs' gate values (`"relu2"`:
+    up-projections; F a pair) that ReLU zeroed."""
     n, top_k = chosen.shape
     held = wgu.shape[0]
-    form, slab = _form(n, top_k, held, num_experts)
+    form, slab = _form(n, top_k, held, num_experts,
+                       (wgu.shape[1], wgu.shape[2], wd.shape[1]))
     key, here = held_pairs(chosen, first_expert, held)
     if form != "dense":  # held pairs first, by expert: the flat pair index a row
         order = jnp.argsort(key, stable=True)

@@ -670,7 +670,8 @@ def _expert_calls(anakin) -> str:
         return ""
     from distributed_reinforcement_learning_tpu.ops.expert_share import call_form
 
-    act, learn = (call_form(n, model.top_k, model.experts_held, model.num_experts)
+    act, learn = (call_form(n, model.top_k, model.experts_held, model.num_experts,
+                            (model.d_model, model.expert_width))
                   for n in (anakin.num_envs, math.gcd(anakin.num_envs, model.row_block)
                             * anakin.agent.cfg.trajectory))
     return f", held experts at act time: {act}; at learn time: {learn}"

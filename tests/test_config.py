@@ -171,7 +171,8 @@ from distributed_reinforcement_learning_tpu.agents.token_families import (  # no
 
 TOKEN_SECTIONS = {"looplm": "ouro_looplm", "hybridlm": "granite_hybrid",
                   "moelm": "qwen3_next", "mlalm": "joyai_flash",
-                  "convlm": "lfm2_moe", "swalm": "smallthinker_moe"}
+                  "convlm": "lfm2_moe", "swalm": "smallthinker_moe",
+                  "ssmoelm": "nemotron_h_moe"}
 RENAMED = {"init_std": "initializer_range"}  # field -> the section's key
 
 
@@ -270,7 +271,7 @@ def test_the_shared_fields_are_written_once():
     shared = {f.name: f.default for f in dataclasses.fields(TokenLMConfig)}
     own = {"looplm": {"trajectory", "total_ut_steps", "exit_entropy_coef"},
            "hybridlm": {"rms_norm_eps"}, "moelm": set(), "mlalm": {"trajectory"},
-           "convlm": set(), "swalm": {"trajectory"}}
+           "convlm": set(), "swalm": {"trajectory"}, "ssmoelm": {"trajectory"}}
     for family, (cls, agent) in TOKEN_FAMILIES.items():
         assert issubclass(cls, TokenLMConfig)
         redefined = {name for name in shared if name in cls.__dict__.get(

@@ -614,3 +614,58 @@ def test_silu_is_the_default_and_is_what_it_was(monkeypatch, form):
     assert "logistic" not in text("relu")
     with pytest.raises(ValueError, match="unknown activation"):
         call("gelu")
+
+
+# -- the UNGATED expert (ISSUE 53): W_d relu(W_u x)^2, one up matrix ------------------
+
+def relu2_by_experts(x, chosen, weight, wu, wd, first_expert):
+    """A loop over the held experts with `relu(.) ** 2`, and the
+    up-projections that ReLU zeroed of the pairs routed to them."""
+    out, zeroed = jnp.zeros(x.shape, F32), 0
+    for e in range(wu.shape[0]):
+        up = x @ wu[e]
+        pairs = jnp.sum(chosen == first_expert + e, -1)  # a router's sets: 0 or 1
+        w = jnp.sum(jnp.where(chosen == first_expert + e, weight, 0.0), -1)
+        out = out + w[:, None] * (jax.nn.relu(up) ** 2 @ wd[e])
+        zeroed = zeroed + jnp.sum(pairs[:, None] * (up <= 0))
+    return out, zeroed
+
+
+@pytest.mark.parametrize("form", ["dense", "sorted", "slabs"])
+def test_ungated_relu2_experts_are_the_equation_in_every_form(monkeypatch, form):
+    """Value, all four gradients and the count of zeroed up-projections of
+    `held_experts(activation="relu2")` with ONE up matrix `[held, D, F]`
+    (the gate's half of the layer's `wgu`) against a loop over the held
+    experts, float32; and a gated activation on the same call is another
+    result."""
+    lay, chosen, first, experts = relu_case(form, monkeypatch)
+    wu = lay["wgu"][..., :lay["wgu"].shape[-1] // 2]
+    assert wu.shape[-1] == lay["wd"].shape[1]
+
+    def loss(fn, x, router, wu, wd):
+        out, aux = fn(x, chosen, weights({**lay, "x": x}, chosen)(router), wu, wd)
+        return jnp.sum(out * jnp.cos(
+            jnp.arange(out.size, dtype=F32).reshape(out.shape))), aux
+
+    def program(*a):
+        out, counters = expert_share.held_experts(*a, first, experts, F32, "relu2")
+        return out, counters["gate_zeroed"]
+
+    args = (lay["x"], lay["router"], wu, lay["wd"])
+    graded = lambda fn: jax.value_and_grad(
+        functools.partial(loss, fn), argnums=(0, 1, 2, 3), has_aux=True)(*args)
+    with jax.default_matmul_precision("highest"):
+        (got, zeroed), grads = graded(program)
+        (want, by_hand), want_grads = graded(
+            lambda *a: relu2_by_experts(*a, first))
+    assert abs(float(got) - float(want)) <= 1e-5 * max(1.0, abs(float(want)))
+    assert int(zeroed) == int(by_hand) > 0
+    for name, a, b in zip(("x", "router", "wu", "wd"), grads, want_grads):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert rel(a, b) < 1e-5, (name, rel(a, b))
+    w = weights(lay, chosen)(lay["router"])
+    gated, _ = expert_share.held_experts(lay["x"], chosen, w, lay["wgu"], lay["wd"],
+                                         first, experts, F32, "relu")
+    ungated, _ = expert_share.held_experts(lay["x"], chosen, w, wu, lay["wd"],
+                                           first, experts, F32, "relu2")
+    assert float(jnp.max(jnp.abs(gated - ungated))) > 1e-3

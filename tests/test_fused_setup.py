@@ -37,6 +37,7 @@ from test_anakin_r2d2 import make as make_r2d2
 from test_granite_hybrid import CFG as HYBRID_CFG
 from test_joyai_flash import CFG as MLA_CFG
 from test_lfm2_moe import CFG as CONV_CFG
+from test_nemotron_h_moe import CFG as SSMOE_CFG
 from test_ouro_looplm import CFG as LOOP_CFG
 from test_qwen3_next import CFG as MOE_CFG
 from test_smallthinker_moe import CFG as SWA_CFG
@@ -47,6 +48,7 @@ from distributed_reinforcement_learning_tpu.agents.impala import ImpalaAgent
 from distributed_reinforcement_learning_tpu.agents.looplm import LoopLMAgent
 from distributed_reinforcement_learning_tpu.agents.mlalm import MLALMAgent
 from distributed_reinforcement_learning_tpu.agents.moelm import MoELMAgent
+from distributed_reinforcement_learning_tpu.agents.ssmoelm import SSMoELMAgent
 from distributed_reinforcement_learning_tpu.agents.swalm import SwaLMAgent
 from distributed_reinforcement_learning_tpu.envs import breakout_jax
 from distributed_reinforcement_learning_tpu.envs.token_recall_jax import TokenRecall
@@ -132,6 +134,8 @@ LOOPS = {
     "convlm": (_tokens(ConvLMAgent, CONV_CFG), 1, (765, 38, 38), 1908),
     # read at PR 49's own tree: the family is new there
     "swalm": (_tokens(SwaLMAgent, SWA_CFG), 1, (585, 30, 30), 1742),
+    # read at PR 53's own tree: the family is new there
+    "ssmoelm": (_tokens(SSMoELMAgent, SSMOE_CFG), 1, (687, 34, 34), 2029),
 }
 
 
@@ -203,6 +207,7 @@ _SMALL = {
         sliding_window_size=8, moe_num_primary_experts=4, router_width=16,
         first_expert=4, moe_num_active_primary_experts=3, moe_ffn_hidden_size=16,
         vocab_size=96, available_action=[96], trajectory=32),
+    "nemotron_h_moe_small": dict(vocab_size=96, available_action=[96]),
 }
 
 

@@ -128,6 +128,20 @@ def program_out(agent, params):
     return _program(agent, params, seeded_batch(0))
 
 
+def _logits(agent, params, nb):
+    """The forward alone: what a planted fault is read from (`_program`
+    compiles the loss, its gradients and a second forward beside it)."""
+    model = agent.model
+
+    def run(p):
+        hs, _ = model.apply(p, jnp.asarray(nb["tokens"]), jnp.asarray(nb["done"]),
+                            method=model.trunk)
+        return model.apply(p, hs, method=model.logits)[0]
+
+    with jax.default_matmul_precision("highest"):
+        return jax.device_get(jax.jit(run)(params))
+
+
 # -- the chunked scan against the step-by-step recurrence ----------------------
 
 
@@ -197,6 +211,72 @@ def test_dropping_the_carried_state_is_seen():
     assert np.abs(whole[:, 8:] - halves[:, 8:]).max() > 1e-2
 
 
+def _grouped_inputs(seed, steps, boundary, groups=4):
+    """`_scan_inputs` with 8 heads in `groups` groups of B and C."""
+    r = np.random.RandomState(seed)
+    seg, start = _scan_inputs(seed, steps, boundary)[-2:]
+    b, n = seg.shape[0], 5
+    normal = lambda *shape: jnp.asarray(r.normal(size=shape), jnp.float32)
+    return (normal(b, steps, 8, 4),
+            jnp.asarray(r.uniform(0.01, 0.5, size=(b, steps, 8)), jnp.float32),
+            -jnp.asarray(r.uniform(0.5, 4.0, size=(8,)), jnp.float32),
+            normal(b, steps, groups, n), normal(b, steps, groups, n), seg, start)
+
+
+@pytest.mark.parametrize("boundary", [None, 10, 7],
+                         ids=["one_episode", "inside_a_chunk", "chunk_end"])
+def test_grouped_scan_equals_the_recurrence_forward_and_backward(boundary):
+    """Eight heads in FOUR B/C groups (head h reads group h // 2) over
+    three chunks of 8 against `reference/nemotron_h_moe.py`'s step-by-step
+    recurrence, values, the last state and all five gradients (ISSUE 53)."""
+    from distributed_reinforcement_learning_tpu.reference import nemotron_h_moe
+
+    x, dt, a, bmat, cmat, seg, start = _grouped_inputs(5, 24, boundary)
+    weight = jnp.asarray(np.random.RandomState(9).normal(size=x.shape), jnp.float32)
+
+    def chunked(x, dt, a, bmat, cmat):
+        y, state = ssd.ssd_chunked(x, dt, a, bmat, cmat, seg, 8, jnp.float32)
+        return jnp.sum(y * weight) + jnp.sum(state), (y, state)
+
+    def stepwise(x, dt, a, bmat, cmat):
+        y, state = nemotron_h_moe.recurrence(x, dt, a, bmat, cmat, start)
+        return jnp.sum(y * weight) + jnp.sum(state), (y, state)
+
+    with jax.default_matmul_precision("highest"):
+        (_, (y, state)), grads = jax.value_and_grad(
+            chunked, argnums=(0, 1, 2, 3, 4), has_aux=True)(x, dt, a, bmat, cmat)
+        (_, (y_ref, state_ref)), grads_ref = jax.value_and_grad(
+            stepwise, argnums=(0, 1, 2, 3, 4), has_aux=True)(x, dt, a, bmat, cmat)
+    np.testing.assert_allclose(y, y_ref, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(state, state_ref, atol=2e-5, rtol=2e-5)
+    for g, g_ref in zip(grads, grads_ref):
+        np.testing.assert_allclose(g, g_ref, atol=1e-4, rtol=1e-4)
+
+
+def test_one_group_is_the_ungrouped_call_and_a_wrong_grouping_is_seen():
+    """`bmat, cmat [B, T, N]` (granite's call) traces to a program with no
+    group axis in it, and returns what `[B, T, 1, N]` returns; heads
+    reading group h % G, or group 0 for every head, are another result."""
+    x, dt, a, bmat, cmat, seg, _ = _scan_inputs(3, 16, 6)
+    run = lambda b, c, x=x, dt=dt, a=a: ssd.ssd_chunked(
+        x, dt, a, b, c, seg, 8, jnp.float32)
+    text = str(jax.make_jaxpr(lambda: run(bmat, cmat))())
+    assert "bgij" not in text and f"{x.shape[0]},1,{bmat.shape[-1]}" not in text
+    for got, want in zip(run(bmat[:, :, None], cmat[:, :, None]), run(bmat, cmat)):
+        np.testing.assert_allclose(got, want, atol=1e-6)
+    x, dt, a, bmat, cmat, seg, _ = _grouped_inputs(5, 16, 6)
+    right, _ = run(bmat, cmat, x, dt, a)
+    modulo = jnp.tile(bmat, (1, 1, 2, 1))  # eight groups of one head: h -> h % 4
+    for wrong in (modulo, jnp.repeat(bmat[:, :, :1], 8, 2)):
+        got, _ = run(wrong, jnp.repeat(cmat, 2, 2), x, dt, a)
+        assert float(jnp.abs(got - right).max()) > 1e-2
+    np.testing.assert_allclose(
+        run(jnp.repeat(bmat, 2, 2), jnp.repeat(cmat, 2, 2), x, dt, a)[0], right,
+        atol=1e-5)  # eight groups of one head, each a copy of its group's: the same
+    with pytest.raises(ValueError, match="whole groups"):
+        run(bmat[:, :, :3], cmat[:, :, :3], x, dt, a)
+
+
 # -- the whole model and the loss against the reference -------------------------
 
 
@@ -260,9 +340,9 @@ def test_each_multiplier_planted_wrong_is_seen(params, reference_out, name):
     """Set to 1, each of the four published scalars moves the logits by
     orders more than the 2e-5 the right program is held to."""
     wrong = HybridLMAgent(dataclasses.replace(CFG, **{name: 1.0}))
-    out = _program(wrong, params, seeded_batch(0))
+    out = _logits(wrong, params, seeded_batch(0))
     scale = np.abs(reference_out["logits"]).max()
-    assert np.abs(out["logits"] - reference_out["logits"]).max() / scale > 1e-2
+    assert np.abs(out - reference_out["logits"]).max() / scale > 1e-2
 
 
 class _Wrong(hybrid_lm.HybridLM):
@@ -307,9 +387,9 @@ def _wrong_agent(fault, **replace):
 @pytest.mark.parametrize("fault", ["decay_sign", "dt_without_bias",
                                    "gate_after_norm"])
 def test_a_wrong_state_space_layer_is_seen(params, reference_out, fault):
-    out = _program(_wrong_agent(fault), params, seeded_batch(0))
+    out = _logits(_wrong_agent(fault), params, seeded_batch(0))
     scale = np.abs(reference_out["logits"]).max()
-    assert np.abs(out["logits"] - reference_out["logits"]).max() / scale > 1e-3
+    assert np.abs(out - reference_out["logits"]).max() / scale > 1e-3
 
 
 # -- acting as decode through the three kinds of state ---------------------------

@@ -12,7 +12,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PROGRAMS = os.path.join(ROOT, "distributed_reinforcement_learning_tpu", "reference")
 BENCHMARKS = os.path.join(ROOT, "perfbench", "references")
-NAMES = ["smallthinker_moe.py", "lfm2_moe.py", "joyai_flash.py", "qwen3_next.py", "granite_hybrid.py",
+NAMES = ["nemotron_h_moe.py", "smallthinker_moe.py", "lfm2_moe.py", "joyai_flash.py", "qwen3_next.py", "granite_hybrid.py",
          "ouro_looplm.py", "r2d2_atari.py"]
 
 

@@ -376,6 +376,62 @@ def test_every_equation_of_the_convolution_mixer_is_under_its_name():
     assert not _equations_under(jaxpr, "collect/act/gdn")
 
 
+def _ssmoe_anakin():
+    """The small Nemotron-H loop (section `nemotron_h_moe_small`: `ME*ME`)."""
+    import dataclasses
+
+    from distributed_reinforcement_learning_tpu.agents.ssmoelm import SSMoELMAgent
+    from distributed_reinforcement_learning_tpu.envs.token_recall_jax import TokenRecall
+    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import AnakinTokens
+    from distributed_reinforcement_learning_tpu.utils.config import load_config
+
+    cfg = dataclasses.replace(load_config("config.json", "nemotron_h_moe_small")[0],
+                              trajectory=16, head_block=16, row_block=2)
+    return AnakinTokens(SSMoELMAgent(cfg), 4, TokenRecall(64, 16))
+
+
+@pytest.fixture(scope="module")
+def ssmoe_chunk():
+    anakin = _ssmoe_anakin()
+    state = anakin.init(jax.random.PRNGKey(0))
+    jaxpr = jax.make_jaxpr(anakin.train_chunk, static_argnums=1)(
+        jax.eval_shape(lambda: state), 1).jaxpr
+    return _op_names(anakin.train_chunk, state, 1), jaxpr
+
+
+@pytest.mark.parametrize("name", scopes.SSMOE_CHUNK_SCOPES)
+def test_ssmoe_chunk_carries_scope(ssmoe_chunk, name):
+    """The names `perfbench/layer_metrics/ssmoelm_*` read (ISSUE 53)."""
+    assert any(name in n for n in ssmoe_chunk[0]), name
+
+
+def test_a_layer_of_one_sublayer_has_its_sublayers_name_alone(ssmoe_chunk):
+    """Two state-space layers, two expert layers and one attention layer a
+    decode body: the state update and its read-out under `collect/act/ssm`,
+    the cache's write under `collect/act/cache` inside `collect/act/attend`,
+    the router's product and top-k under the route's name, the grouped
+    products under the experts', the shared expert's two products under
+    its own; the learner's scan, convolution, attention, route, experts and
+    shared expert each under its own name, and the backward re-enters them."""
+    names, jaxpr = ssmoe_chunk
+    bodies = 1  # 16 steps: one scan
+    assert _equations_under(jaxpr, scopes.ACT_CACHE).count(
+        "dynamic_update_slice") == 2 * bodies
+    assert "dot_general" in _equations_under(jaxpr, scopes.ACT_ATTEND)
+    assert _equations_under(jaxpr, scopes.ACT_SSM).count("exp") >= 2 * bodies
+    for route in (scopes.ACT_MOE_ROUTE, scopes.MOE_ROUTE):
+        found = _equations_under(jaxpr, route)
+        assert "dot_general" in found and "top_k" in found and "logistic" in found
+    assert any("ragged_dot" in p for p in _equations_under(jaxpr, scopes.ACT_MOE_EXPERTS))
+    assert _equations_under(jaxpr, scopes.MOE_SHARED).count("dot_general") >= 2
+    assert "cumsum" in _equations_under(jaxpr, scopes.SSD)
+    assert not any("ragged_dot" in p for p in _equations_under(jaxpr, scopes.SSD))
+    for name in (scopes.LAYERS, scopes.SSD, scopes.CONV, scopes.GLOBAL_ATTENTION,
+                 scopes.MOE_ROUTE, scopes.MOE_EXPERTS, scopes.MOE_SHARED):
+        assert any(f"transpose(jvp({scopes.LOSS}))" in n and name in n
+                   for n in names), name
+
+
 def _swa_anakin(trajectory=16, row_block=2):
     """The small SmallThinker loop: 4 rows of `trajectory` tokens, a window
     of 8."""

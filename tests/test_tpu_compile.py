@@ -5,7 +5,7 @@ described, not opened (`/opt/skills/guides/on-chip-measurement`, section
 2). That catches what interpret mode cannot — a slice not aligned to the
 tiling, a kernel over its VMEM budget, a program Mosaic refuses — at the
 shapes the main path really runs (`config.json` sections `impala`,
-`apex`, `r2d2_pixel`, `r2d2_atari`, `ouro_looplm`, `granite_hybrid`, `qwen3_next`, `joyai_flash`, `lfm2_moe`, `smallthinker_moe`; the Anakin chunk
+`apex`, `r2d2_pixel`, `r2d2_atari`, `ouro_looplm`, `granite_hybrid`, `qwen3_next`, `joyai_flash`, `lfm2_moe`, `smallthinker_moe`, `nemotron_h_moe`; the Anakin chunk
 `chip_smoke.py` drives), and
 costs no chip time. It also shows what the compiler DID with a program:
 which layout copies and which collectives it put in (the fused IMPALA
@@ -682,6 +682,64 @@ def test_smallthinker_moe_chunk_fits_and_holds_its_eight_kernels(chip, kernels_a
     assert not re.findall(r"bf16\[8,\d{4},28,128\]", text)  # no cache of the query heads
     # no array with the router's width AND a capacity beside the tokens
     assert not re.findall(r"\[8192,64,\d+\]|\[65536,64,\d+\]", text)
+
+
+@pytest.mark.slow  # minutes, in the file that ends tier-1's run: before a chip call
+def test_nemotron_h_moe_chunk_fits_and_holds_its_kernels(chip, kernels_as_on_chip):
+    """The fused token chunk at the `nemotron_h_moe` section's sizes (16
+    envs x 2,048 tokens; layers 0-8 of Nemotron-3-Nano-30B-A3B's order at
+    2688 wide, `MEMEM*EME`: four Mamba-2 mixers with eight B/C groups, four
+    128-way routers over 8 held relu^2 experts beside a shared expert, one
+    NoPE attention layer of 32 / 2 heads of 128, an untied head; chunk of
+    1): it compiles for a described v5e and the donated state (667.0 M
+    parameters + their second moments, 8 B each) is aliased whole. The
+    Mosaic kernels in the LOWERED chunk are the configuration file's count:
+    flash attention in the ONE attention layer (forward, rematerialised
+    forward, dq, dkv) and V-trace's two views. The act-time state is four
+    float32 recurrent states, four windows, ONE cache of the two key/value
+    heads and the route record."""
+    import json
+
+    from distributed_reinforcement_learning_tpu.agents.ssmoelm import SSMoELMAgent
+    from distributed_reinforcement_learning_tpu.envs.registry import (
+        make_jittable_env)
+    from distributed_reinforcement_learning_tpu.runtime.anakin_tokens import (
+        AnakinTokens)
+
+    cfg, rt = load_config(CONFIG, "nemotron_h_moe")
+    env = make_jittable_env(rt.envs[0], vocab=cfg.vocab_size,
+                            episode_len=cfg.trajectory,
+                            distance=cfg.recall_distance)
+    anakin = AnakinTokens(SSMoELMAgent(cfg), rt.num_actors * rt.envs_per_actor, env)
+    state = jax.eval_shape(anakin.init, jax.random.PRNGKey(0))
+    lowered = anakin.train_chunk.lower(_on(chip, state), 1)
+    with open(os.path.join(os.path.dirname(CONFIG), "perfbench", "configs",
+                           "nemotron_h_moe.json")) as f:
+        named = json.load(f)["kernels"]["tpu_custom_call"]
+    assert len(re.findall("tpu_custom_call", lowered.as_text())) == named == 6
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    params = 666_965_633
+    assert mem.alias_size_in_bytes == mem.argument_size_in_bytes > 8 * params
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(f"nemotron_h_moe chunk: {held / 1e9:.2f} GB held, arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.2f}, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.2f}")
+    assert held < 19.2e9, held
+    facts = anakin.static_facts
+    assert facts["layer_order"] == "MEMEM*EME"
+    assert facts["ssm_state_bytes"] == 4 * 16 * 64 * 64 * 128 * 4 == 134_217_728
+    assert facts["conv_state_bytes"] == 4 * 16 * 3 * 6144 * 4
+    assert facts["kv_cache_bytes"] == 2 * 16 * 2048 * 2 * 128 * 2 == 33_554_432
+    assert (facts["experts_held"], facts["router_width"]) == (8, 128)
+    text = compiled.as_text()
+    assert re.findall(r"f32\[16,64,64,128\]", text)  # the recurrent state, float32
+    assert not re.findall(r"bf16\[16,64,64,128\]", text)
+    assert re.findall(r"bf16\[16,2048,2,128\]", text)  # the cache: two heads
+    assert not re.findall(r"bf16\[16,\d{4},32,128\]", text)  # none of the query heads
+    # no array with the router's width AND a capacity beside the tokens
+    assert not re.findall(r"\[8192,128,\d+\]|\[32768,128,\d+\]", text)
 
 
 def _breakout_step_text(chip, n: int) -> str:

@@ -20,6 +20,7 @@ the learn step in one compiled chunk. The families are the rows of
     python train_ximpala.py --mode anakin --section joyai_flash --updates 2 --anakin_chunk 1
     python train_ximpala.py --mode anakin --section lfm2_moe --updates 2 --anakin_chunk 1
     python train_ximpala.py --mode anakin --section smallthinker_moe --updates 2 --anakin_chunk 1
+    python train_ximpala.py --mode anakin --section nemotron_h_moe --updates 2 --anakin_chunk 1
 
 Adding a token family: its model file (`models/`), its agent file with
 its config class (`agents/`, a subclass of `agents/looplm.TokenLMConfig`
