@@ -299,41 +299,69 @@ def _graded_jaxpr(n, top_k, experts, held, d, width):
 
 @pytest.mark.parametrize("n,top_k,experts,held", [(16, 8, 256, 16), (32, 10, 512, 32)])
 def test_a_decode_steps_list_is_one_slab_and_no_loop(n, top_k, experts, held):
+    """At experts 64 x 32 wide: the sorted one-slab form."""
     names = {name for name, _ in _primitives(_graded_jaxpr(n, top_k, experts, held, 64, 32))}
     assert "while" not in names and "ragged_dot_general" in names
     assert not any("custom_vjp" in name for name in names)
 
 
-# The rule's table (ISSUE 47): the callers of `held_experts` in the three
-# expert cells, (rows, top_k, router width, held) -> the form of the call.
-RULE = {"lfm2_decode": ((64, 4, 64, 16), "dense", "dense, 64 rows x 16 held"),
-        "qwen3_decode": ((32, 10, 512, 32), "sorted", "sorted, one slab of 320 pairs"),
-        "joyai_decode": ((16, 8, 256, 16), "sorted", "sorted, one slab of 128 pairs"),
-        "lfm2_learner": ((4096, 4, 64, 16), "slabs", "sorted, 16384 pairs in slabs of 5120"),
-        "qwen3_learner": ((4096, 10, 512, 32), "slabs", "sorted, 40960 pairs in slabs of 3584"),
-        "joyai_learner": ((4096, 8, 256, 16), "slabs", "sorted, 32768 pairs in slabs of 2560"),
-        # ISSUE 49: 48 pairs for 64 experts, under one pair an expert: sorted
-        "smallthinker_decode": ((8, 6, 64, 16), "sorted", "sorted, one slab of 48 pairs"),
-        "smallthinker_learner": ((8192, 6, 64, 16), "slabs",
-                                 "sorted, 49152 pairs in slabs of 15360")}
+# The rule's table (ISSUE 47; the touched form: ISSUE 54): the callers of
+# `held_experts` in the six expert cells, (rows, top_k, router width, held,
+# (D, F): the experts' widths) -> the form of the call.
+RULE = {"lfm2_decode": ((64, 4, 64, 16, (2048, 1536)), "dense", "dense, 64 rows x 16 held"),
+        # under one pair an expert, but 1.05 M weights a block: too small for a trip
+        "qwen3_decode": ((32, 10, 512, 32, (2048, 512)), "sorted",
+                         "sorted, one slab of 320 pairs"),
+        "joyai_decode": ((16, 8, 256, 16, (2048, 768)), "touched",
+                         "touched, 16 rows x up to 16 held"),
+        "lfm2_learner": ((4096, 4, 64, 16, (2048, 1536)), "slabs",
+                         "sorted, 16384 pairs in slabs of 5120"),
+        "qwen3_learner": ((4096, 10, 512, 32, (2048, 512)), "slabs",
+                          "sorted, 40960 pairs in slabs of 3584"),
+        "joyai_learner": ((4096, 8, 256, 16, (2048, 768)), "slabs",
+                          "sorted, 32768 pairs in slabs of 2560"),
+        # ISSUE 49: 48 pairs for 64 experts, under one pair an expert
+        "smallthinker_decode": ((8, 6, 64, 16, (2560, 768)), "touched",
+                                "touched, 8 rows x up to 16 held"),
+        "smallthinker_learner": ((8192, 6, 64, 16, (2560, 768)), "slabs",
+                                 "sorted, 49152 pairs in slabs of 15360"),
+        # ISSUE 53: 96 pairs for 128 experts (dense until ISSUE 54, by its widths)
+        "nemotron_decode": ((16, 6, 128, 8, (2688, 1856)), "touched",
+                            "touched, 16 rows x up to 8 held"),
+        "nemotron_learner": ((8192, 6, 128, 8, (2688, 1856)), "slabs",
+                             "sorted, 49152 pairs in slabs of 4096"),
+        # AT one pair an expert: touched; one row more: dense; past 256 rows: sorted;
+        # widths not given: as if large; the tests' own small experts: sorted
+        "at_one_pair": ((16, 4, 64, 16, (2048, 1536)), "touched",
+                        "touched, 16 rows x up to 16 held"),
+        "over_one_pair": ((17, 4, 64, 16, (2048, 1536)), "dense", "dense, 17 rows x 16 held"),
+        "past_the_row_bound": ((257, 1, 1024, 512, (2048, 1536)), "sorted",
+                               "sorted, one slab of 257 pairs"),
+        "widths_not_given": ((8, 6, 64, 16, ()), "touched", "touched, 8 rows x up to 16 held"),
+        "small_experts": ((8, 6, 64, 16, (64, 32)), "sorted", "sorted, one slab of 48 pairs")}
 
 
 @pytest.mark.parametrize("shape,form,said", RULE.values(), ids=RULE.keys())
-def test_the_form_follows_the_shapes_as_the_rules_table_says(shape, form, said):
-    """Forward and backward of a call at each caller's rows: the dense form
-    has no grouped product, no sort and no scatter-add (and no loop); the
-    sorted one-slab form all three and no loop; a learner's call the loop."""
-    n, top_k, experts, held = shape
-    assert expert_share.call_form(n, top_k, held, experts) == said
+def test_the_form_follows_the_shapes_as_the_rules_table_says(monkeypatch, shape, form, said):
+    """Forward and backward of a call at each caller's rows (64 x 32 wide
+    here, so the rule is asked with the caller's widths and the call made in
+    its answer): the dense form has no grouped product, no sort and no
+    scatter-add (and no loop); the touched form none of the three either,
+    and a loop (over the experts some row chose); the sorted one-slab form
+    all three and no loop; a learner's call the loop over slabs."""
+    n, top_k, experts, held, widths = shape
+    assert expert_share.call_form(n, top_k, held, experts, widths) == said
     one_slab = expert_share.slab_rows(n * top_k, held, experts) == n * top_k
     assert one_slab == (form != "slabs")
     if one_slab:
-        assert expert_share.one_slab_form(n, top_k, experts) == form
+        assert expert_share.one_slab_form(n, top_k, experts, widths) == form
+        monkeypatch.setattr(expert_share, "one_slab_form", lambda *_: form)
     names = {name for name, _ in _primitives(_graded_jaxpr(n, top_k, experts, held, 64, 32))}
     sorted_forms = {"ragged_dot_general", "sort", "scatter-add"}
-    if form == "dense":
-        assert not names & (sorted_forms | {"while"}), names
+    if form in ("dense", "touched"):
+        assert not names & sorted_forms, names
         assert "dot_general" in names
+        assert ("while" in names) == (form == "touched")
     else:
         assert sorted_forms <= names
         assert ("while" in names) == (form == "slabs")
@@ -421,10 +449,15 @@ def by_experts(x, chosen, weight, wgu, wd, first_expert):
     return out
 
 
-def sorted_form(monkeypatch, fn, *args):
+def forced(monkeypatch, form, fn, *args):
+    """`fn(*args)` with the rule answering `form`."""
     with monkeypatch.context() as m:
-        m.setattr(expert_share, "one_slab_form", lambda *_: "sorted")
+        m.setattr(expert_share, "one_slab_form", lambda *_: form)
         return fn(*args)
+
+
+def sorted_form(monkeypatch, fn, *args):
+    return forced(monkeypatch, "sorted", fn, *args)
 
 
 def dense_case(seed, count=None):
@@ -436,23 +469,30 @@ def dense_case(seed, count=None):
     return lay, chosen
 
 
-def dense_graded(lay, chosen, dtype):
+def graded_of(lay, chosen, dtype, first, experts, activation="silu"):
+    """(value and four gradients of a version, the program, the equation:
+    a plain loop over the held experts, `EQUATIONS`)."""
     def loss(fn, x, router, wgu, wd):
         out = fn(x, chosen, weights({**lay, "x": x}, chosen)(router), wgu, wd)
         return jnp.sum(out * jnp.cos(jnp.arange(out.size, dtype=F32).reshape(out.shape)))
 
-    program = lambda *a: expert_share.held_experts(*a, DFIRST, DE, dtype)[0]
-    equation = lambda *a: by_experts(*a, DFIRST)
+    program = lambda *a: expert_share.held_experts(*a, first, experts, dtype, activation)[0]
+    equation = lambda *a: EQUATIONS[activation](*a, first)[0]
     args = (lay["x"], lay["router"], lay["wgu"], lay["wd"])
     graded = lambda fn: jax.value_and_grad(functools.partial(loss, fn),
                                            argnums=(0, 1, 2, 3))(*args)
     return graded, program, equation
 
 
+def dense_graded(lay, chosen, dtype):
+    return graded_of(lay, chosen, dtype, DFIRST, DE)
+
+
 def test_the_rule_takes_the_dense_form_at_the_dense_tests_shape():
     assert expert_share.slab_rows(DN * TOP_K, DHELD, DE) == DN * TOP_K
     assert expert_share.one_slab_form(DN, TOP_K, DE) == "dense"
-    assert expert_share.one_slab_form(4, TOP_K, DE) == "sorted"  # ONE pair an expert
+    assert expert_share.one_slab_form(4, TOP_K, DE) == "touched"  # ONE pair an expert
+    assert expert_share.one_slab_form(4, TOP_K, DE, (D, 2 * WIDTH, WIDTH)) == "sorted"  # small
     assert expert_share.one_slab_form(5, TOP_K, DE) == "dense"
     assert expert_share.one_slab_form(256, TOP_K, DE) == "dense"  # the row bound
     assert expert_share.one_slab_form(257, TOP_K, DE) == "sorted"
@@ -669,3 +709,213 @@ def test_ungated_relu2_experts_are_the_equation_in_every_form(monkeypatch, form)
     ungated, _ = expert_share.held_experts(lay["x"], chosen, w, wu, lay["wd"],
                                            first, experts, F32, "relu2")
     assert float(jnp.max(jnp.abs(gated - ungated))) > 1e-3
+
+
+# -- the one-slab path's touched form (ISSUE 54) ------------------------------------
+# 8 rows x 4 choices of 32 experts, 8 held from expert 4 on: 32 pairs, one
+# slab, ONE pair an expert from a uniform router: the rule takes the touched
+# form for experts of a cell's size and the sorted one for these (32 x 16
+# wide), so every test below runs with the rule answering "touched"
+# (`touched_form`); `forced` is a call with it answering another form.
+TN, TE, THELD, TFIRST = 8, 32, 8, 4
+
+
+@pytest.fixture
+def touched_form(monkeypatch):
+    monkeypatch.setattr(expert_share, "one_slab_form", lambda *_: "touched")
+EQUATIONS = {"silu": lambda *a: (by_experts(*a), None), "relu": relu_by_experts,
+             "relu2": relu2_by_experts}
+
+
+def touched_case(seed, activation="silu", count=None):
+    """(layer, chosen): the router's own sets (`count` None: some held
+    experts touched and some not) or exactly `count` held pairs."""
+    lay = layer(seed, n=TN, experts=TE, held=THELD)
+    if activation == "relu2":  # one up matrix: the gate's half
+        lay["wgu"] = lay["wgu"][..., :WIDTH]
+    if count is None:
+        chosen = jax.lax.top_k(lay["x"] @ lay["router"], TOP_K)[1].astype(jnp.int32)
+    else:
+        chosen = choices(count, n=TN, experts=TE, held=THELD, first=TFIRST, seed=seed)
+    return lay, chosen
+
+
+def touched_graded(lay, chosen, dtype, activation):
+    return graded_of(lay, chosen, dtype, TFIRST, TE, activation)
+
+
+def test_the_rule_takes_the_touched_form_at_the_touched_tests_rows():
+    """By the rows and the pairs; and by the experts' size: `D x F` of
+    `TRIP_WEIGHTS` (1.31 M) and more, or widths not given."""
+    assert expert_share.slab_rows(TN * TOP_K, THELD, TE) == TN * TOP_K
+    assert expert_share.one_slab_form(TN, TOP_K, TE) == "touched"
+    assert expert_share.one_slab_form(TN + 1, TOP_K, TE) == "dense"
+    assert expert_share.call_form(TN, TOP_K, THELD, TE) == "touched, 8 rows x up to 8 held"
+    assert expert_share.one_slab_form(256, 4, 1024) == "touched"  # the row bound
+    assert expert_share.one_slab_form(257, 4, 2048) == "sorted"
+    assert expert_share.TRIP_WEIGHTS == 1_310_720
+    for widths, form in (((2048, 1536, 768), "touched"), ((2688, 1856, 1856), "touched"),
+                         ((2560, 768), "touched"), ((2048, 640), "touched"),
+                         ((2048, 1024, 512), "sorted"), ((2048, 512), "sorted"),
+                         ((D, 2 * WIDTH, WIDTH), "sorted")):
+        assert expert_share.one_slab_form(TN, TOP_K, TE, widths) == form, widths
+        assert expert_share.one_slab_form(TN + 1, TOP_K, TE, widths) == "dense"
+
+
+@pytest.mark.parametrize("activation", ["silu", "relu", "relu2"])
+@pytest.mark.parametrize("count", [None, 1, TN * TOP_K], ids=["routed", "one", "every_pair"])
+def test_the_touched_form_is_the_equation_and_the_sorted_form_in_float32(
+        monkeypatch, count, activation, touched_form):
+    """Value and all four gradients (x, the router through the pairs'
+    weights, wgu, wd) against a plain loop over the held experts."""
+    lay, chosen = touched_case(40, activation, count)
+    graded, program, equation = touched_graded(lay, chosen, F32, activation)
+    with jax.default_matmul_precision("highest"):
+        touched = graded(program)
+        by_sort = forced(monkeypatch, "sorted", graded, program)
+        want = graded(equation)
+    for got in (touched, by_sort):
+        assert abs(float(got[0]) - float(want[0])) <= 1e-5 * max(1.0, abs(float(want[0])))
+        for name, a, b in zip(("x", "router", "wgu", "wd"), got[1], want[1]):
+            assert bool(jnp.all(jnp.isfinite(a))), name
+            assert rel(a, b) < 1e-5, (name, rel(a, b))
+
+
+@pytest.mark.parametrize("activation", ["silu", "relu", "relu2"])
+def test_the_touched_form_in_bfloat16_is_inside_the_cells_limits(monkeypatch, activation, touched_form):
+    """Against the dense form in the SAME dtype (the same products of the
+    same operands; the touched form leaves out terms that are exactly 0)
+    under the dense form's own tolerance, and against the float32 equation
+    under 2e-2."""
+    lay, chosen = touched_case(41, activation)
+    graded, program, equation = touched_graded(lay, chosen, jnp.bfloat16, activation)
+    touched = graded(program)
+    dense = forced(monkeypatch, "dense", graded, program)
+    with jax.default_matmul_precision("highest"):
+        want = graded(equation)
+    assert abs(float(touched[0]) - float(dense[0])) <= 5e-3 * max(1.0, abs(float(dense[0])))
+    for name, a, b, c in zip(("x", "router", "wgu", "wd"), touched[1], dense[1], want[1]):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert rel(a, b) < 5e-3, (name, rel(a, b))
+        assert rel(a, c) < 2e-2, (name, rel(a, c))
+
+
+@pytest.mark.parametrize("activation", ["silu", "relu2"])
+def test_the_touched_form_with_no_held_pair_makes_no_trip(activation, touched_form):
+    """No row chose a held expert: zeros out, zero gradients, all finite,
+    and no expert counted."""
+    lay, chosen = touched_case(42, activation, 0)
+    graded, program, _ = touched_graded(lay, chosen, F32, activation)
+    value, grads = graded(program)
+    assert float(value) == 0.0
+    for name, g in zip(("x", "router", "wgu", "wd"), grads):
+        assert bool(jnp.all(jnp.isfinite(g))) and not np.any(np.asarray(g)), name
+    _, counters = expert_share.held_experts(
+        lay["x"], chosen, weights(lay, chosen)(lay["router"]), lay["wgu"], lay["wd"],
+        TFIRST, TE, F32, activation)
+    assert int(counters["touched_experts"]) == 0 == int(counters["held_pairs"])
+
+
+def test_the_touched_form_with_every_pair_on_one_expert_makes_one_trip(touched_form):
+    """Every choice of every row is held expert 2: one trip; the gradients
+    of the seven experts no row chose are exactly 0, and the result is the
+    equation's."""
+    lay, _ = touched_case(43)
+    chosen = jnp.full((TN, TOP_K), TFIRST + 2, jnp.int32)
+    graded, program, equation = touched_graded(lay, chosen, F32, "silu")
+    with jax.default_matmul_precision("highest"):
+        got, want = graded(program), graded(equation)
+    assert abs(float(got[0]) - float(want[0])) <= 1e-5 * max(1.0, abs(float(want[0])))
+    for name, a, b in zip(("x", "router", "wgu", "wd"), got[1], want[1]):
+        if name == "router":  # a row's four weights are 1/4 whatever the router says
+            assert float(jnp.max(jnp.abs(a))) < 1e-6 > float(jnp.max(jnp.abs(b)))
+        else:
+            assert rel(a, b) < 1e-5, (name, rel(a, b))
+    others = np.arange(THELD) != 2
+    assert not np.any(np.asarray(got[1][2])[others]) and np.any(np.asarray(got[1][2])[2])
+    assert not np.any(np.asarray(got[1][3])[others]) and np.any(np.asarray(got[1][3])[2])
+    _, counters = expert_share.held_experts(
+        lay["x"], chosen, jnp.full((TN, TOP_K), 0.25, F32), lay["wgu"], lay["wd"],
+        TFIRST, TE, F32)
+    assert int(counters["touched_experts"]) == 1
+    assert int(counters["held_pairs"]) == TN * TOP_K == int(counters["expert_pairs"][2])
+
+
+def test_in_the_touched_form_an_expert_no_row_chose_is_never_read(touched_form):
+    """Its weights may hold anything, infinite and NaN here: the value and
+    every gradient stay finite, its own gradients are exactly 0, and a row
+    none of whose choices is held gets exactly zero."""
+    lay, _ = touched_case(44)
+    chosen = choices(12, n=TN, experts=TE, held=THELD, first=TFIRST, seed=44)
+    chosen = jnp.where((chosen == TFIRST) | (chosen == TFIRST + 5), 0, chosen)
+    graded, program, equation = touched_graded(lay, chosen, F32, "silu")
+    with jax.default_matmul_precision("highest"):
+        want = graded(equation)
+        lay["wgu"] = lay["wgu"].at[0].set(jnp.inf).at[5].set(jnp.nan)
+        lay["wd"] = lay["wd"].at[0].set(jnp.nan).at[5].set(-jnp.inf)
+        got = touched_graded(lay, chosen, F32, "silu")[0](program)
+        out, counters = expert_share.held_experts(
+            lay["x"], chosen, weights(lay, chosen)(lay["router"]), lay["wgu"],
+            lay["wd"], TFIRST, TE, F32)
+    assert abs(float(got[0]) - float(want[0])) <= 1e-5 * max(1.0, abs(float(want[0])))
+    for name, a, b in zip(("x", "router", "wgu", "wd"), got[1], want[1]):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert rel(a, b) < 1e-5, (name, rel(a, b))
+    for g in got[1][2:]:
+        assert not np.any(np.asarray(g)[[0, 5]])
+    out, chosen = np.asarray(out), np.asarray(chosen)
+    nobody_here = ~np.any((chosen >= TFIRST) & (chosen < TFIRST + THELD), axis=-1)
+    assert nobody_here.any() and not np.any(out[nobody_here])
+    assert np.all(np.isfinite(out)) and np.all(np.any(out[~nobody_here] != 0, axis=-1))
+    assert int(counters["expert_pairs"][0]) == 0 == int(counters["expert_pairs"][5])
+
+
+@pytest.mark.parametrize("count", [None, 0, 1, 9, TN * TOP_K])
+def test_touched_experts_is_the_count_by_hand_in_every_form(monkeypatch, count, touched_form):
+    """The held experts some row chose: the touched form's trips, and the
+    same number from the two other forms; `dense_rows` reads 0 in it."""
+    lay, chosen = touched_case(45, count=count)
+    weight = weights(lay, chosen)(lay["router"])
+    call = lambda: expert_share.held_experts(
+        lay["x"], chosen, weight, lay["wgu"], lay["wd"], TFIRST, TE, F32)[1]
+    local = np.asarray(chosen) - TFIRST
+    by_hand = len(np.unique(local[(local >= 0) & (local < THELD)]))
+    touched = call()
+    assert int(touched["touched_experts"]) == by_hand
+    assert int(touched["touched_experts"]) == int(np.sum(np.asarray(touched["expert_pairs"]) > 0))
+    assert int(touched["dense_rows"]) == 0 and int(touched["dropped_pairs"]) == 0
+    if count is None:
+        assert 0 < by_hand < THELD  # the router's sets leave some held expert alone
+    else:
+        assert int(touched["held_pairs"]) == count
+    for form in ("sorted", "dense"):
+        other = forced(monkeypatch, form, call)
+        for key in ("held_pairs", "expert_pairs", "dropped_pairs", "pair_slabs",
+                    "touched_experts"):
+            assert np.array_equal(np.asarray(touched[key]), np.asarray(other[key])), key
+        assert int(other["dense_rows"]) == (TN * THELD if form == "dense" else 0)
+
+
+@pytest.mark.parametrize("activation", ["silu", "relu", "relu2"])
+def test_a_touched_calls_lowered_text_holds_no_grouped_product_and_no_sort(activation, touched_form):
+    """A decode step's call as the models make it (bfloat16, forward): a
+    `while` whose body holds the two plain products, and neither
+    `ragged_dot`, `sort`, `gather` nor `scatter`; every count is one
+    compilation."""
+    lay, chosen = touched_case(46, activation)
+    weight = weights(lay, chosen)(lay["router"])
+
+    @jax.jit
+    def call(x, chosen, weight, wgu, wd):
+        return expert_share.held_experts(x, chosen, weight, wgu, wd, TFIRST, TE,
+                                         jnp.bfloat16, activation)
+
+    text = call.lower(lay["x"], chosen, weight, lay["wgu"], lay["wd"]).as_text()
+    assert "stablehlo.while" in text and text.count("stablehlo.dot_general") == 2
+    for absent in ("ragged_dot", "stablehlo.sort", "stablehlo.gather", "stablehlo.scatter",
+                   "chlo.top_k"):
+        assert absent not in text, absent
+    for count in (0, 1, 5, TN * TOP_K):
+        call(lay["x"], choices(count, n=TN, experts=TE, held=THELD, first=TFIRST),
+             weight, lay["wgu"], lay["wd"])
+    assert call._cache_size() == 1

@@ -384,22 +384,28 @@ def test_a_wrong_decode_step_is_seen(agent, params, whole_episode, fault, monkey
     assert float(jnp.abs(got - logits).max()) > 1e-2
 
 
-def test_at_the_cells_sizes_acting_is_dense_and_learning_in_slabs():
+def test_at_the_cells_sizes_acting_is_touched_and_learning_in_slabs():
     """16 rows x 6 of 128 at a decode step: 96 pairs for 128 experts, under
-    one pair an expert, which alone would say sorted; but D 2,688 and F
-    1,856 are 10.5 and 7.25 of the grouped product's tiles, where it runs
-    at a quarter of the batched product's rate and its time follows the
-    routing (`one_slab_form`'s table, PR 53): the dense form. The learner's
-    row block of 4 x 2,048 tokens works its 49,152 pairs in slabs of 4,096."""
+    one pair an expert, and experts of D 2,688 x F 1,856 (4.99 M weights a
+    block): the TOUCHED form (ISSUE 54). Those widths are 10.5 and 7.25 of
+    the grouped product's tiles, which sent this call to the dense form in
+    PR 53 (`one_slab_form`'s table); plain products do not care, that
+    clause is gone, and the call reads the 3-4 of 8 held experts some row
+    chose. What the widths still decide is whether an expert is large
+    enough for a trip. The learner's row block of 4 x 2,048 tokens works
+    its 49,152 pairs in slabs of 4,096."""
     widths = (2688, 1856)
-    assert expert_share.call_form(16, 6, 8, 128) == "sorted, one slab of 96 pairs"
-    assert expert_share.call_form(16, 6, 8, 128, widths) == "dense, 16 rows x 8 held"
+    assert expert_share.one_slab_form(16, 6, 128, widths) == "touched"
+    assert expert_share.call_form(16, 6, 8, 128, widths) == "touched, 16 rows x up to 8 held"
     assert expert_share.call_form(4 * 2048, 6, 8, 128, widths) == \
         "sorted, 49152 pairs in slabs of 4096"
-    # whole tiles, and widths under one tile (every test's), change nothing
-    for aligned in ((2048, 1536, 768), (2560, 1536, 768), (2048, 1024, 512), (32, 16)):
-        assert expert_share.one_slab_form(16, 6, 128, aligned) == "sorted"
-    assert expert_share.one_slab_form(16, 6, 128, (2560, 1856)) == "dense"
+    # whole tiles or not, above `TRIP_WEIGHTS`: touched; under it: sorted
+    for large in ((2048, 1536, 768), (2560, 1536, 768), (2560, 1856)):
+        assert expert_share.one_slab_form(16, 6, 128, large) == "touched"
+    for small in ((2048, 1024, 512), (32, 16)):
+        assert expert_share.one_slab_form(16, 6, 128, small) == "sorted"
+    assert expert_share.one_slab_form(21, 6, 128, widths) == "touched"  # 126 pairs of 128
+    assert expert_share.one_slab_form(22, 6, 128, widths) == "dense"  # over one pair an expert
     assert expert_share.one_slab_form(257, 6, 2048, widths) == "sorted"  # the row bound
 
 

@@ -421,14 +421,18 @@ def test_a_span_past_the_cache_is_refused(agent, params):
                     method=model.decode)
 
 
-def test_at_the_cells_sizes_acting_is_sorted_and_learning_in_slabs():
+def test_at_the_cells_sizes_acting_is_touched_and_learning_in_slabs():
     """8 rows x 6 of 64: 48 pairs for 64 experts, under one pair a held
-    expert a call, so the decode step takes the SORTED one-slab form and
-    reads the touched experts alone; the learner's 8,192-token row block
+    expert a call, and experts of D 2,560 x F 768 (1.97 M weights a block,
+    11.8 MB an expert: large enough for a trip), so the decode step takes
+    the TOUCHED one-slab form (ISSUE 54; the sorted one until then): the
+    held experts some row chose, each a plain product over the 8 rows, and
+    no other expert's weights read; the learner's 8,192-token row block
     works its 49,152 pairs in slabs of 15,360."""
-    assert expert_share.one_slab_form(8, 6, 64) == "sorted"
-    assert expert_share.call_form(8, 6, 16, 64) == "sorted, one slab of 48 pairs"
-    assert expert_share.call_form(8192, 6, 16, 64) \
+    widths = (2560, 768)
+    assert expert_share.one_slab_form(8, 6, 64, widths) == "touched"
+    assert expert_share.call_form(8, 6, 16, 64, widths) == "touched, 8 rows x up to 16 held"
+    assert expert_share.call_form(8192, 6, 16, 64, widths) \
         == "sorted, 49152 pairs in slabs of 15360"
     full = SwaLMAgent(load_config("config.json", "smallthinker_moe")[0])
     assert full.model.pair_slab_rows(8, 8192) == 15360

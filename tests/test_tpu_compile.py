@@ -682,6 +682,9 @@ def test_smallthinker_moe_chunk_fits_and_holds_its_eight_kernels(chip, kernels_a
     assert not re.findall(r"bf16\[8,\d{4},28,128\]", text)  # no cache of the query heads
     # no array with the router's width AND a capacity beside the tokens
     assert not re.findall(r"\[8192,64,\d+\]|\[65536,64,\d+\]", text)
+    # the decode step's touched form (PR 54) reads an expert's two matrices
+    # inside its products' fusions: no copy of one
+    assert not re.findall(r"= bf16\[(?:1,)?(?:2560,1536|768,2560)\]\S* copy\(", text)
 
 
 @pytest.mark.slow  # minutes, in the file that ends tier-1's run: before a chip call
@@ -740,6 +743,9 @@ def test_nemotron_h_moe_chunk_fits_and_holds_its_kernels(chip, kernels_as_on_chi
     assert not re.findall(r"bf16\[16,\d{4},32,128\]", text)  # none of the query heads
     # no array with the router's width AND a capacity beside the tokens
     assert not re.findall(r"\[8192,128,\d+\]|\[32768,128,\d+\]", text)
+    # the decode step's touched form (PR 54) reads an expert's two matrices
+    # inside its products' fusions: no copy of one
+    assert not re.findall(r"= bf16\[(?:1,)?(?:2688,1856|1856,2688)\]\S* copy\(", text)
 
 
 def _breakout_step_text(chip, n: int) -> str:
